@@ -195,7 +195,8 @@ def run_higgs(args) -> dict:
 
     set_verbosity(0)
     backend = jax.default_backend()
-    dev = str(jax.devices()[0])
+    dev0 = jax.devices()[0]
+    dev = str(dev0)
 
     t0 = time.perf_counter()
     if args.host_data:
@@ -322,6 +323,9 @@ def run_higgs(args) -> dict:
         "grad_quant_bits": args.quant_bits,
         "backend": backend,
         "device": dev,
+        "platform": dev0.platform,
+        "device_kind": dev0.device_kind,
+        "device_count": len(jax.devices()),
         "phases_s": phases,
         "profile_sync": args.profile,
         "gen_s": round(t_gen, 2),
@@ -1144,11 +1148,13 @@ def run_shard(args) -> dict:
     docs/Sharding.md contract, also gated in CI by check_shard.py).
 
     With fewer than 2 visible devices on a CPU backend the suite
-    re-execs itself once under a forced 4-device host mesh, so the one
-    command works on the container AND the TPU driver.  Non-TPU legs
-    carry ``host_mesh=true`` — forced host-mesh "devices" share the
-    machine's cores, so the scaling/psum timings there validate the
-    plumbing, not the chip (same honesty contract as ``chip_pending``).
+    re-execs itself once under a forced 4-device host mesh.  On an
+    accelerator backend fewer than 2 devices is an ERROR: a forced host
+    mesh there would report CPU seconds under ``ms_per_tree`` from a
+    machine that has a chip.  Non-TPU legs carry ``host_mesh=true`` —
+    forced host-mesh "devices" share the machine's cores, so the
+    scaling/psum timings there validate the plumbing, not the chip
+    (same honesty contract as ``chip_pending``).
 
     ``--hosts N`` switches to the multi-process pod-slice legs
     (:func:`_run_shard_multihost`)."""
@@ -1162,6 +1168,12 @@ def run_shard(args) -> dict:
 
     want_d = int(getattr(args, "shard_devices", 0) or 0)
     if len(jax.devices()) < 2:
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"--suite shard needs >= 2 devices, found "
+                f"{len(jax.devices())} on platform "
+                f"{jax.default_backend()!r}; run it on a multi-chip "
+                f"host (a forced host mesh would time the CPU)")
         if os.environ.get("BENCH_SHARD_REEXEC"):
             raise RuntimeError(
                 "--suite shard needs >= 2 devices and the forced host "
@@ -1338,12 +1350,19 @@ def _coldstart_child(cmd, env, tag, expect_json=True):
 def run_coldstart(args) -> dict:
     """Cold-start suite: how much of a fresh process's
     ``warmup_compile_s`` the persistent compile cache removes
-    (docs/ColdStart.md).  Three fresh subprocesses against temp cache
-    dirs: (1) cold — empty cache; (2) warm — same dir, so every
-    executable loads from disk; (3) aot — a dir pre-filled by the
-    ``lightgbm-tpu warmup`` CLI alone, the deployment-init story.
-    Gates ``pass_5x``: warm cold-start >= 5x faster than cold."""
-    import tempfile
+    (docs/ColdStart.md).  Three fresh subprocesses against two FIXED
+    subdirectories of the resolved cache dir, emptied at the start (a
+    directory that moves never hits): (1) cold — empty cache; (2) warm
+    — same dir, so every executable loads from disk; (3) aot — a dir
+    pre-filled by the ``lightgbm-tpu warmup`` CLI alone, the
+    deployment-init story.  Gates ``pass_5x``: warm cold-start >= 5x
+    faster than cold.
+
+    This parent never initialises a JAX backend: a chip belongs to one
+    process, and the children need it."""
+    import shutil
+
+    from lightgbm_tpu import compile_cache
 
     here = os.path.dirname(os.path.abspath(__file__))
     bench_cmd = [
@@ -1353,8 +1372,8 @@ def run_coldstart(args) -> dict:
         "--num-leaves", str(args.num_leaves),
         "--max-bin", str(args.max_bin), "--eval-rows", "0",
         "--no-stage-profile", "--engine", args.engine,
-        # no --compile-cache-dir: the child's default reads the
-        # LGBM_TPU_COMPILE_CACHE env var set per leg below
+        # the child's cache is placed by the
+        # JAX_COMPILATION_CACHE_DIR env var set per leg below
     ]
     warm_cmd = [
         sys.executable, "-m", "lightgbm_tpu", "warmup",
@@ -1368,16 +1387,15 @@ def run_coldstart(args) -> dict:
     ]
     out = {"metric": "coldstart_warm_speedup", "unit": "x",
            "rows": args.rows, "iters": args.iters, "chunk": args.chunk}
-    with tempfile.TemporaryDirectory(prefix="lgbm_coldstart_") as tmp:
-        dir_a = os.path.join(tmp, "a")
-        dir_b = os.path.join(tmp, "b")
-        env = dict(os.environ)
-        env["LGBM_TPU_COMPILE_CACHE"] = dir_a
-        cold = _coldstart_child(bench_cmd, env, "cold")
-        warm = _coldstart_child(bench_cmd, env, "warm")
-        env["LGBM_TPU_COMPILE_CACHE"] = dir_b
-        _coldstart_child(warm_cmd, env, "aot-warmup", expect_json=False)
-        aot = _coldstart_child(bench_cmd, env, "aot")
+    root = os.path.join(compile_cache.resolve_dir(), "coldstart")
+    shutil.rmtree(root, ignore_errors=True)
+    env = dict(os.environ)
+    env[compile_cache.ENV_VAR] = os.path.join(root, "a")
+    cold = _coldstart_child(bench_cmd, env, "cold")
+    warm = _coldstart_child(bench_cmd, env, "warm")
+    env[compile_cache.ENV_VAR] = os.path.join(root, "b")
+    _coldstart_child(warm_cmd, env, "aot-warmup", expect_json=False)
+    aot = _coldstart_child(bench_cmd, env, "aot")
     cold_s = float(cold["warmup_compile_s"])
     warm_s = float(warm["warmup_compile_s"])
     aot_s = float(aot["warmup_compile_s"])
@@ -1608,14 +1626,6 @@ def main() -> int:
                     help="--suite soak: JSON SoakScenario file "
                          "(docs/Soak.md); empty uses the CI smoke "
                          "shape, LGBM_TPU_SOAK overrides")
-    ap.add_argument("--compile-cache-dir",
-                    default=os.environ.get(
-                        "LGBM_TPU_COMPILE_CACHE",
-                        os.path.expanduser("~/.cache/lgbm_tpu_xla")),
-                    help="persistent XLA compile cache directory "
-                         "(lightgbm_tpu.compile_cache); '0' disables. "
-                         "Default: LGBM_TPU_COMPILE_CACHE or "
-                         "~/.cache/lgbm_tpu_xla")
     ap.add_argument("--cache-admission", action="store_true",
                     help="alias for --suite cache")
     ap.add_argument("--models", type=int,
@@ -1677,16 +1687,18 @@ def main() -> int:
         obs.configure(enabled=False)
 
     # persistent compile cache: the padded-bucket programs recur across
-    # runs (and the coldstart suite measures exactly this effect in
-    # fresh child processes, via their LGBM_TPU_COMPILE_CACHE env)
+    # runs (the coldstart suite measures exactly this effect in fresh
+    # child processes, placing each leg's cache through their
+    # JAX_COMPILATION_CACHE_DIR env)
     from lightgbm_tpu import compile_cache
     if args.suite != "coldstart":
-        compile_cache.configure(args.compile_cache_dir)
+        compile_cache.configure()
 
     if args.cache_admission:
         args.suite = "cache"
     if args.explain:
         args.suite = "explain"
+    rc = 0
     if args.suite == "soak":
         result = run_soak(args)
     elif args.suite == "explain":
@@ -1709,7 +1721,10 @@ def main() -> int:
             try:
                 result["mslr"] = run_mslr(args)
             except Exception as e:   # noqa: BLE001 — keep the headline
-                result["mslr"] = {"error": str(e)}
+                # the higgs line still prints, but a failed cell fails
+                # the command
+                result["mslr"] = {"error": f"{type(e).__name__}: {e}"}
+                rc = 1
 
     if obs.enabled():
         result["obs"] = obs.summary()
@@ -1718,7 +1733,7 @@ def main() -> int:
         if args.trace:
             obs.dump_trace(args.trace)
     print(json.dumps(result))
-    return 0
+    return rc
 
 
 if __name__ == "__main__":

@@ -291,14 +291,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     help="write a Chrome-trace/Perfetto timeline of the "
                          "whole windowed session (--trace is taken by "
                          "the input trace file)")
-    ap.add_argument("--compile-cache",
-                    default=os.environ.get("LGBM_TPU_COMPILE_CACHE", ""),
-                    help="persistent XLA compile cache dir "
-                         "(lightgbm_tpu.compile_cache): a restarted "
-                         "harness process re-loads every window's "
-                         "compiled programs from disk instead of "
-                         "recompiling (docs/ColdStart.md); '' disables "
-                         "unless LGBM_TPU_COMPILE_CACHE is set")
     ap.add_argument("--pipeline", action="store_true",
                     help="run the windowed loop through the async "
                          "retrain pipeline (lightgbm_tpu.pipeline, "
@@ -471,7 +463,10 @@ def run(args) -> dict:
     if args.metrics or args.obs_trace:
         obs.configure(enabled=True, metrics_path=args.metrics or None,
                       trace_path=args.obs_trace or None)
-    compile_cache.configure(getattr(args, "compile_cache", ""))
+    # a restarted harness process re-loads every window's compiled
+    # programs from disk instead of recompiling (docs/ColdStart.md);
+    # JAX_COMPILATION_CACHE_DIR places the cache
+    compile_cache.configure()
 
     if args.trace == "synth":
         ids, sizes, costs = synth_trace(args.requests, args.objects)

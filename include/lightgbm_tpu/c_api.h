@@ -173,7 +173,8 @@ LIGHTGBM_C_EXPORT int LGBM_FleetFree(FleetHandle handle);
  * AOT compile warmup (lightgbm_tpu extension, not in the fork's ABI):
  * precompile the declared (rows, features, parameters) training /
  * serving program families into the persistent XLA compile cache
- * (parameters key compile_cache_dir, or env LGBM_TPU_COMPILE_CACHE),
+ * (env JAX_COMPILATION_CACHE_DIR, else parameters key
+ * compile_cache_dir, else <checkout>/.jax_cache),
  * so a deployment's FIRST real retrain window / first large predict
  * batch runs warm.  Call once at container start, before the request
  * loop; *out_num_compiled returns the number of fresh cache entries

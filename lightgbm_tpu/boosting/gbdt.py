@@ -36,8 +36,7 @@ from ..parallel import create_tree_learner
 K_EPSILON = 1e-15
 MODEL_VERSION = "v2"
 
-#: dispatch errors worth a bounded retry (resolved once: the JAX
-#: runtime error type moved across versions)
+#: dispatch errors worth a bounded retry
 _TRANSIENT_DISPATCH = transient_dispatch_errors()
 
 
@@ -117,10 +116,7 @@ class _PendingTree(_Pending):
         self.shrinkage = shrinkage
         self.bias = bias
         for arr in (rec_i, rec_f, rec_c, nl, root_value):
-            try:
-                arr.copy_to_host_async()
-            except AttributeError:
-                pass
+            arr.copy_to_host_async()
 
     def materialize(self, dataset, config) -> Tree:
         return _replay_records(np.asarray(self.rec_i),
@@ -145,10 +141,7 @@ class _RecStack:
         # never blocks the dispatch pipeline
         self.qscales = qscales
         for a in self.arrs + ((qscales,) if qscales is not None else ()):
-            try:
-                a.copy_to_host_async()
-            except AttributeError:
-                pass
+            a.copy_to_host_async()
 
     def host(self):
         if self._host is None:

@@ -773,8 +773,8 @@ def LGBM_WarmupTrain(parameters, num_row, num_feature,
     num_feature) dataset long enough to compile every program a
     production run with ``parameters`` dispatches (one fused chunk +
     any per-iteration remainder).  ``parameters`` should include
-    ``compile_cache_dir`` (or export LGBM_TPU_COMPILE_CACHE) plus the
-    production training params.  Returns the number of fresh
+    ``compile_cache_dir`` (or export JAX_COMPILATION_CACHE_DIR, which
+    wins) plus the production training params.  Returns the number of fresh
     persistent-cache entries written (0 = already warm)."""
     from .warmup import warmup_train
     cfg = _parse_params(parameters)

@@ -33,11 +33,12 @@ from . import c_api as C
 from . import compile_cache
 from . import obs
 
-# persistent XLA compile cache: the native harness exports
-# LGBM_TPU_COMPILE_CACHE=<dir> and every window's programs load from /
-# persist to disk — a restarted harness process starts warm (the
-# LGBM_WarmupTrain/LGBM_WarmupServe ABI calls pre-fill the same dir)
-compile_cache.configure_from_env()
+# persistent XLA compile cache: every window's programs load from /
+# persist to the directory compile_cache.resolve_dir() places
+# (JAX_COMPILATION_CACHE_DIR when the harness exports it) — a restarted
+# harness process starts warm (the LGBM_WarmupTrain/LGBM_WarmupServe ABI
+# calls pre-fill the same dir)
+compile_cache.configure()
 
 
 def _arr(mv, dtype_const):

@@ -1,22 +1,25 @@
 """Persistent XLA compile cache: zero *recompiles* across processes.
 
-BENCH_r05 measured ``warmup_compile_s`` = 239.4 s against 225.5 s of
-timed training — every fresh process pays a full training-run's worth of
-XLA compilation, which is disqualifying for the fork's
-retrain-every-window production story (the harness retrains through the
-C API every window, and deployments restart).  PR 4's ``GrowerPrograms``
-cache already gives zero *retraces* within a process; this module closes
-the cross-process half by activating JAX's persistent compilation cache
-(``jax_compilation_cache_dir``) as a first-class, library-level
-subsystem instead of a bench.py-only env default:
+Every fresh process otherwise pays a full XLA compilation of the fused
+growth program before its first tree, which is disqualifying for the
+fork's retrain-every-window production story (the harness retrains
+through the C API every window, and deployments restart).  The
+``GrowerPrograms`` cache already gives zero *retraces* within a process;
+this module closes the cross-process half by activating JAX's persistent
+compilation cache as a library-level subsystem:
 
-* ``configure(cache_dir)`` — point JAX at an on-disk LRU cache of
-  compiled executables.  Every entry point calls
-  :func:`configure_from_config` / :func:`configure_from_env`
-  (``GBDT.init_train``, the CLI, ``capi_embed`` import,
-  ``PredictionServer``, ``bench.py``, ``examples/cache_admission.py``),
-  so exporting ``LGBM_TPU_COMPILE_CACHE=/path`` warms ANY driver with no
-  code change;
+* ``configure()`` — point JAX at an on-disk LRU cache of compiled
+  executables.  Every entry point calls it (or
+  :func:`configure_from_config`): ``GBDT.init_train``, the CLI,
+  ``capi_embed`` import, ``PredictionServer``, ``bench.py``,
+  ``chip_smoke.py``, ``examples/cache_admission.py``.  ONE resolution
+  rule (:func:`resolve_dir`) places the directory for all of them:
+  JAX's own ``JAX_COMPILATION_CACHE_DIR`` when it is set — nothing in
+  code names another directory then, so whoever launches the process
+  places the cache — else an explicit ``compile_cache_dir``, else the
+  fixed ``<checkout>/.jax_cache``.  Never ``~/.cache`` and never a
+  tempfile/pid/time name: a directory that moves between processes
+  never hits;
 * the min-compile-time floor is forced to 0 while active: the whole
   point is a warm cold start, and JAX's default 1 s floor would leave
   the eager glue ops (score scatter, boost-from-average add, ...) cold —
@@ -44,7 +47,9 @@ traced arguments).  docs/ColdStart.md lists which parameters shape
 traces.
 
 Everything imports ``jax`` lazily: importing this module costs nothing
-and is safe before backend selection.
+and never initialises a backend (``bench.py --suite coldstart``'s
+parent configures nothing here and must leave the chip to its
+children).
 """
 
 from __future__ import annotations
@@ -55,8 +60,13 @@ from typing import Optional
 
 from . import obs
 
-ENV_VAR = "LGBM_TPU_COMPILE_CACHE"
-_FALSY = ("", "0", "false", "no", "off")
+#: JAX's own variable; the ONLY environment name that places the cache
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: where the cache lives when nobody placed it: fixed and inside the
+#: checkout (gitignored), derived from this file
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 # guarded module state (configure may race between a PredictionServer
 # thread and the training driver)
@@ -139,22 +149,50 @@ def artifact_dir(name: str) -> Optional[str]:
     return os.path.join(d, name)
 
 
-def configure(cache_dir: Optional[str], *,
-              min_entry_bytes: Optional[int] = None,
-              strict_keys: Optional[bool] = None,
-              _pin: bool = True) -> Optional[str]:
-    """Activate the persistent compilation cache at ``cache_dir``.
+def resolve_dir(requested: Optional[str] = None) -> str:
+    """THE resolution rule, used by every entry point (pure: touches
+    neither jax nor the filesystem).
 
-    Returns the expanded directory (created if missing), or None when
-    ``cache_dir`` is falsy ("", "0", "false", "off" all mean "leave the
-    cache alone" — an env var that disabled it stays disabled).  The
-    compile-seconds/hit/miss listeners install either way, so
-    :func:`counters` works even without a cache dir.
+    1. ``JAX_COMPILATION_CACHE_DIR`` set: that directory, always.  A
+       different ``requested`` dir (the ``compile_cache_dir`` param) is
+       ignored with one log line — the launcher placed the cache.
+    2. else ``requested`` when given;
+    3. else the directory already active in this process (so a
+       PredictionServer created mid-training never flips the cache away
+       from the dir a param activated);
+    4. else :data:`DEFAULT_DIR`, the fixed in-checkout path.
+    """
+    requested = str(requested).strip() if requested else ""
+    norm = lambda d: os.path.abspath(os.path.expanduser(d))
+    env = os.environ.get(ENV_VAR, "").strip()
+    if env:
+        if requested and norm(requested) != norm(env):
+            with _LOCK:
+                first = not _STATE.get("warned_ignored")
+                _STATE["warned_ignored"] = True
+            if first:
+                from .utils.log import log_info
+                log_info(f"compile_cache_dir={requested} ignored: "
+                         f"{ENV_VAR}={env} places the compile cache")
+        return norm(env)
+    return norm(requested or _STATE["dir"] or DEFAULT_DIR)
+
+
+def configure(cache_dir: Optional[str] = None, *,
+              min_entry_bytes: Optional[int] = None,
+              strict_keys: Optional[bool] = None) -> Optional[str]:
+    """Activate the persistent compilation cache at
+    ``resolve_dir(cache_dir)``; returns that directory (created if
+    missing).  A directory that cannot be created (read-only checkout)
+    must not take down training/serving over a cache: it logs a warning
+    and returns None — no persistent cache, plans stay process-local.
+    The compile-seconds/hit/miss listeners install either way, so
+    :func:`counters` always works.
 
     ``min_entry_bytes`` / ``strict_keys`` are STICKY: ``None`` keeps
     whatever an earlier configure set (first activation applies the
     schema defaults 0 / False) — a knob explicitly set through params
-    must survive the env-only reconfigures every entry point performs
+    must survive the bare reconfigures every entry point performs
     (``PredictionServer``, the ``capi_embed`` import, later windows).
 
     Re-configuring with the SAME directory is a cheap no-op; switching
@@ -163,20 +201,19 @@ def configure(cache_dir: Optional[str], *,
     at first compile).
     """
     install_listeners()
-    if cache_dir is None or str(cache_dir).strip().lower() in _FALSY:
-        return None
-    path = os.path.abspath(os.path.expanduser(str(cache_dir)))
+    path = resolve_dir(cache_dir)
     import jax
 
-    os.makedirs(path, exist_ok=True)   # before any state change: may raise
+    try:
+        os.makedirs(path, exist_ok=True)   # before any state change
+    except OSError as e:
+        from .utils.log import log_warning
+        log_warning(f"cannot activate the persistent compile cache at "
+                    f"{path}: {e}; continuing without it")
+        return None
     with _LOCK:
         changed = _STATE["dir"] != path
         _STATE["dir"] = path
-        if _pin:
-            # every EXPLICIT activation (param, library call, CLI flag)
-            # pins the dir against later env-only reconfigures; only
-            # the env path itself activates unpinned
-            _STATE["pinned"] = True
         if min_entry_bytes is not None:
             _STATE["min_entry_bytes"] = int(min_entry_bytes)
         if strict_keys is not None:
@@ -194,74 +231,27 @@ def configure(cache_dir: Optional[str], *,
     jax.config.update("jax_compilation_cache_include_metadata_in_key",
                       strict)
     if changed:
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:   # pragma: no cover — private API moved
-            pass
+        from jax._src import compilation_cache as _cc
+        _cc.reset_cache()
     return path
 
 
-def configure_from_env() -> Optional[str]:
-    """Activate from ``LGBM_TPU_COMPILE_CACHE`` (no-op when unset or
-    falsy) — how the native ``liblgbm_tpu`` harness and the
-    ``PredictionServer`` pick the cache up without a config object.
-
-    A dir explicitly configured (param, library call, CLI flag) wins:
-    once any pinned :func:`configure` activated a directory, this call
-    leaves it alone (otherwise creating a PredictionServer mid-training
-    would flip the process-wide cache back to the env dir and abandon
-    the warm entries).  Never raises: a bad env path (read-only FS,
-    permission) must not take down training/serving over a cache — it
-    logs a warning and degrades to no persistent cache."""
-    with _LOCK:
-        current = _STATE["dir"] if (_STATE["dir"]
-                                    and _STATE.get("pinned")) else None
-    if current:
-        install_listeners()
-        return current
-    try:
-        return configure(os.environ.get(ENV_VAR, ""), _pin=False)
-    except OSError as e:
-        from .utils.log import log_warning
-        log_warning(f"cannot activate the persistent compile cache from "
-                    f"{ENV_VAR}: {e}; continuing without it")
-        return None
-
-
 def configure_from_config(cfg) -> Optional[str]:
-    """Activate from a :class:`~lightgbm_tpu.config.Config`.
-
-    ``compile_cache_dir`` wins when set; otherwise the env var decides
-    the DIR while the config's knobs still apply (sticky — see
-    :func:`configure`).  Called on every ``GBDT.init_train`` — once per
-    retrain window — so it must stay cheap (same-dir reconfigure is a
-    string compare).
+    """Activate from a :class:`~lightgbm_tpu.config.Config`: its
+    ``compile_cache_dir`` is the requested dir of :func:`resolve_dir`
+    and its knobs apply (sticky — see :func:`configure`).  Called on
+    every ``GBDT.init_train`` — once per retrain window — so it must
+    stay cheap (same-dir reconfigure is a string compare).
     """
-    path = str(getattr(cfg, "compile_cache_dir", "") or "")
     # schema defaults (0 / False) equal the sticky initial values, so a
     # default-valued config passes None = "keep what's set" — only a
     # non-default knob overrides (and sticks for the process)
     raw_entry = int(getattr(cfg, "compile_cache_min_entry_bytes", 0) or 0)
-    knobs = dict(
+    return configure(
+        str(getattr(cfg, "compile_cache_dir", "") or ""),
         min_entry_bytes=raw_entry if raw_entry else None,
         strict_keys=True if getattr(cfg, "compile_cache_strict_keys",
                                     False) else None)
-    if not path:
-        path = os.environ.get(ENV_VAR, "")
-        if not path or str(path).strip().lower() in _FALSY:
-            install_listeners()
-            return None
-        try:
-            # dir came from the env: activate unpinned, so a later
-            # explicit dir can still take over
-            return configure(path, _pin=False, **knobs)
-        except OSError as e:
-            from .utils.log import log_warning
-            log_warning(f"cannot activate the persistent compile cache "
-                        f"from {ENV_VAR}: {e}; continuing without it")
-            return None
-    return configure(path, **knobs)
 
 
 def counters() -> dict:

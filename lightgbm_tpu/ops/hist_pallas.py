@@ -26,7 +26,8 @@ Two stat-column representations share the kernel body:
   int8->int32 path with int32 accumulators.  Integer accumulation is
   associative, so the kernel is BYTE-identical to the int8 einsum
   formulation — gated on CPU via interpret mode (tests/test_quant.py,
-  scripts/check_quant.py).
+  scripts/check_quant.py) and on the chip, compiled, by
+  ``chip_smoke.py``'s pallas leg.
 
 Layout: B columns are K-major (column k*W + w holds stat k of wave slot
 w), so no 3D intermediates touch the minor-most dimension.
@@ -77,17 +78,26 @@ def _operand_dtypes(ghk_dtype):
 def _build_bmat(leaf_ref, pend_ref, gh_ref, ch, k, w, b, mdtype):
     """K-major (CH, B) stat matrix (column kk*W + slot holds stat kk of
     wave slot), zero-padded to ``b`` lanes.  ``mdtype`` is the operand
-    dtype (bf16 or int8; mask x int8 products stay within int8: the
-    mask is 0/1 and |q| <= 127).  Shared by both kernels."""
+    dtype (bf16 or int8).  Shared by both kernels.
+
+    The leaf-mask x stat product is a SELECT in a 32-bit type, narrowed
+    to ``mdtype`` once at the end: the v5e VPU has no int8 multiply
+    (Mosaic: "failed to legalize operation 'arith.muli'" on
+    ``vector<8x128x4xi8>``), 0/1 x v == where(mask, v, 0) exactly, and
+    the narrowing is exact (|q| <= 127; bf16 round-trips through f32).
+    Every other int8 op of the kernel (i1->i8 one-hot cast, the
+    dim-0-contracting int8 dot, the narrow-minor blocks) lowers as
+    written."""
+    wide = jnp.int32 if mdtype == jnp.int8 else jnp.float32
     leaf = leaf_ref[:]                                  # (CH, 1) i32
     pend = pend_ref[0:1, :w]                            # (1, W) i32
-    lm = (leaf == pend).astype(mdtype)                  # (CH, W)
-    gh = gh_ref[:]                                      # (CH, K)
-    cols = [lm * gh[:, kk:kk + 1] for kk in range(k)]
+    lm = leaf == pend                                   # (CH, W) bool
+    gh = gh_ref[:].astype(wide)                         # (CH, K)
+    cols = [jnp.where(lm, gh[:, kk:kk + 1], 0) for kk in range(k)]
     pad = b - k * w
     if pad:
-        cols.append(jnp.zeros((ch, pad), mdtype))
-    return jnp.concatenate(cols, axis=1)                # (CH, B)
+        cols.append(jnp.zeros((ch, pad), wide))
+    return jnp.concatenate(cols, axis=1).astype(mdtype)  # (CH, B)
 
 
 def _pair_one_hot(bins, iota, g0, g, mdtype):

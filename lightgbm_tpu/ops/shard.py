@@ -80,29 +80,13 @@ ENV_NUM_HOSTS = "LGBM_TPU_NUM_HOSTS"
 ENV_HOST_RANK = "LGBM_TPU_HOST_RANK"
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` with ``check_vma``; 0.4.x keeps
-    it under ``jax.experimental.shard_map`` with ``check_rep``.  Either
-    way replication checking is off: the grower's growth loop carries a
-    ``lax.while_loop`` whose replication rule old jax cannot derive, and
-    the replicated-output contract is enforced by the byte-identity
-    tests instead (tests/test_shard.py, scripts/check_shard.py).
-    """
-    smap = getattr(jax, "shard_map", None)
-    if smap is not None:
-        try:
-            return smap(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
-        except TypeError:
-            # jax versions where jax.shard_map exists but still takes
-            # check_rep
-            return smap(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as smap_exp
-    return smap_exp(fn, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False)
+def shard_map_nocheck(fn, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication (vma) checking off: the
+    replicated-output contract of the grower's growth loop is enforced
+    by the byte-identity tests instead (tests/test_shard.py,
+    scripts/check_shard.py)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class ShardSpec(NamedTuple):
@@ -257,11 +241,8 @@ def multihost_setup(config=None) -> Tuple[int, int]:
             "LGBM_TPU_COORDINATOR/LGBM_TPU_NUM_HOSTS/"
             "LGBM_TPU_HOST_RANK env vars)")
     coord, hosts, rank = resolved
-    try:
-        # scoped to the CPU backend; a no-op for TPU pods
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:   # noqa: BLE001 — option absent on this jax
-        pass
+    # scoped to the CPU backend; a no-op for TPU pods
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     if rank > 0:
         # fail fast with peer context before the (slow) initialize
         # handshake; the probe retries with the shared backoff policy

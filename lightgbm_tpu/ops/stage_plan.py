@@ -275,40 +275,49 @@ def cache_plan(signature: tuple, plan: Sequence,
 # compile cache (ROADMAP 1c).  A stage plan shapes the traced program,
 # so a cross-process warm start needs BOTH the compiled executables and
 # the plan they were compiled for — co-locating them makes "warm the
-# cache dir" one operation.  Files are keyed on a sha1 of the grower's
-# (shape, config) signature repr (PYTHONHASHSEED-independent — the same
-# property tests pin for programs_signature itself) and verified on
-# load: signature text must match exactly and the stored digest must
-# match the stored plan, so a corrupt or hand-edited file degrades to
-# the legacy plan instead of training with an unvetted stage order.
+# cache dir" one operation.  Files are keyed on a sha1 of the backend
+# (platform + device kind) and the grower's (shape, config) signature
+# repr (PYTHONHASHSEED-independent — the same property tests pin for
+# programs_signature itself) and verified on load: backend and
+# signature text must match exactly and the stored digest must match
+# the stored plan, so a corrupt or hand-edited file degrades to the
+# legacy plan instead of training with an unvetted stage order.  The
+# backend is in the key because plans and fusion verdicts are TIMINGS:
+# a cache dir filled by XLA:CPU test runs travels to the chip with the
+# checkout, and a verdict timed on one backend says nothing on another.
 # ---------------------------------------------------------------------------
 
 def store_dir() -> Optional[str]:
-    """``<compile_cache_dir>/stage_plans``, or None when no persistent
+    """``<compile cache dir>/stage_plans``, or None when no persistent
     compile cache is active (plans then live for the process only)."""
     from .. import compile_cache
     return compile_cache.artifact_dir("stage_plans")
 
 
-def _plan_path(signature: tuple) -> Optional[str]:
+def backend_key() -> str:
+    """``platform:device_kind`` of the device the timings were (or
+    would be) taken on."""
+    import jax
+    dev = jax.devices()[0]
+    return f"{dev.platform}:{dev.device_kind}"
+
+
+def _store_path(kind: str, signature: tuple) -> Optional[str]:
     d = store_dir()
     if d is None:
         return None
-    key = hashlib.sha1(repr(tuple(signature)).encode()).hexdigest()[:20]
-    return os.path.join(d, f"plan_{key}.json")
+    key = hashlib.sha1(repr((backend_key(), tuple(signature))).encode()
+                       ).hexdigest()[:20]
+    return os.path.join(d, f"{kind}_{key}.json")
 
 
-def save_plan(signature: tuple, plan: Sequence) -> Optional[str]:
-    """Atomically persist ``plan``; returns the path, or None when no
-    store is active or the write fails (best-effort — a read-only cache
-    dir must not take down training over a plan)."""
-    path = _plan_path(signature)
-    if path is None:
-        return None
-    canon = [[int(w), None if c is None else int(c)] for w, c in plan]
-    payload = {"signature": repr(tuple(signature)),
-               "plan": canon,
-               "digest": plan_digest(canon)}
+def _plan_path(signature: tuple) -> Optional[str]:
+    return _store_path("plan", signature)
+
+
+def _write_payload(path: str, payload: dict, what: str) -> Optional[str]:
+    """Atomic best-effort write shared by the plan and fusion stores (a
+    read-only cache dir must not take down training over a verdict)."""
     tmp = f"{path}.{os.getpid()}.tmp"
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -317,8 +326,8 @@ def save_plan(signature: tuple, plan: Sequence) -> Optional[str]:
         os.replace(tmp, path)
     except OSError as e:
         from ..utils.log import log_warning
-        log_warning(f"cannot persist the profiled stage plan to "
-                    f"{path}: {e}; the plan stays process-local")
+        log_warning(f"cannot persist the {what} to {path}: {e}; it "
+                    f"stays process-local")
         try:
             os.unlink(tmp)    # don't leave orphaned .tmp files behind
         except OSError:
@@ -327,10 +336,9 @@ def save_plan(signature: tuple, plan: Sequence) -> Optional[str]:
     return path
 
 
-def load_plan(signature: tuple) -> Optional[Plan]:
-    """Load a persisted plan for ``signature``; None (-> legacy plan)
-    when absent, unreadable, signature-mismatched, or digest-corrupt."""
-    path = _plan_path(signature)
+def _read_payload(path: Optional[str], signature: tuple) -> Optional[dict]:
+    """The stored payload, or None when absent, unreadable, or written
+    for another backend or signature."""
     if path is None or not os.path.exists(path):
         return None
     try:
@@ -338,7 +346,33 @@ def load_plan(signature: tuple) -> Optional[Plan]:
             payload = json.load(fh)
     except (OSError, ValueError):
         return None
-    if payload.get("signature") != repr(tuple(signature)):
+    if not isinstance(payload, dict) \
+            or payload.get("backend") != backend_key() \
+            or payload.get("signature") != repr(tuple(signature)):
+        return None
+    return payload
+
+
+def save_plan(signature: tuple, plan: Sequence) -> Optional[str]:
+    """Atomically persist ``plan``; returns the path, or None when no
+    store is active or the write fails."""
+    path = _plan_path(signature)
+    if path is None:
+        return None
+    canon = [[int(w), None if c is None else int(c)] for w, c in plan]
+    return _write_payload(
+        path, {"backend": backend_key(),
+               "signature": repr(tuple(signature)),
+               "plan": canon, "digest": plan_digest(canon)},
+        "profiled stage plan")
+
+
+def load_plan(signature: tuple) -> Optional[Plan]:
+    """Load a persisted plan for ``signature``; None (-> legacy plan)
+    when absent, unreadable, backend- or signature-mismatched, or
+    digest-corrupt."""
+    payload = _read_payload(_plan_path(signature), signature)
+    if payload is None:
         return None
     try:
         plan = [(int(w), None if c is None else int(c))
@@ -367,7 +401,7 @@ def forget_plan(signature: tuple) -> None:
 # fused-vs-two-pass verdicts: wave_plan=profiled times the find-best
 # scan in both wave layouts and the winner is recorded here, keyed and
 # persisted EXACTLY like the stage plan it was measured with (same
-# signature, same store beside the compile cache), so
+# backend + signature key, same store beside the compile cache), so
 # ``find_best_fusion=auto`` resolves to the measured layout in this
 # process and every fresh process after it.  Like the plan, the
 # resolved mode shapes the traced program — ops/grow.py keys the
@@ -401,56 +435,29 @@ def cache_fusion(signature: tuple, mode: str, persist: bool = True,
 
 
 def _fusion_path(signature: tuple) -> Optional[str]:
-    d = store_dir()
-    if d is None:
-        return None
-    key = hashlib.sha1(repr(tuple(signature)).encode()).hexdigest()[:20]
-    return os.path.join(d, f"fusion_{key}.json")
+    return _store_path("fusion", signature)
 
 
 def save_fusion(signature: tuple, mode: str,
                 detail: Optional[dict] = None) -> Optional[str]:
     """Atomically persist the fusion verdict; best-effort like
-    :func:`save_plan` (a read-only cache dir must not take down
-    training over a verdict)."""
+    :func:`save_plan`."""
     path = _fusion_path(signature)
     if path is None:
         return None
-    payload = {"signature": repr(tuple(signature)), "mode": str(mode)}
+    payload = {"backend": backend_key(),
+               "signature": repr(tuple(signature)), "mode": str(mode)}
     if detail:
         payload["detail"] = detail
-    tmp = f"{path}.{os.getpid()}.tmp"
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        with open(tmp, "w") as fh:
-            json.dump(payload, fh)
-        os.replace(tmp, path)
-    except OSError as e:
-        from ..utils.log import log_warning
-        log_warning(f"cannot persist the fused-find verdict to "
-                    f"{path}: {e}; the verdict stays process-local")
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return None
-    return path
+    return _write_payload(path, payload, "fused-find verdict")
 
 
 def load_fusion(signature: tuple) -> Optional[str]:
     """Load a persisted fusion verdict; None (-> default fused) when
-    absent, unreadable, signature-mismatched, or not a known mode."""
-    path = _fusion_path(signature)
-    if path is None or not os.path.exists(path):
-        return None
-    try:
-        with open(path) as fh:
-            payload = json.load(fh)
-    except (OSError, ValueError):
-        return None
-    if payload.get("signature") != repr(tuple(signature)):
-        return None
-    mode = payload.get("mode")
+    absent, unreadable, backend- or signature-mismatched, or not a
+    known mode."""
+    payload = _read_payload(_fusion_path(signature), signature)
+    mode = payload.get("mode") if payload is not None else None
     return mode if mode in _FUSION_MODES else None
 
 

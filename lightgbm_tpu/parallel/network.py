@@ -42,7 +42,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs
-from ..ops.shard import shard_map_compat
+from ..ops.shard import shard_map_nocheck
 from ..robust import faults
 from ..robust.retry import RetryError, RetryPolicy, with_retries
 from ..utils.log import LightGBMError, log_info
@@ -190,11 +190,10 @@ class Network:
 
     # -- generic sharded runner -----------------------------------------
     def run_sharded(self, fn, in_specs, out_specs):
-        """``shard_map`` bound to this mesh/axis (replication checking
-        off: the verb wrappers above make collective use explicit; the
-        compat shim covers jax versions where shard_map still lives
-        under jax.experimental)."""
-        return shard_map_compat(fn, self.mesh, in_specs, out_specs)
+        """``jax.shard_map`` bound to this mesh/axis (replication
+        checking off: the verb wrappers above make collective use
+        explicit)."""
+        return shard_map_nocheck(fn, self.mesh, in_specs, out_specs)
 
 
 # ---------------------------------------------------------------------------

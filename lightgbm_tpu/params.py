@@ -646,7 +646,9 @@ PARAM_SCHEMA: Sequence[Param] = (
             "and an unpersistable plan would let same-config processes "
             "grow different trees) and install the derived plan only "
             "when it beats the byte-stable ladder by the 2% bar. "
-            "Profiled plans persist to <compile_cache_dir>/stage_plans "
+            "Profiled plans persist to <compile cache>/stage_plans, "
+            "keyed by platform and device kind (a verdict timed on one "
+            "backend is never adopted on another), "
             "so retrain windows AND fresh processes measure once "
             "(zero re-profiles; docs/ColdStart.md)",
        section="device"),
@@ -777,8 +779,10 @@ PARAM_SCHEMA: Sequence[Param] = (
             "written to an on-disk LRU store so a FRESH process training "
             "the same (bucketed shape, config) pays zero XLA recompiles "
             "— the cross-process completion of the in-process "
-            "grower_cache. Empty = use the LGBM_TPU_COMPILE_CACHE env "
-            "var if set, else no persistent cache. Precompile a "
+            "grower_cache. The JAX_COMPILATION_CACHE_DIR env var, when "
+            "set, wins over this param (ignored with one log line); "
+            "empty and no env var = the fixed <checkout>/.jax_cache. "
+            "Precompile a "
             "deployment's declared shapes with the warmup entry points "
             "(task=warmup / LGBM_WarmupTrain). See docs/ColdStart.md",
        section="device"),

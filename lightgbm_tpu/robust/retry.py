@@ -106,19 +106,9 @@ def with_retries(fn: Callable, policy: Optional[RetryPolicy] = None,
 
 def transient_dispatch_errors() -> Tuple:
     """Exception types a device dispatch may transiently raise (plus
-    the injected flavors so chaos runs exercise the same path).  The
-    JAX runtime error type moved across versions; resolve what exists."""
-    errs = [InjectedFault, OSError, TimeoutError]
-    try:
-        from jax.errors import JaxRuntimeError
-        errs.append(JaxRuntimeError)
-    except ImportError:
-        try:
-            from jaxlib.xla_extension import XlaRuntimeError
-            errs.append(XlaRuntimeError)
-        except ImportError:
-            pass
-    return tuple(errs)
+    the injected flavors so chaos runs exercise the same path)."""
+    from jax.errors import JaxRuntimeError
+    return (InjectedFault, OSError, TimeoutError, JaxRuntimeError)
 
 
 class CircuitBreaker:

@@ -175,11 +175,11 @@ class PredictionServer:
                  device_predict_min_rows: Optional[int] = None,
                  host_fallback: bool = True,
                  breaker: Optional[CircuitBreaker] = None):
-        # serving restarts cold too: pick up the persistent compile
-        # cache from the environment so the packed traversal programs
-        # load from disk (docs/ColdStart.md)
+        # serving restarts cold too: activate the persistent compile
+        # cache so the packed traversal programs load from disk
+        # (docs/ColdStart.md)
         from .. import compile_cache
-        compile_cache.configure_from_env()
+        compile_cache.configure()
         self._lock = threading.Lock()
         self._model: Optional[_Model] = None
         self.num_iteration = int(num_iteration)

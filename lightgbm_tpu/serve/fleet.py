@@ -428,7 +428,7 @@ class FleetServer:
                  num_features: Optional[int] = None,
                  breaker_factory=None):
         from .. import compile_cache
-        compile_cache.configure_from_env()
+        compile_cache.configure()
         if not boosters:
             raise LightGBMError("FleetServer needs at least one tenant")
         self.num_iteration = int(num_iteration)

@@ -1,9 +1,10 @@
 """Ahead-of-time warmup: precompile a deployment's program families.
 
-The retrain-every-window harness restarts; BENCH_r05 showed a fresh
-process paying 239 s of XLA compilation before its first trained tree.
-With the persistent compile cache active (:mod:`~lightgbm_tpu.
-compile_cache`) that bill is paid ONCE — by whoever compiles first.
+The retrain-every-window harness restarts, and a fresh process pays
+minutes of XLA compilation before its first trained tree (PERF.md has
+the chip's cold and warm figures).  With the persistent compile cache
+(:mod:`~lightgbm_tpu.compile_cache`) that bill is paid ONCE — by
+whoever compiles first.
 This module makes "whoever" a deliberate deployment step instead of the
 first production window:
 
@@ -304,8 +305,8 @@ def run_warmup(cfg: Config) -> List[dict]:
     reports: List[dict] = []
     obs.configure_from_config(cfg)
     if compile_cache.configure_from_config(cfg) is None:
-        log_info("[warmup] no compile_cache_dir/LGBM_TPU_COMPILE_CACHE "
-                 "set: programs compile into this process only")
+        log_info("[warmup] the persistent compile cache could not be "
+                 "activated: programs compile into this process only")
     rows_list = [int(r) for r in (cfg.warmup_rows or [])]
     features = int(getattr(cfg, "warmup_features", 0) or 0)
     if getattr(cfg, "data", ""):

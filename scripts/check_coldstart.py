@@ -4,8 +4,9 @@
 Fast contract check for the persistent-compile-cache story
 (docs/ColdStart.md), run by scripts/check.sh:
 
-1. spawn the ``lightgbm-tpu warmup`` CLI into a temp cache dir with a
-   small declared (rows, features, config) shape;
+1. spawn the ``lightgbm-tpu warmup`` CLI into a fixed, emptied
+   subdirectory of the resolved cache dir with a small declared (rows,
+   features, config) shape;
 2. spawn a FRESH subprocess that runs a real training of the SAME
    declaration (same synthetic generator, full iteration count — the
    warmup itself only runs one fused chunk + remainder);
@@ -28,9 +29,9 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 
 ROWS = 3000
 FEATURES = 8
@@ -81,49 +82,55 @@ def probe() -> int:
 def main() -> int:
     here = os.path.dirname(os.path.abspath(__file__))
     repo = os.path.dirname(here)
-    with tempfile.TemporaryDirectory(prefix="lgbm_coldstart_ci_") as tmp:
-        env = dict(os.environ)
-        env.update({
-            "JAX_PLATFORMS": "cpu",
-            "LGBM_TPU_CHUNK": env.get("LGBM_TPU_CHUNK", "8192"),
-            "LGBM_TPU_COMPILE_CACHE": tmp,
-        })
-        warm_cmd = ([sys.executable, "-m", "lightgbm_tpu", "warmup",
-                     f"warmup_rows={ROWS}", f"warmup_features={FEATURES}"]
-                    + DECLARATION)
-        r = subprocess.run(warm_cmd, env=env, cwd=repo,
-                           capture_output=True, text=True)
-        if r.returncode != 0:
-            print(f"FAIL warmup CLI rc={r.returncode}:\n"
-                  f"{r.stderr[-2000:]}")
-            return 1
-        entries = len([f for f in os.listdir(tmp)
-                       if f.endswith("-cache")])
-        r = subprocess.run([sys.executable, os.path.abspath(__file__),
-                            "--probe"], env=env, cwd=repo,
-                           capture_output=True, text=True)
-        if r.returncode != 0:
-            print(f"FAIL training probe rc={r.returncode}:\n"
-                  f"{r.stderr[-2000:]}")
-            return 1
-        counters = json.loads(r.stdout.strip().splitlines()[-1])
+    sys.path.insert(0, repo)
+    from lightgbm_tpu import compile_cache
 
-        # phase 2 — persisted stage plans: a profiled run measures once
-        # and persists beside the compile cache; a fresh subprocess of
-        # the same declaration must adopt the plan from disk with ZERO
-        # re-profiles (ROADMAP 1c / bench --suite coldstart's analog)
-        runs = []
-        for tag in ("profiled", "adopt"):
-            r = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--probe",
-                 "wave_plan=profiled"], env=env, cwd=repo,
-                capture_output=True, text=True)
-            if r.returncode != 0:
-                print(f"FAIL stage-plan {tag} probe rc={r.returncode}:\n"
-                      f"{r.stderr[-2000:]}")
-                return 1
-            runs.append(json.loads(r.stdout.strip().splitlines()[-1]))
-        plan_first, plan_second = runs
+    # a fixed subdirectory of wherever the cache is placed, emptied
+    # first: the children get it through JAX's own variable
+    tmp = os.path.join(compile_cache.resolve_dir(), "check_coldstart")
+    shutil.rmtree(tmp, ignore_errors=True)
+    env = dict(os.environ)
+    env.update({
+        "JAX_PLATFORMS": "cpu",
+        "LGBM_TPU_CHUNK": env.get("LGBM_TPU_CHUNK", "8192"),
+        compile_cache.ENV_VAR: tmp,
+    })
+    warm_cmd = ([sys.executable, "-m", "lightgbm_tpu", "warmup",
+                 f"warmup_rows={ROWS}", f"warmup_features={FEATURES}"]
+                + DECLARATION)
+    r = subprocess.run(warm_cmd, env=env, cwd=repo,
+                       capture_output=True, text=True)
+    if r.returncode != 0:
+        print(f"FAIL warmup CLI rc={r.returncode}:\n"
+              f"{r.stderr[-2000:]}")
+        return 1
+    entries = len([f for f in os.listdir(tmp)
+                   if f.endswith("-cache")])
+    r = subprocess.run([sys.executable, os.path.abspath(__file__),
+                        "--probe"], env=env, cwd=repo,
+                       capture_output=True, text=True)
+    if r.returncode != 0:
+        print(f"FAIL training probe rc={r.returncode}:\n"
+              f"{r.stderr[-2000:]}")
+        return 1
+    counters = json.loads(r.stdout.strip().splitlines()[-1])
+
+    # phase 2 — persisted stage plans: a profiled run measures once
+    # and persists beside the compile cache; a fresh subprocess of
+    # the same declaration must adopt the plan from disk with ZERO
+    # re-profiles (ROADMAP 1c / bench --suite coldstart's analog)
+    runs = []
+    for tag in ("profiled", "adopt"):
+        r = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--probe",
+             "wave_plan=profiled"], env=env, cwd=repo,
+            capture_output=True, text=True)
+        if r.returncode != 0:
+            print(f"FAIL stage-plan {tag} probe rc={r.returncode}:\n"
+                  f"{r.stderr[-2000:]}")
+            return 1
+        runs.append(json.loads(r.stdout.strip().splitlines()[-1]))
+    plan_first, plan_second = runs
     print(f"coldstart smoke: warmup wrote {entries} cache entries; "
           f"fresh training run: {counters['hits']} hits, "
           f"{counters['misses']} misses")

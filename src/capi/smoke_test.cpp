@@ -79,9 +79,9 @@ int main() {
 
   /* deployment-init AOT warmup (docs/ColdStart.md): precompile the
    * declared training + serving program families before the window
-   * loop.  With LGBM_TPU_COMPILE_CACHE set this persists executables so
-   * a RESTARTED harness starts warm; without it it still front-loads
-   * the in-process compiles. */
+   * loop.  The persistent compile cache (JAX_COMPILATION_CACHE_DIR,
+   * else <checkout>/.jax_cache) keeps the executables, so a RESTARTED
+   * harness starts warm. */
   int warmed = -1;
   check(LGBM_WarmupTrain(trainParams, rows, HISTFEATURES + 3, &warmed),
         "WarmupTrain");

@@ -90,10 +90,10 @@ def _probe_pod(cfg):
         from jax.sharding import PartitionSpec as P
         from lightgbm_tpu.ops.shard import (make_pod_mesh,
                                             multihost_setup,
-                                            shard_map_compat)
+                                            shard_map_nocheck)
         multihost_setup(cfg)
         mesh = make_pod_mesh()
-        out = jax.jit(shard_map_compat(
+        out = jax.jit(shard_map_nocheck(
             lambda x: jax.lax.psum(x, "shards"), mesh,
             (P("shards"),), P()))(
             jnp.arange(int(mesh.devices.size) * 2, dtype=jnp.float32))

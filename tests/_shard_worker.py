@@ -48,9 +48,9 @@ def _probe_shard_env():
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from lightgbm_tpu.ops.shard import (make_shard_mesh,
-                                            shard_map_compat)
+                                            shard_map_nocheck)
         mesh = make_shard_mesh(4)
-        out = jax.jit(shard_map_compat(
+        out = jax.jit(shard_map_nocheck(
             lambda x: jax.lax.psum(x, "shards"), mesh,
             (P("shards"),), P()))(jnp.arange(8, dtype=jnp.float32))
         float(out.sum())

@@ -185,52 +185,118 @@ def test_programs_signature_stable_across_hashseeds():
 # persistent compile cache
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_configure(tmp_path):
+def test_compile_cache_configure(private_cache_dir, tmp_path):
     import jax
 
-    # falsy values leave the cache alone
-    assert compile_cache.configure(None) is None
-    assert compile_cache.configure("") is None
-    assert compile_cache.configure("0") is None
-    assert compile_cache.configure("off") is None
-    target = tmp_path / "cc"
-    path = compile_cache.configure(str(target))
-    try:
-        assert path == str(target)
-        assert os.path.isdir(path)
-        assert compile_cache.cache_dir() == path
-        assert jax.config.jax_compilation_cache_dir == path
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
-        # param beats env; env used when param empty
-        cfg = Config({"compile_cache_dir": str(tmp_path / "p"),
-                      "verbosity": -1})
-        assert compile_cache.configure_from_config(cfg) \
-            == str(tmp_path / "p")
-        # a param-configured dir is PINNED against env-only reconfigures
-        # (PredictionServer / capi_embed call configure_from_env): the
-        # env var must not flip the process-wide cache mid-training
-        os.environ[compile_cache.ENV_VAR] = str(tmp_path / "env")
-        try:
-            assert compile_cache.configure_from_env() \
-                == str(tmp_path / "p")
-            assert jax.config.jax_compilation_cache_dir \
-                == str(tmp_path / "p")
-        finally:
-            del os.environ[compile_cache.ENV_VAR]
-        c = compile_cache.counters()
-        assert set(c) >= {"hits", "misses", "requests",
-                          "backend_compile_s"}
-    finally:
-        # restore the session-wide cache dir AND clear the sticky
-        # module state this test set (knobs + explicit-dir pin), so
-        # later tests' configure_from_env behavior doesn't depend on
-        # whether this test ran first
-        with compile_cache._LOCK:
-            compile_cache._STATE.pop("pinned", None)
-            compile_cache._STATE.pop("min_entry_bytes", None)
-            compile_cache._STATE.pop("strict_keys", None)
-        compile_cache.configure(os.path.expanduser(
-            "~/.cache/lgbm_tpu_xla"), _pin=False)
+    # the fixture dropped the env var and activated a private dir
+    path = private_cache_dir
+    assert os.path.isdir(path)
+    assert compile_cache.cache_dir() == path
+    assert jax.config.jax_compilation_cache_dir == path
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    # env unset: the param places the cache ...
+    cfg = Config({"compile_cache_dir": str(tmp_path / "p"),
+                  "verbosity": -1})
+    assert compile_cache.configure_from_config(cfg) == str(tmp_path / "p")
+    # ... and a bare reconfigure (PredictionServer / capi_embed import
+    # mid-training) keeps the ACTIVE dir instead of flipping the
+    # process-wide cache back to the default
+    assert compile_cache.configure() == str(tmp_path / "p")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "p")
+    c = compile_cache.counters()
+    assert set(c) >= {"hits", "misses", "requests", "backend_compile_s"}
+
+
+def test_cache_dir_env_wins_over_param(private_cache_dir, tmp_path,
+                                       monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: that directory IS the cache and
+    the stage-plan store; compile_cache_dir is ignored."""
+    import jax
+
+    from lightgbm_tpu.ops import stage_plan as sp
+
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    cfg = Config({"compile_cache_dir": str(tmp_path / "from_param"),
+                  "verbosity": -1})
+    assert compile_cache.resolve_dir(cfg.compile_cache_dir) == env_dir
+    assert compile_cache.configure_from_config(cfg) == env_dir
+    assert compile_cache.configure(str(tmp_path / "other")) == env_dir
+    assert compile_cache.cache_dir() == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert sp.store_dir() == os.path.join(env_dir, "stage_plans")
+    assert not os.path.exists(tmp_path / "from_param")
+    assert not os.path.exists(tmp_path / "other")
+
+
+def test_cache_dir_default_is_fixed_in_checkout(monkeypatch):
+    """Env unset and nothing requested: the fixed, gitignored
+    <checkout>/.jax_cache — never ~/.cache, never a tempfile name."""
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.setitem(compile_cache._STATE, "dir", None)
+    assert compile_cache.DEFAULT_DIR == os.path.join(REPO, ".jax_cache")
+    assert compile_cache.resolve_dir() == compile_cache.DEFAULT_DIR
+    assert compile_cache.resolve_dir("") == compile_cache.DEFAULT_DIR
+    with open(os.path.join(REPO, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
+
+
+def test_private_cache_dir_restored_the_session_dir():
+    """After the private-dir tests above, the session is back on the
+    directory conftest activated (a fixture that left it on a tmp dir
+    would make every later test compile cold)."""
+    expected = os.environ.get(compile_cache.ENV_VAR) \
+        or compile_cache.DEFAULT_DIR
+    assert compile_cache.cache_dir() == os.path.abspath(expected)
+
+
+def test_no_entry_point_builds_a_tempfile_cache_dir():
+    """No shipped entry point may place a compile cache (or stage-plan
+    store) under a tempfile/pid/time name or ~/.cache, or through the
+    retired private variable: a directory that moves never hits."""
+    import re
+
+    banned = re.compile(
+        r"LGBM_TPU_COMPILE_CACHE|lgbm_tpu_xla|mkdtemp|TemporaryDirectory")
+    # the two tempfile uses that are NOT cache dirs: the pod bench's
+    # rank-result exchange dir and the soak's checkpoint workdir
+    allowed = {("bench.py", 'outdir = tempfile.mkdtemp(prefix="bench_mh_")'),
+               ("lightgbm_tpu/soak/driver.py",
+                'or tempfile.mkdtemp(prefix="lgbm_soak_"))')}
+    files = [os.path.join(REPO, f) for f in ("bench.py", "chip_smoke.py")]
+    for root, _, names in os.walk(os.path.join(REPO, "lightgbm_tpu")):
+        files += [os.path.join(root, n) for n in names
+                  if n.endswith(".py")]
+    hits = []
+    for path in files:
+        rel = os.path.relpath(path, REPO)
+        with open(path) as fh:
+            for ln in fh:
+                if banned.search(ln) and (rel, ln.strip()) not in allowed:
+                    hits.append((rel, ln.strip()))
+    assert not hits, hits
+
+
+def test_plan_not_adopted_across_backends(private_cache_dir, monkeypatch):
+    """Plans and fusion verdicts are timings: one persisted under a
+    (platform, device_kind) must never load under another — the cache
+    dir travels with the checkout, and XLA:CPU test runs fill it."""
+    from lightgbm_tpu.ops import stage_plan as sp
+
+    sig = ("backend-sig", 4096, 3, 64, False, "digest")
+    plan = [(4, 8), (128, None)]
+    monkeypatch.setattr(sp, "backend_key", lambda: "cpu:cpu")
+    cpu_path = sp.save_plan(sig, plan)
+    sp.save_fusion(sig, "two_pass")
+    assert sp.load_plan(sig) == plan
+    assert sp.load_fusion(sig) == "two_pass"
+    monkeypatch.setattr(sp, "backend_key", lambda: "tpu:TPU v5 lite")
+    assert sp.load_plan(sig) is None
+    assert sp.load_fusion(sig) is None
+    # another backend's file at THIS backend's path (a copied or
+    # renamed store) is refused on its stored backend field too
+    os.replace(cpu_path, sp._plan_path(sig))
+    assert sp.load_plan(sig) is None
 
 
 _COLD_SCRIPT = """
@@ -246,7 +312,7 @@ set_verbosity(-1)
 cfg = Config({{"objective": "binary", "num_leaves": 31, "max_bin": 63,
               "num_iterations": 2, "fused_chunk": 2,
               "device_growth": "on", "verbosity": -1}})
-compile_cache.configure_from_env()
+compile_cache.configure()
 ds = _synth_dataset(3000, 8, cfg)
 t0 = time.perf_counter()
 bst = create_boosting(cfg)
@@ -271,7 +337,7 @@ def test_warm_cold_start_5x_less_compile(tmp_path):
     so the wall-clock gate there is strictly-faster (the TPU bench
     gates the >= 5x wall ratio via ``bench.py --suite coldstart``)."""
     script = _COLD_SCRIPT.format(repo=REPO)
-    env = _subprocess_env(LGBM_TPU_COMPILE_CACHE=str(tmp_path / "cc"))
+    env = _subprocess_env(JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
     runs = []
     for tag in ("cold", "warm"):
         r = subprocess.run([sys.executable, "-c", script], env=env,
@@ -287,6 +353,61 @@ def test_warm_cold_start_5x_less_compile(tmp_path):
         warm["backend_compile_s"], 1e-3), (cold, warm)
     # and the end-to-end cold start is strictly faster
     assert warm["warmup_wall_s"] < cold["warmup_wall_s"], (cold, warm)
+
+
+_BENCH_PARENT_SCRIPT = """
+import json, sys
+sys.path.insert(0, {repo!r})
+from jax._src import xla_bridge
+import bench
+
+def boom(args):
+    raise RuntimeError("mslr cell down")
+
+# --suite all: a failed mslr cell keeps the higgs line but fails the run
+bench.run_higgs = lambda args: {{"metric": "higgs"}}
+bench.run_mslr = boom
+sys.argv = ["bench.py", "--suite", "all", "--quick"]
+rc_all = bench.main()
+
+# --suite coldstart: the parent must leave the chip to its children
+legs = []
+def fake_child(cmd, env, tag, expect_json=True):
+    assert not xla_bridge.backends_are_initialized(), tag
+    legs.append((tag, env["JAX_COMPILATION_CACHE_DIR"]))
+    return {{"warmup_compile_s": 1.0, "xla_compile_s": 1.0}}
+bench._coldstart_child = fake_child
+sys.argv = ["bench.py", "--suite", "coldstart", "--rows", "1000",
+            "--iters", "2"]
+rc_cold = bench.main()
+print(json.dumps({{"rc_all": rc_all, "rc_cold": rc_cold, "legs": legs,
+                  "backend_up": xla_bridge.backends_are_initialized()}}))
+"""
+
+
+@pytest.mark.timeout(120)
+def test_bench_fails_loudly_and_coldstart_parent_stays_off_backend(
+        tmp_path):
+    """bench.py's entry point: ``--suite all`` exits non-zero when the
+    mslr cell fails (it used to write {"error": ...} and exit 0), and
+    the ``--suite coldstart`` parent never initialises a JAX backend (a
+    chip belongs to one process, and its three children need it) while
+    handing them FIXED per-leg cache directories under the resolved
+    cache dir."""
+    root = str(tmp_path / "cc")
+    r = subprocess.run(
+        [sys.executable, "-c", _BENCH_PARENT_SCRIPT.format(repo=REPO)],
+        env=_subprocess_env(JAX_COMPILATION_CACHE_DIR=root),
+        capture_output=True, text=True, cwd=REPO)
+    assert r.returncode == 0, r.stderr[-2000:]
+    lines = r.stdout.strip().splitlines()
+    out = json.loads(lines[-1])
+    assert out["rc_all"] == 1 and out["rc_cold"] == 0
+    assert "mslr cell down" in json.loads(lines[0])["mslr"]["error"]
+    assert not out["backend_up"]
+    a, b = (os.path.join(root, "coldstart", d) for d in "ab")
+    assert out["legs"] == [["cold", a], ["warm", a],
+                           ["aot-warmup", b], ["aot", b]]
 
 
 # ---------------------------------------------------------------------------

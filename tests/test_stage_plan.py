@@ -2,7 +2,6 @@
 byte-stable default, the profile-guided install path, and the on-disk
 plan store beside the persistent compile cache."""
 
-import contextlib
 import json
 import os
 import subprocess
@@ -13,22 +12,6 @@ import numpy as np
 from lightgbm_tpu.ops import stage_plan as sp
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@contextlib.contextmanager
-def _plan_store(tmp_path):
-    """Point the compile cache (and thus the stage-plan store) at a tmp
-    dir, restoring the session-wide default afterwards."""
-    from lightgbm_tpu import compile_cache
-
-    prev = compile_cache.cache_dir()
-    compile_cache.configure(str(tmp_path / "cc"), _pin=False)
-    try:
-        yield
-    finally:
-        compile_cache.configure(
-            prev or os.path.expanduser("~/.cache/lgbm_tpu_xla"),
-            _pin=False)
 
 
 def test_legacy_plan_matches_historical_doubling():
@@ -125,57 +108,52 @@ def test_derive_beats_legacy_gate():
     assert not sp.plan_beats(legacy, legacy, 255, 3, 10.0, 0.1)
 
 
-def test_plan_persistence_roundtrip(tmp_path):
+def test_plan_persistence_roundtrip(private_cache_dir):
     """save_plan/load_plan round-trip beside the compile cache; corrupt
     digests, foreign signatures and absent stores all degrade to None
     (-> legacy plan), never to a bad plan."""
     sig = ("persist-sig", 4096, 3, 64, False, "digest")
     plan = [(4, 8), (16, 32), (128, None)]
-    with _plan_store(tmp_path):
-        assert sp.load_plan(sig) is None
-        path = sp.save_plan(sig, plan)
-        assert path is not None and os.path.exists(path)
-        assert sp.load_plan(sig) == plan
-        # cache_plan writes through to disk by default
-        sig2 = sig + ("v2",)
-        sp.cache_plan(sig2, plan)
-        assert sp.load_plan(sig2) == plan
-        # ... and persist=False keeps it process-local
-        sig3 = sig + ("v3",)
-        sp.cache_plan(sig3, plan, persist=False)
-        assert sp.load_plan(sig3) is None
-        # digest mismatch (hand-edited/corrupt file) -> fallback
-        with open(path) as fh:
-            payload = json.load(fh)
-        payload["plan"] = [[8, 16], [128, None]]     # digest now stale
-        with open(path, "w") as fh:
-            json.dump(payload, fh)
-        assert sp.load_plan(sig) is None
-        # signature mismatch (hash-prefix collision paranoia) -> None
-        sp.save_plan(sig, plan)
-        with open(path) as fh:
-            payload = json.load(fh)
-        payload["signature"] = "something else"
-        with open(path, "w") as fh:
-            json.dump(payload, fh)
-        assert sp.load_plan(sig) is None
-        # unparseable file -> None
-        with open(path, "w") as fh:
-            fh.write("{not json")
-        assert sp.load_plan(sig) is None
-        # forget_plan removes both layers
-        sp.save_plan(sig, plan)
-        sp.cache_plan(sig, plan, persist=False)
-        sp.forget_plan(sig)
-        assert sp.cached_plan(sig) is None
-        assert sp.load_plan(sig) is None
-    # no active store: save/load are clean no-ops
-    from lightgbm_tpu import compile_cache
-    if compile_cache.cache_dir() is None:
-        assert sp.save_plan(sig, plan) is None
+    assert sp.load_plan(sig) is None
+    path = sp.save_plan(sig, plan)
+    assert path is not None and os.path.exists(path)
+    assert sp.load_plan(sig) == plan
+    # cache_plan writes through to disk by default
+    sig2 = sig + ("v2",)
+    sp.cache_plan(sig2, plan)
+    assert sp.load_plan(sig2) == plan
+    # ... and persist=False keeps it process-local
+    sig3 = sig + ("v3",)
+    sp.cache_plan(sig3, plan, persist=False)
+    assert sp.load_plan(sig3) is None
+    # digest mismatch (hand-edited/corrupt file) -> fallback
+    with open(path) as fh:
+        payload = json.load(fh)
+    payload["plan"] = [[8, 16], [128, None]]     # digest now stale
+    with open(path, "w") as fh:
+        json.dump(payload, fh)
+    assert sp.load_plan(sig) is None
+    # signature mismatch (hash-prefix collision paranoia) -> None
+    sp.save_plan(sig, plan)
+    with open(path) as fh:
+        payload = json.load(fh)
+    payload["signature"] = "something else"
+    with open(path, "w") as fh:
+        json.dump(payload, fh)
+    assert sp.load_plan(sig) is None
+    # unparseable file -> None
+    with open(path, "w") as fh:
+        fh.write("{not json")
+    assert sp.load_plan(sig) is None
+    # forget_plan removes both layers
+    sp.save_plan(sig, plan)
+    sp.cache_plan(sig, plan, persist=False)
+    sp.forget_plan(sig)
+    assert sp.cached_plan(sig) is None
+    assert sp.load_plan(sig) is None
 
 
-def test_auto_grower_adopts_persisted_plan(tmp_path):
+def test_auto_grower_adopts_persisted_plan(private_cache_dir):
     """get_grower_programs under wave_plan=auto adopts a persisted plan
     from a 'previous process' (plan_source='persisted'), and a corrupt
     file falls back to the legacy plan."""
@@ -186,31 +164,30 @@ def test_auto_grower_adopts_persisted_plan(tmp_path):
                   "verbosity": -1, "seed": 424243})
     sig = grow.programs_signature(4096, 3, 64, 3, False, cfg)
     custom = [(8, 16), (31, None)]
-    with _plan_store(tmp_path):
-        sp.forget_plan(sig)
-        sp.save_plan(sig, custom)
-        progs = grow.get_grower_programs(4096, 3, 64, 3, False, cfg)
-        assert progs.stage_plan == custom
-        assert progs.plan_source == "persisted"
-        # corrupt the file: a FRESH signature lookup (cleared caches)
-        # degrades to the legacy default
-        sp.forget_plan(sig)
-        path = sp.save_plan(sig, custom)
-        with open(path, "w") as fh:
-            fh.write("garbage")
+    sp.forget_plan(sig)
+    sp.save_plan(sig, custom)
+    progs = grow.get_grower_programs(4096, 3, 64, 3, False, cfg)
+    assert progs.stage_plan == custom
+    assert progs.plan_source == "persisted"
+    # corrupt the file: a FRESH signature lookup (cleared caches)
+    # degrades to the legacy default
+    sp.forget_plan(sig)
+    path = sp.save_plan(sig, custom)
+    with open(path, "w") as fh:
+        fh.write("garbage")
+    with grow._PROGRAM_CACHE_LOCK:
+        saved = dict(grow._PROGRAM_CACHE)
+        grow._PROGRAM_CACHE.clear()
+    try:
+        progs2 = grow.get_grower_programs(4096, 3, 64, 3, False, cfg)
+        assert progs2.plan_source == "default"
+        assert progs2.stage_plan == grow.default_stage_plan(4096,
+                                                            cfg)
+    finally:
         with grow._PROGRAM_CACHE_LOCK:
-            saved = dict(grow._PROGRAM_CACHE)
             grow._PROGRAM_CACHE.clear()
-        try:
-            progs2 = grow.get_grower_programs(4096, 3, 64, 3, False, cfg)
-            assert progs2.plan_source == "default"
-            assert progs2.stage_plan == grow.default_stage_plan(4096,
-                                                                cfg)
-        finally:
-            with grow._PROGRAM_CACHE_LOCK:
-                grow._PROGRAM_CACHE.clear()
-                grow._PROGRAM_CACHE.update(saved)
-            sp.forget_plan(sig)
+            grow._PROGRAM_CACHE.update(saved)
+        sp.forget_plan(sig)
 
 
 def test_persisted_plan_key_stable_across_hashseeds(tmp_path):
@@ -222,7 +199,7 @@ import json, sys
 sys.path.insert(0, {repo!r})
 from lightgbm_tpu import compile_cache
 from lightgbm_tpu.ops import stage_plan as sp
-compile_cache.configure({store!r}, _pin=False)
+compile_cache.configure({store!r})
 sig = ("sig", 4096, 3, 64, False, "abc123")
 print(json.dumps({{"path": sp._plan_path(sig)}}))
 """.format(repo=REPO, store=str(tmp_path / "cc"))
@@ -230,6 +207,7 @@ print(json.dumps({{"path": sp._plan_path(sig)}}))
     for seed in ("1", "271828"):
         env = dict(os.environ)
         env.update({"JAX_PLATFORMS": "cpu", "PYTHONHASHSEED": seed})
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         r = subprocess.run([sys.executable, "-c", script], env=env,
                            capture_output=True, text=True, cwd=REPO)
         assert r.returncode == 0, r.stderr[-2000:]
