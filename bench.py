@@ -85,18 +85,23 @@ def timed_train(bst, iters: int, chunk_arg: int):
     return chunk, warm, warmup_s, timed_s, bst.num_iterations() - warm
 
 
-def _waves_per_tree(bst):
-    """Mean wave count per tree from the booster's device handles (the
-    fused path stacks one (chunk,) array per dispatch)."""
-    handles = getattr(bst, "_wave_handles", None)
-    if not handles:
-        return None
-    tot = cnt = 0
-    for h in handles:
-        a = np.asarray(h)
-        tot += int(a.sum())
-        cnt += int(a.size)
-    return round(tot / max(cnt, 1), 2)
+def _work_counters() -> dict:
+    """The scan's work counters (``grow.waves`` / ``grow.trees``) as the
+    registry holds them now; empty while telemetry is off."""
+    from lightgbm_tpu import obs
+    if not obs.enabled():
+        return {}
+    return obs.registry().snapshot()["counters"]
+
+
+def _waves_per_tree(before: dict):
+    """Mean wave count per tree since the ``_work_counters()`` snapshot
+    ``before`` (call after ``block_until_ready``: the registry's
+    snapshot brings in every finished dispatch)."""
+    after = _work_counters()
+    trees = after.get("grow.trees", 0) - before.get("grow.trees", 0)
+    waves = after.get("grow.waves", 0) - before.get("grow.waves", 0)
+    return round(waves / trees, 2) if trees else None
 
 
 def _phases_from_obs() -> dict:
@@ -233,6 +238,7 @@ def run_higgs(args) -> dict:
     ds.metadata.set_label(y)
     t_bin = time.perf_counter() - t0
 
+    work0 = _work_counters()
     bst = create_boosting(cfg)
     TRAIN_TIMER.reset()
     TRAIN_TIMER.sync = args.profile
@@ -291,14 +297,7 @@ def run_higgs(args) -> dict:
     if not phases:
         # fused path: TRAIN_TIMER never runs — rebuild from obs spans
         phases = _phases_from_obs()
-    waves_per_tree = _waves_per_tree(bst)
-    if args.profile and getattr(bst, "_grower", None) is not None:
-        # per-phase ms for ONE wave's components, separately jitted and
-        # synced (the production while_loop hides phases from the host)
-        g, h = bst.objective.get_gradients(bst.train_score)
-        if g.ndim > 1:
-            g, h = g[0], h[0]
-        phases["device_wave_ms"] = bst._grower.profile_phases(g, h)
+    waves_per_tree = _waves_per_tree(work0)
     result = {
         "metric": f"higgs_synth_{args.rows}x28_{args.iters}iter_wallclock",
         "value": round(train_s, 3),
@@ -751,7 +750,7 @@ def run_quant(args) -> dict:
             bst, args.iters, args.chunk)
         per_iter = timed_s / max(iters_timed, 1)
         grower = getattr(bst, "_grower", None)
-        wpt = _waves_per_tree(bst)
+        wpt = _waves_per_tree(before)
         fused = bool(getattr(grower, "fused_find", False))
         leg_out[name] = {
             "ms_per_tree": round(1000.0 * per_iter, 2),
@@ -821,230 +820,6 @@ def run_quant(args) -> dict:
         "chip_pending": backend != "tpu",
         "host_sentinel_ms": host_sentinel_ms(),
     }
-
-
-def run_explain(args) -> dict:
-    """Phase-attribution explainer (``--explain``): where does a tree's
-    wall time actually go?
-
-    Trains one quant-suite-shaped leg, then rebuilds the measured
-    ms_per_tree from the device-phase probes (ops/grow.py): the
-    per-stage-width wave histogram timings scaled by the stage plan and
-    the observed waves/tree, find_best + split_apply per wave,
-    score_update per tree, and the psum collective when sharded.  With
-    ``profile_attribution`` on, each probe also carries its XLA
-    cost-analysis estimate (FLOPs/bytes -> achieved GFLOP/s).  The
-    report's ``coverage`` is attributed/measured (clamped at 1.0);
-    the acceptance bar is >= 0.9 — anything the probes cannot see
-    (while_loop glue, totals fetch, host dispatch) shows up as
-    ``unattributed_ms`` instead of being papered over."""
-    import jax
-    from lightgbm_tpu import obs
-    from lightgbm_tpu.boosting import create_boosting
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.data.dataset import BinnedDataset
-
-    obs.configure(profile_attribution=True)
-    backend = jax.default_backend()
-    wave_plan = "fixed" if args.wave_plan == "auto" else args.wave_plan
-    cfg = Config({
-        "objective": "binary", "metric": "auc",
-        "num_leaves": args.num_leaves, "max_bin": args.max_bin,
-        "learning_rate": args.learning_rate,
-        "min_data_in_leaf": 20, "min_sum_hessian_in_leaf": 1e-3,
-        "bagging_fraction": 1.0, "feature_fraction": 1.0,
-        "verbosity": 0, "wave_plan": wave_plan,
-        "grad_quant_bits": args.quant_bits,
-        "profile_attribution": True,
-        "device_growth": {"device": "on", "host": "off",
-                          "auto": "auto"}[args.engine],
-    })
-    t0 = time.perf_counter()
-    if args.host_data:
-        x, y = synth_higgs(args.rows)
-        ds = BinnedDataset.construct_from_matrix(x, cfg)
-    else:
-        x, y = synth_higgs_device(args.rows)
-        ds = BinnedDataset.construct_from_device_matrix(x, cfg)
-        jax.block_until_ready(ds.binned)
-    ds.metadata.set_label(y)
-    t_prep = time.perf_counter() - t0
-
-    bst = create_boosting(cfg)
-    bst.init_train(ds)
-    # per-chunk timing instead of timed_train's single aggregate: each
-    # fused dispatch is blocked on individually, and the BEST chunk is
-    # the attribution denominator — the probes measure steady-state
-    # device time, so comparing them against a mean contaminated by
-    # host scheduling noise would understate coverage
-    chunk = args.chunk if args.chunk > 1 and bst.fused_eligible() else 0
-    with obs.profile.device_trace(args.device_profile) as profiled:
-        if chunk:
-            t0 = time.perf_counter()
-            bst.train_chunked(chunk, chunk=chunk)      # warm + compile
-            jax.block_until_ready(bst.train_score)
-            t_warm = time.perf_counter() - t0
-            warm = chunk
-            chunk_s = []
-            # full chunks only: a shorter remainder would recompile
-            # with a new scan length and pollute the timing
-            while bst.num_iterations() + chunk <= args.iters:
-                t0 = time.perf_counter()
-                bst.train_chunked(chunk, chunk=chunk)
-                jax.block_until_ready(bst.train_score)
-                chunk_s.append(time.perf_counter() - t0)
-            timed_s = sum(chunk_s)
-            iters_timed = chunk * len(chunk_s)
-            per_tree_ms = (min(chunk_s) / chunk * 1e3
-                           if chunk_s else 0.0)
-        else:
-            chunk, warm, t_warm, timed_s, iters_timed = timed_train(
-                bst, args.iters, args.chunk)
-            per_tree_ms = timed_s / max(iters_timed, 1) * 1e3
-
-    grower = getattr(bst, "_grower", None)
-    result = {
-        "metric": f"explain_higgs_{args.rows}x28_{args.iters}iter"
-                  f"_coverage",
-        "unit": "fraction",
-        "rows": args.rows,
-        "iters": args.iters,
-        "num_leaves": args.num_leaves,
-        "max_bin": args.max_bin,
-        "quant_bits": args.quant_bits,
-        "fused_chunk": chunk,
-        "wave_plan": wave_plan,
-        "prep_s": round(t_prep, 2),
-        "timed_s": round(timed_s, 3),
-        "timed_iters": iters_timed,
-        # best-chunk per-tree time (the attribution denominator) plus
-        # the noisier all-chunks mean for context
-        "ms_per_tree": round(per_tree_ms, 2),
-        "ms_per_tree_mean": round(
-            timed_s / max(iters_timed, 1) * 1e3, 2),
-        "device_profile": bool(profiled),
-        "backend": backend,
-        "device": str(jax.devices()[0]),
-        # attribution numbers on a non-TPU backend validate the math,
-        # not the chip (BENCH_r06 convention)
-        "chip_pending": backend != "tpu",
-        "host_sentinel_ms": host_sentinel_ms(),
-    }
-    if grower is None:
-        # host engine: the legacy TRAIN_TIMER is the only attribution
-        from lightgbm_tpu.utils.log import TRAIN_TIMER
-        phases_ms = {k: v / max(iters_timed, 1) * 1e3
-                     for k, v in TRAIN_TIMER.acc.items()}
-        report = obs.profile.attribution_report(per_tree_ms, phases_ms)
-        result["value"] = report["coverage"]
-        result["attribution"] = report
-        result["attribution_source"] = "host_train_timer"
-        return result
-
-    # device-phase probes on the trained grower's real operands
-    g, h = bst.objective.get_gradients(bst.train_score)
-    if g.ndim > 1:
-        g, h = g[0], h[0]
-    wave = grower.profile_phases(g, h, reps=10)
-    prof = grower.profile_stage_plan(reps=2, install=False)
-    psum = grower.profile_psum(reps=5)
-
-    # replay the stage plan's wave sequence: a plan entry (w, cap)
-    # runs width-w waves until the tree holds cap leaves (None = grown
-    # out), and the splittable frontier roughly doubles per wave — so
-    # [(4, 8), (30, None)] at 31 leaves is waves [4, 4, 4, 30, 30],
-    # NOT "8 waves then a tail".  Per-wave hist cost then rolls up
-    # from the per-width stage timings.
-    L = int(args.num_leaves)
-    widths, nl, pending = [], 1, 1
-    for w, cap in grower.stage_plan:
-        lim = L if cap is None else min(int(cap), L)
-        while nl < lim:
-            nsplit = min(pending, int(w), L - nl)
-            if nsplit <= 0:
-                break
-            widths.append(int(w))
-            nl += nsplit
-            pending += nsplit
-    plan_waves = float(len(widths)) or 1.0
-    wpt = _waves_per_tree(bst) or plan_waves
-    # trees terminate waves early: scale the full plan's cost down to
-    # the waves that actually ran
-    f = wpt / plan_waves
-    stage_ms = prof.get("stage_ms") or {}
-    full_hist = wave.get("wave_hist", 0.0)
-    hist_ms = sum(stage_ms.get(w, full_hist) for w in widths) * f
-    fused_find = bool(getattr(grower, "fused_find", False))
-    costs = dict(wave.get("costs") or {})
-
-    def _scale_cost(name, mult):
-        c = costs.get(name)
-        if c:
-            costs[name] = {k: (v * mult if v is not None else None)
-                           for k, v in c.items()}
-
-    if fused_find:
-        # fused find-best-in-wave: the gain scan rides the histogram
-        # program, so the replay prices ONE phase per wave — pricing
-        # hist and find as separate dispatches would claim a dispatch
-        # (and its fixed overhead) the fused layout never pays
-        phases_ms = {"fused_hist_find":
-                     hist_ms + wave.get("find_best", 0.0) * wpt}
-        _scale_cost("find_best", wpt)
-        if "split_apply" in wave:
-            phases_ms["split_apply"] = wave["split_apply"] * wpt
-            _scale_cost("split_apply", wpt)
-    else:
-        phases_ms = {"wave_hist": hist_ms}
-        for name in ("find_best", "split_apply"):
-            if name in wave:
-                phases_ms[name] = wave[name] * wpt
-                _scale_cost(name, wpt)
-    if "score_update" in wave:
-        phases_ms["score_update"] = wave["score_update"]
-    # the per-wave histogram cost estimate follows the same wave
-    # sequence when the stage probes produced per-width costs
-    stage_cost = prof.get("stage_cost") or {}
-    if stage_cost and all(w in stage_cost for w in set(widths)):
-        agg = {}
-        for w in widths:
-            for k, v in stage_cost[w].items():
-                if v is not None:
-                    agg[k] = agg.get(k, 0.0) + v
-        costs["wave_hist"] = {k: v * f for k, v in agg.items()}
-    else:
-        _scale_cost("wave_hist", wpt)
-    if fused_find:
-        # fold the hist and find cost estimates into the single fused
-        # phase so the FLOPs/bytes line up with the merged timing above
-        merged = {}
-        for name in ("wave_hist", "find_best"):
-            for k, v in (costs.pop(name, None) or {}).items():
-                if v is not None:
-                    merged[k] = merged.get(k, 0.0) + v
-        if merged:
-            costs["fused_hist_find"] = merged
-    if psum is not None:
-        phases_ms["psum"] = psum["psum_ms"] * wpt
-        if psum.get("cost"):
-            costs["psum"] = {k: (v * wpt if v is not None else None)
-                             for k, v in psum["cost"].items()}
-    report = obs.profile.attribution_report(per_tree_ms, phases_ms,
-                                            costs)
-    result["value"] = report["coverage"]
-    result["attribution"] = report
-    result["attribution_source"] = "device_phase_probes"
-    result["waves_per_tree"] = wpt
-    result["plan_waves"] = plan_waves
-    result["stage_plan"] = [[w, c] for w, c in grower.stage_plan]
-    result["stage_wave_widths"] = widths
-    result["stage_wave_ms"] = {str(k): v for k, v in stage_ms.items()}
-    result["dispatch_floor_ms"] = wave.get("dispatch_floor")
-    result["hist_kernel_tag"] = getattr(grower, "hist_kernel_tag", None)
-    result["find_best_fusion"] = getattr(grower, "find_fusion", None)
-    result["dispatches_per_tree"] = round(
-        wpt * (1 if fused_find else 2), 2)
-    return result
 
 
 def _run_shard_multihost(args) -> dict:
@@ -1243,6 +1018,7 @@ def run_shard(args) -> dict:
     psum = None
     for name, extra in legs:
         cfg = Config({**base, **extra})
+        work0 = _work_counters()
         bst = create_boosting(cfg)
         t0 = time.perf_counter()
         bst.init_train(ds)
@@ -1256,7 +1032,7 @@ def run_shard(args) -> dict:
             "timed_s": round(timed_s, 3),
             "timed_iters": iters_timed,
             "warmup_compile_s": round(t_warm + t_init, 2),
-            "waves_per_tree": _waves_per_tree(bst),
+            "waves_per_tree": _waves_per_tree(work0),
             "fused": bool(chunk),
             "int_scan": bool(getattr(grower, "int_scan", False)),
         }
@@ -1265,23 +1041,13 @@ def run_shard(args) -> dict:
             models[name] = bst.model_to_string().split("\nparameters:",
                                                        1)[0]
         if name == "sharded" and grower is not None:
-            # PR-16 attribution: enable cost capture so the probe also
-            # lowers the collective program through cost_of and reports
-            # its XLA bytes — a mesh-topology fact that stays honest on
-            # forced host meshes where the wall-clock does not
-            was_enabled = obs.enabled()
-            obs.configure(enabled=True, profile_attribution=True)
             psum = grower.profile_psum(reps=5)
-            if not was_enabled:
-                obs.configure(enabled=False)
         del bst
 
     single_ms = leg_out["single"]["ms_per_tree"]
     shard_ms = leg_out["sharded"]["ms_per_tree"]
     waves = leg_out["sharded"]["waves_per_tree"] or 0.0
     psum_ms = (psum or {}).get("psum_ms")
-    psum_cost = (psum or {}).get("cost")
-    psum_bytes = (psum_cost or {}).get("bytes_accessed")
     host_mesh = jax.default_backend() != "tpu"
     return {
         "metric": f"shard_suite_higgs_{args.rows}x28_{args.iters}iter"
@@ -1309,12 +1075,6 @@ def run_shard(args) -> dict:
         "psum_ms": psum_ms,
         "psum_ms_per_tree": round(psum_ms * waves, 3)
         if psum_ms is not None else None,
-        # mesh-topology facts from the PR-16 attribution path (XLA
-        # cost analysis of the collective program): honest even when
-        # the timing above is not
-        "psum_cost": psum_cost,
-        "psum_bytes_per_tree": round(psum_bytes * waves)
-        if psum_bytes else None,
         "trees_byte_identical": models["single"] == models["sharded"],
         "backend": jax.default_backend(),
         "device": str(jax.devices()[0]),
@@ -1578,25 +1338,9 @@ def main() -> int:
                          "mesh; emits multihost_scaling_efficiency, "
                          "ingest_rows_per_s_per_host and the byte-"
                          "identity verdict (docs/Sharding.md)")
-    ap.add_argument("--explain", action="store_true",
-                    help="alias for --suite explain: train one quant-"
-                         "shaped leg, then rebuild its ms_per_tree from "
-                         "the device-phase probes (per-stage wave "
-                         "histogram x stage plan, find_best/split_apply "
-                         "per wave, score_update per tree, psum when "
-                         "sharded) into a phase-attribution report with "
-                         "XLA FLOPs/bytes estimates; value = coverage "
-                         "(attributed/measured, bar >= 0.9)")
-    ap.add_argument("--device-profile",
-                    default=os.environ.get("BENCH_DEVICE_PROFILE", ""),
-                    help="--explain: also capture a jax.profiler device "
-                         "trace of the timed region into this directory "
-                         "(viewable in Perfetto/TensorBoard; silently "
-                         "skipped where the profiler is unavailable)")
     ap.add_argument("--suite",
                     choices=["all", "higgs", "mslr", "cache", "serve",
-                             "coldstart", "quant", "shard", "explain",
-                             "soak"],
+                             "coldstart", "quant", "shard", "soak"],
                     default=os.environ.get("BENCH_SUITE", "all"),
                     help="all = HIGGS headline + MSLR lambdarank "
                          "(both north stars, BASELINE.md); cache = the "
@@ -1696,13 +1440,9 @@ def main() -> int:
 
     if args.cache_admission:
         args.suite = "cache"
-    if args.explain:
-        args.suite = "explain"
     rc = 0
     if args.suite == "soak":
         result = run_soak(args)
-    elif args.suite == "explain":
-        result = run_explain(args)
     elif args.suite == "coldstart":
         result = run_coldstart(args)
     elif args.suite == "shard":

@@ -12,6 +12,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from . import obs
 from .boosting import create_boosting
 from .boosting.gbdt import GBDT
 from .config import Config, normalize_params
@@ -91,6 +92,10 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._handle is not None:
             return self
+        with obs.span("dataset.construct", cat="data"):
+            return self._construct()
+
+    def _construct(self) -> "Dataset":
         params = dict(self.params)
         if self.reference is not None:
             self.reference.construct()

@@ -9,8 +9,9 @@ on-device tree traversal.  Model text format is the reference's "v2".
 
 from __future__ import annotations
 
+import collections
 import functools
-import time
+import threading
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -168,6 +169,58 @@ class _PendingChunkTree(_Pending):
                                self.shrinkage, self.bias, dataset, config)
 
 
+class _WorkDrain:
+    """The device scan's work counters on their way to the registry.
+
+    Each dispatch returns, per tree, its leaf count and ``[waves, wave
+    slots]`` as device arrays.  ``push`` queues the handles (their async
+    host copies already started) and ``drain`` adds whatever
+    ``is_ready()`` to ``grow.trees`` / ``leaves`` / ``waves`` /
+    ``wave_slots`` / ``rows_scanned`` / ``rows_real`` — at the next
+    dispatch and whenever the registry is snapshotted (the booster
+    registers ``drain`` as a collector), so the dispatch path never
+    waits for the device and a chunk whose ``block_until_ready`` has
+    returned is in the snapshot that follows it.  Past ``CAP`` queued
+    dispatches the oldest is read outright (it finished long ago), so
+    the queue is bounded whatever the backend says about readiness."""
+
+    CAP = 8
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._pending = collections.deque()
+
+    def __len__(self):
+        return len(self._pending)
+
+    def push(self, nl, work, rows_scanned: int, rows_real: int) -> None:
+        if not obs.enabled():
+            return
+        work.copy_to_host_async()
+        with self._lock:
+            self._pending.append((nl, work, rows_scanned, rows_real))
+        self.drain()
+
+    def drain(self) -> None:
+        with self._lock:
+            done = []
+            while self._pending and (
+                    len(self._pending) > self.CAP
+                    or (self._pending[0][0].is_ready()
+                        and self._pending[0][1].is_ready())):
+                done.append(self._pending.popleft())
+        for nl, work, rows_scanned, rows_real in done:
+            nl = np.asarray(nl).reshape(-1)
+            work = np.asarray(work).reshape(-1, 2)
+            waves = int(work[:, 0].sum())
+            obs.inc("grow.trees", int(nl.size))
+            obs.inc("grow.leaves", int(nl.sum()))
+            obs.inc("grow.waves", waves)
+            obs.inc("grow.wave_slots", int(work[:, 1].sum()))
+            obs.inc("grow.rows_scanned", waves * rows_scanned)
+            obs.inc("grow.rows_real", waves * rows_real)
+
+
 class GBDT:
     """Gradient Boosting Decision Tree driver."""
 
@@ -196,7 +249,11 @@ class GBDT:
         # in-flight (num_leaves handles, quant-scale handle) per
         # iteration, fetched with a 4-iteration lag
         self._nl_queue: List = []
-        self._wave_handles: List = []  # per-iter wave counts (device scalars)
+        # per-tree work counters of the device scan, drained into the
+        # registry without ever blocking a dispatch (weakly registered:
+        # the collector dies with the booster)
+        self._work = _WorkDrain()
+        obs.registry().add_collector(self._work.drain)
         self._fused_grad = False    # cached objective.device_grad() result
         self._last_chunk_stack = None   # previous fused chunk's _RecStack
         self._row_mask_cache = None     # device bagging mask (per draw)
@@ -204,11 +261,15 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def init_train(self, train_set: BinnedDataset, objective=None):
-        cfg = self.config
         # telemetry: params may enable the obs subsystem; in the windowed
         # harness this runs once per retrain window, so it must stay
         # additive (cross-window recompile/memory totals are the point)
-        obs.configure_from_config(cfg)
+        obs.configure_from_config(self.config)
+        with obs.span("train.init", cat="boost"):
+            self._init_train(train_set, objective)
+
+    def _init_train(self, train_set: BinnedDataset, objective):
+        cfg = self.config
         # persistent XLA compile cache: params/env may point every jit
         # this booster compiles at an on-disk store, so a fresh process
         # (the windowed harness restarts, deployments roll) re-loads
@@ -631,17 +692,17 @@ class GBDT:
             # own numpy stream)
             tree_idx = self.iter * self.num_model + k
             mask = self._grower.feature_mask_for(tree_idx)
-            score, rec_i, rec_f, rec_c, nl, root_val, waves, qscale = \
+            score, rec_i, rec_f, rec_c, nl, root_val, work, qscale = \
                 self._dispatch_guard(functools.partial(
                     self._grower.grow_one_iter, self.train_score[k],
                     grad[k], hess[k], mask, shrink, row_mask,
                     tree_idx=tree_idx))
             self.train_score = self.train_score.at[k].set(score)
             last_qscale = qscale
-            self._wave_handles.append(waves)
             self.models.append(_PendingTree(
                 rec_i, rec_f, rec_c, nl, root_val, shrink,
                 init_scores[k]))
+            self._push_work(nl, work)
             nls.append(nl)
         self.iter += 1
         # stump check: inspect num_leaves with a 4-iteration lag — the
@@ -777,45 +838,63 @@ class GBDT:
                     if self.train_one_iter():
                         return True
                 return False
-            bias = self.boost_from_average(0) if not self.models else 0.0
-            fused = self._grower.fused_train(chunk)
-            t0 = time.perf_counter() if obs.enabled() else None
-            score, (rec_i, rec_f, rec_c, nl, _root, waves, qscales) = \
+            with obs.span("train.chunk", cat="boost", iteration=self.iter,
+                          chunk=chunk) as sp:
+                stalled = self._fused_chunk(chunk, lr, gargs, grad_fn, sp)
+            self._obs_chunk(sp, chunk)
+            if stalled:
+                self._trim_device_stumps()
+                return True
+            done += chunk
+            fused_ran = True
+        if fused_ran:
+            self._sync_fused_bagging()
+        return False
+
+    def _fused_chunk(self, chunk, lr, gargs, grad_fn, sp) -> bool:
+        """One fused dispatch of ``chunk`` trees inside the
+        ``train.chunk`` span ``sp``; True when the PREVIOUS chunk turned
+        out to have stalled (every tree a stump)."""
+        bias = self.boost_from_average(0) if not self.models else 0.0
+        fused = self._grower.fused_train(chunk)
+        with obs.span("chunk.enqueue", cat="boost"):
+            score, (rec_i, rec_f, rec_c, nl, _root, work, qscales) = \
                 self._dispatch_guard(lambda: fused(
                     self._grower.binned, self._grower.binned_t,
                     self.train_score[0], lr, gargs,
                     jnp.asarray(self.iter, jnp.int32), grad_fn=grad_fn))
-            if t0 is not None:
-                self._obs_chunk(t0, chunk, score)
-            self.train_score = self.train_score.at[0].set(score)
-            quant = bool(getattr(self._grower, "quant_bits", 0))
-            stack = _RecStack(rec_i, rec_f, rec_c, nl,
-                              qscales if quant else None)
-            for i in range(chunk):
-                self.models.append(_PendingChunkTree(
-                    stack, i, self.shrinkage_rate * self._tree_multiplier(),
-                    bias if i == 0 else 0.0))
-            self._wave_handles.append(waves)
-            self.iter += chunk
-            done += chunk
-            fused_ran = True
-            # lagged stall check: the PREVIOUS chunk's records have
-            # landed by now (this chunk is seconds of device work), so
-            # reading them never blocks the dispatch pipeline
-            prev, self._last_chunk_stack = self._last_chunk_stack, stack
-            if prev is not None:
-                if prev.qscales is not None and obs.enabled():
-                    # lagged fetch (the previous chunk's copies landed
-                    # long ago): record the chunk's last per-tree
-                    # quantization scales without stalling dispatch
-                    self._record_quant_scales(
-                        np.asarray(prev.qscales)[-1].tolist())
-                if (prev.host()[3] <= 1).all():
-                    self._trim_device_stumps()
-                    return True
-        if fused_ran:
-            self._sync_fused_bagging()
-        return False
+        sp.sync_value = score
+        self.train_score = self.train_score.at[0].set(score)
+        quant = bool(getattr(self._grower, "quant_bits", 0))
+        stack = _RecStack(rec_i, rec_f, rec_c, nl,
+                          qscales if quant else None)
+        for i in range(chunk):
+            self.models.append(_PendingChunkTree(
+                stack, i, self.shrinkage_rate * self._tree_multiplier(),
+                bias if i == 0 else 0.0))
+        self._push_work(nl, work)
+        self.iter += chunk
+        # lagged stall check: the PREVIOUS chunk's records have landed
+        # by now (this chunk is seconds of device work), so reading
+        # them never blocks the dispatch pipeline
+        prev, self._last_chunk_stack = self._last_chunk_stack, stack
+        if prev is None:
+            return False
+        with obs.span("chunk.stall_check", cat="boost"):
+            if prev.qscales is not None and obs.enabled():
+                # lagged fetch (the previous chunk's copies landed long
+                # ago): record the chunk's last per-tree quantization
+                # scales without stalling dispatch
+                self._record_quant_scales(
+                    np.asarray(prev.qscales)[-1].tolist())
+            return bool((prev.host()[3] <= 1).all())
+
+    def _push_work(self, nl, work) -> None:
+        """Queue one dispatch's per-tree leaf counts and ``[waves, wave
+        slots]`` for the registry (``_WorkDrain``)."""
+        g = self._grower
+        shards = g.shard.n_shards if g.shard is not None else 1
+        self._work.push(nl, work, shards * int(g.n_pad), self.num_data)
 
     def _sync_fused_bagging(self):
         """Restore the host-side bagging state to what a pure
@@ -838,23 +917,21 @@ class GBDT:
         self.bag_buffer, self.bag_count = self.learner.bagging_state(
             seed, self.bag_fraction)
 
-    def _obs_chunk(self, t0, chunk, score):
-        """Record one fused multi-iteration dispatch: a ``train.chunk``
-        span plus ``chunk`` synthetic ``train.iter`` observations (the
-        chunk mean) so iteration counts/percentiles stay comparable with
-        the per-iteration paths.  Without obs sync this times the
-        dispatch, not device execution."""
-        from ..obs.state import STATE
-        if STATE.sync:
-            jax.block_until_ready(score)
-        dt = time.perf_counter() - t0
-        STATE.registry.observe("train.chunk", dt)
-        STATE.registry.inc("train.fused_chunks")
-        STATE.registry.set_gauge("train.fused_chunk_len", chunk)
-        STATE.trace.add("train.chunk", cat="boost", t0=t0, dur=dt,
-                        args={"iteration": self.iter, "chunk": chunk})
+    @staticmethod
+    def _obs_chunk(sp, chunk):
+        """What a closed ``train.chunk`` span leaves besides itself: the
+        chunk counter and length gauge, and ``chunk`` synthetic
+        ``train.iter`` observations (the chunk mean) so iteration
+        counts/percentiles stay comparable with the per-iteration paths.
+        Without obs sync the span times the dispatch, not device
+        execution."""
+        dur = sp.dur
+        if dur is None:                  # obs disabled: the null span
+            return
+        obs.inc("train.fused_chunks")
+        obs.set_gauge("train.fused_chunk_len", chunk)
         for _ in range(chunk):
-            STATE.registry.observe("train.iter", dt / chunk)
+            obs.observe("train.iter", dur / chunk)
         obs.sample_device_memory()
 
     @staticmethod
@@ -885,6 +962,7 @@ class GBDT:
         device path trims those iterations here (not just at the lagged
         stall check) to keep predict()/save consistent with the training
         scores no matter when training stopped."""
+        self._work.drain()
         pending = [i for i, m in enumerate(self.models)
                    if isinstance(m, _Pending)]
         if pending:

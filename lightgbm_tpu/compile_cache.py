@@ -77,7 +77,8 @@ _STATE = {"dir": None, "listeners": False}
 # warmup reports and the CI zero-miss smoke must not depend on the obs
 # registry being enabled.  Mirrored into obs when telemetry is on.
 _COUNTS = {"hits": 0, "misses": 0, "requests": 0,
-           "backend_compile_s": 0.0, "time_saved_s": 0.0}
+           "backend_compile_s": 0.0, "time_saved_s": 0.0,
+           "trace_s": 0.0, "lower_s": 0.0}
 
 _EVENT_COUNTERS = {
     "/jax/compilation_cache/cache_hits": "hits",
@@ -92,6 +93,12 @@ _EVENT_COUNTERS = {
 # the coldstart test gates on THIS ratio while the TPU bench gates the
 # wall-clock one)
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# what no cache removes: JAX's own seconds tracing Python to a jaxpr and
+# lowering the jaxpr to an MLIR module, paid again by every process
+_DURATION_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+}
 
 
 def _on_event(event, **kwargs) -> None:
@@ -116,6 +123,11 @@ def _on_duration(event, duration, **kwargs) -> None:
         with _LOCK:
             _COUNTS["time_saved_s"] += saved
         obs.observe("compile_cache.time_saved", saved)
+    else:
+        key = _DURATION_COUNTERS.get(event)
+        if key is not None:
+            with _LOCK:
+                _COUNTS[key] += float(duration)
 
 
 def install_listeners() -> None:
@@ -255,8 +267,10 @@ def configure_from_config(cfg) -> Optional[str]:
 
 
 def counters() -> dict:
-    """Process-lifetime persistent-cache hit/miss/request counts
-    (independent of the obs registry, which mirrors them as
-    ``compile_cache.*`` counters while telemetry is enabled)."""
+    """Process-lifetime persistent-cache hit/miss/request counts and
+    JAX's own compile-path seconds (``trace_s``, ``lower_s``,
+    ``backend_compile_s``) — independent of the obs registry, which
+    mirrors the counts as ``compile_cache.*`` counters while telemetry
+    is enabled."""
     with _LOCK:
         return dict(_COUNTS)

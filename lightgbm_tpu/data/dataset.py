@@ -189,13 +189,18 @@ class BinnedDataset:
             ds.feature_names = list(feature_names)
 
         if reference is not None:
-            ds._align_with_reference(data, reference)
+            with obs.span("bin.apply", cat="data"):
+                ds._align_with_reference(data, reference)
             return ds
 
-        ds._find_bins(data, config, set(int(c) for c in categorical),
-                      predefined_mappers)
-        ds._bundle_features(data, config)
-        ds._build_group_matrix(data)
+        with obs.span("bin.find", cat="data"):
+            ds._find_bins(data, config,
+                          set(int(c) for c in categorical),
+                          predefined_mappers)
+        with obs.span("bin.bundle", cat="data"):
+            ds._bundle_features(data, config)
+        with obs.span("bin.apply", cat="data"):
+            ds._build_group_matrix(data)
         ds._build_feature_lookups(config)
         return ds
 
@@ -245,15 +250,19 @@ class BinnedDataset:
                    if sample_cnt < n else np.arange(n))
             sample = np.asarray(
                 jnp.take(data_dev, jnp.asarray(idx), axis=0), np.float64)
-            ds._find_bins(sample, config, set(), None, presampled=True)
-            ds._bundle_features(sample, config)
+            with obs.span("bin.find", cat="data"):
+                ds._find_bins(sample, config, set(), None,
+                              presampled=True)
+            with obs.span("bin.bundle", cat="data"):
+                ds._bundle_features(sample, config)
             ds._build_feature_lookups(config)
         if any(m.bin_type == BIN_CATEGORICAL for m in ds.bin_mappers
                if m is not None):
             raise LightGBMError(
                 "construct_from_device_matrix supports numerical "
                 "features only; use construct_from_matrix")
-        ds.binned = ds._bin_on_device(data_dev)
+        with obs.span("bin.device", cat="data") as sp:
+            ds.binned = sp.sync_value = ds._bin_on_device(data_dev)
         ds.device_binned = True
         return ds
 
@@ -261,6 +270,10 @@ class BinnedDataset:
         """(N, F) f32 device matrix -> (N, G) uint8 device matrix using
         the host-found bin mappers; bundle conflicts resolve by feature
         order (last writer wins), matching _build_group_matrix."""
+        return self._bin_program()(data_dev)
+
+    def _bin_program(self):
+        """The jitted binning program of :meth:`_bin_on_device`."""
         import jax
         import jax.numpy as jnp
         specs = []
@@ -284,25 +297,25 @@ class BinnedDataset:
 
         @jax.jit
         def build(x):
-            cols = []
-            for fspecs in specs:
-                col = jnp.zeros(x.shape[0], jnp.int32)
-                for (f, b32, num_bin, default_bin, mt, off,
-                     shift) in fspecs:
-                    v = x[:, f]
-                    nanm = jnp.isnan(v)
-                    filled = jnp.where(nanm, jnp.float32(0.0), v)
-                    b = jnp.searchsorted(jnp.asarray(b32), filled,
-                                         side="left").astype(jnp.int32)
-                    if mt == "nan":
-                        b = jnp.where(nanm, num_bin - 1, b)
-                    col = jnp.where(b != default_bin, b + off - shift,
-                                    col)
-                cols.append(col)
-            return jnp.stack(cols, axis=1).astype(jnp.uint8)
+            with jax.named_scope("lgb.bin"):
+                cols = []
+                for fspecs in specs:
+                    col = jnp.zeros(x.shape[0], jnp.int32)
+                    for (f, b32, num_bin, default_bin, mt, off,
+                         shift) in fspecs:
+                        v = x[:, f]
+                        nanm = jnp.isnan(v)
+                        filled = jnp.where(nanm, jnp.float32(0.0), v)
+                        b = jnp.searchsorted(jnp.asarray(b32), filled,
+                                             side="left").astype(jnp.int32)
+                        if mt == "nan":
+                            b = jnp.where(nanm, num_bin - 1, b)
+                        col = jnp.where(b != default_bin, b + off - shift,
+                                        col)
+                    cols.append(col)
+                return jnp.stack(cols, axis=1).astype(jnp.uint8)
 
-        build = obs.track_jit("dataset.build_binned", build)
-        return build(data_dev)
+        return obs.track_jit("dataset.build_binned", build)
 
     # -- CSR-native construction ------------------------------------------
     @classmethod
@@ -349,64 +362,70 @@ class BinnedDataset:
                     f"validation data has {num_col} features, train has "
                     f"{reference.num_total_features}")
             ds._align_with_reference_shared(reference)
-            ds._build_group_matrix_csr(col_bounds, rows_by_col, vals_by_col)
+            with obs.span("bin.apply", cat="data"):
+                ds._build_group_matrix_csr(col_bounds, rows_by_col,
+                                           vals_by_col)
             return ds
 
         # stage 1: sampled bin finding per feature (recorded = nonzero/NaN
         # values of sampled rows; zeros implicit - the same contract as the
         # reference's sparse sampling, dataset_loader.cpp:161-264)
-        sample_cnt = min(n, int(config.bin_construct_sample_cnt))
-        rng = make_rng(config.data_random_seed)
-        if sample_cnt < n:
-            sample_idx = np.sort(rng.choice(n, size=sample_cnt,
-                                            replace=False))
-        else:
-            sample_idx = np.arange(n)
-        in_sample = np.zeros(n, bool)
-        in_sample[sample_idx] = True
-        sample_pos = np.full(n, -1, np.int64)
-        sample_pos[sample_idx] = np.arange(sample_cnt)
+        with obs.span("bin.find", cat="data"):
+            sample_cnt = min(n, int(config.bin_construct_sample_cnt))
+            rng = make_rng(config.data_random_seed)
+            if sample_cnt < n:
+                sample_idx = np.sort(rng.choice(n, size=sample_cnt,
+                                                replace=False))
+            else:
+                sample_idx = np.arange(n)
+            in_sample = np.zeros(n, bool)
+            in_sample[sample_idx] = True
+            sample_pos = np.full(n, -1, np.int64)
+            sample_pos[sample_idx] = np.arange(sample_cnt)
 
-        filter_cnt = int(0.95 * config.min_data_in_leaf / max(n, 1)
-                         * sample_cnt)
-        cat = set(int(c) for c in categorical)
-        ds.bin_mappers = []
-        nz_masks: Dict[int, np.ndarray] = {}
-        nz_counts: Dict[int, int] = {}
-        for f in range(num_col):
-            s, e = col_bounds[f], col_bounds[f + 1]
-            rs = rows_by_col[s:e]
-            vs = vals_by_col[s:e]
-            keep = in_sample[rs]
-            vs_s = vs[keep]
-            rec_mask = (vs_s != 0.0) | np.isnan(vs_s)
-            recorded = vs_s[rec_mask]
-            m = BinMapper()
-            m.find_bin(recorded, sample_cnt, config.max_bin,
-                       config.min_data_in_bin, filter_cnt,
-                       BIN_CATEGORICAL if f in cat else BIN_NUMERICAL,
-                       config.use_missing, config.zero_as_missing)
-            ds.bin_mappers.append(m)
-            mask = np.zeros(sample_cnt, bool)
-            mask[sample_pos[rs[keep][rec_mask]]] = True
-            nz_masks[f] = mask
-            nz_counts[f] = int(mask.sum())
-        ds.used_features = [f for f in range(num_col)
-                            if not ds.bin_mappers[f].is_trivial]
-        if not ds.used_features:
-            log_warning("There are no meaningful features, as all feature "
-                        "values are constant.")
+            filter_cnt = int(0.95 * config.min_data_in_leaf / max(n, 1)
+                             * sample_cnt)
+            cat = set(int(c) for c in categorical)
+            ds.bin_mappers = []
+            nz_masks: Dict[int, np.ndarray] = {}
+            nz_counts: Dict[int, int] = {}
+            for f in range(num_col):
+                s, e = col_bounds[f], col_bounds[f + 1]
+                rs = rows_by_col[s:e]
+                vs = vals_by_col[s:e]
+                keep = in_sample[rs]
+                vs_s = vs[keep]
+                rec_mask = (vs_s != 0.0) | np.isnan(vs_s)
+                recorded = vs_s[rec_mask]
+                m = BinMapper()
+                m.find_bin(recorded, sample_cnt, config.max_bin,
+                           config.min_data_in_bin, filter_cnt,
+                           BIN_CATEGORICAL if f in cat else BIN_NUMERICAL,
+                           config.use_missing, config.zero_as_missing)
+                ds.bin_mappers.append(m)
+                mask = np.zeros(sample_cnt, bool)
+                mask[sample_pos[rs[keep][rec_mask]]] = True
+                nz_masks[f] = mask
+                nz_counts[f] = int(mask.sum())
+            ds.used_features = [f for f in range(num_col)
+                                if not ds.bin_mappers[f].is_trivial]
+            if not ds.used_features:
+                log_warning("There are no meaningful features, as all feature "
+                            "values are constant.")
 
         # stage 2: EFB bundling on the sampled masks
-        if not ds.used_features:
-            ds.groups = []
-        elif not config.enable_bundle or len(ds.used_features) == 1:
-            ds._set_groups([[f] for f in ds.used_features])
-        else:
-            ds._set_groups(ds._bundle_from_masks(config, nz_masks,
-                                                 nz_counts, sample_cnt))
+        with obs.span("bin.bundle", cat="data"):
+            if not ds.used_features:
+                ds.groups = []
+            elif not config.enable_bundle or len(ds.used_features) == 1:
+                ds._set_groups([[f] for f in ds.used_features])
+            else:
+                ds._set_groups(ds._bundle_from_masks(config, nz_masks,
+                                                     nz_counts, sample_cnt))
 
-        ds._build_group_matrix_csr(col_bounds, rows_by_col, vals_by_col)
+        with obs.span("bin.apply", cat="data"):
+            ds._build_group_matrix_csr(col_bounds, rows_by_col,
+                                       vals_by_col)
         ds._build_feature_lookups(config)
         return ds
 

@@ -82,8 +82,7 @@ def configure(enabled: Optional[bool] = None,
               export_interval_s: Optional[float] = None,
               http_port: Optional[int] = None,
               slo_spec=None,
-              trace_context: Optional[bool] = None,
-              profile_attribution: Optional[bool] = None) -> None:
+              trace_context: Optional[bool] = None) -> None:
     """Update the global observability state.
 
     Additive: ``None`` leaves a setting untouched, and enabling twice
@@ -99,8 +98,7 @@ def configure(enabled: Optional[bool] = None,
     ``export_interval_s`` seconds (default 5); ``slo_spec`` makes each
     flush carry a fresh SLO evaluation (docs/Observability.md).
     ``trace_context`` turns causal span propagation on/off
-    (obs/tracing.py); ``profile_attribution`` attaches XLA
-    cost-analysis FLOPs/bytes to the profile probes (obs/profile.py).
+    (obs/tracing.py).
     """
     if metrics_path:
         STATE.metrics_path = metrics_path
@@ -112,8 +110,6 @@ def configure(enabled: Optional[bool] = None,
         STATE.sync = bool(sync)
     if trace_context is not None:
         STATE.trace_context = bool(trace_context)
-    if profile_attribution is not None:
-        STATE.profile_attribution = bool(profile_attribution)
     if enabled is not None:
         was = STATE.enabled
         STATE.enabled = bool(enabled)
@@ -201,10 +197,8 @@ def configure_from_config(cfg) -> None:
     prom_path = str(getattr(cfg, "prom_path", "") or "")
     http_port = int(getattr(cfg, "obs_http_port", 0) or 0)
     trace_ctx = bool(getattr(cfg, "trace_context_enabled", False))
-    profile_attr = bool(getattr(cfg, "profile_attribution", False))
     if not (want or trace_path or metrics_path or events_path
-            or stream_path or prom_path or http_port or trace_ctx
-            or profile_attr):
+            or stream_path or prom_path or http_port or trace_ctx):
         return
     configure(enabled=True, metrics_path=metrics_path or None,
               trace_path=trace_path or None,
@@ -216,8 +210,7 @@ def configure_from_config(cfg) -> None:
               http_port=http_port if http_port > 0 else None,
               # additive like every other setting: a later window's
               # config without the flag must not disable propagation
-              trace_context=True if trace_ctx else None,
-              profile_attribution=True if profile_attr else None)
+              trace_context=True if trace_ctx else None)
 
 
 def reset() -> None:
@@ -272,6 +265,7 @@ class _NullSpan:
     reference to a (possibly multi-MB) device array."""
 
     __slots__ = ()
+    dur = None          # a real span's seconds once it has closed
 
     @property
     def sync_value(self):
@@ -294,15 +288,26 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str):
+    """The profiler-side twin of a span: a ``TraceAnnotation`` named
+    ``lgb.<name>``, which a running ``jax.profiler`` session records in
+    its ``/host:CPU`` plane on the device planes' clock (and which costs
+    one flag check when no session runs)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation("lgb." + name)
+
+
 class _Span:
-    __slots__ = ("name", "cat", "args", "t0", "sync_value",
-                 "trace_id", "span_id", "parent_id", "_ctx_token")
+    __slots__ = ("name", "cat", "args", "t0", "dur", "sync_value",
+                 "trace_id", "span_id", "parent_id", "_ctx_token",
+                 "_annotation")
 
     def __init__(self, name, cat, args):
         self.name = name
         self.cat = cat
         self.args = args
         self.sync_value = None
+        self.dur = None
         if STATE.trace_context:
             # becomes the current context for everything opened inside
             # this span on this thread (obs/tracing.py); a cross-thread
@@ -318,6 +323,8 @@ class _Span:
         else:
             self.trace_id = self.span_id = self.parent_id = None
             self._ctx_token = None
+        self._annotation = _annotation(name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
 
     def set(self, **args):
@@ -334,8 +341,13 @@ class _Span:
         if STATE.sync and self.sync_value is not None:
             import jax
             jax.block_until_ready(self.sync_value)
-        dur = time.perf_counter() - self.t0
+        dur = self.dur = time.perf_counter() - self.t0
+        self._annotation.__exit__(*exc)
         STATE.registry.observe(self.name, dur)
+        # the same seconds as counters: a counter delta between two
+        # snapshots is the one conduit every reader already has
+        STATE.registry.inc("span_s." + self.name, dur)
+        STATE.registry.inc("span_n." + self.name)
         r = STATE.rolling
         if r is not None:
             r.observe(self.name, dur)
@@ -352,7 +364,10 @@ class _Span:
 def span(name: str, cat: str = "train", **args):
     """Timed span: ``with obs.span("grow_tree", iter=k): ...``.
 
-    Records a timing observation under ``name`` and a trace event.  Set
+    Records a timing observation under ``name``, the counters
+    ``span_s.<name>`` (seconds) and ``span_n.<name>``, a trace event,
+    and — for a running ``jax.profiler`` session — a host annotation
+    ``lgb.<name>`` on the device trace's clock.  Set
     ``span.sync_value = device_array`` inside the block to make the exit
     block on the device value when sync profiling is on (honest device
     attribution; guarded so production runs never block).

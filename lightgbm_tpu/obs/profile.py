@@ -1,94 +1,20 @@
-"""Device-time attribution: XLA cost analysis, profiler traces, and the
-phase-attribution report behind ``bench.py --explain``.
+"""Capturing a device profile.
 
-Three layers, all dependency-light and failure-tolerant (every JAX
-surface here has shifted across releases, and a missing backend
-counter must degrade to ``None``, never to an exception):
-
-* :func:`cost_of` lowers + compiles a jitted callable on concrete
-  operands and normalizes ``Compiled.cost_analysis()`` into a flat
-  ``{flops, bytes_accessed, transcendentals}`` dict — the static
-  FLOPs/bytes estimate per program that turns a measured stage time
-  into an achieved-FLOPs / achieved-bandwidth number;
-* :func:`device_trace` wraps ``jax.profiler.trace`` as a context
-  manager that no-ops cleanly when the profiler is unavailable, so a
-  ``--explain`` run can drop a Perfetto-compatible device profile next
-  to the report;
-* :func:`attribution_report` folds measured wall time + per-phase
-  estimates into the report shape ``bench.py --explain`` emits: named
-  phases, their share of the measured training wall time, and the
-  coverage fraction (the acceptance bar is >= 0.9 — below that the
-  report says so instead of pretending).
-
-The per-phase *measurements* live with the probes themselves
-(``DeviceGrower.profile_stage_plan`` / ``profile_phases`` /
-``profile_psum`` in ops/grow.py); with ``profile_attribution`` on they
-attach :func:`cost_of` estimates to each probe program.
+:func:`device_trace` wraps ``jax.profiler.trace`` as a context manager
+that no-ops cleanly when the profiler is unavailable.  The trace it
+writes carries the package's ``jax.named_scope`` names (obs/scopes.py)
+on every device operation and the ``lgb.<span>`` host annotations that
+``obs.span`` opens while telemetry is on, all on one clock: open the
+directory in xprof (trace viewer, op profile), or print the per-scope
+table with ``python3 benchmark/scope_reduce.py <dir>``.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Optional
+from typing import Optional
 
-from .state import STATE
-
-__all__ = ["enabled", "normalize_cost", "cost_of", "device_trace",
-           "attribution_report"]
-
-#: cost_analysis key aliases across jax/XLA versions
-_FLOPS_KEYS = ("flops",)
-_BYTES_KEYS = ("bytes accessed", "bytes_accessed")
-_TRANS_KEYS = ("transcendentals",)
-
-
-def enabled() -> bool:
-    """True when probes should attach cost-analysis estimates."""
-    return STATE.enabled and STATE.profile_attribution
-
-
-def _pick(d: Dict, keys) -> Optional[float]:
-    for k in keys:
-        v = d.get(k)
-        if v is not None:
-            try:
-                return float(v)
-            except (TypeError, ValueError):
-                return None
-    return None
-
-
-def normalize_cost(ca) -> Optional[Dict]:
-    """Flatten a ``Compiled.cost_analysis()`` result.
-
-    Handles both historical shapes — a list with one dict per device
-    program and a plain dict — and returns ``{"flops", "bytes_accessed",
-    "transcendentals"}`` (values ``None`` when the backend does not
-    report them), or ``None`` for an empty/unusable analysis."""
-    if ca is None:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict) or not ca:
-        return None
-    return {
-        "flops": _pick(ca, _FLOPS_KEYS),
-        "bytes_accessed": _pick(ca, _BYTES_KEYS),
-        "transcendentals": _pick(ca, _TRANS_KEYS),
-    }
-
-
-def cost_of(fn, *args) -> Optional[Dict]:
-    """Static per-program cost estimate for a jitted callable on the
-    given concrete operands: lower, compile (a cache hit when the
-    program already ran), normalize the XLA cost analysis.  Returns
-    ``None`` when any step is unsupported on this backend — callers
-    treat the estimate as optional garnish, never as a gate."""
-    try:
-        lowered = fn.lower(*args)
-        return normalize_cost(lowered.compile().cost_analysis())
-    except Exception:   # noqa: BLE001 — version/backend dependent
-        return None
+__all__ = ["device_trace"]
 
 
 @contextlib.contextmanager
@@ -111,46 +37,3 @@ def device_trace(path: Optional[str]):
             yield True
     except Exception:   # noqa: BLE001
         yield False
-
-
-def attribution_report(measured_ms: float, phases_ms: Dict[str, float],
-                       costs: Optional[Dict[str, Optional[Dict]]] = None,
-                       ) -> Dict:
-    """Fold per-phase estimates into the ``--explain`` report.
-
-    ``measured_ms`` is the ground truth (the timed training region);
-    ``phases_ms`` maps phase name -> estimated ms over that same
-    region.  The report carries each phase's ms and share, the
-    unattributed residual, and ``coverage`` = attributed/measured
-    (clamped to 1.0 — probes measured hotter than the run overshoot,
-    which is misattribution of a different kind and is reported
-    verbatim in ``attributed_ratio``).  ``costs`` optionally maps phase
-    name -> :func:`cost_of` dict; phases with both a time and a FLOPs
-    estimate gain an achieved-GFLOP/s figure."""
-    measured_ms = float(measured_ms)
-    total = sum(float(v) for v in phases_ms.values())
-    phases = {}
-    for name in sorted(phases_ms, key=lambda k: -float(phases_ms[k])):
-        ms = float(phases_ms[name])
-        entry = {
-            "ms": round(ms, 3),
-            "share": round(ms / measured_ms, 4) if measured_ms > 0
-            else None,
-        }
-        cost = (costs or {}).get(name)
-        if cost:
-            entry["cost"] = {k: v for k, v in cost.items()
-                             if v is not None}
-            flops = cost.get("flops")
-            if flops and ms > 0:
-                entry["achieved_gflops"] = round(flops / (ms * 1e6), 2)
-        phases[name] = entry
-    ratio = total / measured_ms if measured_ms > 0 else 0.0
-    return {
-        "measured_ms": round(measured_ms, 3),
-        "attributed_ms": round(total, 3),
-        "attributed_ratio": round(ratio, 4),
-        "coverage": round(min(ratio, 1.0), 4),
-        "unattributed_ms": round(max(measured_ms - total, 0.0), 3),
-        "phases": phases,
-    }

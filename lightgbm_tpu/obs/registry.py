@@ -2,7 +2,8 @@
 
 The registry is the canonical store behind every number the telemetry
 subsystem emits: monotonically increasing **counters** (recompiles,
-retrain windows, dispatches), last/peak **gauges** (device memory,
+retrain windows, dispatches; also the float seconds a span adds to
+``span_s.<name>`` when it closes), last/peak **gauges** (device memory,
 profile results) and **timings** — per-name duration accumulators that
 keep total/count plus a bounded reservoir of samples so snapshots can
 report p50/p95/max without unbounded memory.
@@ -18,7 +19,8 @@ from __future__ import annotations
 import random
 import threading
 import time
-from typing import Dict, List, Optional
+import weakref
+from typing import Callable, Dict, List, Optional
 
 #: samples kept per timing name; beyond this, reservoir sampling keeps an
 #: unbiased subset (percentiles become estimates, exact below the cap)
@@ -73,16 +75,38 @@ class MetricsRegistry:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._counters: Dict[str, int] = {}
+        self._counters: Dict[str, float] = {}
         self._gauges: Dict[str, float] = {}
         self._timings: Dict[str, TimingStat] = {}
         # jit compile attribution: name -> {"compiles": n,
         # "signatures": {sig: count}} (fed by obs.jit_track)
         self._jit: Dict[str, Dict] = {}
+        # weakly held callables run at the head of snapshot(): owners of
+        # counts still on the device (GBDT's work drain) bring in what
+        # has landed, so a snapshot never trails a finished dispatch
+        self._collectors: List[weakref.ReferenceType] = []
         self.created_unix = time.time()
 
+    def add_collector(self, fn: Callable[[], None]) -> None:
+        """Call ``fn()`` before every snapshot for as long as its owner
+        lives (a bound method is held through ``WeakMethod``)."""
+        ref = (weakref.WeakMethod(fn) if hasattr(fn, "__self__")
+               else weakref.ref(fn))
+        with self._lock:
+            self._collectors.append(ref)
+
+    def _collect(self) -> None:
+        with self._lock:
+            fns = [ref() for ref in self._collectors]
+            if None in fns:                      # owners that are gone
+                self._collectors = [r for r, fn in zip(self._collectors, fns)
+                                    if fn is not None]
+        for fn in fns:
+            if fn is not None:
+                fn()
+
     # -- counters ---------------------------------------------------------
-    def inc(self, name: str, value: int = 1) -> None:
+    def inc(self, name: str, value: float = 1) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + value
 
@@ -134,6 +158,7 @@ class MetricsRegistry:
 
     # -- snapshot ---------------------------------------------------------
     def snapshot(self) -> Dict:
+        self._collect()
         with self._lock:
             return {
                 "counters": dict(self._counters),

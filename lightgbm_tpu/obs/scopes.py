@@ -1,0 +1,31 @@
+"""The names the device trace carries: every ``jax.named_scope`` of the
+package, in one tuple.
+
+A scope adds a component to the ``op_name`` of each HLO instruction
+traced inside it (``jit(scan_core)/while/body/lgb.wave_hist/dot_general``),
+which the profiler stores per instruction in the trace's event metadata
+(``tf_op``).  ``benchmark/scope_reduce.py`` gives each device event the
+innermost name of this tuple found in its path; xprof's trace viewer and
+op profile show the same names.  A scope changes metadata and nothing
+else: the compiled program, and so the model, are the same with or
+without it.
+
+Call sites write the literal (``with jax.named_scope("lgb.wave_hist")``)
+so that ``grep -rn named_scope lightgbm_tpu/`` lists them;
+``tests/test_scopes.py`` holds the literals to this tuple.
+"""
+
+SCOPES = (
+    "lgb.gradient",      # objective gradients inside the fused scan
+    "lgb.bag_draw",      # bagging row mask / feature_fraction mask draws
+    "lgb.stat_cols",     # pad/valid masking, stat columns, quantisation
+    "lgb.wave_hist",     # the wave histogram (einsum or Pallas route)
+    "lgb.hist_state",    # sibling subtraction + per-leaf histogram writes
+    "lgb.find_best",     # the gain scan over a histogram stack
+    "lgb.split_apply",   # top-k selection, leaf_id routing, record writes
+    "lgb.leaf_refit",    # quantised runs: full-precision leaf refit
+    "lgb.score_update",  # score += lr * value[leaf_id]
+    "lgb.psum",          # cross-device sums (histograms, refit sums)
+    "lgb.traverse",      # packed serving traversal
+    "lgb.bin",           # device-side binning
+)

@@ -18,7 +18,7 @@ from .registry import MetricsRegistry
 
 class ObsState:
     __slots__ = ("enabled", "sync", "trace_context",
-                 "profile_attribution", "registry", "trace", "rolling",
+                 "registry", "trace", "rolling",
                  "rolling_opt_out", "exporter", "last_slo",
                  "pending_slo_spec",
                  "metrics_path", "trace_path", "events_path",
@@ -35,9 +35,6 @@ class ObsState:
         # trace_id/span_id/parent_id and contexts flow across the
         # pipeline/serve thread boundaries; off = zero context objects
         self.trace_context = False
-        # attach XLA cost-analysis (FLOPs / bytes) to the profile
-        # probes (obs/profile.py; bench.py --explain turns it on)
-        self.profile_attribution = False
         self.registry = MetricsRegistry()
         self.trace = TraceBuffer()
         # rolling-window mirror of the registry (obs/rolling.py) —

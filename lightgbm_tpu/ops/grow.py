@@ -81,8 +81,7 @@ from .shard import (ShardSpec, local_valid_rows, shard_map_nocheck,
 from .split import (F_DEFAULT_LEFT, F_FEATURE, F_GAIN, F_IS_CAT, F_LEFT_C,
                     F_LEFT_G, F_LEFT_H, F_LEFT_OUT, F_RIGHT_C, F_RIGHT_G,
                     F_RIGHT_H, F_RIGHT_OUT, F_THRESHOLD, FeatureMeta,
-                    NEG_INF, SplitHyper, find_best_split_impl,
-                    find_best_split_quant, find_best_split_stack)
+                    NEG_INF, SplitHyper, find_best_split_stack)
 
 # rows per histogram chunk: large chunks amortize MXU ramp-up; the
 # per-chunk one-hot (CH, G, NB) bf16 stays fusable into the dot operand
@@ -397,8 +396,9 @@ class GrowerPrograms:
         accepts traced values inside the fused scan."""
         if self._ff_frac >= 1.0 or self._ff_nf <= 1:
             return jnp.ones(self._ff_nf, dtype=bool)
-        return feature_fraction_mask(self._ff_seed, tree_idx,
-                                     self._ff_nf, self._ff_k)
+        with jax.named_scope("lgb.bag_draw"):
+            return feature_fraction_mask(self._ff_seed, tree_idx,
+                                         self._ff_nf, self._ff_k)
 
     # ------------------------------------------------------------------
     # single-controller sharding hooks (ops/shard.py).  All no-ops when
@@ -441,12 +441,13 @@ class GrowerPrograms:
         sp = self.shard
         if sp is None:
             return hist
-        if hist.dtype == jnp.int32:
-            return jax.lax.psum(hist, sp.axis)
-        gh = jax.lax.psum(hist[..., :2], sp.axis)
-        cnt = jax.lax.psum(jnp.round(hist[..., 2]).astype(jnp.int32),
-                           sp.axis).astype(jnp.float32)
-        return jnp.concatenate([gh, cnt[..., None]], axis=-1)
+        with jax.named_scope("lgb.psum"):
+            if hist.dtype == jnp.int32:
+                return jax.lax.psum(hist, sp.axis)
+            gh = jax.lax.psum(hist[..., :2], sp.axis)
+            cnt = jax.lax.psum(jnp.round(hist[..., 2]).astype(jnp.int32),
+                               sp.axis).astype(jnp.float32)
+            return jnp.concatenate([gh, cnt[..., None]], axis=-1)
 
     def _quantize_sharded(self, grad, hess, qkey):
         """Sharded :func:`~.histogram.quantize_gh`: the per-tree global
@@ -473,6 +474,18 @@ class GrowerPrograms:
     # wave histogram: one dense pass for up to W pending leaves
     # ------------------------------------------------------------------
     def _wave_hist(self, binned, leaf_id, ghk, pending, scales=None):
+        """The wave histogram of :meth:`_wave_hist_local`, summed over
+        the mesh when sharded."""
+        with jax.named_scope("lgb.wave_hist"):
+            hist = self._wave_hist_local(binned, leaf_id, ghk, pending,
+                                         scales)
+        # sharded: psum the combined per-shard histograms — the growth
+        # loop's sole cross-device sync (docs/Sharding.md); everything
+        # downstream (find-best, totals, root stats) then runs on
+        # replicated global values
+        return self._psum_hist(hist)
+
+    def _wave_hist_local(self, binned, leaf_id, ghk, pending, scales):
         """(n_pad,) leaf ids, (n_pad, K) stat columns (bf16 — K=3:
         [g,h,1]; K=5: [g_hi,g_lo,h_hi,h_lo,1] — or int8 under
         grad_quant_bits), (W,) pending leaf ids (-1 = empty slot)
@@ -568,12 +581,7 @@ class GrowerPrograms:
                              axis=-1)
         else:
             hist = _combine_hist_cols(acc, k)                    # (G,NB,W,3)
-        # sharded: psum the combined per-shard histograms — the growth
-        # loop's sole cross-device sync (docs/Sharding.md); everything
-        # downstream (find-best, totals, root stats) then runs on
-        # replicated global values
-        return self._psum_hist(
-            hist.transpose(2, 0, 1, 3).reshape(w, self.num_slots, 3))
+        return hist.transpose(2, 0, 1, 3).reshape(w, self.num_slots, 3)
 
     # ------------------------------------------------------------------
     def _stat_columns(self, grad, hess, one_f, tree_idx):
@@ -644,7 +652,8 @@ class GrowerPrograms:
                    *, with_mask):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
-        i32, root_value f32, num_waves i32, quant_scales (2,) f32).
+        i32, root_value f32, work (2,) i32 = [waves run, sum of their
+        stage widths], quant_scales (2,) f32).
         ``lr`` is traced so callbacks may reset the learning rate without
         recompiling; ``tree_idx`` is the global tree index keying the
         quantization rounding noise (unused when grad_quant_bits=0).
@@ -663,25 +672,26 @@ class GrowerPrograms:
         n = self.n_pad
         npad_rows = n - self.num_data
 
-        grad = jnp.pad(grad, (0, npad_rows))
-        hess = jnp.pad(hess, (0, npad_rows))
-        valid_f = jnp.where(jnp.arange(n) < num_valid, 1.0, 0.0)
-        # bucket-pad rows may carry garbage gradients (the fused path's
-        # grad_fn computes them from padded scores/labels): zero them
-        # BEFORE quantization scales / stat columns see them.  For real
-        # rows this is an exact f32 no-op (x * 1.0 == x bitwise), which
-        # keeps the bucketed and unbucketed paths byte-identical.
-        grad = grad * valid_f
-        hess = hess * valid_f
-        one_f = valid_f
-        if with_mask:
-            # bagging/GOSS: 0/1 in-bag indicator. Out-of-bag rows drop out
-            # of histograms and counts (their grad/hess are already zeroed
-            # by the caller) but still get leaf-routed, so the score
-            # update reaches them - the reference's OOB traversal update
-            # (gbdt.cpp:451-471) falls out for free.
-            one_f = one_f * jnp.pad(row_mask, (0, npad_rows))
-        gh5, qscales = self._stat_columns(grad, hess, one_f, tree_idx)
+        with jax.named_scope("lgb.stat_cols"):
+            grad = jnp.pad(grad, (0, npad_rows))
+            hess = jnp.pad(hess, (0, npad_rows))
+            valid_f = jnp.where(jnp.arange(n) < num_valid, 1.0, 0.0)
+            # bucket-pad rows may carry garbage gradients (the fused path's
+            # grad_fn computes them from padded scores/labels): zero them
+            # BEFORE quantization scales / stat columns see them.  For real
+            # rows this is an exact f32 no-op (x * 1.0 == x bitwise), which
+            # keeps the bucketed and unbucketed paths byte-identical.
+            grad = grad * valid_f
+            hess = hess * valid_f
+            one_f = valid_f
+            if with_mask:
+                # bagging/GOSS: 0/1 in-bag indicator. Out-of-bag rows drop out
+                # of histograms and counts (their grad/hess are already zeroed
+                # by the caller) but still get leaf-routed, so the score
+                # update reaches them - the reference's OOB traversal update
+                # (gbdt.cpp:451-471) falls out for free.
+                one_f = one_f * jnp.pad(row_mask, (0, npad_rows))
+            gh5, qscales = self._stat_columns(grad, hess, one_f, tree_idx)
         wave_scales = qscales if self.quant_bits else None
         # int32 scan (grad_quant_bits=8 below INT32_SCAN_ROWS): the
         # per-leaf hist/total state stays in quantized integer units —
@@ -706,7 +716,8 @@ class GrowerPrograms:
             #                             of the best split (int scan;
             #                             (1, 3) dummy otherwise)
             nl: jnp.ndarray             # i32 leaves so far
-            waves: jnp.ndarray          # i32 wave count (profiling)
+            waves: jnp.ndarray          # i32 wave count
+            slots: jnp.ndarray          # i32 sum of wave widths run
             done: jnp.ndarray           # bool
             rec_i: jnp.ndarray          # (L, 5) i32   (last row = junk)
             rec_f: jnp.ndarray          # (L, 9) f32   (last row = junk)
@@ -732,6 +743,7 @@ class GrowerPrograms:
                             jnp.int32),
             nl=jnp.asarray(1, jnp.int32),
             waves=jnp.asarray(0, jnp.int32),
+            slots=jnp.asarray(0, jnp.int32),
             done=jnp.asarray(False),
             rec_i=jnp.full((L, REC_I_FIELDS), -1, jnp.int32),
             rec_f=jnp.zeros((L, REC_F_FIELDS), jnp.float32),
@@ -778,220 +790,224 @@ class GrowerPrograms:
             # 1. fresh histograms for pending smaller children
             fresh = self._wave_hist(binned, st.leaf_id, gh5,
                                     st.p_small, wave_scales)  # (W,S,3)
-            root_wave = st.p_parent[0] < 0
-            # root total from group-0 slot sums (every row hits one slot)
-            root_total = fresh[0, :self.nb, :].sum(0)
-            total = jnp.where(
-                root_wave & (st.p_small[0] == 0),
-                st.total.at[0].set(root_total), st.total)
-            # 2. larger sibling = parent - smaller (parent hist still lives
-            # at the parent's slot; smaller may reuse that slot, so read
-            # parents BEFORE writing fresh)
-            par = jnp.where(st.p_parent >= 0, st.p_parent, L)
-            large = st.hist[par] - fresh                          # (W,S,3)
-            sm_ok = st.p_small >= 0
-            lg_ok = st.p_large >= 0
-            sm_idx = jnp.where(sm_ok, st.p_small, L)
-            lg_idx = jnp.where(lg_ok, st.p_large, L)
-            hist = st.hist.at[sm_idx].set(
-                jnp.where(sm_ok[:, None, None], fresh, st.hist[sm_idx]))
-            hist = hist.at[lg_idx].set(
-                jnp.where(lg_ok[:, None, None], large, hist[lg_idx]))
-            # root value (stump case + records); int scan: the root
-            # totals are quantized units, dequantize for the output
-            if int_scan:
-                rt_g = total[0, 0].astype(jnp.float32) * qscales[0]
-                rt_h = total[0, 1].astype(jnp.float32) * qscales[1]
-            else:
-                rt_g, rt_h = total[0, 0], total[0, 1]
-            value = jnp.where(
-                root_wave,
-                st.value.at[0].set(self._leaf_output(rt_g, rt_h, hyper)),
-                st.value)
+            with jax.named_scope("lgb.hist_state"):
+                root_wave = st.p_parent[0] < 0
+                # root total from group-0 slot sums (every row hits one slot)
+                root_total = fresh[0, :self.nb, :].sum(0)
+                total = jnp.where(
+                    root_wave & (st.p_small[0] == 0),
+                    st.total.at[0].set(root_total), st.total)
+                # 2. larger sibling = parent - smaller (parent hist still lives
+                # at the parent's slot; smaller may reuse that slot, so read
+                # parents BEFORE writing fresh)
+                par = jnp.where(st.p_parent >= 0, st.p_parent, L)
+                large = st.hist[par] - fresh                          # (W,S,3)
+                sm_ok = st.p_small >= 0
+                lg_ok = st.p_large >= 0
+                sm_idx = jnp.where(sm_ok, st.p_small, L)
+                lg_idx = jnp.where(lg_ok, st.p_large, L)
+                hist = st.hist.at[sm_idx].set(
+                    jnp.where(sm_ok[:, None, None], fresh, st.hist[sm_idx]))
+                hist = hist.at[lg_idx].set(
+                    jnp.where(lg_ok[:, None, None], large, hist[lg_idx]))
+                # root value (stump case + records); int scan: the root
+                # totals are quantized units, dequantize for the output
+                if int_scan:
+                    rt_g = total[0, 0].astype(jnp.float32) * qscales[0]
+                    rt_h = total[0, 1].astype(jnp.float32) * qscales[1]
+                else:
+                    rt_g, rt_h = total[0, 0], total[0, 1]
+                value = jnp.where(
+                    root_wave,
+                    st.value.at[0].set(self._leaf_output(rt_g, rt_h, hyper)),
+                    st.value)
 
             # 3. find-best for the new leaves (both siblings); reuse the
             # fresh/large buffers rather than re-gathering from hist
-            ids_s = jnp.where(sm_ok, st.p_small, -1)
-            ids_l = jnp.where(lg_ok, st.p_large, -1)
-            ids = jnp.concatenate([ids_s, ids_l])
-            idc = jnp.clip(ids, 0, L - 1)
-            if fused_find:
-                # fused find-best-in-wave: the gain scan consumes the
-                # fresh histogram product and the parent-minus-sibling
-                # residual IN PLACE — no (2*Ws, S, 3) concatenated
-                # tensor materializes between the contraction and the
-                # scan, so XLA fuses the hist+find of a wave into one
-                # program region and only the packed winner records
-                # (and the residual scattered into the leaf state)
-                # survive it.  vmap is per-lane, so each half is
-                # bitwise the rows the concatenated scan would produce
-                # (tests/test_fused_find.py pins this per regime).
-                ics, icl = idc[:Ws], idc[Ws:]
-                pk_s, cm_s, li_s = evaluate(fresh, total[ics], ids_s,
-                                            st.depth[ics], feature_mask)
-                pk_l, cm_l, li_l = evaluate(large, total[icl], ids_l,
-                                            st.depth[icl], feature_mask)
-                packed = jnp.concatenate([pk_s, pk_l])
-                catm = jnp.concatenate([cm_s, cm_l])
-                lint = jnp.concatenate([li_s, li_l]) if int_scan \
-                    else None
-            else:
-                # two-pass layout: one concatenated (2*Ws, S, 3) stack
-                # scanned by a single second pass
-                hists2 = jnp.concatenate([fresh, large])
-                packed, catm, lint = evaluate(hists2, total[idc], ids,
-                                              st.depth[idc],
-                                              feature_mask)
-            safe = jnp.where(ids >= 0, ids, L)
-            best = st.best.at[safe].set(
-                jnp.where((ids >= 0)[:, None], packed, st.best[safe]))
-            bestc = st.bestc.at[safe].set(
-                jnp.where((ids >= 0)[:, None], catm, st.bestc[safe]))
-            if int_scan:
-                bestl = st.bestl.at[safe].set(
-                    jnp.where((ids >= 0)[:, None], lint, st.bestl[safe]))
-            else:
-                bestl = st.bestl
+            with jax.named_scope("lgb.find_best"):
+                ids_s = jnp.where(sm_ok, st.p_small, -1)
+                ids_l = jnp.where(lg_ok, st.p_large, -1)
+                ids = jnp.concatenate([ids_s, ids_l])
+                idc = jnp.clip(ids, 0, L - 1)
+                if fused_find:
+                    # fused find-best-in-wave: the gain scan consumes the
+                    # fresh histogram product and the parent-minus-sibling
+                    # residual IN PLACE — no (2*Ws, S, 3) concatenated
+                    # tensor materializes between the contraction and the
+                    # scan, so XLA fuses the hist+find of a wave into one
+                    # program region and only the packed winner records
+                    # (and the residual scattered into the leaf state)
+                    # survive it.  vmap is per-lane, so each half is
+                    # bitwise the rows the concatenated scan would produce
+                    # (tests/test_fused_find.py pins this per regime).
+                    ics, icl = idc[:Ws], idc[Ws:]
+                    pk_s, cm_s, li_s = evaluate(fresh, total[ics], ids_s,
+                                                st.depth[ics], feature_mask)
+                    pk_l, cm_l, li_l = evaluate(large, total[icl], ids_l,
+                                                st.depth[icl], feature_mask)
+                    packed = jnp.concatenate([pk_s, pk_l])
+                    catm = jnp.concatenate([cm_s, cm_l])
+                    lint = jnp.concatenate([li_s, li_l]) if int_scan \
+                        else None
+                else:
+                    # two-pass layout: one concatenated (2*Ws, S, 3) stack
+                    # scanned by a single second pass
+                    hists2 = jnp.concatenate([fresh, large])
+                    packed, catm, lint = evaluate(hists2, total[idc], ids,
+                                                  st.depth[idc],
+                                                  feature_mask)
+                safe = jnp.where(ids >= 0, ids, L)
+                best = st.best.at[safe].set(
+                    jnp.where((ids >= 0)[:, None], packed, st.best[safe]))
+                bestc = st.bestc.at[safe].set(
+                    jnp.where((ids >= 0)[:, None], catm, st.bestc[safe]))
+                if int_scan:
+                    bestl = st.bestl.at[safe].set(
+                        jnp.where((ids >= 0)[:, None], lint, st.bestl[safe]))
+                else:
+                    bestl = st.bestl
 
-            # 4. select up to Ws best-gain splits within budget
-            gains = best[:L, F_GAIN]
-            top_vals, top_idx = jax.lax.top_k(gains, Ws)
-            budget = (L - st.nl).astype(jnp.int32)
-            sel = (top_vals > 0.0) & (jnp.arange(Ws) < budget)
-            napply = sel.sum().astype(jnp.int32)
-            rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
+            with jax.named_scope("lgb.split_apply"):
+                # 4. select up to Ws best-gain splits within budget
+                gains = best[:L, F_GAIN]
+                top_vals, top_idx = jax.lax.top_k(gains, Ws)
+                budget = (L - st.nl).astype(jnp.int32)
+                sel = (top_vals > 0.0) & (jnp.arange(Ws) < budget)
+                napply = sel.sum().astype(jnp.int32)
+                rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
 
-            # 5. apply all selected splits at once.  Selected leaves are
-            # distinct (top_k) and so are the new right ids, so scatters
-            # can't collide; invalid lanes are routed to the junk rows.
-            lsel = top_idx.astype(jnp.int32)                  # (W,)
-            vecs = best[lsel]                                 # (W,13)
-            r_ids = st.nl + rank                              # (W,)
-            f = vecs[:, F_FEATURE].astype(jnp.int32)
-            thr = vecs[:, F_THRESHOLD].astype(jnp.int32)
-            dl = vecs[:, F_DEFAULT_LEFT] > 0.5
-            grp = tables.group[f]
-            off = tables.offset[f]
-            wid = tables.width[f]
-            db = meta.default_bin[f]
-            nbin = meta.num_bin[f]
-            miss = meta.missing[f]
-            def_left = jnp.where(miss == 1, dl, db <= thr)    # (W,)
+                # 5. apply all selected splits at once.  Selected leaves are
+                # distinct (top_k) and so are the new right ids, so scatters
+                # can't collide; invalid lanes are routed to the junk rows.
+                lsel = top_idx.astype(jnp.int32)                  # (W,)
+                vecs = best[lsel]                                 # (W,13)
+                r_ids = st.nl + rank                              # (W,)
+                f = vecs[:, F_FEATURE].astype(jnp.int32)
+                thr = vecs[:, F_THRESHOLD].astype(jnp.int32)
+                dl = vecs[:, F_DEFAULT_LEFT] > 0.5
+                grp = tables.group[f]
+                off = tables.offset[f]
+                wid = tables.width[f]
+                db = meta.default_bin[f]
+                nbin = meta.num_bin[f]
+                miss = meta.missing[f]
+                def_left = jnp.where(miss == 1, dl, db <= thr)    # (W,)
 
-            # leaf_id update: ONE fused vectorized pass over the W
-            # selected feature rows of the contiguous (G, N) matrix
-            # (replaces r3's W-times-unrolled dynamic-slice loop, which
-            # re-read leaf_id and re-wrote the update vector per split).
-            # Masks are disjoint (a row belongs to at most one selected
-            # leaf), so the masked deltas sum without collisions.  All
-            # values are group-local bins (< nb <= 256), so the whole
-            # (W, N) chain runs in int16 — at W=128 the materialized
-            # intermediates drop from ~5.4 GB to ~2.7 GB of HBM traffic.
-            i16 = lambda a: a.astype(jnp.int16)
-            cols = i16(jnp.take(binned_t, grp, axis=0))           # (W,N)
-            off16, wid16 = i16(off)[:, None], i16(wid)[:, None]
-            db16, nbin16 = i16(db)[:, None], i16(nbin)[:, None]
-            thr16 = i16(thr)[:, None]
-            shift = jnp.where(db16 == 0, jnp.int16(1), jnp.int16(0))
-            in_range = (cols >= off16) & (cols < off16 + wid16)
-            bin_ = jnp.where(in_range, cols - off16 + shift, db16)
-            is_default = bin_ == db16
-            is_na = (miss[:, None] == 2) & (bin_ == nbin16 - 1)
-            goes_left = jnp.where(is_default, def_left[:, None],
-                                  jnp.where(is_na, dl[:, None],
-                                            bin_ <= thr16))
-            if has_cat:
-                # categorical routing: left iff the decoded bin is in the
-                # winning category set (partition.py:49 semantics); the
-                # (W,256) membership is packed into 8 x i32 words and the
-                # per-row word picked with an 8-way select chain (a
-                # table gather here measured far slower on TPU)
-                cm = bestc[jnp.clip(lsel, 0, L)]            # (W, 256)
-                cmw = jnp.sum(
-                    cm.reshape(Ws, 8, 32).astype(jnp.int32)
-                    << jnp.arange(32, dtype=jnp.int32)[None, None, :],
-                    axis=-1)                                # (W, 8)
-                binc = bin_.astype(jnp.int32)   # 32-bit word arithmetic
-                widx = binc >> 5
-                bit = binc & 31
-                wv = jnp.zeros_like(binc)
-                for j in range(8):
-                    wv = wv + jnp.where(widx == j, cmw[:, j:j + 1], 0)
-                left_cat = ((wv >> bit) & 1) == 1
-                is_cat_w = vecs[:, F_IS_CAT] > 0.5
-                goes_left = jnp.where(is_cat_w[:, None], left_cat,
-                                      goes_left)
-            mask = (sel[:, None] & (st.leaf_id[None, :] == lsel[:, None])
-                    & ~goes_left)
-            upd = jnp.sum(mask * (r_ids - lsel)[:, None], axis=0,
-                          dtype=jnp.int32)
-            leaf_id = st.leaf_id + upd
+                # leaf_id update: ONE fused vectorized pass over the W
+                # selected feature rows of the contiguous (G, N) matrix
+                # (replaces r3's W-times-unrolled dynamic-slice loop, which
+                # re-read leaf_id and re-wrote the update vector per split).
+                # Masks are disjoint (a row belongs to at most one selected
+                # leaf), so the masked deltas sum without collisions.  All
+                # values are group-local bins (< nb <= 256), so the whole
+                # (W, N) chain runs in int16 — at W=128 the materialized
+                # intermediates drop from ~5.4 GB to ~2.7 GB of HBM traffic.
+                i16 = lambda a: a.astype(jnp.int16)
+                cols = i16(jnp.take(binned_t, grp, axis=0))           # (W,N)
+                off16, wid16 = i16(off)[:, None], i16(wid)[:, None]
+                db16, nbin16 = i16(db)[:, None], i16(nbin)[:, None]
+                thr16 = i16(thr)[:, None]
+                shift = jnp.where(db16 == 0, jnp.int16(1), jnp.int16(0))
+                in_range = (cols >= off16) & (cols < off16 + wid16)
+                bin_ = jnp.where(in_range, cols - off16 + shift, db16)
+                is_default = bin_ == db16
+                is_na = (miss[:, None] == 2) & (bin_ == nbin16 - 1)
+                goes_left = jnp.where(is_default, def_left[:, None],
+                                      jnp.where(is_na, dl[:, None],
+                                                bin_ <= thr16))
+                if has_cat:
+                    # categorical routing: left iff the decoded bin is in the
+                    # winning category set (partition.py:49 semantics); the
+                    # (W,256) membership is packed into 8 x i32 words and the
+                    # per-row word picked with an 8-way select chain (a
+                    # table gather here measured far slower on TPU)
+                    cm = bestc[jnp.clip(lsel, 0, L)]            # (W, 256)
+                    cmw = jnp.sum(
+                        cm.reshape(Ws, 8, 32).astype(jnp.int32)
+                        << jnp.arange(32, dtype=jnp.int32)[None, None, :],
+                        axis=-1)                                # (W, 8)
+                    binc = bin_.astype(jnp.int32)   # 32-bit word arithmetic
+                    widx = binc >> 5
+                    bit = binc & 31
+                    wv = jnp.zeros_like(binc)
+                    for j in range(8):
+                        wv = wv + jnp.where(widx == j, cmw[:, j:j + 1], 0)
+                    left_cat = ((wv >> bit) & 1) == 1
+                    is_cat_w = vecs[:, F_IS_CAT] > 0.5
+                    goes_left = jnp.where(is_cat_w[:, None], left_cat,
+                                          goes_left)
+                mask = (sel[:, None] & (st.leaf_id[None, :] == lsel[:, None])
+                        & ~goes_left)
+                upd = jnp.sum(mask * (r_ids - lsel)[:, None], axis=0,
+                              dtype=jnp.int32)
+                leaf_id = st.leaf_id + upd
 
-            # bookkeeping (vectorized scatters into the L-padded arrays)
-            safe_l = jnp.where(sel, lsel, L)
-            safe_r = jnp.where(sel, r_ids, L)
-            if int_scan:
-                # exact integer child totals: the winner's left sums
-                # come straight from the scan (bestl) and the right
-                # child is the parent total minus them — both in
-                # quantized units, both exact (read the parent BEFORE
-                # the scatter overwrites its slot)
-                lsum = bestl[jnp.clip(lsel, 0, L)]
-                rsum = total[jnp.clip(lsel, 0, L)] - lsum
-            else:
-                lsum = vecs[:, jnp.asarray([F_LEFT_G, F_LEFT_H,
-                                            F_LEFT_C])]
-                rsum = vecs[:, jnp.asarray([F_RIGHT_G, F_RIGHT_H,
-                                            F_RIGHT_C])]
-            total = total.at[safe_l].set(
-                jnp.where(sel[:, None], lsum, total[safe_l]))
-            total = total.at[safe_r].set(
-                jnp.where(sel[:, None], rsum, total[safe_r]))
-            value = value.at[safe_l].set(
-                jnp.where(sel, vecs[:, F_LEFT_OUT], value[safe_l]))
-            value = value.at[safe_r].set(
-                jnp.where(sel, vecs[:, F_RIGHT_OUT], value[safe_r]))
-            child_d = st.depth[jnp.clip(lsel, 0, L)] + 1
-            depth = st.depth.at[safe_l].set(
-                jnp.where(sel, child_d, st.depth[safe_l]))
-            depth = depth.at[safe_r].set(
-                jnp.where(sel, child_d, depth[safe_r]))
-            best = best.at[safe_l].set(
-                jnp.where(sel[:, None], neg[0][None, :], best[safe_l]))
-            best = best.at[safe_r].set(
-                jnp.where(sel[:, None], neg[0][None, :], best[safe_r]))
-            # split records (rows are padded by one junk row at index L-1)
-            ridx = jnp.where(sel, st.nl - 1 + rank, L - 1)
-            new_ri = jnp.stack([lsel, r_ids, f, thr,
-                                dl.astype(jnp.int32)], axis=1)
-            new_rf = jnp.stack(
-                [vecs[:, F_GAIN], vecs[:, F_LEFT_G], vecs[:, F_LEFT_H],
-                 vecs[:, F_LEFT_C], vecs[:, F_RIGHT_G], vecs[:, F_RIGHT_H],
-                 vecs[:, F_RIGHT_C], vecs[:, F_LEFT_OUT],
-                 vecs[:, F_RIGHT_OUT]], axis=1)
-            rec_i = st.rec_i.at[ridx].set(
-                jnp.where(sel[:, None], new_ri, st.rec_i[ridx]))
-            rec_f = st.rec_f.at[ridx].set(
-                jnp.where(sel[:, None], new_rf, st.rec_f[ridx]))
-            if has_cat:
-                rec_c = st.rec_c.at[ridx].set(
-                    jnp.where(sel[:, None], cmw, st.rec_c[ridx]))
-            else:
-                rec_c = st.rec_c
-            # pending for the next wave (int scan: exact integer counts
-            # decide the smaller sibling — f32 counts round past 2^24)
-            if int_scan:
-                small_left = lsum[:, 2] <= rsum[:, 2]
-            else:
-                small_left = vecs[:, F_LEFT_C] <= vecs[:, F_RIGHT_C]
-            pp = jnp.where(sel, lsel, -1)
-            ps = jnp.where(sel, jnp.where(small_left, lsel, r_ids), -1)
-            pl = jnp.where(sel, jnp.where(small_left, r_ids, lsel), -1)
+                # bookkeeping (vectorized scatters into the L-padded arrays)
+                safe_l = jnp.where(sel, lsel, L)
+                safe_r = jnp.where(sel, r_ids, L)
+                if int_scan:
+                    # exact integer child totals: the winner's left sums
+                    # come straight from the scan (bestl) and the right
+                    # child is the parent total minus them — both in
+                    # quantized units, both exact (read the parent BEFORE
+                    # the scatter overwrites its slot)
+                    lsum = bestl[jnp.clip(lsel, 0, L)]
+                    rsum = total[jnp.clip(lsel, 0, L)] - lsum
+                else:
+                    lsum = vecs[:, jnp.asarray([F_LEFT_G, F_LEFT_H,
+                                                F_LEFT_C])]
+                    rsum = vecs[:, jnp.asarray([F_RIGHT_G, F_RIGHT_H,
+                                                F_RIGHT_C])]
+                total = total.at[safe_l].set(
+                    jnp.where(sel[:, None], lsum, total[safe_l]))
+                total = total.at[safe_r].set(
+                    jnp.where(sel[:, None], rsum, total[safe_r]))
+                value = value.at[safe_l].set(
+                    jnp.where(sel, vecs[:, F_LEFT_OUT], value[safe_l]))
+                value = value.at[safe_r].set(
+                    jnp.where(sel, vecs[:, F_RIGHT_OUT], value[safe_r]))
+                child_d = st.depth[jnp.clip(lsel, 0, L)] + 1
+                depth = st.depth.at[safe_l].set(
+                    jnp.where(sel, child_d, st.depth[safe_l]))
+                depth = depth.at[safe_r].set(
+                    jnp.where(sel, child_d, depth[safe_r]))
+                best = best.at[safe_l].set(
+                    jnp.where(sel[:, None], neg[0][None, :], best[safe_l]))
+                best = best.at[safe_r].set(
+                    jnp.where(sel[:, None], neg[0][None, :], best[safe_r]))
+                # split records (rows are padded by one junk row at index L-1)
+                ridx = jnp.where(sel, st.nl - 1 + rank, L - 1)
+                new_ri = jnp.stack([lsel, r_ids, f, thr,
+                                    dl.astype(jnp.int32)], axis=1)
+                new_rf = jnp.stack(
+                    [vecs[:, F_GAIN], vecs[:, F_LEFT_G], vecs[:, F_LEFT_H],
+                     vecs[:, F_LEFT_C], vecs[:, F_RIGHT_G], vecs[:, F_RIGHT_H],
+                     vecs[:, F_RIGHT_C], vecs[:, F_LEFT_OUT],
+                     vecs[:, F_RIGHT_OUT]], axis=1)
+                rec_i = st.rec_i.at[ridx].set(
+                    jnp.where(sel[:, None], new_ri, st.rec_i[ridx]))
+                rec_f = st.rec_f.at[ridx].set(
+                    jnp.where(sel[:, None], new_rf, st.rec_f[ridx]))
+                if has_cat:
+                    rec_c = st.rec_c.at[ridx].set(
+                        jnp.where(sel[:, None], cmw, st.rec_c[ridx]))
+                else:
+                    rec_c = st.rec_c
+                # pending for the next wave (int scan: exact integer counts
+                # decide the smaller sibling — f32 counts round past 2^24)
+                if int_scan:
+                    small_left = lsum[:, 2] <= rsum[:, 2]
+                else:
+                    small_left = vecs[:, F_LEFT_C] <= vecs[:, F_RIGHT_C]
+                pp = jnp.where(sel, lsel, -1)
+                ps = jnp.where(sel, jnp.where(small_left, lsel, r_ids), -1)
+                pl = jnp.where(sel, jnp.where(small_left, r_ids, lsel), -1)
 
             return _S(leaf_id=leaf_id, hist=hist, total=total, value=value,
                       depth=depth, best=best, bestc=bestc, bestl=bestl,
                       nl=st.nl + napply,
-                      waves=st.waves + 1, done=napply == 0,
+                      waves=st.waves + 1, slots=st.slots + Ws,
+                      done=napply == 0,
                       rec_i=rec_i, rec_f=rec_f, rec_c=rec_c,
                       p_parent=pp, p_small=ps, p_large=pl)
           return wave
@@ -1027,90 +1043,93 @@ class GrowerPrograms:
         rec_f_out = final.rec_f
 
         if self.quant_bits:
-            # full-precision leaf-value REFIT (Shi et al. §4.3): tree
-            # STRUCTURE came from quantized histograms, but each final
-            # leaf's value is recomputed from the full-precision
-            # gradients, then written back into the split records so
-            # host-materialized trees match the device score update.
-            if int_scan:
-                # exact integer refit: each masked gradient is split
-                # into THREE base-128 int8 digits against the (global)
-                # quantization scale — deterministic round-to-nearest,
-                # no noise — and the per-leaf digit sums accumulate
-                # int8->int32 on the MXU.  Per-row representation error
-                # is <= scale/2^15 ~ max|g| * 2^-22 (f32-class), the
-                # SUMS are bit-exact in any order — which is what keeps
-                # sharded leaf values byte-identical to single-device
-                # (an f32 contraction's accumulation order would not
-                # survive the psum split).  |digit sums| <= 127 * rows
-                # stays in int32 under the same INT32_SCAN_ROWS gate as
-                # the histograms.
-                def _digits(x, s):
-                    cols = []
-                    r, sd = x, s
-                    for _ in range(3):
-                        d = jnp.clip(jnp.round(r / sd), -QUANT_MAX,
-                                     QUANT_MAX)
-                        r = r - d * sd
-                        cols.append(d.astype(jnp.int8))
-                        sd = sd / 128.0
-                    return cols
-                dcols = jnp.stack(_digits(grad * one_f, qscales[0])
-                                  + _digits(hess * one_f, qscales[1]), 1)
-                oh8 = jax.nn.one_hot(leaf_final, L, dtype=jnp.int8)
-                sums6 = jnp.einsum("nl,nk->lk", oh8, dcols,
-                                   preferred_element_type=jnp.int32)
-                if self.shard is not None:
-                    sums6 = jax.lax.psum(sums6, self.shard.axis)
-                f32 = lambda a: a.astype(jnp.float32)
-                gsum = (f32(sums6[:, 0]) + f32(sums6[:, 1]) * (1 / 128.0)
-                        + f32(sums6[:, 2]) * (1 / 16384.0)) * qscales[0]
-                hsum = (f32(sums6[:, 3]) + f32(sums6[:, 4]) * (1 / 128.0)
-                        + f32(sums6[:, 5]) * (1 / 16384.0)) * qscales[1]
-                refit = self._leaf_output(gsum, hsum, hyper)
-            else:
-                # f32 fallback regime: hi/lo-bf16 one-hot contraction
-                # (same cost class as the score update); sharded, the
-                # per-shard partial sums psum in f32 — deterministic,
-                # though not bitwise equal to single-device order (no
-                # byte-identity contract past the int32 bound)
-                one_b = one_f.astype(jnp.bfloat16)
-                cols4 = jnp.stack(_hi_lo_cols(grad, hess, one_b), 1)
-                ohl = jax.nn.one_hot(leaf_final, L, dtype=jnp.bfloat16)
-                sums = jnp.einsum("nl,nk->lk", ohl, cols4,
-                                  preferred_element_type=jnp.float32)
-                if self.shard is not None:
-                    sums = jax.lax.psum(sums, self.shard.axis)
-                refit = self._leaf_output(sums[:, 0] + sums[:, 1],
-                                          sums[:, 2] + sums[:, 3], hyper)
-            exists = jnp.arange(L, dtype=jnp.int32) < final.nl
-            # each final leaf's value lives in its CREATING record (the
-            # last record mentioning the leaf id: left children keep the
-            # parent's id, right ids are fresh); segment-max over the
-            # record index finds it without a host loop
-            recs_r = jnp.arange(L, dtype=jnp.int32)
-            lid, rid = final.rec_i[:, 0], final.rec_i[:, 1]
-            base = jnp.full((L + 1,), -1, jnp.int32)
-            last_l = base.at[jnp.where(lid >= 0, lid, L)].max(recs_r)
-            last_r = base.at[jnp.where(rid >= 0, rid, L)].max(recs_r)
-            crec = jnp.maximum(last_l[:L], last_r[:L])
-            is_left = last_l[:L] >= last_r[:L]
-            do = exists & (crec >= 0)
-            if self.has_cat:
-                # leaves created by a categorical split keep their
-                # growth value: sorted-mode cat splits regularize with
-                # lambda_l2 + cat_l2 (split.py pack_best use_l2), which
-                # the plain-lambda_l2 refit formula would drop —
-                # under-regularizing exactly those leaves
-                cfeat = final.rec_i[jnp.where(do, crec, 0), 2]
-                from_cat = do & (meta.is_cat[jnp.clip(cfeat, 0, None)]
-                                 == 1)
-                refit = jnp.where(from_cat, final.value[:L], refit)
-            leaf_vals = jnp.where(exists, refit, 0.0)
-            rows = jnp.where(do, crec, L - 1)        # junk record row
-            cols_i = jnp.where(is_left, REC_F_LEFT_OUT, REC_F_RIGHT_OUT)
-            rec_f_out = rec_f_out.at[rows, cols_i].set(
-                jnp.where(do, leaf_vals, rec_f_out[rows, cols_i]))
+            with jax.named_scope("lgb.leaf_refit"):
+                # full-precision leaf-value REFIT (Shi et al. §4.3): tree
+                # STRUCTURE came from quantized histograms, but each final
+                # leaf's value is recomputed from the full-precision
+                # gradients, then written back into the split records so
+                # host-materialized trees match the device score update.
+                if int_scan:
+                    # exact integer refit: each masked gradient is split
+                    # into THREE base-128 int8 digits against the (global)
+                    # quantization scale — deterministic round-to-nearest,
+                    # no noise — and the per-leaf digit sums accumulate
+                    # int8->int32 on the MXU.  Per-row representation error
+                    # is <= scale/2^15 ~ max|g| * 2^-22 (f32-class), the
+                    # SUMS are bit-exact in any order — which is what keeps
+                    # sharded leaf values byte-identical to single-device
+                    # (an f32 contraction's accumulation order would not
+                    # survive the psum split).  |digit sums| <= 127 * rows
+                    # stays in int32 under the same INT32_SCAN_ROWS gate as
+                    # the histograms.
+                    def _digits(x, s):
+                        cols = []
+                        r, sd = x, s
+                        for _ in range(3):
+                            d = jnp.clip(jnp.round(r / sd), -QUANT_MAX,
+                                         QUANT_MAX)
+                            r = r - d * sd
+                            cols.append(d.astype(jnp.int8))
+                            sd = sd / 128.0
+                        return cols
+                    dcols = jnp.stack(_digits(grad * one_f, qscales[0])
+                                      + _digits(hess * one_f, qscales[1]), 1)
+                    oh8 = jax.nn.one_hot(leaf_final, L, dtype=jnp.int8)
+                    sums6 = jnp.einsum("nl,nk->lk", oh8, dcols,
+                                       preferred_element_type=jnp.int32)
+                    if self.shard is not None:
+                        with jax.named_scope("lgb.psum"):
+                            sums6 = jax.lax.psum(sums6, self.shard.axis)
+                    f32 = lambda a: a.astype(jnp.float32)
+                    gsum = (f32(sums6[:, 0]) + f32(sums6[:, 1]) * (1 / 128.0)
+                            + f32(sums6[:, 2]) * (1 / 16384.0)) * qscales[0]
+                    hsum = (f32(sums6[:, 3]) + f32(sums6[:, 4]) * (1 / 128.0)
+                            + f32(sums6[:, 5]) * (1 / 16384.0)) * qscales[1]
+                    refit = self._leaf_output(gsum, hsum, hyper)
+                else:
+                    # f32 fallback regime: hi/lo-bf16 one-hot contraction
+                    # (same cost class as the score update); sharded, the
+                    # per-shard partial sums psum in f32 — deterministic,
+                    # though not bitwise equal to single-device order (no
+                    # byte-identity contract past the int32 bound)
+                    one_b = one_f.astype(jnp.bfloat16)
+                    cols4 = jnp.stack(_hi_lo_cols(grad, hess, one_b), 1)
+                    ohl = jax.nn.one_hot(leaf_final, L, dtype=jnp.bfloat16)
+                    sums = jnp.einsum("nl,nk->lk", ohl, cols4,
+                                      preferred_element_type=jnp.float32)
+                    if self.shard is not None:
+                        with jax.named_scope("lgb.psum"):
+                            sums = jax.lax.psum(sums, self.shard.axis)
+                    refit = self._leaf_output(sums[:, 0] + sums[:, 1],
+                                              sums[:, 2] + sums[:, 3], hyper)
+                exists = jnp.arange(L, dtype=jnp.int32) < final.nl
+                # each final leaf's value lives in its CREATING record (the
+                # last record mentioning the leaf id: left children keep the
+                # parent's id, right ids are fresh); segment-max over the
+                # record index finds it without a host loop
+                recs_r = jnp.arange(L, dtype=jnp.int32)
+                lid, rid = final.rec_i[:, 0], final.rec_i[:, 1]
+                base = jnp.full((L + 1,), -1, jnp.int32)
+                last_l = base.at[jnp.where(lid >= 0, lid, L)].max(recs_r)
+                last_r = base.at[jnp.where(rid >= 0, rid, L)].max(recs_r)
+                crec = jnp.maximum(last_l[:L], last_r[:L])
+                is_left = last_l[:L] >= last_r[:L]
+                do = exists & (crec >= 0)
+                if self.has_cat:
+                    # leaves created by a categorical split keep their
+                    # growth value: sorted-mode cat splits regularize with
+                    # lambda_l2 + cat_l2 (split.py pack_best use_l2), which
+                    # the plain-lambda_l2 refit formula would drop —
+                    # under-regularizing exactly those leaves
+                    cfeat = final.rec_i[jnp.where(do, crec, 0), 2]
+                    from_cat = do & (meta.is_cat[jnp.clip(cfeat, 0, None)]
+                                     == 1)
+                    refit = jnp.where(from_cat, final.value[:L], refit)
+                leaf_vals = jnp.where(exists, refit, 0.0)
+                rows = jnp.where(do, crec, L - 1)        # junk record row
+                cols_i = jnp.where(is_left, REC_F_LEFT_OUT, REC_F_RIGHT_OUT)
+                rec_f_out = rec_f_out.at[rows, cols_i].set(
+                    jnp.where(do, leaf_vals, rec_f_out[rows, cols_i]))
         else:
             leaf_vals = final.value[:L]
 
@@ -1118,19 +1137,20 @@ class GrowerPrograms:
         # matmul (hi/lo split keeps f32-level precision at bf16 speed).
         # A stump (root never split) applies nothing: the boosting driver
         # treats it as the stop signal, matching GBDT::TrainOneIter.
-        scaled = leaf_vals * lr * (final.nl > 1)
-        vhi = scaled.astype(jnp.bfloat16)
-        vlo = (scaled - vhi.astype(jnp.float32)).astype(jnp.bfloat16)
-        vmat = jnp.stack([vhi, vlo], 1)                       # (L, 2)
-        oh = jax.nn.one_hot(leaf_final, L, dtype=jnp.bfloat16)
-        upd = jnp.einsum("nl,lk->nk", oh, vmat,
-                         preferred_element_type=jnp.float32)
-        new_score = score + (upd[:, 0] + upd[:, 1])[:self.num_data]
+        with jax.named_scope("lgb.score_update"):
+            scaled = leaf_vals * lr * (final.nl > 1)
+            vhi = scaled.astype(jnp.bfloat16)
+            vlo = (scaled - vhi.astype(jnp.float32)).astype(jnp.bfloat16)
+            vmat = jnp.stack([vhi, vlo], 1)                       # (L, 2)
+            oh = jax.nn.one_hot(leaf_final, L, dtype=jnp.bfloat16)
+            upd = jnp.einsum("nl,lk->nk", oh, vmat,
+                             preferred_element_type=jnp.float32)
+            new_score = score + (upd[:, 0] + upd[:, 1])[:self.num_data]
 
         return (new_score, final.rec_i[:max(L - 1, 1)],
                 rec_f_out[:max(L - 1, 1)],
                 final.rec_c[:max(L - 1, 1)], final.nl, final.value[0],
-                final.waves, qscales)
+                jnp.stack([final.waves, final.slots]), qscales)
 
     # ------------------------------------------------------------------
     def fused_train(self, length: int):
@@ -1160,7 +1180,7 @@ class GrowerPrograms:
                 meta, hyper, tables, grad_fn=fn)
             -> (final_score,
                 (rec_i (K,L-1,5), rec_f (K,L-1,9), rec_c (K,L-1,8),
-                 nl (K,), root_value (K,), waves (K,), qscales (K,2)))
+                 nl (K,), root_value (K,), work (K,2), qscales (K,2)))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);
@@ -1183,6 +1203,7 @@ class GrowerPrograms:
             bag_frac, bag_npad = self._bag_fraction, self._bag_npad
             sp = self.shard
 
+            @jax.named_scope("lgb.bag_draw")
             def draw_bag(it):
                 seed = (bag_seed + it) & 0x7FFFFFFF
                 if sp is None:
@@ -1206,7 +1227,8 @@ class GrowerPrograms:
 
                 def body(carry, it):
                     sc, bmask = (carry if use_bag else (carry, None))
-                    g, h = grad_fn(sc, gargs)
+                    with jax.named_scope("lgb.gradient"):
+                        g, h = grad_fn(sc, gargs)
                     fmask = self.feature_mask_for(it)
                     if use_bag:
                         # cond, not where: only redraw steps pay the
@@ -1214,12 +1236,12 @@ class GrowerPrograms:
                         bmask = jax.lax.cond(it % bag_freq == 0,
                                              lambda: draw_bag(it),
                                              lambda: bmask)
-                    (new_score, rec_i, rec_f, rec_c, nl, root, waves,
+                    (new_score, rec_i, rec_f, rec_c, nl, root, work,
                      qs) = self._grow_impl(
                         binned, binned_t, sc, g, h, fmask, lr,
                         bmask if use_bag else no_mask, it, num_valid,
                         meta, hyper, tables, with_mask=use_bag)
-                    out = (rec_i, rec_f, rec_c, nl, root, waves, qs)
+                    out = (rec_i, rec_f, rec_c, nl, root, work, qs)
                     return ((new_score, bmask) if use_bag
                             else new_score), out
 
@@ -1548,6 +1570,13 @@ class DeviceGrower:
         ascontiguousarray pass — ~seconds at 10M rows).  Sharded, both
         layouts are placed row-split over the mesh axis so each device
         holds ONLY its shard's rows."""
+        with obs.span("grow.upload", cat="grow", pad=int(pad)):
+            self._upload_binned_traced(dataset, pad)
+            if obs.enabled():
+                # the span ends with the transfer, not with its enqueue
+                jax.block_until_ready((self.binned, self.binned_t))
+
+    def _upload_binned_traced(self, dataset, pad: int):
         if self._multihost:
             self._upload_binned_multihost(dataset)
             return
@@ -1648,7 +1677,7 @@ class DeviceGrower:
                       row_mask=None, tree_idx=0):
         """Dispatch one boosting iteration; returns device handles
         (new_score, rec_i, rec_f, rec_c, num_leaves, root_value,
-        num_waves, quant_scales) without blocking.  ``row_mask`` is an
+        work, quant_scales) without blocking.  ``row_mask`` is an
         optional (N,) f32 0/1 in-bag indicator (bagging / GOSS);
         ``tree_idx`` is the global tree index keying the per-tree
         quantization rounding noise."""
@@ -1781,8 +1810,6 @@ class DeviceGrower:
 
         Returns ``{"stage_ms", "fixed_ms", "col_ms", "plan",
         "plan_digest", "installed"}``."""
-        import time as _time
-
         reps = max(1, int(reps))
         progs = self.programs
         if progs.shard is not None:
@@ -1791,7 +1818,7 @@ class DeviceGrower:
             # byte-stable default ladder (a profiled plan would also
             # have to match across mesh sizes to preserve the
             # byte-identity contract, docs/Sharding.md)
-            return {"stage_ms": {}, "stage_cost": {}, "fixed_ms": None,
+            return {"stage_ms": {}, "fixed_ms": None,
                     "col_ms": None,
                     "plan": list(progs.stage_plan),
                     "plan_digest":
@@ -1800,12 +1827,22 @@ class DeviceGrower:
         if install and progs.plan_source in ("profiled", "persisted"):
             # already measured for this signature in this process, or
             # adopted from the on-disk store: zero re-profiles
-            return {"stage_ms": {}, "stage_cost": {}, "fixed_ms": None,
+            return {"stage_ms": {}, "fixed_ms": None,
                     "col_ms": None,
                     "plan": list(progs.stage_plan),
                     "plan_digest":
                         stage_plan_mod.plan_digest(progs.stage_plan),
                     "installed": False}
+        with obs.span("grow.plan_probe", cat="grow",
+                      hist_cols=progs.hist_cols):
+            return self._probe_stage_plan(reps, install,
+                                          require_beat_legacy)
+
+    def _probe_stage_plan(self, reps, install, require_beat_legacy):
+        """The measuring half of :meth:`profile_stage_plan`."""
+        import time as _time
+
+        progs = self.programs
         obs.inc("grow.plan_profiles")
         k = progs.hist_cols
         n = progs.n_pad
@@ -1831,31 +1868,17 @@ class DeviceGrower:
                         progs._wave_hist(b, l, g2, p, wave_scales)))
             return fn, leaf, ghk, pend
 
-        stage_cost = {}
         hist_out = {}
         for w in widths:
             fn, leaf, ghk, pend = probe_for(w)
             jax.block_until_ready(fn(self.binned, leaf, ghk, pend))
-            with obs.span("grow.stage_probe", cat="grow", width=w,
-                          hist_cols=k):
-                t0 = _time.perf_counter()
-                for _ in range(reps):
-                    r = fn(self.binned, leaf, ghk, pend)
-                jax.block_until_ready(r)
-                ms = (_time.perf_counter() - t0) / reps * 1e3
+            t0 = _time.perf_counter()
+            for _ in range(reps):
+                r = fn(self.binned, leaf, ghk, pend)
+            jax.block_until_ready(r)
+            ms = (_time.perf_counter() - t0) / reps * 1e3
             hist_out[w] = r
             stage_ms[w] = round(ms, 3)
-            if obs.profile.enabled():
-                # static XLA estimate for the already-compiled probe (a
-                # compile-cache hit): measured ms + estimated FLOPs =
-                # achieved compute per stage width
-                cost = obs.profile.cost_of(fn, self.binned, leaf, ghk,
-                                           pend)
-                if cost is not None:
-                    stage_cost[w] = cost
-                    if cost.get("flops"):
-                        obs.set_gauge(f"grow.stage.w{w}_gflops",
-                                      round(cost["flops"] / 1e9, 3))
             obs.observe(f"grow.stage.w{w}", ms / 1e3)
             obs.set_gauge(f"grow.stage.w{w}_ms", round(ms, 3))
             if w == progs.wave_width:
@@ -1988,7 +2011,7 @@ class DeviceGrower:
                 # the plan is now measurement-confirmed (keeps the
                 # early-exit above from re-probing this signature)
                 progs.plan_source = "profiled"
-        return {"stage_ms": stage_ms, "stage_cost": stage_cost,
+        return {"stage_ms": stage_ms,
                 "fixed_ms": round(fixed, 3),
                 "col_ms": round(col, 5), "plan": plan,
                 "plan_digest": stage_plan_mod.plan_digest(plan),
@@ -2028,168 +2051,7 @@ class DeviceGrower:
         ms = (_time.perf_counter() - t0) / max(1, int(reps)) * 1e3
         obs.observe("shard.psum", ms / 1e3)
         obs.set_gauge("shard.psum_ms", round(ms, 3))
-        out = {"psum_ms": round(ms, 3)}
-        if obs.profile.enabled():
-            cost = obs.profile.cost_of(fn, buf)
-            if cost is not None:
-                out["cost"] = cost
-                if cost.get("bytes_accessed"):
-                    obs.set_gauge("shard.psum_gbytes",
-                                  round(cost["bytes_accessed"] / 1e9, 4))
-        return out
-
-    # ------------------------------------------------------------------
-    def profile_phases(self, grad, hess, reps: int = 20) -> dict:
-        """Honest per-phase attribution for one wave (bench --profile).
-
-        The production grower runs the whole tree inside one
-        ``lax.while_loop`` — individual phases are invisible from the
-        host.  This method times separately-jitted programs equivalent
-        to the wave's phases on the real binned matrices and a
-        representative leaf state (rows spread over W leaves, all
-        pending), syncing after each, and returns {phase: ms}.
-        """
-        import time as _time
-
-        if self.programs.shard is not None:
-            from ..utils.log import log_warning
-            log_warning("profile_phases is unavailable under "
-                        "data_sharding (phase probes run outside the "
-                        "mesh); use profile_psum for collective time")
-            return {}
-        w, n = self.wave_width, self.n_pad
-        rng = np.random.default_rng(0)
-        leaf_id = jnp.asarray(
-            rng.integers(0, w, n).astype(np.int32))
-        pending = jnp.arange(w, dtype=jnp.int32)
-        grad = jnp.pad(grad, (0, n - self.num_data))
-        hess = jnp.pad(hess, (0, n - self.num_data))
-
-        quant = bool(self.quant_bits)
-
-        @jax.jit
-        def p_hist(binned, leaf, g, h, pend):
-            # the real operand pipeline (shared _stat_columns), so the
-            # profiled wave_hist matches production bit-for-bit
-            ghk, scales = self.programs._stat_columns(
-                g, h, jnp.ones((n,), jnp.float32), 0)
-            return self.programs._wave_hist(binned, leaf, ghk, pend,
-                                            scales if quant else None)
-
-        p_hist = obs.track_jit("grow.probe.hist", p_hist)
-        int_scan = bool(self.int_scan)
-
-        @jax.jit
-        def p_find(hists, feature_mask):
-            cons = jnp.asarray([-jnp.inf, jnp.inf], jnp.float32)
-            totals = hists[:, :self.nb, :].sum(1)
-            if int_scan:
-                # the int32 scan variant (what production runs when
-                # quantized); unit scales keep the probe self-contained
-                find_q = functools.partial(find_best_split_quant,
-                                           meta=self.meta, hp=self.hyper,
-                                           has_cat=False)
-                ones2 = jnp.ones((2,), jnp.float32)
-                packed, _, _ = jax.vmap(
-                    lambda hh, t: find_q(hh, t, ones2, cons,
-                                         feature_mask))(hists, totals)
-                return packed
-            find_one = functools.partial(find_best_split_impl,
-                                         meta=self.meta, hp=self.hyper,
-                                         has_cat=False)
-            packed, _ = jax.vmap(
-                lambda hh, t: find_one(hh, t, cons, feature_mask))(hists,
-                                                                   totals)
-            return packed
-
-        p_find = obs.track_jit("grow.probe.find", p_find)
-
-        @jax.jit
-        def p_apply(binned_t, leaf, grp, thr, rdel):
-            cols = jnp.take(binned_t, grp, axis=0).astype(jnp.int32)
-            mask = (leaf[None, :] == jnp.arange(w)[:, None]) \
-                & (cols > thr[:, None])
-            return leaf + jnp.sum(mask * rdel[:, None], axis=0,
-                                  dtype=jnp.int32)
-
-        p_apply = obs.track_jit("grow.probe.apply", p_apply)
-
-        @jax.jit
-        def p_score(score, leaf, vals):
-            L = self.num_leaves
-            oh = jax.nn.one_hot(leaf, L, dtype=jnp.bfloat16)
-            vhi = vals.astype(jnp.bfloat16)
-            vlo = (vals - vhi.astype(jnp.float32)).astype(jnp.bfloat16)
-            upd = jnp.einsum("nl,lk->nk", oh, jnp.stack([vhi, vlo], 1),
-                             preferred_element_type=jnp.float32)
-            return score + upd[:, 0] + upd[:, 1]
-
-        p_score = obs.track_jit("grow.probe.score", p_score)
-
-        mask = jnp.ones((self.num_features,), bool)
-        grp = jnp.asarray(rng.integers(0, self.num_groups, w, np.int32))
-        thr = jnp.asarray(rng.integers(0, self.nb, w, np.int32))
-        rdel = jnp.asarray(rng.integers(1, w + 1, w, np.int32))
-        vals = jnp.asarray(rng.standard_normal(self.num_leaves)
-                           .astype(np.float32))
-        score = jnp.zeros((n,), jnp.float32)
-
-        # dispatch-latency floor: an empty jitted program measured the
-        # same way; subtracted from every phase so host dispatch
-        # latency doesn't masquerade as device time
-        @jax.jit
-        def p_null(x):
-            return x + 1.0
-
-        p_null = obs.track_jit("grow.probe.null", p_null)
-
-        out = {}
-        cases = {
-            "null_dispatch": lambda: p_null(score[:8]),
-            "wave_hist": lambda: p_hist(self.binned, leaf_id, grad, hess,
-                                        pending),
-            "find_best": None,   # filled after hist exists
-            "split_apply": lambda: p_apply(self.binned_t, leaf_id, grp,
-                                           thr, rdel),
-            "score_update": lambda: p_score(score, leaf_id, vals),
-        }
-        hists = jax.block_until_ready(cases["wave_hist"]())
-        cases["find_best"] = lambda: p_find(hists, mask)
-        for name, fn in cases.items():
-            jax.block_until_ready(fn())          # compile + warm
-            t0 = _time.perf_counter()
-            for _ in range(reps):
-                r = fn()
-            jax.block_until_ready(r)
-            out[name] = round((_time.perf_counter() - t0) / reps * 1e3, 2)
-        floor = out.pop("null_dispatch")
-        out = {k: round(max(v - floor, 0.0), 2) for k, v in out.items()}
-        out["dispatch_floor"] = floor
-        for name, ms in out.items():
-            obs.set_gauge(f"profile.{name}_ms", ms)
-        if obs.profile.enabled():
-            # static XLA estimates for the (already compiled) phase
-            # probes; nested under "costs" so {phase: ms} consumers are
-            # unaffected
-            probe_args = {
-                "wave_hist": (p_hist, (self.binned, leaf_id, grad, hess,
-                                       pending)),
-                "find_best": (p_find, (hists, mask)),
-                "split_apply": (p_apply, (self.binned_t, leaf_id, grp,
-                                          thr, rdel)),
-                "score_update": (p_score, (score, leaf_id, vals)),
-            }
-            costs = {}
-            for name, (fn, a) in probe_args.items():
-                cost = obs.profile.cost_of(fn, *a)
-                if cost is not None:
-                    costs[name] = cost
-                    if cost.get("flops"):
-                        obs.set_gauge(f"profile.{name}_gflops",
-                                      round(cost["flops"] / 1e9, 3))
-            if costs:
-                out["costs"] = costs
-        return out
+        return {"psum_ms": round(ms, 3)}
 
 
 def device_growth_eligible(config, dataset, objective, num_model,

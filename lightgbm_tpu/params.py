@@ -408,13 +408,6 @@ PARAM_SCHEMA: Sequence[Param] = (
             "\"Tracing & attribution\"). Off: zero context objects are "
             "allocated. Env override: LGBM_TPU_TRACE_CTX=1",
        section="io"),
-    _p("profile_attribution", bool, False, (),
-       desc="attach XLA cost-analysis estimates (FLOPs / bytes accessed "
-            "per compiled program) to the device profiling probes "
-            "(profile_stage_plan / profile_phases / profile_psum, implies "
-            "metrics_enabled); bench.py --explain turns this on to emit "
-            "the phase-attribution report with achieved-GFLOP/s figures",
-       section="io"),
     _p("pipeline_checkpoint_dir", str, "", (),
        desc="windowed pipeline: directory for per-window fault-tolerance "
             "checkpoints (docs/Robustness.md). After every completed "

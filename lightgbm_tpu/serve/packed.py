@@ -348,17 +348,20 @@ def _apply_scores(pe: PackedEnsemble, xhi, xlo):
     """(K, R) float32 raw scores: traverse + leaf-value gather + per-
     class sum, one fused program."""
     r, t = xhi.shape[0], pe.split_feature.shape[0]
-    leaves = _traverse(pe, xhi, xlo)
-    vals = pe.leaf_value[jnp.arange(t, dtype=jnp.int32)[None, :], leaves]
-    per_class = vals.reshape(r, t // pe.num_model, pe.num_model)
-    return per_class.sum(axis=1).T
+    with jax.named_scope("lgb.traverse"):
+        leaves = _traverse(pe, xhi, xlo)
+        vals = pe.leaf_value[jnp.arange(t, dtype=jnp.int32)[None, :],
+                             leaves]
+        per_class = vals.reshape(r, t // pe.num_model, pe.num_model)
+        return per_class.sum(axis=1).T
 
 
 @jax.jit
 def _apply_leaves(pe: PackedEnsemble, xhi, xlo):
     """(R, T) int32 leaf index per (row, tree) — padding trees
     included; callers slice to ``pe.num_trees``."""
-    return _traverse(pe, xhi, xlo)
+    with jax.named_scope("lgb.traverse"):
+        return _traverse(pe, xhi, xlo)
 
 
 _apply_scores = obs.track_jit("serve.scores", _apply_scores)

@@ -67,8 +67,12 @@ def validate(doc: Dict) -> List[str]:
         err("counters missing or not an object")
     else:
         for k, v in counters.items():
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                err(f"counter {k!r} is not a non-negative int: {v!r}")
+            # ints, save the seconds a closed span adds to span_s.<name>
+            ok = _num(v) and v >= 0 and (
+                isinstance(v, int) or k.startswith("span_s."))
+            if not ok:
+                err(f"counter {k!r} is not a non-negative int "
+                    f"(or span_s.* seconds): {v!r}")
 
     gauges = doc.get("gauges")
     if not isinstance(gauges, dict):

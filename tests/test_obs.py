@@ -53,6 +53,19 @@ def _small_train(params_extra=None, rounds=4, evals=True):
                      verbose_eval=False)
 
 
+def _device_booster():
+    """A tiny device-grower booster after one fused chunk of 2 trees."""
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((600, 5))
+    y = (x[:, 0] > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+              "device_growth": "on", "fused_chunk": 2,
+              "min_data_in_leaf": 5}
+    ds = lgb.Dataset(x, label=y, params=params).construct()
+    return lgb.train(params, ds, num_boost_round=2, verbose_eval=False,
+                     keep_training_booster=True)
+
+
 # ---------------------------------------------------------------------------
 # registry
 # ---------------------------------------------------------------------------
@@ -343,6 +356,164 @@ class TestValidator:
         errs = validate_metrics.validate(doc) \
             or validate_metrics.validate_training_run(doc)
         assert errs and any(frag in e for e in errs), errs
+
+
+# ---------------------------------------------------------------------------
+# span seconds as counters, JAX's own trace/lower seconds, and program
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+class TestSpanCounters:
+    def test_span_close_adds_seconds_and_count(self):
+        obs.configure(enabled=True)
+        for _ in range(3):
+            with obs.span("layer.work"):
+                pass
+        c = STATE.registry.snapshot()["counters"]
+        assert c["span_n.layer.work"] == 3
+        assert isinstance(c["span_s.layer.work"], float)
+        t = STATE.registry.snapshot()["timings"]["layer.work"]
+        assert c["span_s.layer.work"] == pytest.approx(t["total_s"],
+                                                       abs=1e-5)
+
+    def test_span_seconds_pass_the_validator_and_the_exposition(self):
+        from lightgbm_tpu.obs.export import prometheus_text
+        obs.configure(enabled=True)
+        obs.observe("train.iter", 0.01)
+        with obs.span("dataset.construct"):
+            pass
+        doc = obs.snapshot()
+        assert validate_metrics.validate(doc) == []
+        text, collisions = prometheus_text(doc)
+        assert collisions == 0
+        assert "lgbm_span_s_dataset_construct_total " in text
+        # any other counter still has to be an int
+        doc["counters"]["grow.trees"] = 1.5
+        assert any("grow.trees" in e for e in validate_metrics.validate(doc))
+
+    _trained = {}
+
+    @classmethod
+    def _trained_counters(cls):
+        """Counters after a tiny device-grower run of two fused chunks
+        (trained once; the registry itself is reset between tests)."""
+        if not cls._trained:
+            obs.configure(enabled=True)
+            bst = _device_booster()
+            bst.update_chunked(2)  # the stall check looks one chunk back
+            cls._trained.update(STATE.registry.snapshot()["counters"])
+        return cls._trained
+
+    @pytest.mark.parametrize("name", ["dataset.construct", "bin.find",
+                                      "bin.bundle", "bin.apply",
+                                      "train.init", "grow.upload",
+                                      "train.chunk", "chunk.enqueue",
+                                      "chunk.stall_check"])
+    def test_layer_boundary_spans_reach_the_counters(self, name):
+        c = self._trained_counters()
+        assert c[f"span_n.{name}"] >= 1 and c[f"span_s.{name}"] >= 0.0
+
+    def test_child_spans_lie_inside_their_parents(self):
+        c = self._trained_counters()
+        assert c["span_s.chunk.enqueue"] <= c["span_s.train.chunk"]
+        assert c["span_s.bin.find"] + c["span_s.bin.apply"] \
+            <= c["span_s.dataset.construct"]
+        assert c["span_s.grow.upload"] <= c["span_s.train.init"]
+        assert c["train.fused_chunks"] == c["span_n.train.chunk"] == 2
+
+    def test_compile_cache_counts_trace_and_lower_seconds(self):
+        import jax
+        import jax.numpy as jnp
+        from lightgbm_tpu import compile_cache
+        compile_cache.install_listeners()
+        before = compile_cache.counters()
+        assert {"trace_s", "lower_s", "backend_compile_s"} <= set(before)
+        jax.jit(lambda a: jnp.cumsum(a * 3.25) - 1.5)(
+            jnp.arange(17.0)).block_until_ready()
+        after = compile_cache.counters()
+        assert after["trace_s"] > before["trace_s"]
+        assert after["lower_s"] > before["lower_s"]
+
+    def test_disabled_span_builds_no_annotation(self, monkeypatch):
+        built = []
+        monkeypatch.setattr(obs, "_annotation",
+                            lambda name: built.append(name))
+        sp = obs.span("x")
+        assert sp is obs._NULL_SPAN
+        with sp:
+            pass
+        assert built == []
+        assert STATE.registry.snapshot()["counters"] == {}
+
+    def test_enabled_span_holds_one_annotation_for_its_lifetime(
+            self, monkeypatch):
+        events = []
+
+        class Fake:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                events.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                events.append(("exit", self.name))
+
+        monkeypatch.setattr(obs, "_annotation",
+                            lambda name: Fake("lgb." + name))
+        obs.configure(enabled=True)
+        with obs.span("outer"):
+            with obs.span("inner"):
+                pass
+        obs.span_event("crossed.threads", 0.0, 1.0)   # nothing new
+        assert events == [("enter", "lgb.outer"), ("enter", "lgb.inner"),
+                          ("exit", "lgb.inner"), ("exit", "lgb.outer")]
+
+    def test_program_spans_are_in_the_profilers_host_plane(self, tmp_path):
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        obs.configure(enabled=True)
+        bst = _device_booster()
+        jax.block_until_ready(bst._gbdt.train_score)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            bst.update_chunked(2)
+            jax.block_until_ready(bst._gbdt.train_score)
+        finally:
+            jax.profiler.stop_trace()
+        pb, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+        host, = [p for p in ProfileData.from_file(pb).planes
+                 if p.name == "/host:CPU"]
+        spans = {}
+        for line in host.lines:
+            for e in line.events:
+                if e.name.startswith("lgb."):
+                    spans[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+        assert {"lgb.train.chunk", "lgb.chunk.enqueue"} <= set(spans)
+        (c0, c1), (e0, e1) = spans["lgb.train.chunk"], \
+            spans["lgb.chunk.enqueue"]
+        assert c0 <= e0 and e1 <= c1      # nested, on one clock
+
+
+class TestCollectors:
+    def test_snapshot_runs_collectors_and_forgets_dead_owners(self):
+        reg = MetricsRegistry()
+
+        class Owner:
+            def collect(self):
+                reg.inc("seen")
+
+        o = Owner()
+        reg.add_collector(o.collect)
+        assert reg.snapshot()["counters"] == {"seen": 1}
+        assert reg.snapshot()["counters"] == {"seen": 2}
+        del o
+        assert reg.snapshot()["counters"] == {"seen": 2}
+        assert reg._collectors == []
 
 
 # ---------------------------------------------------------------------------

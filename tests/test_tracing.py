@@ -265,45 +265,6 @@ class TestDisabled:
 # ---------------------------------------------------------------------------
 
 class TestProfile:
-    def test_normalize_cost_dict_and_list_forms(self):
-        got = profile.normalize_cost({"flops": 10, "bytes accessed": 5,
-                                      "transcendentals": 2})
-        assert got == {"flops": 10.0, "bytes_accessed": 5.0,
-                       "transcendentals": 2.0}
-        # newer jax returns a one-element list; underscore key alias
-        got = profile.normalize_cost([{"flops": 3,
-                                       "bytes_accessed": 7}])
-        assert got["flops"] == 3.0 and got["bytes_accessed"] == 7.0
-
-    def test_normalize_cost_unusable_inputs(self):
-        assert profile.normalize_cost(None) is None
-        assert profile.normalize_cost({}) is None
-        assert profile.normalize_cost([]) is None
-        assert profile.normalize_cost("not a dict") is None
-
-    def test_attribution_report_math_and_clamp(self):
-        rep = profile.attribution_report(10.0, {"a": 6.0, "b": 3.0})
-        assert rep["attributed_ms"] == pytest.approx(9.0)
-        assert rep["coverage"] == pytest.approx(0.9)
-        assert rep["unattributed_ms"] == pytest.approx(1.0)
-        assert list(rep["phases"]) == ["a", "b"]   # sorted by ms desc
-        assert rep["phases"]["a"]["share"] == pytest.approx(0.6)
-        # probes can overshoot the fused loop: coverage clamps at 1.0
-        over = profile.attribution_report(10.0, {"a": 12.0})
-        assert over["attributed_ratio"] == pytest.approx(1.2)
-        assert over["coverage"] == 1.0
-
-    def test_attribution_report_costs_attach(self):
-        rep = profile.attribution_report(
-            10.0, {"a": 5.0}, costs={"a": {"flops": 5e9}})
-        ph = rep["phases"]["a"]
-        assert ph["cost"]["flops"] == 5e9
-        # 5 GFLOP in 5 ms -> 1000 GFLOP/s
-        assert ph["achieved_gflops"] == pytest.approx(1000.0)
-
-    def test_cost_of_degrades_to_none(self):
-        assert profile.cost_of(lambda x: x, 1) is None   # no .lower
-
     def test_device_trace_noop_without_path(self):
         with profile.device_trace(None) as profiled:
             assert profiled is False
