@@ -24,7 +24,8 @@ from ..data.dataset import BinnedDataset
 from ..metrics import create_metrics
 from ..objectives import create_objective
 from ..ops import stage_plan as stage_plan_mod
-from ..ops.grow import DeviceGrower, device_growth_eligible
+from ..ops.grow import (DeviceGrower, device_growth_eligible,
+                        wave_rows_scanned)
 from ..ops.traverse import add_tree_score, device_tree
 from ..robust import checkpoint as _checkpoint
 from ..robust import faults
@@ -894,7 +895,9 @@ class GBDT:
         slots]`` for the registry (``_WorkDrain``)."""
         g = self._grower
         shards = g.shard.n_shards if g.shard is not None else 1
-        self._work.push(nl, work, shards * int(g.n_pad), self.num_data)
+        self._work.push(nl, work,
+                        wave_rows_scanned(self.num_data, int(g.n_pad),
+                                          shards), self.num_data)
 
     def _sync_fused_bagging(self):
         """Restore the host-side bagging state to what a pure

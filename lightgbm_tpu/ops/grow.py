@@ -15,9 +15,14 @@ The TPU-native formulation is **dense**:
   updates it with one elementwise pass over a contiguous feature column
   (the ``(G, N)`` transposed copy of the binned matrix);
 * histograms for a whole *wave* of fresh leaves are built in ONE pass over
-  all rows: per feature-group, ``one_hot(bins) . (leaf_mask x [g,h,1])`` —
+  the rows: per feature-group, ``one_hot(bins) . (leaf_mask x [g,h,1])`` —
   the leaf-mask columns widen the matmul's N dimension to fill the MXU's
-  128-lane tiles (a single leaf's 3 stat columns would waste 97% of them);
+  128-lane tiles (a single leaf's 3 stat columns would waste 97% of them).
+  The pass runs in ``_CHUNK``-row chunks and stops after the last chunk
+  that holds a real row (the traced ``num_valid``), so under
+  ``train_row_bucketing`` a tree costs its rows, not its pow2 bucket;
+  the stage-plan probes scan all ``n_pad`` rows, every one weighted,
+  because their plan has to hold for every window size of the bucket;
 * the gradient operand is split hi/lo into two bfloat16 columns whose
   float32-accumulated sum reconstructs float32-accurate histograms at
   bfloat16 matmul speed (counts are exact: 0/1 products, f32 accumulation);
@@ -116,6 +121,19 @@ INT32_SCAN_ROWS = ((1 << 31) - 1) // 127
 
 def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def wave_rows_scanned(num_valid: int, n_pad: int, n_shards: int = 1) -> int:
+    """Rows one einsum wave histogram visits (``grow.rows_scanned`` per
+    wave): on each shard the whole chunks up to the last one that holds
+    a real row, the shards being contiguous ``n_pad``-row blocks of the
+    global rows (``shard.local_valid_rows``).  Host arithmetic mirroring
+    the traced loop bound in ``GrowerPrograms._wave_hist_local``; the
+    Pallas route's full-width stage (``hist_kernel=pallas``) still
+    passes over all ``n_pad`` rows and is not told apart here."""
+    return sum(
+        _ceil_to(min(max(num_valid - d * n_pad, 0), n_pad), _CHUNK)
+        for d in range(n_shards))
 
 
 class FTables(NamedTuple):
@@ -269,8 +287,10 @@ class GrowerPrograms:
         # (split.find_best_split_quant) and the per-leaf hist/total
         # state, dequantizing only at gain/leaf-value math; counts and
         # the parent-minus-sibling subtraction become exact.  The bound
-        # is on n_pad: the stage-profiling probes weight every padded
-        # row, and pad rows are zero-masked in production anyway.
+        # is on n_pad: the stage-profiling probes give every padded row
+        # a weight and scan all of them (they pass n_pad as the valid
+        # count), while training zero-masks the pad rows and its
+        # histogram loop leaves out the chunks that hold nothing else.
         # Sharded, the bound applies to the GLOBAL padded row space —
         # the psum accumulates |sum q| <= 127 * total rows across the
         # whole mesh into the same int32 cells.
@@ -473,19 +493,21 @@ class GrowerPrograms:
     # ------------------------------------------------------------------
     # wave histogram: one dense pass for up to W pending leaves
     # ------------------------------------------------------------------
-    def _wave_hist(self, binned, leaf_id, ghk, pending, scales=None):
+    def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
+                   scales=None):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
         the mesh when sharded."""
         with jax.named_scope("lgb.wave_hist"):
             hist = self._wave_hist_local(binned, leaf_id, ghk, pending,
-                                         scales)
+                                         num_valid, scales)
         # sharded: psum the combined per-shard histograms — the growth
         # loop's sole cross-device sync (docs/Sharding.md); everything
         # downstream (find-best, totals, root stats) then runs on
         # replicated global values
         return self._psum_hist(hist)
 
-    def _wave_hist_local(self, binned, leaf_id, ghk, pending, scales):
+    def _wave_hist_local(self, binned, leaf_id, ghk, pending, num_valid,
+                         scales):
         """(n_pad,) leaf ids, (n_pad, K) stat columns (bf16 — K=3:
         [g,h,1]; K=5: [g_hi,g_lo,h_hi,h_lo,1] — or int8 under
         grad_quant_bits), (W,) pending leaf ids (-1 = empty slot)
@@ -493,6 +515,14 @@ class GrowerPrograms:
         ``self.int_scan`` (the find-best scan then stays integer).
         ``scales`` is the (2,) [scale_g, scale_h] dequantization vector
         (quantized f32-fallback mode only).
+
+        ``num_valid`` is the (shard-local) row count past which every
+        row is padding with all-zero stat columns: the einsum's chunk
+        loop stops after the last chunk that holds a row below it, so a
+        wave costs ``ceil(num_valid / _CHUNK)`` chunk passes and not
+        ``n_pad // _CHUNK``.  Traced in training (one program per row
+        bucket, whatever the window size); the plan probes pass
+        ``n_pad``.  The skipped chunks would each add an exact zero.
 
         The one-hot must stay a bare iota-compare so XLA fuses its
         generation into the dot operand (a multi-hot built as
@@ -527,8 +557,12 @@ class GrowerPrograms:
             mdtype = jnp.int8 if quant else jnp.bfloat16
             adtype = jnp.int32 if quant else jnp.float32
 
-            def body(acc, xs):
-                b, l, gk = xs
+            live = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
+
+            def body(i, acc):
+                b, l, gk = (jax.lax.dynamic_index_in_dim(
+                    a, i, keepdims=False)
+                    for a in (binned_c, leaf_c, ghk_c))
                 lm = (l[:, None] == pending[None, :]).astype(mdtype)
                 bmat = (lm[:, :, None] * gk[:, None, :]).reshape(ch,
                                                                  w * k)
@@ -547,10 +581,10 @@ class GrowerPrograms:
                                            preferred_element_type=adtype))
                 out = outs[0] if len(outs) == 1 \
                     else jnp.concatenate(outs, axis=1)
-                return acc + out, None
+                return acc + out
 
             acc0 = jnp.zeros((g, nb, w * k), adtype)
-            acc, _ = jax.lax.scan(body, acc0, (binned_c, leaf_c, ghk_c))
+            acc = jax.lax.fori_loop(0, live, body, acc0)
             acc = acc.reshape(g, nb, w, k)
         if quant and self.int_scan:
             # int32 end-to-end: the histogram stays in quantized units
@@ -788,8 +822,8 @@ class GrowerPrograms:
         def make_wave(Ws: int):
           def wave(st: _S) -> _S:
             # 1. fresh histograms for pending smaller children
-            fresh = self._wave_hist(binned, st.leaf_id, gh5,
-                                    st.p_small, wave_scales)  # (W,S,3)
+            fresh = self._wave_hist(binned, st.leaf_id, gh5, st.p_small,
+                                    num_valid, wave_scales)   # (W,S,3)
             with jax.named_scope("lgb.hist_state"):
                 root_wave = st.p_parent[0] < 0
                 # root total from group-0 slot sums (every row hits one slot)
@@ -1865,7 +1899,7 @@ class DeviceGrower:
             fn = obs.track_jit(
                 f"stage_probe_w{w}",
                 jax.jit(lambda b, l, g2, p:
-                        progs._wave_hist(b, l, g2, p, wave_scales)))
+                        progs._wave_hist(b, l, g2, p, n, wave_scales)))
             return fn, leaf, ghk, pend
 
         hist_out = {}
@@ -1938,7 +1972,7 @@ class DeviceGrower:
                 find_ms[w] = round(timed(two_fn, h2, mask_all), 3)
 
                 def fused_body(b, l, g2, p, m):
-                    fr = progs._wave_hist(b, l, g2, p, wave_scales)
+                    fr = progs._wave_hist(b, l, g2, p, n, wave_scales)
                     return jnp.concatenate([scan_stack(fr, m),
                                             scan_stack(-fr, m)])
 

@@ -80,9 +80,11 @@ def _kernel_parity() -> bool:
     ghk = jnp.asarray(
         rng.integers(-127, 128, (n, k)).astype(np.int8))
     pending = jnp.arange(w, dtype=jnp.int32)
-    got = np.asarray(progs._wave_hist(grower.binned, leaf, ghk, pending))
+    got = np.asarray(progs._wave_hist(grower.binned, leaf, ghk, pending,
+                                      n))
     progs.use_pallas = False
-    ref = np.asarray(progs._wave_hist(grower.binned, leaf, ghk, pending))
+    ref = np.asarray(progs._wave_hist(grower.binned, leaf, ghk, pending,
+                                      n))
     if got.dtype != np.int32 or ref.dtype != np.int32:
         print(f"FAIL kernel parity: expected int32 histograms, got "
               f"pallas={got.dtype} einsum={ref.dtype}")

@@ -138,6 +138,22 @@ def scenario_core():
     out["warm_window_cache_hit"] = \
         snap["counters"].get("grow.cache_hits", 0) > hits_before
     out["shard_digest"] = obs.summary().get("shard")
+
+    # the work counters of one sharded run whose rows do not fill the
+    # mesh: 20,000 rows lie in contiguous 8,192-row blocks, so the
+    # shards hold 8,192 / 8,192 / 3,616 / 0 of them
+    def work():
+        c = obs.registry().snapshot()["counters"]
+        return [c.get(f"grow.{k}", 0)
+                for k in ("waves", "rows_scanned", "rows_real")]
+
+    x3, y3 = _data(rows=20000)
+    w0 = work()
+    bst = _train(x3, y3, SHARD, iters=2, return_booster=True)
+    waves, scanned, real = (a - b for a, b in zip(work(), w0))
+    out["work"] = {"waves": waves, "rows_scanned": scanned,
+                   "rows_real": real, "n_pad": int(bst._grower.n_pad),
+                   "shards": int(bst._grower.shard.n_shards)}
     return out
 
 

@@ -81,6 +81,48 @@ def test_row_bucketing_trees_byte_identical():
     assert _trees_only(on_pi) == _trees_only(on)
 
 
+# rows, the extra params, (live, total) histogram chunks of the bucketed
+# grower at the suite's LGBM_TPU_CHUNK=8192
+_CHUNK_CASES = {
+    "dead_chunks": (20000, {}, (3, 4)),
+    "dead_chunks_bagged": (20000, {"bagging_fraction": 0.8,
+                                   "bagging_freq": 1}, (3, 4)),
+    "chunk_boundary": (16384, {}, (2, 2)),
+    "single_chunk": (1500, {}, (1, 1)),
+}
+
+
+@pytest.mark.parametrize("case", _CHUNK_CASES)
+def test_histogram_skips_dead_chunks_trees_byte_identical(case):
+    """The wave histogram stops after the last row chunk that holds a
+    real row.  Whether the pow2 bucket leaves whole chunks of padding
+    behind it, ends on a chunk boundary or is a single chunk, the model
+    is the exact-rows model byte for byte, fused and per-iteration."""
+    from lightgbm_tpu.ops.grow import _CHUNK, wave_rows_scanned
+
+    if _CHUNK != 8192:
+        pytest.skip("row counts chosen for LGBM_TPU_CHUNK=8192")
+    set_verbosity(-1)
+    rows, extra, (live, total) = _CHUNK_CASES[case]
+    rng = np.random.default_rng(27)
+    x = rng.standard_normal((rows, 8))
+    y = (x[:, 0] + np.abs(x[:, 1]) > 0.4).astype(np.float32)
+    texts = {}
+    for bucketing in (True, False):
+        for per_iter in (False, True):
+            bst = _train_small(
+                x, y, {**extra, "train_row_bucketing": bucketing},
+                per_iter=per_iter)
+            texts[bucketing, per_iter] = _trees_only(bst)
+            if bucketing:
+                n_pad = int(bst._grower.n_pad)
+                assert (wave_rows_scanned(rows, n_pad) // _CHUNK,
+                        n_pad // _CHUNK) == (live, total)
+    assert "Tree=3" in texts[True, False]
+    assert len(set(texts.values())) == 1, \
+        [k for k, v in texts.items() if v != texts[False, False]]
+
+
 def test_row_bucketing_shares_programs_across_window_sizes():
     """Two retrain windows with DIFFERENT row counts in the same pow2
     bucket must adopt the same GrowerPrograms object and trigger zero

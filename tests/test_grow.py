@@ -293,11 +293,85 @@ def test_pallas_hist_matches_einsum(reg_data):
         np.concatenate([np.arange(6), [-1] * (grower.wave_width - 6)])
         .astype(np.int32))
     grower.use_pallas = False
-    ref = np.asarray(grower._wave_hist(binned, leaf, ghk, pending))
+    ref = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n))
     grower.use_pallas = True
     grower.pallas_interpret = True
-    got = np.asarray(grower._wave_hist(binned, leaf, ghk, pending))
+    got = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n))
     np.testing.assert_allclose(got, ref, atol=1e-3, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the wave histogram's chunk loop stops at the last chunk with a real row
+# ---------------------------------------------------------------------------
+
+_LAYOUTS = {"bf16_k3": ({}, False, 3), "bf16_k4_striped": ({}, True, 4),
+            "int8_k3": ({"grad_quant_bits": 8}, False, 3)}
+
+
+@pytest.fixture(scope="module", params=list(_LAYOUTS))
+def wave_hist_case(request):
+    """(jitted ``_wave_hist`` with a traced ``num_valid``, n_pad, and a
+    maker of inputs masked past a row count the way training masks
+    them) for one stat-column layout, over four row chunks."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import grow as growmod
+
+    extra, striped, k = _LAYOUTS[request.param]
+    n, groups, nb, w = 4 * growmod._CHUNK, 5, 64, 6
+    old = growmod.COUNT_SPLIT_ROWS
+    try:
+        # the module's comment invites this: stripe the counts on few rows
+        growmod.COUNT_SPLIT_ROWS = 1 if striped else old
+        progs = growmod.GrowerPrograms(
+            num_data=n, num_groups=groups, nb=nb, num_features=groups,
+            has_cat=False, plan=[(w, None)],
+            config=Config({"objective": "binary", "num_leaves": w + 1,
+                           "verbosity": -1, **extra}))
+    finally:
+        growmod.COUNT_SPLIT_ROWS = old
+    assert (progs.n_pad, progs.hist_cols, progs.striped) == (n, k, striped)
+    rng = np.random.default_rng(27)
+    binned = jnp.asarray(rng.integers(0, nb - 1, (n, groups))
+                         .astype(np.uint8))
+    leaf_all = rng.integers(0, w, n).astype(np.int32)
+    grad = jnp.asarray(rng.standard_normal(n).astype(np.float32))
+    hess = jnp.asarray(rng.random(n).astype(np.float32))
+    pending = jnp.asarray([0, 1, 2, 3, 4, -1], jnp.int32)
+
+    def masked(num_valid):
+        valid = np.arange(n) < num_valid
+        vf = jnp.asarray(valid.astype(np.float32))
+        ghk, scales = progs._stat_columns(grad * vf, hess * vf, vf, 0)
+        return (binned, jnp.asarray(np.where(valid, leaf_all, -1)), ghk,
+                pending), (scales if extra else None)
+
+    fn = jax.jit(lambda args, nv, scales:
+                 progs._wave_hist(*args, nv, scales))
+    return fn, n, masked
+
+
+@pytest.mark.parametrize("rows", ["none", "one", "one_chunk",
+                                  "one_chunk_and_a_row", "all"])
+def test_wave_hist_stops_at_the_last_live_chunk(wave_hist_case, rows):
+    """With ``num_valid`` real rows the loop visits
+    ``ceil(num_valid / _CHUNK)`` chunks; the chunks it leaves out hold
+    only masked rows, so the histogram equals the full-length one over
+    the same masked inputs bit for bit — float32 and int32 alike."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.grow import _CHUNK
+
+    fn, n, masked = wave_hist_case
+    nv = {"none": 0, "one": 1, "one_chunk": _CHUNK,
+          "one_chunk_and_a_row": _CHUNK + 1, "all": n}[rows]
+    args, scales = masked(nv)
+    got = np.asarray(fn(args, jnp.int32(nv), scales))
+    full = np.asarray(fn(args, jnp.int32(n), scales))
+    np.testing.assert_array_equal(got, full)
+    # every real row of a pending leaf (0..4; leaf 5 is not in the wave)
+    # is counted once in each of the 5 groups: nothing live was skipped
+    in_wave = int(np.isin(np.asarray(args[1]), np.arange(5)).sum())
+    assert int(np.asarray(got, np.float64)[..., 2].sum()) == 5 * in_wave
 
 
 def test_device_bagging_matches_host(reg_data):

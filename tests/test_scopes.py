@@ -22,6 +22,7 @@ from lightgbm_tpu import obs
 from lightgbm_tpu.boosting.gbdt import _WorkDrain
 from lightgbm_tpu.obs.scopes import SCOPES
 from lightgbm_tpu.obs.state import STATE
+from lightgbm_tpu.ops.grow import _CHUNK
 
 PKG = os.path.dirname(os.path.abspath(lgb.__file__))
 
@@ -46,8 +47,8 @@ def _data(rows=3000, features=6, seed=3):
     return x, y
 
 
-def _booster(extra=None, rounds=2):
-    x, y = _data()
+def _booster(extra=None, rounds=2, rows=3000):
+    x, y = _data(rows)
     params = {**BASE, **(extra or {})}
     ds = lgb.Dataset(x, label=y, params=params).construct()
     return lgb.train(params, ds, num_boost_round=rounds,
@@ -194,8 +195,10 @@ def _grow_counters():
             if k.startswith("grow.")}
 
 
-@pytest.fixture
-def two_chunks():
+# 3,000 rows sit in one histogram chunk (the suite's LGBM_TPU_CHUNK is
+# 8192); 17,000 pad to the 32,768 bucket, whose fourth chunk holds no row
+@pytest.fixture(params=[3000, 17000], ids=["one_chunk", "dead_chunk"])
+def two_chunks(request):
     """(booster, per-chunk [(nl, work)] as the program returned them,
     counters after chunk 1, counters after chunk 2)."""
     obs.configure(enabled=True)
@@ -208,7 +211,7 @@ def two_chunks():
 
     _WorkDrain.push = spy
     try:
-        bst = _booster(rounds=2)
+        bst = _booster(rounds=2, rows=request.param)
         jax.block_until_ready(bst._gbdt.train_score)
         after1 = _grow_counters()
         bst.update_chunked(2)
@@ -233,10 +236,16 @@ def test_counters_hold_every_tree_and_the_returned_waves(two_chunks):
 
 def test_counters_bound_each_other(two_chunks):
     bst, returned, _, c = two_chunks
-    assert 0 < c["grow.rows_real"] <= c["grow.rows_scanned"]
-    assert c["grow.rows_real"] == c["grow.waves"] * bst._gbdt.num_data
-    assert c["grow.rows_scanned"] == \
-        c["grow.waves"] * int(bst._gbdt._grower.n_pad)
+    rows, n_pad = bst._gbdt.num_data, int(bst._gbdt._grower.n_pad)
+    live_chunks = -(-rows // _CHUNK)
+    assert 0 < c["grow.rows_real"] <= c["grow.rows_scanned"] \
+        <= c["grow.waves"] * n_pad
+    assert c["grow.rows_real"] == c["grow.waves"] * rows
+    # the histogram visits the chunks that hold a real row, no more
+    assert c["grow.rows_scanned"] == c["grow.waves"] * live_chunks * _CHUNK
+    if rows == 17000:
+        assert (live_chunks, n_pad // _CHUNK) == (3, 4)
+        assert c["grow.rows_scanned"] < c["grow.waves"] * n_pad
     # a wave of width W applies at most W splits
     assert 0 < c["grow.leaves"] - c["grow.trees"] <= c["grow.wave_slots"]
     # every wave offers at least one slot and at most the widest stage

@@ -92,6 +92,19 @@ def test_shard_obs_digest(core_report):
     assert digest["sharded_dispatches"] > 0
 
 
+def test_shard_rows_scanned_is_the_sum_of_the_shards_live_chunks(
+        core_report):
+    # each shard's histogram loop stops at its own last live chunk:
+    # 20,000 rows in contiguous 8,192-row blocks give the four shards
+    # 1, 1, 1 and 0 live chunks (LGBM_TPU_CHUNK=8192 in the worker)
+    w = core_report["work"]
+    assert (w["shards"], w["n_pad"]) == (4, 8192)
+    assert w["waves"] > 0
+    assert w["rows_real"] == w["waves"] * 20000
+    assert w["rows_scanned"] == w["waves"] * 3 * 8192 \
+        < w["waves"] * w["shards"] * w["n_pad"]
+
+
 @pytest.mark.slow
 @pytest.mark.timeout(600)
 def test_shard_row_bucketing_invariant():
