@@ -1,0 +1,13 @@
+"""Share of the device's busy time spent drawing the bag and the feature
+masks: self time under ``lgb.bag_draw`` over all self time, from the
+per-scope reduction of the window's trace (``run["scopes"]``).  ``None``
+when the run has no such reduction or the trace never reaches the
+scope."""
+
+
+def read(run):
+    scopes = run.get("scopes")
+    if not scopes or not scopes.get("busy_s") \
+            or "lgb.bag_draw" not in scopes:
+        return None
+    return 100.0 * scopes["lgb.bag_draw"]["self_s"] / scopes["busy_s"]

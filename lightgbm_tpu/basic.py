@@ -445,8 +445,27 @@ class Booster:
                                       num_iteration)
         return self
 
+    def sampled_rows(self, iteration: int) -> np.ndarray:
+        """``bool[num_data]``: the training rows in the bag of boosting
+        iteration ``iteration`` (0-based; all True without bagging).  The
+        bag is redrawn every ``bagging_freq`` iterations and holds each
+        row with probability ``bagging_fraction``; it is drawn again here
+        on demand, not stored, so it also answers for trees a fused
+        chunk has not yet brought to the host.  Out-of-bag rows take no
+        part in a tree's histograms, counts or leaf outputs, and still
+        get its output added to their training score."""
+        return self._gbdt.sampled_rows(iteration)
+
+    def sampled_features(self, tree_index: int) -> np.ndarray:
+        """``bool[num_features]`` over the dataset's columns: those tree
+        ``tree_index`` was allowed to split on (``feature_fraction``;
+        ``ceil(fraction * usable columns)`` of them, drawn per tree).
+        Device-grown trees only."""
+        return self._gbdt.sampled_features(tree_index)
+
     def dump_model(self, num_iteration=-1, start_iteration=0) -> dict:
         g = self._gbdt
+        g._flush_pending()      # trees a fused chunk left on the device
         return {
             "name": "tree",
             "version": "v2",

@@ -174,10 +174,12 @@ class _WorkDrain:
     """The device scan's work counters on their way to the registry.
 
     Each dispatch returns, per tree, its leaf count and ``[waves, wave
-    slots]`` as device arrays.  ``push`` queues the handles (their async
-    host copies already started) and ``drain`` adds whatever
-    ``is_ready()`` to ``grow.trees`` / ``leaves`` / ``waves`` /
-    ``wave_slots`` / ``rows_scanned`` / ``rows_real`` — at the next
+    slots, in-bag rows, features in the mask]`` as device arrays.
+    ``push`` queues the handles (their async host copies already
+    started) and ``drain`` adds whatever ``is_ready()`` to
+    ``grow.trees`` / ``leaves`` / ``waves`` / ``wave_slots`` /
+    ``rows_scanned`` / ``rows_real`` (both per wave) / ``rows_in_bag`` /
+    ``features_in_mask`` (both per tree) — at the next
     dispatch and whenever the registry is snapshotted (the booster
     registers ``drain`` as a collector), so the dispatch path never
     waits for the device and a chunk whose ``block_until_ready`` has
@@ -212,7 +214,7 @@ class _WorkDrain:
                 done.append(self._pending.popleft())
         for nl, work, rows_scanned, rows_real in done:
             nl = np.asarray(nl).reshape(-1)
-            work = np.asarray(work).reshape(-1, 2)
+            work = np.asarray(work, np.int64).reshape(-1, 4)
             waves = int(work[:, 0].sum())
             obs.inc("grow.trees", int(nl.size))
             obs.inc("grow.leaves", int(nl.sum()))
@@ -220,6 +222,8 @@ class _WorkDrain:
             obs.inc("grow.wave_slots", int(work[:, 1].sum()))
             obs.inc("grow.rows_scanned", waves * rows_scanned)
             obs.inc("grow.rows_real", waves * rows_real)
+            obs.inc("grow.rows_in_bag", int(work[:, 2].sum()))
+            obs.inc("grow.features_in_mask", int(work[:, 3].sum()))
 
 
 class GBDT:
@@ -891,8 +895,8 @@ class GBDT:
             return bool((prev.host()[3] <= 1).all())
 
     def _push_work(self, nl, work) -> None:
-        """Queue one dispatch's per-tree leaf counts and ``[waves, wave
-        slots]`` for the registry (``_WorkDrain``)."""
+        """Queue one dispatch's per-tree leaf counts and work counters
+        for the registry (``_WorkDrain``)."""
         g = self._grower
         shards = g.shard.n_shards if g.shard is not None else 1
         self._work.push(nl, work,
@@ -919,6 +923,54 @@ class GBDT:
         seed = (self.config.bagging_seed + it_last) & 0x7FFFFFFF
         self.bag_buffer, self.bag_count = self.learner.bagging_state(
             seed, self.bag_fraction)
+
+    # ------------------------------------------------------------------
+    # what was sampled, recomputed on demand (nothing is kept per tree)
+    def sampled_rows(self, iteration: int) -> np.ndarray:
+        """Host ``bool[num_data]``: the rows in the bag of boosting
+        iteration ``iteration`` (0-based), i.e. the Bernoulli draw made at
+        the last multiple of ``bagging_freq`` at or before it, seeded
+        ``(bagging_seed + that iteration) & 0x7FFFFFFF``.  Recomputed by
+        the draw training makes — the fused scan's and the per-iteration
+        device path's ``bagging_row_mask``, else the learner's
+        ``bagging_state`` — so it holds for trees still pending on the
+        device; all True without bagging."""
+        if type(self).bagging is not GBDT.bagging:
+            raise LightGBMError(
+                f"{type(self).__name__} selects rows from the gradients "
+                f"of the moment; its selection cannot be drawn again")
+        n = self.num_data
+        if not self.need_bagging:
+            return np.ones(n, bool)
+        it0 = int(iteration) - int(iteration) % self.bag_freq
+        if self._grower is not None:
+            return np.asarray(self._grower.bag_mask(it0))
+        seed = (self.config.bagging_seed + it0) & 0x7FFFFFFF
+        buf, cnt = self.learner.bagging_state(seed, self.bag_fraction)
+        buf = np.asarray(buf)
+        if buf.ndim != 1:
+            raise LightGBMError(
+                "this tree learner bags each rank's rows for itself; the "
+                "global bag is not available")
+        mask = np.zeros(max(n, buf.shape[0]), bool)
+        mask[buf[:int(cnt)]] = True
+        return mask[:n]
+
+    def sampled_features(self, tree_index: int) -> np.ndarray:
+        """Host ``bool[num_total_features]``: the columns tree
+        ``tree_index`` (``iteration * num_tree_per_iteration + class``)
+        could split on under ``feature_fraction`` — the device grower's
+        draw, ``fold_in(PRNGKey(feature_fraction_seed), tree_index)``,
+        made again.  Columns binning found trivial are never in it."""
+        if self._grower is None:
+            raise LightGBMError(
+                "the host tree learner draws feature subsets from a "
+                "running stream; only device-grown trees' can be drawn "
+                "again")
+        out = np.zeros(self.train_set.num_total_features, bool)
+        out[np.asarray(self.train_set.used_features, np.int64)] = \
+            np.asarray(self._grower.feature_mask_for(int(tree_index)))
+        return out
 
     @staticmethod
     def _obs_chunk(sp, chunk):

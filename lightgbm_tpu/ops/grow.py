@@ -686,8 +686,9 @@ class GrowerPrograms:
                    *, with_mask):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
-        i32, root_value f32, work (2,) i32 = [waves run, sum of their
-        stage widths], quant_scales (2,) f32).
+        i32, root_value f32, work (4,) i32 = [waves run, sum of their
+        stage widths, in-bag real rows, features in the mask],
+        quant_scales (2,) f32).
         ``lr`` is traced so callbacks may reset the learning rate without
         recompiling; ``tree_idx`` is the global tree index keying the
         quantization rounding noise (unused when grad_quant_bits=0).
@@ -726,6 +727,12 @@ class GrowerPrograms:
                 # (gbdt.cpp:451-471) falls out for free.
                 one_f = one_f * jnp.pad(row_mask, (0, npad_rows))
             gh5, qscales = self._stat_columns(grad, hess, one_f, tree_idx)
+            # work counters: the rows this tree's histograms count
+            rows_in_bag = jnp.sum(one_f > 0, dtype=jnp.int32)
+            if self.shard is not None:
+                with jax.named_scope("lgb.psum"):
+                    rows_in_bag = jax.lax.psum(rows_in_bag,
+                                               self.shard.axis)
         wave_scales = qscales if self.quant_bits else None
         # int32 scan (grad_quant_bits=8 below INT32_SCAN_ROWS): the
         # per-leaf hist/total state stays in quantized integer units —
@@ -1184,7 +1191,9 @@ class GrowerPrograms:
         return (new_score, final.rec_i[:max(L - 1, 1)],
                 rec_f_out[:max(L - 1, 1)],
                 final.rec_c[:max(L - 1, 1)], final.nl, final.value[0],
-                jnp.stack([final.waves, final.slots]), qscales)
+                jnp.stack([final.waves, final.slots, rows_in_bag,
+                           jnp.sum(feature_mask, dtype=jnp.int32)]),
+                qscales)
 
     # ------------------------------------------------------------------
     def fused_train(self, length: int):
@@ -1214,7 +1223,7 @@ class GrowerPrograms:
                 meta, hyper, tables, grad_fn=fn)
             -> (final_score,
                 (rec_i (K,L-1,5), rec_f (K,L-1,9), rec_c (K,L-1,8),
-                 nl (K,), root_value (K,), work (K,2), qscales (K,2)))
+                 nl (K,), root_value (K,), work (K,4), qscales (K,2)))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);
@@ -1565,19 +1574,26 @@ class DeviceGrower:
             if bucket >= 2 * COUNT_SPLIT_ROWS:
                 # the pow2 bucket would cross the striped-count
                 # eligibility bound the exact row count still satisfies
-                # (device_growth_eligible checks the REAL rows) — fall
-                # back to exact rows rather than to the host learner.
-                # Say so: an operator counting on one program family
-                # per bucket should see why >16.7M-row windows each
-                # compile their own
+                # (device_growth_eligible checks the REAL rows): step
+                # down to sixty-fourths of it instead of to the exact row
+                # count.  A row count with a large odd factor (20M rows
+                # pad to 611 chunks) costs the TPU compiler 12x the time
+                # and 2x the code of a 6-bit multiple of a power of two
+                # (PERF.md section 6, PR 28), and one window size more
+                # or less then shares the program.  Only where even that
+                # reaches the bound do the exact rows remain.
                 from ..utils.log import log_info
+                fine = _ceil_to(self.num_data, bucket // 64)
+                if fine >= 2 * COUNT_SPLIT_ROWS:
+                    fine = self.num_data
+                kind = ("exact rows" if fine == self.num_data
+                        else "the finer bucket")
                 log_info(
                     f"train_row_bucketing: row bucket {bucket} would "
                     f"reach the striped-count bound "
-                    f"({2 * COUNT_SPLIT_ROWS}); using exact rows "
-                    f"({self.num_data}) — programs are per-row-count "
-                    f"at this scale")
-                bucket = self.num_data
+                    f"({2 * COUNT_SPLIT_ROWS}); using {kind} ({fine}) "
+                    f"for {self.num_data} rows")
+                bucket = fine
         self.row_bucket = int(bucket)
 
         has_cat = bool(np.asarray(dataset.f_is_categorical).any())
@@ -1762,6 +1778,18 @@ class DeviceGrower:
         if self._row_pad:
             out = (out[0][:self.num_data],) + tuple(out[1:])
         return out
+
+    # ------------------------------------------------------------------
+    def bag_mask(self, it: int):
+        """(num_data,) bool device array: the rows in the bag drawn at
+        boosting iteration ``it`` (a redraw boundary), by the draw the
+        fused scan and ``learner.bagging_state`` make for that iteration
+        (``bagging_row_mask`` over the same pad)."""
+        from .bagging import bagging_row_mask
+        p = self.programs
+        return bagging_row_mask((p._bag_seed + int(it)) & 0x7FFFFFFF,
+                                p._bag_npad, self.num_data,
+                                p._bag_fraction) > 0
 
     # ------------------------------------------------------------------
     def fused_train(self, length: int):

@@ -733,9 +733,10 @@ PARAM_SCHEMA: Sequence[Param] = (
             "are byte-identical to the unbucketed path. Auto-disabled "
             "with grad_quant_bits=8 (the stochastic rounding stream is "
             "keyed on the padded shape), for objectives whose fused "
-            "device gradient is not row-local (lambdarank), and when "
-            "the pow2 bucket would cross the striped-count bound "
-            "(datasets over 2^24 rows fall back to exact rows, logged). "
+            "device gradient is not row-local (lambdarank). Where the "
+            "pow2 bucket would cross the striped-count bound (datasets "
+            "over 2^24 rows) the bucket is the next sixty-fourth of it "
+            "instead (logged). "
             "See docs/ColdStart.md", section="device"),
     _p("data_sharding", str, "off", (),
        check="off/single_controller/multi_controller",

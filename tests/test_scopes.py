@@ -225,13 +225,18 @@ def two_chunks(request):
 def test_counters_hold_every_tree_and_the_returned_waves(two_chunks):
     _, returned, _, c = two_chunks
     assert c["grow.trees"] == 4
-    work = np.concatenate([np.asarray(w).reshape(-1, 2)
+    work = np.concatenate([np.asarray(w).reshape(-1, 4)
                            for _, w, _, _ in returned])
     nl = np.concatenate([np.asarray(n).reshape(-1)
                          for n, _, _, _ in returned])
     assert c["grow.waves"] == int(work[:, 0].sum()) > 0
     assert c["grow.wave_slots"] == int(work[:, 1].sum())
     assert c["grow.leaves"] == int(nl.sum())
+    # no bagging, no feature sampling: every real row and every feature
+    assert c["grow.rows_in_bag"] == int(work[:, 2].sum()) \
+        == 4 * two_chunks[0]._gbdt.num_data
+    assert c["grow.features_in_mask"] == int(work[:, 3].sum()) \
+        == 4 * two_chunks[0]._gbdt.train_set.num_features
 
 
 def test_counters_bound_each_other(two_chunks):
@@ -258,7 +263,7 @@ def test_snapshot_delta_is_exactly_the_chunk_between(two_chunks):
     _, returned, c1, c2 = two_chunks
     assert c1["grow.trees"] == 2
     nl, work, scanned, real = returned[1]
-    work = np.asarray(work).reshape(-1, 2)
+    work = np.asarray(work).reshape(-1, 4)
     waves = int(work[:, 0].sum())
     want = {"grow.trees": 2, "grow.leaves": int(np.asarray(nl).sum()),
             "grow.waves": waves, "grow.wave_slots": int(work[:, 1].sum()),
