@@ -164,7 +164,7 @@ def leg_pallas(rows: int = 32768, groups: int = FEATURES,
             ghk = jnp.asarray(rng.standard_normal((rows, k))
                               .astype(np.float32)).astype(jnp.bfloat16)
         ref = np.asarray(jax.jit(
-            lambda b, l, g2, p: progs._wave_hist(b, l, g2, p, rows))(
+            lambda b, l, g2, p: progs._wave_hist(b, l, g2, p, rows)[0])(
                 binned, leaf, ghk, pending))
         t0 = time.perf_counter()
         try:

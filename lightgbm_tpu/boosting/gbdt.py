@@ -24,8 +24,7 @@ from ..data.dataset import BinnedDataset
 from ..metrics import create_metrics
 from ..objectives import create_objective
 from ..ops import stage_plan as stage_plan_mod
-from ..ops.grow import (DeviceGrower, device_growth_eligible,
-                        wave_rows_scanned)
+from ..ops.grow import _CHUNK, DeviceGrower, device_growth_eligible
 from ..ops.traverse import add_tree_score, device_tree
 from ..robust import checkpoint as _checkpoint
 from ..robust import faults
@@ -174,12 +173,15 @@ class _WorkDrain:
     """The device scan's work counters on their way to the registry.
 
     Each dispatch returns, per tree, its leaf count and ``[waves, wave
-    slots, in-bag rows, features in the mask]`` as device arrays.
-    ``push`` queues the handles (their async host copies already
-    started) and ``drain`` adds whatever ``is_ready()`` to
+    slots, in-bag rows, features in the mask, row chunks its wave
+    histograms visited, their live rows // _CHUNK, the remainders]`` as
+    device arrays.  ``push`` queues the handles (their async host copies
+    already started) and ``drain`` adds whatever ``is_ready()`` to
     ``grow.trees`` / ``leaves`` / ``waves`` / ``wave_slots`` /
-    ``rows_scanned`` / ``rows_real`` (both per wave) / ``rows_in_bag`` /
-    ``features_in_mask`` (both per tree) — at the next
+    ``rows_real`` (real rows x waves) / ``rows_scanned`` (the visited
+    chunks' rows) / ``rows_live`` (both counted by the program, summed
+    over waves and shards) / ``rows_in_bag`` / ``features_in_mask``
+    (both per tree) — at the next
     dispatch and whenever the registry is snapshotted (the booster
     registers ``drain`` as a collector), so the dispatch path never
     waits for the device and a chunk whose ``block_until_ready`` has
@@ -196,12 +198,12 @@ class _WorkDrain:
     def __len__(self):
         return len(self._pending)
 
-    def push(self, nl, work, rows_scanned: int, rows_real: int) -> None:
+    def push(self, nl, work, rows_real: int) -> None:
         if not obs.enabled():
             return
         work.copy_to_host_async()
         with self._lock:
-            self._pending.append((nl, work, rows_scanned, rows_real))
+            self._pending.append((nl, work, rows_real))
         self.drain()
 
     def drain(self) -> None:
@@ -212,16 +214,18 @@ class _WorkDrain:
                     or (self._pending[0][0].is_ready()
                         and self._pending[0][1].is_ready())):
                 done.append(self._pending.popleft())
-        for nl, work, rows_scanned, rows_real in done:
+        for nl, work, rows_real in done:
             nl = np.asarray(nl).reshape(-1)
-            work = np.asarray(work, np.int64).reshape(-1, 4)
+            work = np.asarray(work, np.int64).reshape(-1, 7)
             waves = int(work[:, 0].sum())
             obs.inc("grow.trees", int(nl.size))
             obs.inc("grow.leaves", int(nl.sum()))
             obs.inc("grow.waves", waves)
             obs.inc("grow.wave_slots", int(work[:, 1].sum()))
-            obs.inc("grow.rows_scanned", waves * rows_scanned)
             obs.inc("grow.rows_real", waves * rows_real)
+            obs.inc("grow.rows_scanned", int(work[:, 4].sum()) * _CHUNK)
+            obs.inc("grow.rows_live", int(work[:, 5].sum()) * _CHUNK
+                    + int(work[:, 6].sum()))
             obs.inc("grow.rows_in_bag", int(work[:, 2].sum()))
             obs.inc("grow.features_in_mask", int(work[:, 3].sum()))
 
@@ -897,11 +901,7 @@ class GBDT:
     def _push_work(self, nl, work) -> None:
         """Queue one dispatch's per-tree leaf counts and work counters
         for the registry (``_WorkDrain``)."""
-        g = self._grower
-        shards = g.shard.n_shards if g.shard is not None else 1
-        self._work.push(nl, work,
-                        wave_rows_scanned(self.num_data, int(g.n_pad),
-                                          shards), self.num_data)
+        self._work.push(nl, work, self.num_data)
 
     def _sync_fused_bagging(self):
         """Restore the host-side bagging state to what a pure

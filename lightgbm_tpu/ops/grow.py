@@ -93,6 +93,15 @@ from .split import (F_DEFAULT_LEFT, F_FEATURE, F_GAIN, F_IS_CAT, F_LEFT_C,
 import os as _os
 _CHUNK = int(_os.environ.get("LGBM_TPU_CHUNK", 32768))
 
+# a wave whose operand is at least this many lanes wide (stage width x
+# stat columns) gathers its live rows ahead of the chunk loop.  Measured
+# on the chip at 2^24 rows x 67 groups, 45% of them live (PERF.md §6):
+# the gather costs 0.135 s whatever the width; at 16 and 32 lanes the
+# wave takes 0.24-0.26 s with it or without, at 64 lanes 0.32 against
+# 0.42 s, at 384 lanes 0.78 against 1.42 s.  Module-level so tests can
+# gather in narrow waves on small data.
+_GATHER_MIN_LANES = 64
+
 # record field layout (host replay reads these)
 REC_I_FIELDS = 5    # leaf, right, feature, threshold, default_left
 REC_F_FIELDS = 9    # gain, lg, lh, lc, rg, rh, rc, left_out, right_out
@@ -121,19 +130,6 @@ INT32_SCAN_ROWS = ((1 << 31) - 1) // 127
 
 def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def wave_rows_scanned(num_valid: int, n_pad: int, n_shards: int = 1) -> int:
-    """Rows one einsum wave histogram visits (``grow.rows_scanned`` per
-    wave): on each shard the whole chunks up to the last one that holds
-    a real row, the shards being contiguous ``n_pad``-row blocks of the
-    global rows (``shard.local_valid_rows``).  Host arithmetic mirroring
-    the traced loop bound in ``GrowerPrograms._wave_hist_local``; the
-    Pallas route's full-width stage (``hist_kernel=pallas``) still
-    passes over all ``n_pad`` rows and is not told apart here."""
-    return sum(
-        _ceil_to(min(max(num_valid - d * n_pad, 0), n_pad), _CHUNK)
-        for d in range(n_shards))
 
 
 class FTables(NamedTuple):
@@ -308,6 +304,7 @@ class GrowerPrograms:
         # with one very wide multi-tile wave for the tail.  gpu_use_dp
         # (k=5) scales each width down by 3/k to hold the column budget.
         self.wave_width = _wave_width(self.num_leaves, self.hist_cols)
+        self.gather_min_lanes = _GATHER_MIN_LANES
         # plan is required and resolved by get_grower_programs (its
         # digest is part of the program-cache key — resolving it here
         # too could silently diverge from the keyed digest)
@@ -496,15 +493,100 @@ class GrowerPrograms:
     def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
                    scales=None):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
-        the mesh when sharded."""
+        the mesh when sharded, and this shard's (2,) i32 ``[row chunks
+        the contraction visited, live rows it found]``."""
         with jax.named_scope("lgb.wave_hist"):
-            hist = self._wave_hist_local(binned, leaf_id, ghk, pending,
-                                         num_valid, scales)
+            hist, work = self._wave_hist_local(binned, leaf_id, ghk,
+                                               pending, num_valid, scales)
         # sharded: psum the combined per-shard histograms — the growth
         # loop's sole cross-device sync (docs/Sharding.md); everything
         # downstream (find-best, totals, root stats) then runs on
         # replicated global values
-        return self._psum_hist(hist)
+        return self._psum_hist(hist), work
+
+    def _gather_live(self, binned, leaf_id, ghk, live, n_live):
+        """Bring the ``n_live`` rows flagged in ``live`` (n_pad,) to the
+        front of chunked copies of the three row arrays, in row order:
+        ``(n_chunks, CH, G)``, ``(n_chunks, CH)``, ``(n_chunks, CH, K)``.
+        Only the ``ceil(n_live / CH)`` chunks the contraction will visit
+        are written; positions behind ``n_live`` in the last of them get
+        leaf id -2, which is no pending slot's.
+
+        What the chip measured (PERF.md §6) decides each step.  The live
+        rows' ids, in row order, come from a sort inside each chunk
+        (local row ids with the dead flag as the top bit: distinct keys)
+        whose live prefixes are then laid end to end; a stable argsort
+        of all n_pad dead flags gives the same ids 1.5-2.5x slower and
+        compiles for half a minute per stage.  Each chunk of ids is then
+        ONE row gather: a gathered row costs the same ~11 ns whether it
+        is 4 bytes or 128, so bins, leaf id and stat columns travel as
+        the bytes of one row padded to whole 128-lane tiles (a gather
+        per array measured 3x the one).  The bytes are cut and joined
+        by shifts (a ``bitcast_convert_type`` that changes the shape
+        leaves (n_pad, 4) and (n_pad, 2K) arrays behind, each padded to
+        128 lanes in HBM) and put in their lanes by the MXU: the byte
+        columns lie along the lanes, a row wants them along its own, and
+        a product with a 0/1 placement matrix is that transpose — exact,
+        a byte being an integer bfloat16 holds — where a select per
+        column measured 83 ms a wave.  The gather is a loop
+        of its own ahead of the contraction's: gathering inside that
+        loop's body measured the same seconds, but this way the body
+        stays as it was."""
+        ch, n = _CHUNK, self.n_pad
+        n_chunks = n // ch
+        g, k = self.num_groups, self.hist_cols
+        wide = ghk.dtype.itemsize == 2             # bf16, else int8
+        i32 = lambda a: a.astype(jnp.int32)
+        stat = i32(jax.lax.bitcast_convert_type(
+            ghk, jnp.uint16 if wide else jnp.uint8))
+        cols = [(leaf_id >> s) & 0xFF for s in (0, 8, 16, 24)]
+        for c in range(k):
+            cols += [stat[:, c] & 0xFF, stat[:, c] >> 8] if wide \
+                else [stat[:, c]]
+        width = _ceil_to(g + len(cols), 128)
+        place = jnp.arange(len(cols), dtype=jnp.int32)[:, None] + g \
+            == jnp.arange(width, dtype=jnp.int32)[None, :]
+        rows = jnp.pad(binned, ((0, 0), (0, width - g))) | jnp.einsum(
+            "cn,cl->nl", jnp.stack(cols).astype(jnp.bfloat16),
+            place.astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32).astype(jnp.uint8)
+        pos = jnp.arange(ch, dtype=jnp.int32)
+        live_c = live.reshape(n_chunks, ch)
+        dead = jnp.uint32(1 << 31)
+        key = pos.astype(jnp.uint32)[None, :]
+        ids = (jax.lax.sort(jnp.where(live_c, key, key | dead),
+                            dimension=1) & ~dead).astype(jnp.int32) \
+            + (jnp.arange(n_chunks, dtype=jnp.int32) * ch)[:, None]
+        cnt = live_c.sum(1, dtype=jnp.int32)
+        start = jnp.cumsum(cnt) - cnt
+        # chunk c's ids land behind chunk c-1's live ones, and the next
+        # chunk's overwrite its dead tail
+        order = jax.lax.fori_loop(
+            0, n_chunks,
+            lambda c, buf: jax.lax.dynamic_update_slice(
+                buf, ids[c], (start[c],)),
+            jnp.zeros((n + ch,), jnp.int32))[:n].reshape(n_chunks, ch)
+
+        def body(i, bufs):
+            idx = jax.lax.dynamic_index_in_dim(order, i, keepdims=False)
+            r = jnp.take(rows, idx, axis=0)
+            x = i32(r[:, g:g + len(cols)])
+            leaf = x[:, 0] | (x[:, 1] << 8) | (x[:, 2] << 16) \
+                | (x[:, 3] << 24)
+            if wide:
+                bits = (x[:, 4::2] | (x[:, 5::2] << 8)).astype(jnp.uint16)
+            else:
+                bits = x[:, 4:].astype(jnp.uint8)
+            out = (r[:, :g],
+                   jnp.where(i * ch + pos < n_live, leaf, -2),
+                   jax.lax.bitcast_convert_type(bits, ghk.dtype))
+            return tuple(jax.lax.dynamic_update_index_in_dim(b, o, i, 0)
+                         for b, o in zip(bufs, out))
+
+        bufs = (jnp.zeros((n_chunks, ch, g), binned.dtype),
+                jnp.full((n_chunks, ch), -2, jnp.int32),
+                jnp.zeros((n_chunks, ch, k), ghk.dtype))
+        return jax.lax.fori_loop(0, (n_live + ch - 1) // ch, body, bufs)
 
     def _wave_hist_local(self, binned, leaf_id, ghk, pending, num_valid,
                          scales):
@@ -516,13 +598,23 @@ class GrowerPrograms:
         ``scales`` is the (2,) [scale_g, scale_h] dequantization vector
         (quantized f32-fallback mode only).
 
-        ``num_valid`` is the (shard-local) row count past which every
-        row is padding with all-zero stat columns: the einsum's chunk
-        loop stops after the last chunk that holds a row below it, so a
-        wave costs ``ceil(num_valid / _CHUNK)`` chunk passes and not
-        ``n_pad // _CHUNK``.  Traced in training (one program per row
-        bucket, whatever the window size); the plan probes pass
-        ``n_pad``.  The skipped chunks would each add an exact zero.
+        Also returns (2,) i32 ``[row chunks visited, live rows]``.
+
+        A row is LIVE in a wave when its leaf is one of ``pending`` and
+        its count column(s) are non-zero: every other row — another
+        leaf's, bucket or shard padding, out of the bag, dropped by GOSS
+        — multiplies an all-zero operand row.  The einsum route gathers
+        the live rows to the front first (:meth:`_gather_live`, by rank
+        among live rows, so the chunks hold the same rows whatever
+        ``n_pad`` is) and its chunk loop then runs
+        ``ceil(live / _CHUNK)`` passes: a wave costs what the smaller
+        children and the bag hold, not every row.  Where that cannot
+        pay — a single chunk, or a stage narrower than
+        ``_GATHER_MIN_LANES`` — the rows stay where they are and the
+        loop's bound is the chunk that holds a row below ``num_valid``,
+        the (shard-local) count past which all rows are padding (traced
+        in training, ``n_pad`` in the plan probes).  Both are static
+        shapes: no parameter selects the path.
 
         The one-hot must stay a bare iota-compare so XLA fuses its
         generation into the dot operand (a multi-hot built as
@@ -533,6 +625,12 @@ class GrowerPrograms:
         w = pending.shape[0]
         k = self.hist_cols
         quant = bool(self.quant_bits)
+        ch = _CHUNK
+        n_chunks = self.n_pad // ch
+        live = ((leaf_id[:, None] == pending[None, :])
+                & (pending >= 0)[None, :]).any(1) \
+            & (ghk[:, 2 if k in (3, 4) else 4:] != 0).any(1)
+        n_live = jnp.sum(live, dtype=jnp.int32)
         from .hist_pallas import fits_single_tile
         if self.use_pallas and w == self.wave_width \
                 and fits_single_tile(w, k):
@@ -548,16 +646,20 @@ class GrowerPrograms:
                                    g=g, nb=nb, k=k, w=w,
                                    interpret=self.pallas_interpret)
             acc = out.reshape(g, nb, k, w).transpose(0, 1, 3, 2)
+            visited = jnp.asarray(n_chunks, jnp.int32)  # one pass, all rows
         else:
-            ch = _CHUNK
-            n_chunks = self.n_pad // ch
-            binned_c = binned.reshape(n_chunks, ch, g)
-            leaf_c = leaf_id.reshape(n_chunks, ch)
-            ghk_c = ghk.reshape(n_chunks, ch, k)
+            if n_chunks > 1 and w * k >= self.gather_min_lanes:
+                with jax.named_scope("lgb.wave_gather"):
+                    binned_c, leaf_c, ghk_c = self._gather_live(
+                        binned, leaf_id, ghk, live, n_live)
+                visited = (n_live + ch - 1) // ch
+            else:
+                binned_c = binned.reshape(n_chunks, ch, g)
+                leaf_c = leaf_id.reshape(n_chunks, ch)
+                ghk_c = ghk.reshape(n_chunks, ch, k)
+                visited = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
             mdtype = jnp.int8 if quant else jnp.bfloat16
             adtype = jnp.int32 if quant else jnp.float32
-
-            live = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
 
             def body(i, acc):
                 b, l, gk = (jax.lax.dynamic_index_in_dim(
@@ -584,7 +686,7 @@ class GrowerPrograms:
                 return acc + out
 
             acc0 = jnp.zeros((g, nb, w * k), adtype)
-            acc = jax.lax.fori_loop(0, live, body, acc0)
+            acc = jax.lax.fori_loop(0, visited, body, acc0)
             acc = acc.reshape(g, nb, w, k)
         if quant and self.int_scan:
             # int32 end-to-end: the histogram stays in quantized units
@@ -615,7 +717,8 @@ class GrowerPrograms:
                              axis=-1)
         else:
             hist = _combine_hist_cols(acc, k)                    # (G,NB,W,3)
-        return hist.transpose(2, 0, 1, 3).reshape(w, self.num_slots, 3)
+        return (hist.transpose(2, 0, 1, 3).reshape(w, self.num_slots, 3),
+                jnp.stack([visited, n_live]))
 
     # ------------------------------------------------------------------
     def _stat_columns(self, grad, hess, one_f, tree_idx):
@@ -686,9 +789,10 @@ class GrowerPrograms:
                    *, with_mask):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
-        i32, root_value f32, work (4,) i32 = [waves run, sum of their
-        stage widths, in-bag real rows, features in the mask],
-        quant_scales (2,) f32).
+        i32, root_value f32, work (7,) i32 = [waves run, sum of their
+        stage widths, in-bag real rows, features in the mask, row chunks
+        the wave histograms visited, their live rows // _CHUNK, the sum
+        of the remainders], quant_scales (2,) f32).
         ``lr`` is traced so callbacks may reset the learning rate without
         recompiling; ``tree_idx`` is the global tree index keying the
         quantization rounding noise (unused when grad_quant_bits=0).
@@ -759,6 +863,10 @@ class GrowerPrograms:
             nl: jnp.ndarray             # i32 leaves so far
             waves: jnp.ndarray          # i32 wave count
             slots: jnp.ndarray          # i32 sum of wave widths run
+            hwork: jnp.ndarray          # (3,) i32 histogram work so far:
+            #                             chunks visited, live rows as
+            #                             (// _CHUNK, % _CHUNK) sums — a
+            #                             tree's rows can pass int32
             done: jnp.ndarray           # bool
             rec_i: jnp.ndarray          # (L, 5) i32   (last row = junk)
             rec_f: jnp.ndarray          # (L, 9) f32   (last row = junk)
@@ -785,6 +893,7 @@ class GrowerPrograms:
             nl=jnp.asarray(1, jnp.int32),
             waves=jnp.asarray(0, jnp.int32),
             slots=jnp.asarray(0, jnp.int32),
+            hwork=jnp.zeros((3,), jnp.int32),
             done=jnp.asarray(False),
             rec_i=jnp.full((L, REC_I_FIELDS), -1, jnp.int32),
             rec_f=jnp.zeros((L, REC_F_FIELDS), jnp.float32),
@@ -829,8 +938,9 @@ class GrowerPrograms:
         def make_wave(Ws: int):
           def wave(st: _S) -> _S:
             # 1. fresh histograms for pending smaller children
-            fresh = self._wave_hist(binned, st.leaf_id, gh5, st.p_small,
-                                    num_valid, wave_scales)   # (W,S,3)
+            fresh, hw = self._wave_hist(binned, st.leaf_id, gh5,
+                                        st.p_small, num_valid,
+                                        wave_scales)           # (W,S,3)
             with jax.named_scope("lgb.hist_state"):
                 root_wave = st.p_parent[0] < 0
                 # root total from group-0 slot sums (every row hits one slot)
@@ -1048,6 +1158,8 @@ class GrowerPrograms:
                       depth=depth, best=best, bestc=bestc, bestl=bestl,
                       nl=st.nl + napply,
                       waves=st.waves + 1, slots=st.slots + Ws,
+                      hwork=st.hwork + jnp.stack(
+                          [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK]),
                       done=napply == 0,
                       rec_i=rec_i, rec_f=rec_f, rec_c=rec_c,
                       p_parent=pp, p_small=ps, p_large=pl)
@@ -1188,11 +1300,18 @@ class GrowerPrograms:
                              preferred_element_type=jnp.float32)
             new_score = score + (upd[:, 0] + upd[:, 1])[:self.num_data]
 
+        hwork = final.hwork
+        if self.shard is not None:
+            # each shard gathers and scans its own live rows
+            with jax.named_scope("lgb.psum"):
+                hwork = jax.lax.psum(hwork, self.shard.axis)
         return (new_score, final.rec_i[:max(L - 1, 1)],
                 rec_f_out[:max(L - 1, 1)],
                 final.rec_c[:max(L - 1, 1)], final.nl, final.value[0],
-                jnp.stack([final.waves, final.slots, rows_in_bag,
-                           jnp.sum(feature_mask, dtype=jnp.int32)]),
+                jnp.concatenate([
+                    jnp.stack([final.waves, final.slots, rows_in_bag,
+                               jnp.sum(feature_mask, dtype=jnp.int32)]),
+                    hwork]),
                 qscales)
 
     # ------------------------------------------------------------------
@@ -1223,7 +1342,7 @@ class GrowerPrograms:
                 meta, hyper, tables, grad_fn=fn)
             -> (final_score,
                 (rec_i (K,L-1,5), rec_f (K,L-1,9), rec_c (K,L-1,8),
-                 nl (K,), root_value (K,), work (K,4), qscales (K,2)))
+                 nl (K,), root_value (K,), work (K,7), qscales (K,2)))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);
@@ -1927,7 +2046,7 @@ class DeviceGrower:
             fn = obs.track_jit(
                 f"stage_probe_w{w}",
                 jax.jit(lambda b, l, g2, p:
-                        progs._wave_hist(b, l, g2, p, n, wave_scales)))
+                        progs._wave_hist(b, l, g2, p, n, wave_scales)[0]))
             return fn, leaf, ghk, pend
 
         hist_out = {}
@@ -2000,7 +2119,7 @@ class DeviceGrower:
                 find_ms[w] = round(timed(two_fn, h2, mask_all), 3)
 
                 def fused_body(b, l, g2, p, m):
-                    fr = progs._wave_hist(b, l, g2, p, n, wave_scales)
+                    fr, _ = progs._wave_hist(b, l, g2, p, n, wave_scales)
                     return jnp.concatenate([scan_stack(fr, m),
                                             scan_stack(-fr, m)])
 

@@ -1,0 +1,97 @@
+"""One wave histogram at a cell's shape, timed on the chip: the program's
+own ``GrowerPrograms._wave_hist`` at a stage width and a share of live
+rows, with the live rows gathered ahead of the chunk loop (``on``), left
+where they lie (``off``), or as the program's own width rule has it
+(``as_is``).
+
+    python3 scripts/bench_wave_hist.py --shape criteo --live 0.45 \\
+        --variants "on:4,8,16,32,96 off:4,8,16"
+
+Lines go to stdout and to ``chiprun_out/wave_hist_bench.jsonl``.
+``--repo`` runs another unpacked tree (one without the gather answers
+every variant the same way).
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+SHAPES = {"criteo": (1 << 24, 67, 255), "cdn": (20_447_232, 53, 31),
+          "tiny": (1 << 16, 9, 31)}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--repo", default=".")
+    ap.add_argument("--tag", default="")
+    ap.add_argument("--shape", default="criteo")
+    ap.add_argument("--widths", default="4,8,32,96")
+    ap.add_argument("--live", default="1.0,0.45")
+    ap.add_argument("--variants", default="as_is",
+                    help="space-separated as_is | on:W,W | off:W,W")
+    args = ap.parse_args(argv)
+    sys.path.insert(0, os.path.abspath(args.repo))
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops import grow as growmod
+
+    n, g, leaves = SHAPES[args.shape]
+    dev = jax.devices()[0]
+    os.makedirs("chiprun_out", exist_ok=True)
+    sink = open("chiprun_out/wave_hist_bench.jsonl", "a")
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(11), 4)
+    binned = jax.random.randint(k1, (n, g), 0, 255, jnp.int32) \
+        .astype(jnp.uint8)
+    grad = jax.random.normal(k2, (n,), jnp.float32)
+    hess = jnp.abs(grad) + 0.1
+    u = jax.random.uniform(k3, (n,))
+    todo = []
+    for v in args.variants.split():
+        name, _, ws = v.partition(":")
+        todo += [(name, int(w)) for w in (ws or args.widths).split(",")]
+    for variant, w in todo:
+        if variant != "as_is":
+            # read when the programs object is built
+            growmod._GATHER_MIN_LANES = 1 << 30 if variant == "off" else 0
+        progs = growmod.GrowerPrograms(
+            num_data=n, num_groups=g, nb=256, num_features=g,
+            has_cat=False, plan=[(w, None)],
+            config=Config({"objective": "binary", "num_leaves": leaves,
+                           "verbosity": -1}))
+        ghk, _ = progs._stat_columns(grad, hess,
+                                     jnp.ones((n,), jnp.float32), 0)
+        pend = jnp.arange(w, dtype=jnp.int32)
+
+        def call(b, l, g2, p):
+            out = progs._wave_hist(b, l, g2, p, n, None)
+            return out[0] if isinstance(out, tuple) else out
+
+        fn = jax.jit(call)
+        for share in (float(v) for v in args.live.split(",")):
+            # leaves 0..w-1 are pending and hold ``share`` of the rows;
+            # the others sit in leaf w
+            leaf = jnp.where(u < share,
+                             jax.random.randint(k4, (n,), 0, w), w)
+            jax.block_until_ready(fn(binned, leaf, ghk, pend))
+            t0 = time.perf_counter()
+            for _ in range(2):
+                r = fn(binned, leaf, ghk, pend)
+            jax.block_until_ready(r)
+            line = json.dumps({
+                "tag": args.tag, "variant": variant,
+                "shape": args.shape, "n": n, "g": g,
+                "k": int(progs.hist_cols), "w": w, "live_share": share,
+                "seconds": round((time.perf_counter() - t0) / 2, 6),
+                "count_sum": float(r[..., 2].sum()),
+                "device": dev.device_kind})
+            print(line, flush=True)
+            sink.write(line + "\n")
+            sink.flush()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -81,10 +81,10 @@ def _kernel_parity() -> bool:
         rng.integers(-127, 128, (n, k)).astype(np.int8))
     pending = jnp.arange(w, dtype=jnp.int32)
     got = np.asarray(progs._wave_hist(grower.binned, leaf, ghk, pending,
-                                      n))
+                                      n)[0])
     progs.use_pallas = False
     ref = np.asarray(progs._wave_hist(grower.binned, leaf, ghk, pending,
-                                      n))
+                                      n)[0])
     if got.dtype != np.int32 or ref.dtype != np.int32:
         print(f"FAIL kernel parity: expected int32 histograms, got "
               f"pallas={got.dtype} einsum={ref.dtype}")

@@ -145,14 +145,17 @@ def scenario_core():
     def work():
         c = obs.registry().snapshot()["counters"]
         return [c.get(f"grow.{k}", 0)
-                for k in ("waves", "rows_scanned", "rows_real")]
+                for k in ("waves", "rows_scanned", "rows_real",
+                          "rows_live", "trees")]
 
     x3, y3 = _data(rows=20000)
     w0 = work()
     bst = _train(x3, y3, SHARD, iters=2, return_booster=True)
-    waves, scanned, real = (a - b for a, b in zip(work(), w0))
+    waves, scanned, real, live, trees = (a - b
+                                         for a, b in zip(work(), w0))
     out["work"] = {"waves": waves, "rows_scanned": scanned,
-                   "rows_real": real, "n_pad": int(bst._grower.n_pad),
+                   "rows_real": real, "rows_live": live, "trees": trees,
+                   "n_pad": int(bst._grower.n_pad),
                    "shards": int(bst._grower.shard.n_shards)}
     return out
 

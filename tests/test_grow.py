@@ -293,10 +293,10 @@ def test_pallas_hist_matches_einsum(reg_data):
         np.concatenate([np.arange(6), [-1] * (grower.wave_width - 6)])
         .astype(np.int32))
     grower.use_pallas = False
-    ref = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n))
+    ref = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n)[0])
     grower.use_pallas = True
     grower.pallas_interpret = True
-    got = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n))
+    got = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n)[0])
     np.testing.assert_allclose(got, ref, atol=1e-3, rtol=1e-4)
 
 
@@ -308,6 +308,30 @@ _LAYOUTS = {"bf16_k3": ({}, False, 3), "bf16_k4_striped": ({}, True, 4),
             "int8_k3": ({"grad_quant_bits": 8}, False, 3)}
 
 
+def _six_slot_programs(layout):
+    """``GrowerPrograms`` of one six-slot stage over four row chunks in
+    the stat-column layout ``layout`` — the module's comments invite
+    both overrides: counts striped on few rows, live rows gathered in a
+    narrow wave — and (n_pad, groups, bins, slots)."""
+    from lightgbm_tpu.ops import grow as growmod
+
+    extra, striped, k = _LAYOUTS[layout]
+    n, groups, nb, w = 4 * growmod._CHUNK, 5, 64, 6
+    old = growmod.COUNT_SPLIT_ROWS, growmod._GATHER_MIN_LANES
+    try:
+        growmod.COUNT_SPLIT_ROWS = 1 if striped else old[0]
+        growmod._GATHER_MIN_LANES = 0
+        progs = growmod.GrowerPrograms(
+            num_data=n, num_groups=groups, nb=nb, num_features=groups,
+            has_cat=False, plan=[(w, None)],
+            config=Config({"objective": "binary", "num_leaves": w + 1,
+                           "verbosity": -1, **extra}))
+    finally:
+        growmod.COUNT_SPLIT_ROWS, growmod._GATHER_MIN_LANES = old
+    assert (progs.n_pad, progs.hist_cols, progs.striped) == (n, k, striped)
+    return progs, (n, groups, nb, w)
+
+
 @pytest.fixture(scope="module", params=list(_LAYOUTS))
 def wave_hist_case(request):
     """(jitted ``_wave_hist`` with a traced ``num_valid``, n_pad, and a
@@ -315,22 +339,9 @@ def wave_hist_case(request):
     them) for one stat-column layout, over four row chunks."""
     import jax
     import jax.numpy as jnp
-    from lightgbm_tpu.ops import grow as growmod
 
-    extra, striped, k = _LAYOUTS[request.param]
-    n, groups, nb, w = 4 * growmod._CHUNK, 5, 64, 6
-    old = growmod.COUNT_SPLIT_ROWS
-    try:
-        # the module's comment invites this: stripe the counts on few rows
-        growmod.COUNT_SPLIT_ROWS = 1 if striped else old
-        progs = growmod.GrowerPrograms(
-            num_data=n, num_groups=groups, nb=nb, num_features=groups,
-            has_cat=False, plan=[(w, None)],
-            config=Config({"objective": "binary", "num_leaves": w + 1,
-                           "verbosity": -1, **extra}))
-    finally:
-        growmod.COUNT_SPLIT_ROWS = old
-    assert (progs.n_pad, progs.hist_cols, progs.striped) == (n, k, striped)
+    extra = _LAYOUTS[request.param][0]
+    progs, (n, groups, nb, w) = _six_slot_programs(request.param)
     rng = np.random.default_rng(27)
     binned = jnp.asarray(rng.integers(0, nb - 1, (n, groups))
                          .astype(np.uint8))
@@ -347,7 +358,7 @@ def wave_hist_case(request):
                 pending), (scales if extra else None)
 
     fn = jax.jit(lambda args, nv, scales:
-                 progs._wave_hist(*args, nv, scales))
+                 progs._wave_hist(*args, nv, scales)[0])
     return fn, n, masked
 
 
@@ -372,6 +383,96 @@ def test_wave_hist_stops_at_the_last_live_chunk(wave_hist_case, rows):
     # is counted once in each of the 5 groups: nothing live was skipped
     in_wave = int(np.isin(np.asarray(args[1]), np.arange(5)).sum())
     assert int(np.asarray(got, np.float64)[..., 2].sum()) == 5 * in_wave
+
+
+# ---------------------------------------------------------------------------
+# the wave histogram contracts only the live rows of its pending leaves
+# ---------------------------------------------------------------------------
+
+_PENDING = {"one_leaf": [3, -1, -1, -1, -1, -1],
+            "half_the_leaves": [4, -1, 0, -1, 2, -1],
+            "all_leaves": [0, 1, 2, 3, 4, 5],
+            "none": [-1] * 6}
+
+
+@pytest.fixture(scope="module", params=list(_LAYOUTS))
+def live_rows_case(request):
+    """(jitted ``(pending, bag) -> (hist, [chunks visited, live rows])``
+    over four row chunks whose last 100 rows are padding, and the numpy
+    operands of the same call: bins, leaf ids, stat columns as float64 /
+    int64, the count columns' positions) for one stat-column layout."""
+    import jax
+    import jax.numpy as jnp
+
+    extra, striped, _ = _LAYOUTS[request.param]
+    progs, (n, groups, nb, w) = _six_slot_programs(request.param)
+    rng = np.random.default_rng(29)
+    bins = rng.integers(0, nb - 1, (n, groups)).astype(np.uint8)
+    valid = np.arange(n) < n - 100
+    leaf = np.where(valid, rng.integers(0, w, n), -1).astype(np.int32)
+    grad = rng.standard_normal(n).astype(np.float32)
+    hess = rng.random(n).astype(np.float32)
+    binned = jnp.asarray(bins)
+
+    def operands(bag):
+        one = jnp.asarray((valid & bag).astype(np.float32))
+        return progs._stat_columns(jnp.asarray(grad) * one,
+                                   jnp.asarray(hess) * one, one, 0)
+
+    @jax.jit
+    def fn(pending, bag):
+        ghk, scales = operands(bag)
+        return progs._wave_hist(binned, jnp.asarray(leaf), ghk, pending,
+                                jnp.int32(n - 100),
+                                scales if extra else None)
+
+    def numpy_operands(bag):
+        ghk = np.asarray(operands(jnp.asarray(bag))[0].astype(jnp.float32))
+        return ghk.astype(np.int64 if extra else np.float64)
+
+    return fn, bins, leaf, numpy_operands, (2, 3) if striped else (2,)
+
+
+@pytest.mark.parametrize("bag", ["no_bag", "bag_0.8"])
+@pytest.mark.parametrize("pending", list(_PENDING))
+def test_wave_hist_contracts_only_the_live_rows(live_rows_case, pending,
+                                                bag):
+    """The compacted histogram is the plain histogram of the same
+    operands — counts and int8 sums exactly, bfloat16 sums to float32
+    re-association — and the loop visited ``ceil(live / _CHUNK)`` chunks,
+    live being the in-bag real rows of the pending leaves."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.grow import _CHUNK
+
+    fn, bins, leaf, numpy_operands, cnt_cols = live_rows_case
+    n, groups = bins.shape
+    nb = 64
+    in_bag = np.ones(n, bool) if bag == "no_bag" \
+        else np.random.default_rng(31).random(n) < 0.8
+    pend = np.asarray(_PENDING[pending], np.int32)
+    hist, work = fn(jnp.asarray(pend), jnp.asarray(in_bag))
+    hist = np.asarray(hist).reshape(len(pend), groups, nb, 3)
+    ghk = numpy_operands(in_bag)
+    cols = [ghk[:, 0], ghk[:, 1], ghk[:, list(cnt_cols)].sum(1)]
+    live = np.isin(leaf, pend[pend >= 0]) & (cols[2] != 0)
+    assert [int(v) for v in np.asarray(work)] \
+        == [-(-int(live.sum()) // _CHUNK), int(live.sum())]
+    if pending != "none":
+        assert 0 < live.sum() <= n - 100
+    for slot, lf in enumerate(pend):
+        rows = live & (leaf == lf)
+        for gi in range(groups):
+            for c, col in enumerate(cols):
+                want = np.bincount(bins[rows, gi], weights=col[rows],
+                                   minlength=nb)
+                got = hist[slot, gi, :, c]
+                if c == 2 or ghk.dtype == np.int64:
+                    np.testing.assert_array_equal(got, want)
+                else:
+                    room = np.bincount(bins[rows, gi],
+                                       weights=np.abs(col[rows]),
+                                       minlength=nb)
+                    assert (np.abs(got - want) <= 1e-6 * room).all()
 
 
 def test_device_bagging_matches_host(reg_data):

@@ -184,6 +184,62 @@ def test_quant_pallas_striped_byte_identical():
         growmod.COUNT_SPLIT_ROWS = old
 
 
+_CHUNK_WORKER = """
+import hashlib, json, sys
+import numpy as np
+sys.path.insert(0, {root!r})
+import lightgbm_tpu as lgb
+from lightgbm_tpu.ops.grow import _CHUNK
+rng = np.random.default_rng(29)
+x = rng.standard_normal((32768, 8)).astype(np.float32)
+y = (x[:, 0] + np.abs(x[:, 1]) + 0.3 * rng.standard_normal(32768)
+     > 0.8).astype(np.float32)
+params = dict(objective="binary", verbosity=-1, device_growth="on",
+              num_leaves=31, max_bin=63, min_data_in_leaf=20,
+              grad_quant_bits=8, bagging_fraction=0.8, bagging_freq=2,
+              fused_chunk=2)
+ds = lgb.Dataset(x, label=y, params=params).construct()
+bst = lgb.train(params, ds, num_boost_round=4, verbose_eval=False,
+                keep_training_booster=True)
+text = bst.model_to_string()
+text = text[:text.index("parameters:")]
+grower = bst._gbdt._grower
+print(json.dumps(dict(chunks=int(grower.n_pad) // _CHUNK,
+                      lanes=max(w for w, _ in grower.stage_plan)
+                      * int(grower.hist_cols),
+                      trees=text.count("Tree="),
+                      sha=hashlib.sha256(text.encode()).hexdigest())))
+"""
+
+
+@pytest.mark.timeout(400)
+def test_quant_model_is_the_same_gathered_or_not():
+    """Integer histograms are exact in any row order, so the model text
+    does not depend on whether a wave gathers its live rows ahead of the
+    chunk loop: four chunks of 8,192 rows (gathered in the wide stage)
+    and one chunk of 32,768 (every row contracted where it lies, the
+    path before the gather existed) write the same bytes."""
+    import json
+    import subprocess
+
+    from lightgbm_tpu.ops.grow import _GATHER_MIN_LANES
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    got = {}
+    for chunk in (8192, 32768):
+        env = {**os.environ, "LGBM_TPU_CHUNK": str(chunk),
+               "JAX_PLATFORMS": "cpu"}
+        proc = subprocess.run(
+            [sys.executable, "-c", _CHUNK_WORKER.format(root=root)],
+            env=env, capture_output=True, text=True, timeout=380)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        got[chunk] = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert (got[8192]["chunks"], got[32768]["chunks"]) == (4, 1)
+    assert got[8192]["lanes"] >= _GATHER_MIN_LANES
+    assert got[8192]["trees"] == 4
+    assert got[8192]["sha"] == got[32768]["sha"]
+
+
 def test_quant_int_scan_bound_and_f32_fallback():
     """The int32 find-best scan engages below INT32_SCAN_ROWS (every
     |sum| <= 127 * rows fits int32) and falls back to the PR-4 f32

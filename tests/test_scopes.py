@@ -67,10 +67,10 @@ class _Recorder:
         return self.fn(*args, **kwargs)
 
 
-def _fused_text(extra=None) -> str:
+def _fused_text(extra=None, rows=3000) -> str:
     """Debug text of the fused program ``update_chunked`` dispatches
     under ``extra`` params."""
-    bst = _booster(extra)
+    bst = _booster(extra, rows=rows)
     progs = bst._gbdt._grower.programs
     (length, fn), = progs._fused.items()
     rec = progs._fused[length] = _Recorder(fn)
@@ -108,6 +108,9 @@ _TEXTS = {
                                     "feature_fraction": 0.8}),
     "sharded": lambda: _fused_text({"data_sharding": "single_controller",
                                     "shard_devices": 2}),
+    # 17,000 rows are three histogram chunks and 31 leaves end in a
+    # stage of 30 x 3 lanes: only such a wave gathers its live rows
+    "chunks": lambda: _fused_text({"num_leaves": 31}, rows=17000),
     "traverse": _traverse_text,
     "bin": _bin_text,
 }
@@ -122,7 +125,8 @@ def _text(which: str) -> str:
 
 REACHED_BY = {
     "lgb.gradient": "plain", "lgb.stat_cols": "plain",
-    "lgb.wave_hist": "plain", "lgb.hist_state": "plain",
+    "lgb.wave_hist": "plain", "lgb.wave_gather": "chunks",
+    "lgb.hist_state": "plain",
     "lgb.find_best": "plain", "lgb.split_apply": "plain",
     "lgb.score_update": "plain", "lgb.leaf_refit": "quant",
     "lgb.bag_draw": "bagging", "lgb.psum": "sharded",
@@ -149,6 +153,8 @@ def test_scopes_sit_where_the_program_runs_them():
     # the histogram runs inside the tree's while loop
     assert hist and all("while" in p.split("/")[:p.split("/").index(
         "lgb.wave_hist")] for p in hist)
+    # one chunk of rows is contracted where it lies
+    assert not any("lgb.wave_gather" in p.split("/") for p in paths)
     # the one-chip program has no collective: nothing carries lgb.psum
     assert not any("lgb.psum" in p.split("/") for p in paths)
     # and an unquantised run has no refit block
@@ -196,7 +202,8 @@ def _grow_counters():
 
 
 # 3,000 rows sit in one histogram chunk (the suite's LGBM_TPU_CHUNK is
-# 8192); 17,000 pad to the 32,768 bucket, whose fourth chunk holds no row
+# 8192); 17,000 pad to the 32,768 bucket, whose fourth chunk holds no
+# row, and grow 31 leaves there: the last stage is wide enough to gather
 @pytest.fixture(params=[3000, 17000], ids=["one_chunk", "dead_chunk"])
 def two_chunks(request):
     """(booster, per-chunk [(nl, work)] as the program returned them,
@@ -205,13 +212,14 @@ def two_chunks(request):
     returned = []
     orig = _WorkDrain.push
 
-    def spy(self, nl, work, rows_scanned, rows_real):
-        returned.append((nl, work, rows_scanned, rows_real))
-        return orig(self, nl, work, rows_scanned, rows_real)
+    def spy(self, nl, work, rows_real):
+        returned.append((nl, work, rows_real))
+        return orig(self, nl, work, rows_real)
 
     _WorkDrain.push = spy
     try:
-        bst = _booster(rounds=2, rows=request.param)
+        bst = _booster({"num_leaves": 31} if request.param > _CHUNK
+                       else None, rounds=2, rows=request.param)
         jax.block_until_ready(bst._gbdt.train_score)
         after1 = _grow_counters()
         bst.update_chunked(2)
@@ -225,10 +233,10 @@ def two_chunks(request):
 def test_counters_hold_every_tree_and_the_returned_waves(two_chunks):
     _, returned, _, c = two_chunks
     assert c["grow.trees"] == 4
-    work = np.concatenate([np.asarray(w).reshape(-1, 4)
-                           for _, w, _, _ in returned])
+    work = np.concatenate([np.asarray(w).reshape(-1, 7)
+                           for _, w, _ in returned])
     nl = np.concatenate([np.asarray(n).reshape(-1)
-                         for n, _, _, _ in returned])
+                         for n, _, _ in returned])
     assert c["grow.waves"] == int(work[:, 0].sum()) > 0
     assert c["grow.wave_slots"] == int(work[:, 1].sum())
     assert c["grow.leaves"] == int(nl.sum())
@@ -242,15 +250,25 @@ def test_counters_hold_every_tree_and_the_returned_waves(two_chunks):
 def test_counters_bound_each_other(two_chunks):
     bst, returned, _, c = two_chunks
     rows, n_pad = bst._gbdt.num_data, int(bst._gbdt._grower.n_pad)
-    live_chunks = -(-rows // _CHUNK)
-    assert 0 < c["grow.rows_real"] <= c["grow.rows_scanned"] \
-        <= c["grow.waves"] * n_pad
     assert c["grow.rows_real"] == c["grow.waves"] * rows
-    # the histogram visits the chunks that hold a real row, no more
-    assert c["grow.rows_scanned"] == c["grow.waves"] * live_chunks * _CHUNK
-    if rows == 17000:
-        assert (live_chunks, n_pad // _CHUNK) == (3, 4)
-        assert c["grow.rows_scanned"] < c["grow.waves"] * n_pad
+    # the program counts the chunks its histograms visit and the live
+    # rows in them: whole chunks, never more than hold a live row
+    assert 0 < c["grow.rows_live"] <= c["grow.rows_scanned"] \
+        <= c["grow.waves"] * n_pad
+    assert c["grow.rows_scanned"] % _CHUNK == 0
+    # every root wave finds all rows live, every later one at most half
+    assert 4 * rows <= c["grow.rows_live"] \
+        <= 4 * rows + (c["grow.waves"] - 4) * (rows // 2)
+    if rows == 3000:
+        # a single chunk is contracted where it lies, in every wave
+        assert c["grow.rows_scanned"] == c["grow.waves"] * _CHUNK
+    else:
+        # three chunks hold the 17,000 rows: the narrow stage's waves
+        # visit them all, the wide stage's the chunks their live rows
+        # fill (at most 8,500 rows: two)
+        assert n_pad // _CHUNK == 4
+        assert [w for w, _ in bst._gbdt._grower.stage_plan] == [4, 30]
+        assert c["grow.rows_scanned"] < c["grow.waves"] * 3 * _CHUNK
     # a wave of width W applies at most W splits
     assert 0 < c["grow.leaves"] - c["grow.trees"] <= c["grow.wave_slots"]
     # every wave offers at least one slot and at most the widest stage
@@ -259,15 +277,60 @@ def test_counters_bound_each_other(two_chunks):
         <= widest * c["grow.waves"]
 
 
+@pytest.mark.parametrize("bag", [None, 0.8], ids=["no_bag", "bag_0.8"])
+def test_rows_live_is_the_bag_and_the_smaller_children(bag):
+    """``grow.rows_live`` against the model's own counts: the root wave
+    finds the in-bag rows, each later wave the smaller child of every
+    split of the wave before.  With 7 leaves the plan is one stage as
+    wide as the leaf budget, so a split is applied in the wave after its
+    parent's, and only the last wave's children go uncontracted — when
+    the tree is full; else one more wave runs and splits nothing."""
+    obs.configure(enabled=True)
+    extra = {} if bag is None else {"bagging_fraction": bag,
+                                    "bagging_freq": 1}
+    bst = _booster(extra, rounds=2, rows=30000)
+    jax.block_until_ready(bst._gbdt.train_score)
+    c = _grow_counters()
+    grower = bst._gbdt._grower
+    assert grower.n_pad // _CHUNK >= 4
+    assert [w for w, _ in grower.stage_plan] == [BASE["num_leaves"] - 1]
+    bst._gbdt._flush_pending()
+    live = waves = 0
+    for it, tree in enumerate(bst._gbdt.models):
+        splits = tree.num_leaves - 1
+        count = lambda ch: int(tree.internal_count[ch] if ch >= 0
+                               else tree.leaf_count[~ch])
+        wave_of = np.ones(splits, int)
+        for node in range(splits):
+            for ch in (tree.left_child[node], tree.right_child[node]):
+                if ch >= 0:
+                    wave_of[ch] = wave_of[node] + 1
+        full = tree.num_leaves == BASE["num_leaves"]
+        last = wave_of.max()
+        live += int(bst.sampled_rows(it).sum())
+        live += sum(min(count(tree.left_child[node]),
+                        count(tree.right_child[node]))
+                    for node in range(splits)
+                    if not (full and wave_of[node] == last))
+        waves += last + (0 if full else 1)
+    assert len(bst._gbdt.models) == 2 and waves >= 6
+    assert c["grow.waves"] == waves
+    assert c["grow.rows_live"] == live
+    if bag is not None:
+        assert c["grow.rows_in_bag"] < 0.85 * 2 * 30000
+
+
 def test_snapshot_delta_is_exactly_the_chunk_between(two_chunks):
     _, returned, c1, c2 = two_chunks
     assert c1["grow.trees"] == 2
-    nl, work, scanned, real = returned[1]
-    work = np.asarray(work).reshape(-1, 4)
+    nl, work, real = returned[1]
+    work = np.asarray(work).reshape(-1, 7)
     waves = int(work[:, 0].sum())
     want = {"grow.trees": 2, "grow.leaves": int(np.asarray(nl).sum()),
             "grow.waves": waves, "grow.wave_slots": int(work[:, 1].sum()),
-            "grow.rows_scanned": waves * scanned,
+            "grow.rows_scanned": int(work[:, 4].sum()) * _CHUNK,
+            "grow.rows_live": int(work[:, 5].sum()) * _CHUNK
+            + int(work[:, 6].sum()),
             "grow.rows_real": waves * real}
     assert {k: c2[k] - c1[k] for k in want} == want
 

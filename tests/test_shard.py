@@ -94,15 +94,22 @@ def test_shard_obs_digest(core_report):
 
 def test_shard_rows_scanned_is_the_sum_of_the_shards_live_chunks(
         core_report):
-    # each shard's histogram loop stops at its own last live chunk:
+    # the program counts, per shard, the chunks its histogram loop
+    # visits and the live rows it finds, and sums them over the mesh:
     # 20,000 rows in contiguous 8,192-row blocks give the four shards
-    # 1, 1, 1 and 0 live chunks (LGBM_TPU_CHUNK=8192 in the worker)
+    # 1, 1, 1 and 0 chunks a wave (LGBM_TPU_CHUNK=8192 in the worker;
+    # a shard of one chunk contracts its rows where they lie)
     w = core_report["work"]
     assert (w["shards"], w["n_pad"]) == (4, 8192)
-    assert w["waves"] > 0
+    assert w["waves"] > w["trees"] > 0
     assert w["rows_real"] == w["waves"] * 20000
     assert w["rows_scanned"] == w["waves"] * 3 * 8192 \
         < w["waves"] * w["shards"] * w["n_pad"]
+    # every root wave finds all rows live, every later one at most half,
+    # whichever shards hold them
+    assert w["trees"] * 20000 <= w["rows_live"] \
+        <= w["trees"] * 20000 + (w["waves"] - w["trees"]) * 10000
+    assert w["rows_live"] <= w["rows_scanned"]
 
 
 @pytest.mark.slow
