@@ -650,10 +650,10 @@ def _kernel_route_counts(snapshot_before: dict,
                          prefixes=("grow.hist.",
                                    "grow.fused_find.")) -> dict:
     """grow.hist.* / grow.fused_find.* routing counter deltas since
-    ``snapshot_before`` — which histogram kernel (einsum/pallas x
-    bf16/int8) actually served the dispatches of one benchmark leg, and
-    whether the find-best scan rode those dispatches (fused) or paid
-    its own.  grow.hist.* keys keep their historical short form
+    ``snapshot_before`` — under which tag (einsum x bf16/int8) the
+    dispatches of one benchmark leg counted their histogram and the
+    find-best scan that rides it.  grow.hist.* keys keep their
+    historical short form
     (``einsum_int8``); other prefixes keep a qualifier
     (``fused_find.einsum_int8``) so the two families stay distinct."""
     from lightgbm_tpu import obs
@@ -675,18 +675,11 @@ def _kernel_route_counts(snapshot_before: dict,
 
 
 def run_quant(args) -> dict:
-    """Paired quantization benchmark: f32 / int8-einsum / int8-pallas
-    legs over ONE shared dataset in ONE process (warm compile cache,
-    identical bins), reporting ms_per_tree per leg plus the speedup
-    matrix — BENCH_r06's int8 claims as a single command producing a
-    single JSON line.
-
-    The pallas leg uses the VMEM kernel on TPU and interpret mode
-    elsewhere (CPU: plumbing/parity validation, not a perf number);
-    routing counters per leg record which kernel actually ran — the
-    kernel only serves full-width stages whose stat columns fit one
-    128-lane tile (wave_width * hist_cols <= 128), wider configs fall
-    back to the einsum and the JSON says so."""
+    """Paired quantization benchmark: f32 and int8 legs over ONE shared
+    dataset in ONE process (warm compile cache, identical bins),
+    reporting ms_per_tree per leg plus the speedup — BENCH_r06's int8
+    claim as a single command producing a single JSON line (off the
+    TPU: plumbing validation, not a perf number)."""
     import jax
     from lightgbm_tpu import obs
     from lightgbm_tpu.boosting import create_boosting
@@ -694,12 +687,11 @@ def run_quant(args) -> dict:
     from lightgbm_tpu.data.dataset import BinnedDataset
 
     backend = jax.default_backend()
-    pallas_mode = "pallas" if backend == "tpu" else "interpret"
     # paired legs need ONE stage plan: each leg has its own config
-    # digest (grad_quant_bits/hist_kernel differ), so wave_plan=auto's
+    # digest (grad_quant_bits differs), so wave_plan=auto's
     # profile-on-first-use would let every leg install a different
-    # measured plan and the speedup matrix would conflate plan deltas
-    # with kernel deltas.  Default to the byte-stable fixed ladder;
+    # measured plan and the speedup would conflate plan deltas with
+    # quantization deltas.  Default to the byte-stable fixed ladder;
     # an explicit --wave-plan profiled still profiles per leg (then
     # waves_per_tree in the JSON is the cross-check).
     wave_plan = "fixed" if args.wave_plan == "auto" else args.wave_plan
@@ -726,16 +718,7 @@ def run_quant(args) -> dict:
 
     legs = [
         ("f32", {"grad_quant_bits": 0}),
-        ("int8_einsum", {"grad_quant_bits": 8, "hist_kernel": "einsum"}),
-        # the paired find-best leg: identical kernel/quant config to
-        # int8_einsum, but the gain scan pays its own dispatch per wave
-        # instead of riding the histogram program — the fused_delta
-        # block below is the tentpole's before/after on ONE dataset
-        ("int8_two_pass", {"grad_quant_bits": 8,
-                           "hist_kernel": "einsum",
-                           "find_best_fusion": "two_pass"}),
-        ("int8_pallas", {"grad_quant_bits": 8,
-                         "hist_kernel": pallas_mode}),
+        ("int8_einsum", {"grad_quant_bits": 8}),
     ]
     leg_out = {}
     for name, extra in legs:
@@ -751,7 +734,6 @@ def run_quant(args) -> dict:
         per_iter = timed_s / max(iters_timed, 1)
         grower = getattr(bst, "_grower", None)
         wpt = _waves_per_tree(before)
-        fused = bool(getattr(grower, "fused_find", False))
         leg_out[name] = {
             "ms_per_tree": round(1000.0 * per_iter, 2),
             "timed_s": round(timed_s, 3),
@@ -760,12 +742,6 @@ def run_quant(args) -> dict:
             "waves_per_tree": wpt,
             "hist_kernel_tag": getattr(grower, "hist_kernel_tag", None),
             "int_scan": bool(getattr(grower, "int_scan", False)),
-            "find_best_fusion": getattr(grower, "find_fusion", None),
-            # program dispatches per tree under the leg's layout: a
-            # fused wave is ONE dispatch, two-pass pays the second
-            # find-best program every wave
-            "dispatches_per_tree": round(wpt * (1 if fused else 2), 2)
-            if wpt is not None else None,
             "kernel_dispatches": _kernel_route_counts(before),
         }
 
@@ -776,7 +752,7 @@ def run_quant(args) -> dict:
     return {
         "metric": f"quant_suite_higgs_{args.rows}x28_{args.iters}iter"
                   f"_ms_per_tree",
-        "value": leg_out["int8_pallas"]["ms_per_tree"],
+        "value": leg_out["int8_einsum"]["ms_per_tree"],
         "unit": "ms",
         "rows": args.rows,
         "iters": args.iters,
@@ -788,29 +764,6 @@ def run_quant(args) -> dict:
         "legs": leg_out,
         "speedup": {
             "f32_vs_int8_einsum": _speedup("f32", "int8_einsum"),
-            "f32_vs_int8_pallas": _speedup("f32", "int8_pallas"),
-            "int8_einsum_vs_int8_pallas": _speedup("int8_einsum",
-                                                   "int8_pallas"),
-            "two_pass_vs_fused": _speedup("int8_two_pass",
-                                          "int8_einsum"),
-        },
-        # the tentpole's before/after at matched kernel/quant config:
-        # fused (int8_einsum) vs two_pass on the SAME shared dataset
-        "fused_delta": {
-            "ms_per_tree_fused": leg_out["int8_einsum"]["ms_per_tree"],
-            "ms_per_tree_two_pass":
-                leg_out["int8_two_pass"]["ms_per_tree"],
-            "ms_per_tree_saved": round(
-                leg_out["int8_two_pass"]["ms_per_tree"]
-                - leg_out["int8_einsum"]["ms_per_tree"], 2),
-            "waves_per_tree_fused":
-                leg_out["int8_einsum"]["waves_per_tree"],
-            "waves_per_tree_two_pass":
-                leg_out["int8_two_pass"]["waves_per_tree"],
-            "dispatches_per_tree_fused":
-                leg_out["int8_einsum"]["dispatches_per_tree"],
-            "dispatches_per_tree_two_pass":
-                leg_out["int8_two_pass"]["dispatches_per_tree"],
         },
         "backend": backend,
         "device": str(jax.devices()[0]),
@@ -1351,10 +1304,10 @@ def main() -> int:
                          "fresh-subprocess warmup_compile_s cold vs "
                          "persistent-compile-cache warm vs AOT-warmed "
                          "(docs/ColdStart.md; gates warm >= 5x cold); "
-                         "quant = paired f32 / int8-einsum / int8-pallas "
+                         "quant = paired f32 / int8 "
                          "legs over one shared dataset in one process, "
                          "emitting ms_per_tree per leg + the speedup "
-                         "matrix + kernel routing counters (BENCH_r06); "
+                         "+ routing counters (BENCH_r06); "
                          "shard = single-device vs N-device single-"
                          "controller legs + the multiprocess mesh path "
                          "over one shared dataset, emitting "

@@ -15,14 +15,11 @@ It exits non-zero — printing no result line — unless
 ``jax.devices()[0].platform == "tpu"``.  There is no CPU mode and no
 switch that allows one: a run that fell back to the CPU would prove
 nothing about the chip.  The legs are plain functions that take sizes, so
-``tests/test_chip_smoke.py`` drives them tiny on the CPU mesh (Pallas
-under ``interpret=True``) without touching :func:`main`.
+``tests/test_chip_smoke.py`` drives them tiny on the CPU mesh without
+touching :func:`main`.
 
 Legs (all run even when an earlier one fails; any failure fails the run):
 
-``pallas``      ``wave_hist_pallas`` with ``interpret=False`` at 32,768
-                rows x 28 groups, nb=64, w=42, k=3, bf16 and int8, against
-                the grower's einsum body on the chip (int8 byte-equal).
 ``train``       ``lgb.train`` 20 rounds (one fused chunk: bin, profile,
                 compile, train = ``warmup_compile_s``, bench.py's
                 definition) then ``Booster.update_chunked`` 20 more, which
@@ -37,9 +34,8 @@ Legs (all run even when an earlier one fails; any failure fails the run):
 
 Standard output is two lines of JSON.  The first is the report: the jax /
 jaxlib / libtpu versions, per-leg seconds and findings,
-``warmup_compile_s``, peak HBM, ``compile_cache.counters()``,
-``stage_plan_source`` and the resolved find-best fusion.  The LAST is the
-verdict, with exactly these keys and the device as JAX reports it::
+``warmup_compile_s``, peak HBM, ``compile_cache.counters()`` and
+``stage_plan_source``.  The LAST is the verdict, with exactly these keys and the device as JAX reports it::
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
@@ -127,74 +123,6 @@ def _peak_hbm_bytes():
 # legs
 # ---------------------------------------------------------------------------
 
-def leg_pallas(rows: int = 32768, groups: int = FEATURES,
-               interpret: bool = False) -> dict:
-    """The VMEM wave-histogram kernel against the grower's einsum body,
-    at the widest single-tile wave (num_leaves=43: w=42, k=3, 126 of 128
-    lanes).  Also confirms which branch the host learner's histogram
-    (``ops/histogram.py``) traces on this backend."""
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.ops import grow
-    from lightgbm_tpu.ops.hist_pallas import wave_hist_pallas
-    from lightgbm_tpu.ops.histogram import _chunk_histogram
-
-    nb, w, k = 64, 42, 3
-    rng = np.random.default_rng(5)
-    binned = jnp.asarray(rng.integers(0, nb - 1, (rows, groups))
-                         .astype(np.uint8))
-    leaf = jnp.asarray(rng.integers(-1, w + 1, rows).astype(np.int32))
-    pending = jnp.arange(w, dtype=jnp.int32)
-    out = {}
-    for name, quant in (("bf16", 0), ("int8", 8)):
-        progs = grow.GrowerPrograms(
-            num_data=rows, num_groups=groups, nb=nb, num_features=groups,
-            has_cat=False,
-            config=Config({**PARAMS, "num_leaves": w + 1,
-                           "grad_quant_bits": quant,
-                           "hist_kernel": "einsum", "verbosity": -1}),
-            plan=[(w, None)])
-        assert (progs.wave_width, progs.hist_cols) == (w, k)
-        if quant:
-            ghk = jnp.asarray(rng.integers(-127, 128, (rows, k))
-                              .astype(np.int8))
-        else:
-            ghk = jnp.asarray(rng.standard_normal((rows, k))
-                              .astype(np.float32)).astype(jnp.bfloat16)
-        ref = np.asarray(jax.jit(
-            lambda b, l, g2, p: progs._wave_hist(b, l, g2, p, rows)[0])(
-                binned, leaf, ghk, pending))
-        t0 = time.perf_counter()
-        try:
-            got = wave_hist_pallas(binned, leaf, ghk, pending, g=groups,
-                                   nb=nb, k=k, w=w, interpret=interpret)
-            # (G*NB, K, W) -> the grower's (W, S, 3)
-            got = np.asarray(got.reshape(groups * nb, k, w)
-                             .transpose(2, 0, 1))
-            assert got.shape == ref.shape == (w, groups * nb, 3)
-            if quant:
-                assert got.dtype == ref.dtype == np.int32
-                np.testing.assert_array_equal(got, ref)
-                verdict = "compiled, byte-equal to the einsum"
-            else:
-                np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-2)
-                verdict = "compiled, matches the einsum"
-        except Exception as e:   # noqa: BLE001 — one verdict per dtype
-            traceback.print_exc()
-            verdict = f"FAILED {type(e).__name__}: {str(e)[:600]}"
-            out["error"] = f"{out.get('error', '')}{name} {verdict}; "
-        out[name] = {"verdict": verdict,
-                     "seconds": round(time.perf_counter() - t0, 2)}
-    # ops/histogram.py branches its kernel on jax.default_backend()
-    jaxpr = str(jax.make_jaxpr(_chunk_histogram)(
-        jnp.zeros((64, 2), jnp.uint8), jnp.zeros((64, 3), jnp.float32)))
-    out["host_learner_hist"] = ("einsum" if "dot_general" in jaxpr
-                                else "scatter_add")
-    return out
-
-
 def leg_train(rows: int, rounds_per_chunk: int = CHUNK,
               eval_rows: int = 100_000, extra_params=None,
               min_auc: float = MIN_AUC):
@@ -248,12 +176,9 @@ def leg_train(rows: int, rounds_per_chunk: int = CHUNK,
     rep.update(
         stage_plan_source=grower.plan_source,
         stage_plan=[[w_, c] for w_, c in grower.stage_plan],
-        find_best_fusion=grower.find_fusion,
         int_scan=bool(grower.int_scan),
         plan_profiles=_delta(c2, c0).get("grow.plan_profiles", 0),
-        # the routing evidence: which histogram kernel each fused
-        # dispatch ran (hist_kernel=pallas yields to the einsum past one
-        # lane tile)
+        # the histogram route each fused dispatch counted
         hist_dispatches=_delta(c2, c0, "grow.hist."),
         fused_train_compiles={
             k: v for k, v in _delta(c2, c0, "jit_compiles.").items()
@@ -376,7 +301,6 @@ def main() -> int:
     def train_int8():
         return leg_train(1 << 20, extra_params={"grad_quant_bits": 8})[0]
 
-    run("pallas", leg_pallas)
     run("train", train)
     if "bst" in state:
         run("serve", lambda: leg_serve(state["bst"], state["xt"]))
@@ -385,8 +309,7 @@ def main() -> int:
     run("train_int8", train_int8)
 
     train_rep = result["legs"]["train"]
-    for key in ("warmup_compile_s", "stage_plan_source",
-                "find_best_fusion"):
+    for key in ("warmup_compile_s", "stage_plan_source"):
         result[key] = train_rep.get(key)
     result["peak_hbm_bytes"] = _peak_hbm_bytes()
     result["compile_cache"] = {

@@ -4,7 +4,7 @@ Brand-new implementation of the LightGBM v2.2.2 capability surface
 (histogram-based leaf-wise GBDT, GOSS/DART/RF, EFB, categorical optimal
 splits, monotone constraints, full objective/metric set, feature/data/voting
 parallel distributed training) designed for TPU: the binned feature matrix is
-HBM-resident, histogram construction and split scanning run as Pallas/XLA
+HBM-resident, histogram construction and split scanning run as XLA
 kernels, and distributed modes use jax.lax collectives over a device mesh.
 """
 

@@ -252,12 +252,6 @@ class Config:
             raise ValueError(f"unknown wave_plan: {self.wave_plan}")
         self.wave_plan = wp
 
-        fbf = str(self.find_best_fusion).strip().lower()
-        if fbf not in ("auto", "fused", "two_pass"):
-            raise ValueError(
-                f"unknown find_best_fusion: {self.find_best_fusion}")
-        self.find_best_fusion = fbf
-
         dp = str(self.device_predict).strip().lower()
         if dp not in ("auto", "force", "off"):
             raise ValueError(f"unknown device_predict: "

@@ -248,7 +248,6 @@ class GrowerPrograms:
     def __init__(self, *, num_data: int, num_groups: int, nb: int,
                  num_features: int, has_cat: bool, config,
                  plan: list, plan_source: str = "default",
-                 fusion: Optional[str] = None,
                  shard: Optional[ShardSpec] = None, mesh=None):
         self.config = config.clone()
         config = self.config
@@ -294,8 +293,8 @@ class GrowerPrograms:
             else shard.n_shards * self.n_pad
         self.int_scan = bool(self.quant_bits) \
             and int_rows <= INT32_SCAN_ROWS
-        # Wave cost measured on the chip (scripts/ubench_hist.py,
-        # 10.5M rows): ~15.9 ms fixed (the one-hot operand generation
+        # Wave cost measured on an earlier backend's chip (10.5M
+        # rows): ~15.9 ms fixed (the one-hot operand generation
         # over all N, width-independent) + ~0.203 ms per stat column —
         # LINEAR in columns, not column-tile-quantized, and 72% of MXU
         # peak at 2 tiles (hist3_w84: 67.1 ms, 141.7 TF).  Since a wave
@@ -311,54 +310,11 @@ class GrowerPrograms:
         self.stage_plan = [(int(w), None if c is None else int(c))
                            for w, c in plan]
         self.plan_source = plan_source
-        # hist_kernel: "auto"/"einsum" use the XLA einsum formulation —
-        # the best measured for bf16 (both Pallas kernels lost to it,
-        # see ops/hist_pallas.py); "pallas" opts into the VMEM kernel
-        # on hardware, "interpret" runs it in interpreter mode (CPU
-        # tests).  Both the bf16 and the int8 quantized stat columns
-        # route through the same gate; the kernel accumulates
-        # int8->int32 on the MXU for grad_quant_bits=8 and is
-        # byte-identical to the int8 einsum (integer accumulation).
-        mode = str(getattr(config, "hist_kernel", "auto")
-                   or "auto").lower()
-        self.pallas_interpret = mode == "interpret"
-        self.use_pallas = mode in ("pallas", "interpret")
-        # routing attribution for BENCH digests: which kernel serves
-        # the full-width stage (narrow stages always stay on the
-        # einsum; multi-tile waves fall back to it too)
-        from .hist_pallas import fits_single_tile
-        kern = "pallas" if (self.use_pallas
-                            and fits_single_tile(self.wave_width,
-                                                 self.hist_cols)) \
-            else "einsum"
+        # the one histogram route (an einsum over 64-bin strips, see
+        # _wave_hist_local) under the name its counters carry:
+        # grow.hist.<tag> and grow.fused_find.<tag>
         self.hist_kernel_tag = \
-            f"{kern}_{'int8' if self.quant_bits else 'bf16'}"
-        if self.use_pallas and kern == "einsum":
-            # asked for the kernel, served by the einsum: say so once
-            # (programs are built once per signature); the
-            # grow.hist.<tag> counter stays the evidence of what ran
-            from ..utils.log import log_warning
-            log_warning(
-                f"hist_kernel={mode}: the full-width stage "
-                f"({self.wave_width} leaves x {self.hist_cols} stat "
-                f"columns = {self.wave_width * self.hist_cols} lanes) "
-                f"does not fit the Pallas kernel's single 128-lane "
-                f"tile (num_leaves <= 43 at 3 columns does); every "
-                f"stage runs the einsum — grow.hist."
-                f"{self.hist_kernel_tag} counts it")
-        # find-best placement inside the wave: "fused" keeps the gain
-        # scan in the SAME traced region as the histogram contraction —
-        # the fresh product and the parent-minus-sibling residual are
-        # scanned in place and no concatenated (2W, S, 3) tensor
-        # round-trips through HBM between them — while "two_pass" keeps
-        # the legacy concat layout.  The caller (get_grower_programs)
-        # resolves auto against a wave_plan=profiled verdict persisted
-        # for this signature; a direct construction without one adopts
-        # the default resolution here so the trace never depends on an
-        # unset attribute.
-        self.find_fusion = fusion if fusion in ("fused", "two_pass") \
-            else resolve_find_fusion(config)
-        self.fused_find = self.find_fusion == "fused"
+            f"einsum_{'int8' if self.quant_bits else 'bf16'}"
         # recompile tracking: these TrackedJit wrappers are shared by
         # every grower that adopts this programs object, so in the
         # retrain-every-window pattern a warm window re-dispatches into
@@ -603,8 +559,8 @@ class GrowerPrograms:
         A row is LIVE in a wave when its leaf is one of ``pending`` and
         its count column(s) are non-zero: every other row — another
         leaf's, bucket or shard padding, out of the bag, dropped by GOSS
-        — multiplies an all-zero operand row.  The einsum route gathers
-        the live rows to the front first (:meth:`_gather_live`, by rank
+        — multiplies an all-zero operand row.  The live rows are gathered
+        to the front first (:meth:`_gather_live`, by rank
         among live rows, so the chunks hold the same rows whatever
         ``n_pad`` is) and its chunk loop then runs
         ``ceil(live / _CHUNK)`` passes: a wave costs what the smaller
@@ -631,63 +587,46 @@ class GrowerPrograms:
                 & (pending >= 0)[None, :]).any(1) \
             & (ghk[:, 2 if k in (3, 4) else 4:] != 0).any(1)
         n_live = jnp.sum(live, dtype=jnp.int32)
-        from .hist_pallas import fits_single_tile
-        if self.use_pallas and w == self.wave_width \
-                and fits_single_tile(w, k):
-            # the VMEM kernel packs all stat columns into one 128-lane
-            # tile; wider (multi-tile) waves stay on the einsum
-            # full-width stage: MXU cost is tile-bound regardless of W,
-            # so the VMEM-resident kernel wins; narrow early stages stay
-            # on the einsum (XLA lowers small-N contractions cheaper).
-            # int8 stat columns take the kernel's int8->int32 variant —
-            # integer accumulation, so byte-identical to the einsum.
-            from .hist_pallas import wave_hist_pallas
-            out = wave_hist_pallas(binned, leaf_id, ghk, pending,
-                                   g=g, nb=nb, k=k, w=w,
-                                   interpret=self.pallas_interpret)
-            acc = out.reshape(g, nb, k, w).transpose(0, 1, 3, 2)
-            visited = jnp.asarray(n_chunks, jnp.int32)  # one pass, all rows
+        if n_chunks > 1 and w * k >= self.gather_min_lanes:
+            with jax.named_scope("lgb.wave_gather"):
+                binned_c, leaf_c, ghk_c = self._gather_live(
+                    binned, leaf_id, ghk, live, n_live)
+            visited = (n_live + ch - 1) // ch
         else:
-            if n_chunks > 1 and w * k >= self.gather_min_lanes:
-                with jax.named_scope("lgb.wave_gather"):
-                    binned_c, leaf_c, ghk_c = self._gather_live(
-                        binned, leaf_id, ghk, live, n_live)
-                visited = (n_live + ch - 1) // ch
-            else:
-                binned_c = binned.reshape(n_chunks, ch, g)
-                leaf_c = leaf_id.reshape(n_chunks, ch)
-                ghk_c = ghk.reshape(n_chunks, ch, k)
-                visited = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
-            mdtype = jnp.int8 if quant else jnp.bfloat16
-            adtype = jnp.int32 if quant else jnp.float32
+            binned_c = binned.reshape(n_chunks, ch, g)
+            leaf_c = leaf_id.reshape(n_chunks, ch)
+            ghk_c = ghk.reshape(n_chunks, ch, k)
+            visited = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
+        mdtype = jnp.int8 if quant else jnp.bfloat16
+        adtype = jnp.int32 if quant else jnp.float32
 
-            def body(i, acc):
-                b, l, gk = (jax.lax.dynamic_index_in_dim(
-                    a, i, keepdims=False)
-                    for a in (binned_c, leaf_c, ghk_c))
-                lm = (l[:, None] == pending[None, :]).astype(mdtype)
-                bmat = (lm[:, :, None] * gk[:, None, :]).reshape(ch,
-                                                                 w * k)
-                # bin tiling: a one-hot wider than 64 breaks XLA's
-                # operand fusion (max_bin=255 measured 10x the
-                # max_bin=63 wave, not the expected 4x) — strips of 64
-                # keep each einsum in the known-fused regime; out-of-
-                # strip bins make all-zero one-hot rows, so the concat
-                # reassembles exactly
-                bi = b.astype(jnp.int32)
-                outs = []
-                for off in range(0, nb, 64):
-                    oh = jax.nn.one_hot(bi - off, min(nb, 64),
-                                        dtype=mdtype)           # (CH,G,64)
-                    outs.append(jnp.einsum("cgn,cb->gnb", oh, bmat,
-                                           preferred_element_type=adtype))
-                out = outs[0] if len(outs) == 1 \
-                    else jnp.concatenate(outs, axis=1)
-                return acc + out
+        def body(i, acc):
+            b, l, gk = (jax.lax.dynamic_index_in_dim(
+                a, i, keepdims=False)
+                for a in (binned_c, leaf_c, ghk_c))
+            lm = (l[:, None] == pending[None, :]).astype(mdtype)
+            bmat = (lm[:, :, None] * gk[:, None, :]).reshape(ch,
+                                                             w * k)
+            # bin tiling: a one-hot wider than 64 breaks XLA's
+            # operand fusion (max_bin=255 measured 10x the
+            # max_bin=63 wave, not the expected 4x) — strips of 64
+            # keep each einsum in the known-fused regime; out-of-
+            # strip bins make all-zero one-hot rows, so the concat
+            # reassembles exactly
+            bi = b.astype(jnp.int32)
+            outs = []
+            for off in range(0, nb, 64):
+                oh = jax.nn.one_hot(bi - off, min(nb, 64),
+                                    dtype=mdtype)           # (CH,G,64)
+                outs.append(jnp.einsum("cgn,cb->gnb", oh, bmat,
+                                       preferred_element_type=adtype))
+            out = outs[0] if len(outs) == 1 \
+                else jnp.concatenate(outs, axis=1)
+            return acc + out
 
-            acc0 = jnp.zeros((g, nb, w * k), adtype)
-            acc = jax.lax.fori_loop(0, visited, body, acc0)
-            acc = acc.reshape(g, nb, w, k)
+        acc0 = jnp.zeros((g, nb, w * k), adtype)
+        acc = jax.lax.fori_loop(0, visited, body, acc0)
+        acc = acc.reshape(g, nb, w, k)
         if quant and self.int_scan:
             # int32 end-to-end: the histogram stays in quantized units
             # for the find-best scan (split.find_best_split_quant
@@ -906,17 +845,6 @@ class GrowerPrograms:
         )
 
         has_cat = self.has_cat
-        # find-best placement for THIS trace: an explicit param wins,
-        # auto adopts the construction-time verdict (possibly the
-        # wave_plan=profiled winner).  Read from config inside the
-        # traced region on purpose — the mode shapes the trace, so it
-        # must stay in the program-cache signature (jaxlint JL101 pins
-        # that coupling; dropping it via _NON_TRACE_PARAMS would let a
-        # mode switch silently reuse the other mode's cached program).
-        fmode = str(self.config.find_best_fusion or "auto").lower()
-        fused_find = self.fused_find if fmode == "auto" \
-            else fmode == "fused"
-
         def evaluate(hists, totals, ids, depths, feature_mask):
             """find-best over ONE histogram stack (split.py
             find_best_split_stack), gated by splittability.  Returns
@@ -980,33 +908,24 @@ class GrowerPrograms:
                 ids_l = jnp.where(lg_ok, st.p_large, -1)
                 ids = jnp.concatenate([ids_s, ids_l])
                 idc = jnp.clip(ids, 0, L - 1)
-                if fused_find:
-                    # fused find-best-in-wave: the gain scan consumes the
-                    # fresh histogram product and the parent-minus-sibling
-                    # residual IN PLACE — no (2*Ws, S, 3) concatenated
-                    # tensor materializes between the contraction and the
-                    # scan, so XLA fuses the hist+find of a wave into one
-                    # program region and only the packed winner records
-                    # (and the residual scattered into the leaf state)
-                    # survive it.  vmap is per-lane, so each half is
-                    # bitwise the rows the concatenated scan would produce
-                    # (tests/test_fused_find.py pins this per regime).
-                    ics, icl = idc[:Ws], idc[Ws:]
-                    pk_s, cm_s, li_s = evaluate(fresh, total[ics], ids_s,
-                                                st.depth[ics], feature_mask)
-                    pk_l, cm_l, li_l = evaluate(large, total[icl], ids_l,
-                                                st.depth[icl], feature_mask)
-                    packed = jnp.concatenate([pk_s, pk_l])
-                    catm = jnp.concatenate([cm_s, cm_l])
-                    lint = jnp.concatenate([li_s, li_l]) if int_scan \
-                        else None
-                else:
-                    # two-pass layout: one concatenated (2*Ws, S, 3) stack
-                    # scanned by a single second pass
-                    hists2 = jnp.concatenate([fresh, large])
-                    packed, catm, lint = evaluate(hists2, total[idc], ids,
-                                                  st.depth[idc],
-                                                  feature_mask)
+                # the gain scan consumes the fresh histogram product and
+                # the parent-minus-sibling residual IN PLACE — no
+                # (2*Ws, S, 3) concatenated tensor materializes between
+                # the contraction and the scan, so XLA fuses the hist+find
+                # of a wave into one program region and only the packed
+                # winner records (and the residual scattered into the leaf
+                # state) survive it.  vmap is per-lane, so each half is
+                # bitwise the rows a scan of the concatenated stack would
+                # produce.
+                ics, icl = idc[:Ws], idc[Ws:]
+                pk_s, cm_s, li_s = evaluate(fresh, total[ics], ids_s,
+                                            st.depth[ics], feature_mask)
+                pk_l, cm_l, li_l = evaluate(large, total[icl], ids_l,
+                                            st.depth[icl], feature_mask)
+                packed = jnp.concatenate([pk_s, pk_l])
+                catm = jnp.concatenate([cm_s, cm_l])
+                lint = jnp.concatenate([li_s, li_l]) if int_scan \
+                    else None
                 safe = jnp.where(ids >= 0, ids, L)
                 best = st.best.at[safe].set(
                     jnp.where((ids >= 0)[:, None], packed, st.best[safe]))
@@ -1482,32 +1401,6 @@ def _config_digest(config) -> str:
     return hashlib.sha1(repr(items).encode()).hexdigest()
 
 
-def resolve_find_fusion(config, signature: Optional[tuple] = None) -> str:
-    """Resolve ``find_best_fusion`` to the concrete wave layout
-    ("fused" / "two_pass"): explicit values pass through; ``auto``
-    adopts a ``wave_plan=profiled`` fused-vs-two-pass verdict cached in
-    process or persisted beside the compile cache for this signature
-    (ops/stage_plan.py), else defaults to fused.  The resolved mode
-    joins the program-cache key in :func:`get_grower_programs` — two
-    processes whose ``auto`` resolves differently must re-trace, never
-    reuse the other layout's compiled program."""
-    mode = str(getattr(config, "find_best_fusion", "auto")
-               or "auto").lower()
-    if mode in ("fused", "two_pass"):
-        return mode
-    if signature is not None:
-        cached = stage_plan_mod.cached_fusion(signature)
-        if cached is None:
-            cached = stage_plan_mod.load_fusion(signature)
-            if cached is not None:
-                stage_plan_mod.cache_fusion(signature, cached,
-                                            persist=False)
-                obs.inc("grow.fusion_persisted_loads")
-        if cached in ("fused", "two_pass"):
-            return cached
-    return "fused"
-
-
 def programs_signature(num_data: int, num_groups: int, nb: int,
                        num_features: int, has_cat: bool, config,
                        shard: Optional[ShardSpec] = None) -> tuple:
@@ -1561,18 +1454,13 @@ def get_grower_programs(num_data: int, num_groups: int, nb: int,
     if plan is None:
         plan = default_stage_plan(num_data, config)
     pd = stage_plan_mod.plan_digest(plan)
-    # resolved find-best layout: like the plan digest, auto's verdict
-    # is resolved HERE (once) and keyed — a cached entry built under
-    # the other layout must never serve this resolution
-    fusion = resolve_find_fusion(config, base)
     build = functools.partial(
         GrowerPrograms, num_data=num_data, num_groups=num_groups, nb=nb,
         num_features=num_features, has_cat=has_cat, config=config,
-        plan=plan, plan_source=plan_source, fusion=fusion, shard=shard,
-        mesh=mesh)
+        plan=plan, plan_source=plan_source, shard=shard, mesh=mesh)
     if not bool(getattr(config, "grower_cache", True)):
         return build()
-    key = base + (pd, fusion)
+    key = base + (pd,)
     with _PROGRAM_CACHE_LOCK:
         progs = _PROGRAM_CACHE.get(key)
         if progs is not None:
@@ -1829,7 +1717,7 @@ class DeviceGrower:
         # instance attribute: reads would show the new value while the
         # programs (which the jitted code consults) keep the old one —
         # the silent no-op failure mode of the pre-refactor pattern
-        # `grower.use_pallas = True`.  Fail loudly instead; mutate
+        # `grower.wave_width = 8`.  Fail loudly instead; mutate
         # `grower.programs.<attr>` explicitly (with grower_cache=false
         # for a private, non-process-shared instance).
         progs = self.__dict__.get("programs")
@@ -1856,15 +1744,12 @@ class DeviceGrower:
         # routing attribution: which kernel serves this dispatch's
         # full-width histogram stage (BENCH digests read these)
         obs.inc(f"grow.hist.{self.programs.hist_kernel_tag}")
-        # fused-find twin counters (same tag family as grow.hist.*):
-        # under find_best_fusion=fused each wave's hist+find is ONE
-        # dispatch equivalent, two_pass prices two — rollups multiply
-        # wave counts by the factor gauge instead of assuming 2/wave
+        # twin counter and gauge (same tag family as grow.hist.*): a
+        # wave's hist+find is ONE dispatch equivalent, and rollups
+        # multiply wave counts by the gauge instead of assuming 2/wave
         # (the PR-16 counts-as-waves bug class)
-        if self.programs.fused_find:
-            obs.inc(f"grow.fused_find.{self.programs.hist_kernel_tag}")
-        obs.set_gauge("grow.wave_dispatch_factor",
-                      1 if self.programs.fused_find else 2)
+        obs.inc(f"grow.fused_find.{self.programs.hist_kernel_tag}")
+        obs.set_gauge("grow.wave_dispatch_factor", 1)
         if self.programs.shard is not None:
             obs.inc("grow.sharded_dispatches")
         ti = jnp.asarray(tree_idx, jnp.int32)
@@ -1935,7 +1820,6 @@ class DeviceGrower:
 
         kernel_tag = self.programs.hist_kernel_tag
         sharded = self.programs.shard is not None
-        fused_find = self.programs.fused_find
         if self._multihost:
             from .shard import replicate_to_all
             replicate = replicate_to_all(self.mesh)
@@ -1944,13 +1828,9 @@ class DeviceGrower:
 
         def run(binned, binned_t, score, lr, gargs, it0, grad_fn):
             obs.inc(f"grow.hist.{kernel_tag}")
-            # fused-find twin + dispatch factor: mirror of the
-            # per-iteration site so fused-chunk rollups price waves
-            # with the same 1-vs-2 dispatch accounting
-            if fused_find:
-                obs.inc(f"grow.fused_find.{kernel_tag}")
-            obs.set_gauge("grow.wave_dispatch_factor",
-                          1 if fused_find else 2)
+            # mirror of the per-iteration site's twin counter and gauge
+            obs.inc(f"grow.fused_find.{kernel_tag}")
+            obs.set_gauge("grow.wave_dispatch_factor", 1)
             if sharded:
                 obs.inc("grow.sharded_dispatches")
             if row_pad:
@@ -1990,7 +1870,9 @@ class DeviceGrower:
         once per signature either way.
 
         Returns ``{"stage_ms", "fixed_ms", "col_ms", "plan",
-        "plan_digest", "installed"}``."""
+        "plan_digest", "installed"}`` and, when it measured,
+        ``"fused_ms"`` (the whole wave per width, which prices the
+        plan)."""
         reps = max(1, int(reps))
         progs = self.programs
         if progs.shard is not None:
@@ -2049,23 +1931,23 @@ class DeviceGrower:
                         progs._wave_hist(b, l, g2, p, n, wave_scales)[0]))
             return fn, leaf, ghk, pend
 
-        hist_out = {}
-        for w in widths:
-            fn, leaf, ghk, pend = probe_for(w)
-            jax.block_until_ready(fn(self.binned, leaf, ghk, pend))
+        def timed(fn, *args):
+            jax.block_until_ready(fn(*args))
             t0 = _time.perf_counter()
             for _ in range(reps):
-                r = fn(self.binned, leaf, ghk, pend)
+                r = fn(*args)
             jax.block_until_ready(r)
-            ms = (_time.perf_counter() - t0) / reps * 1e3
-            hist_out[w] = r
+            return (_time.perf_counter() - t0) / reps * 1e3
+
+        for w in widths:
+            fn, leaf, ghk, pend = probe_for(w)
+            ms = timed(fn, self.binned, leaf, ghk, pend)
             stage_ms[w] = round(ms, 3)
             obs.observe(f"grow.stage.w{w}", ms / 1e3)
             obs.set_gauge(f"grow.stage.w{w}_ms", round(ms, 3))
             if w == progs.wave_width:
-                # per-kernel attribution: the full-width probe times the
-                # exact kernel (pallas_int8/einsum_bf16/...) production
-                # dispatches at this stage
+                # the full-width probe under the tag production's
+                # grow.hist.<tag> counters carry
                 tag = progs.hist_kernel_tag
                 obs.observe(f"grow.hist.{tag}", ms / 1e3)
                 obs.set_gauge(f"grow.hist.{tag}_ms", round(ms, 3))
@@ -2073,115 +1955,57 @@ class DeviceGrower:
             widths, [stage_ms[w] for w in widths], k,
             num_data=progs.num_data)
 
-        # fused-vs-two-pass verdict (find_best_fusion=auto): time the
-        # per-width gain scan both ways — as its own second program
-        # over a materialized (2W, S, 3) stack (the two-pass wave's
-        # extra dispatch) and riding the histogram program end-to-end
-        # (the fused wave) — then price a full tree under each layout
-        # and persist the winner beside the stage plan.  An explicit
-        # find_best_fusion skips the measurement: the layout is forced.
-        find_ms, fused_ms = {}, {}
-        fusion_cfg = str(getattr(self.config, "find_best_fusion",
-                                 "auto") or "auto").lower()
-        fusion = fusion_cfg if fusion_cfg in ("fused", "two_pass") \
-            else "fused"
-        fusion_detail = None
-        if fusion_cfg == "auto":
-            mask_all = jnp.ones((progs.num_features,), bool)
-            stack_scales = scales if progs.int_scan else None
+        # the wave end to end: the gain scan rides the histogram program
+        # (both children's stacks, as a training wave scans them), and
+        # the plan is priced on that, not on the histogram alone
+        fused_ms = {}
+        mask_all = jnp.ones((progs.num_features,), bool)
+        stack_scales = scales if progs.int_scan else None
 
-            def scan_stack(hists, m):
-                cons = jnp.asarray([-jnp.inf, jnp.inf], jnp.float32)
-                totals = hists[:, :progs.nb, :].sum(1)
-                packed, _, _ = find_best_split_stack(
-                    hists, totals, cons, m, self.meta, self.hyper,
-                    progs.has_cat, scales=stack_scales)
-                return packed
+        def scan_stack(hists, m):
+            cons = jnp.asarray([-jnp.inf, jnp.inf], jnp.float32)
+            totals = hists[:, :progs.nb, :].sum(1)
+            packed, _, _ = find_best_split_stack(
+                hists, totals, cons, m, self.meta, self.hyper,
+                progs.has_cat, scales=stack_scales)
+            return packed
 
-            def timed(fn, *args):
-                jax.block_until_ready(fn(*args))
-                t0 = _time.perf_counter()
-                for _ in range(reps):
-                    r = fn(*args)
-                jax.block_until_ready(r)
-                return (_time.perf_counter() - t0) / reps * 1e3
+        for w in widths:
+            leaf = jnp.asarray(
+                rng.integers(0, w, n).astype(np.int32))
+            pend = jnp.arange(w, dtype=jnp.int32)
 
-            for w in widths:
-                leaf = jnp.asarray(
-                    rng.integers(0, w, n).astype(np.int32))
-                pend = jnp.arange(w, dtype=jnp.int32)
-                # the negated fresh product stands in for the
-                # parent-minus-sibling residual: shape/dtype-faithful,
-                # and the scan cost is data-independent
-                h2 = jnp.concatenate([hist_out[w], -hist_out[w]])
-                two_fn = obs.track_jit(f"fusion_probe_find_w{w}",
-                                       jax.jit(scan_stack))
-                find_ms[w] = round(timed(two_fn, h2, mask_all), 3)
+            # the negated fresh product stands in for the
+            # parent-minus-sibling residual: shape/dtype-faithful,
+            # and the scan cost is data-independent
+            def fused_body(b, l, g2, p, m):
+                fr, _ = progs._wave_hist(b, l, g2, p, n, wave_scales)
+                return jnp.concatenate([scan_stack(fr, m),
+                                        scan_stack(-fr, m)])
 
-                def fused_body(b, l, g2, p, m):
-                    fr, _ = progs._wave_hist(b, l, g2, p, n, wave_scales)
-                    return jnp.concatenate([scan_stack(fr, m),
-                                            scan_stack(-fr, m)])
+            fused_fn = obs.track_jit(f"fusion_probe_fused_w{w}",
+                                     jax.jit(fused_body))
+            fused_ms[w] = round(
+                timed(fused_fn, self.binned, leaf, ghk, pend,
+                      mask_all), 3)
+            obs.set_gauge(f"grow.fused.w{w}_ms", fused_ms[w])
 
-                fused_fn = obs.track_jit(f"fusion_probe_fused_w{w}",
-                                         jax.jit(fused_body))
-                fused_ms[w] = round(
-                    timed(fused_fn, self.binned, leaf, ghk, pend,
-                          mask_all), 3)
-                obs.set_gauge(f"grow.find.w{w}_ms", find_ms[w])
-                obs.set_gauge(f"grow.fused.w{w}_ms", fused_ms[w])
-
-            plan_tp = stage_plan_mod.derive_stage_plan(
-                progs.num_leaves, progs.wave_width, k, fixed, col,
-                measured_ms=stage_ms, find_ms=find_ms,
-                fusion="two_pass")
-            plan_f = stage_plan_mod.derive_stage_plan(
-                progs.num_leaves, progs.wave_width, k, fixed, col,
-                measured_ms=fused_ms)
-            cost_tp, _ = stage_plan_mod.plan_cost_fn(
-                plan_tp, progs.num_leaves,
-                stage_plan_mod.wave_cost_fn(
-                    k, fixed, col, stage_ms, find_ms=find_ms,
-                    fusion="two_pass"))
-            cost_f, _ = stage_plan_mod.plan_cost_fn(
-                plan_f, progs.num_leaves,
-                stage_plan_mod.wave_cost_fn(k, fixed, col, fused_ms))
-            if cost_tp < cost_f * (1.0 - stage_plan_mod.MIN_IMPROVEMENT):
-                fusion, plan = "two_pass", plan_tp
-            else:
-                fusion, plan = "fused", plan_f
-            fusion_detail = {"fused_ms_per_tree": round(cost_f, 3),
-                             "two_pass_ms_per_tree": round(cost_tp, 3)}
-            obs.inc(f"grow.fusion_profiled.{fusion}")
-        else:
-            plan = stage_plan_mod.derive_stage_plan(
-                progs.num_leaves, progs.wave_width, k, fixed, col,
-                measured_ms=stage_ms, find_ms=find_ms or None,
-                fusion=fusion)
+        plan = stage_plan_mod.derive_stage_plan(
+            progs.num_leaves, progs.wave_width, k, fixed, col,
+            measured_ms=fused_ms)
         if require_beat_legacy:
             legacy = stage_plan_mod.legacy_stage_plan(
                 progs.num_leaves, progs.wave_width, k)
-            meas = fused_ms if (fusion == "fused" and fused_ms) \
-                else stage_ms
             if not stage_plan_mod.plan_beats(
                     plan, legacy, progs.num_leaves, k, fixed, col,
-                    measured_ms=meas,
-                    find_ms=find_ms if fusion == "two_pass" else None,
-                    fusion=fusion):
+                    measured_ms=fused_ms):
                 plan = legacy
         obs.set_gauge("grow.stage.fixed_ms", round(fixed, 3))
         obs.set_gauge("grow.stage.col_ms", round(col, 5))
         installed = False
         if install:
             stage_plan_mod.cache_plan(self._base_signature, plan)
-            if fusion_cfg == "auto":
-                # the verdict persists beside the plan, so
-                # find_best_fusion=auto in THIS process (the rebuild
-                # below) and every fresh process resolves to it
-                stage_plan_mod.cache_fusion(self._base_signature,
-                                            fusion,
-                                            detail=fusion_detail)
-            if plan != progs.stage_plan or fusion != progs.find_fusion:
+            if plan != progs.stage_plan:
                 self.programs = get_grower_programs(
                     progs.num_data, progs.num_groups, progs.nb,
                     progs.num_features, progs.has_cat, self.config,
@@ -2196,9 +2020,7 @@ class DeviceGrower:
                 "fixed_ms": round(fixed, 3),
                 "col_ms": round(col, 5), "plan": plan,
                 "plan_digest": stage_plan_mod.plan_digest(plan),
-                "find_ms": find_ms, "fused_ms": fused_ms,
-                "fusion": fusion, "fusion_detail": fusion_detail,
-                "installed": installed}
+                "fused_ms": fused_ms, "installed": installed}
 
     # ------------------------------------------------------------------
     def profile_psum(self, reps: int = 10) -> Optional[dict]:

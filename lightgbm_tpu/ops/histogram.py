@@ -15,12 +15,11 @@ Accumulation is float32 (like the reference GPU learner's single-precision
 histograms, ``gpu_tree_learner.h:73-77``); per-bin partial sums come out of
 the MXU's float32 accumulators so there is no bf16 accumulation error.
 
-Under the fused find-best-in-wave layout (``find_best_fusion``,
-ops/grow.py) the wave histograms these builders produce never leave the
-growth program: the per-feature gain scan consumes them in place and
-only packed winner records plus the parent-minus-sibling residuals
-survive to HBM, so the (2W, S, 3) stack the two-pass layout materialises
-between its two dispatches is XLA-fusible intermediate state here.
+In the device grower's wave (ops/grow.py) the wave histograms never
+leave the growth program: the per-feature gain scan consumes them in
+place and only packed winner records plus the parent-minus-sibling
+residuals survive to HBM; a (2W, S, 3) stack of both children is
+XLA-fusible intermediate state there.
 """
 
 from __future__ import annotations

@@ -48,14 +48,13 @@ Determinism / byte-identity contract (docs/Sharding.md)
   compiled program, so results are bit-reproducible run-to-run but not
   bitwise equal to the single-device accumulation order.  Counts psum
   as int32 either way, so row counts stay exact past 2^24 global rows.
-* fused find-best-in-wave (``find_best_fusion``, ops/grow.py) composes
-  with all of the above: the psum happens INSIDE the fused program,
-  directly between the shard-local wave histograms and the replicated
-  gain scan that consumes them, so fusing removes the two-pass layout's
-  second dispatch without adding any collective — the reduced stack is
-  scanned where it lands instead of round-tripping through HBM first.
-  The 1-vs-N byte-identity contract is pinned per layout by
-  tests/_shard_worker.py's ``fused_find`` scenario.
+* find-best inside the wave (ops/grow.py) composes with all of the
+  above: the psum happens INSIDE the wave's program, directly between
+  the shard-local wave histograms and the replicated gain scan that
+  consumes them — the reduced stack is scanned where it lands instead
+  of round-tripping through HBM first.  The 1-vs-N byte-identity
+  contract is pinned by tests/_shard_worker.py's ``fused_find``
+  scenario.
 """
 
 from __future__ import annotations

@@ -500,16 +500,14 @@ def find_best_split_stack(hists, totals, constraint, feature_mask,
                           meta: FeatureMeta, hp: SplitHyper,
                           has_cat: bool, scales=None):
     """vmapped gain scan over a (B, S, 3) histogram STACK — the device
-    grower's per-wave reduction unit.  Under ``find_best_fusion=fused``
-    the wave calls this once on the fresh histogram product and once on
-    the parent-minus-sibling residual, so the two stacks are consumed
-    IN PLACE by the same traced program that produced them and no
-    concatenated ``(2 * wave, slots, 3)`` tensor ever materializes
-    between the histogram contraction and the scan; the two-pass layout
-    calls it once on the concatenated stack.  vmap semantics are
-    per-lane, so the halves are bitwise the rows the concatenated scan
-    yields — this shared body is what makes the fused/two-pass
-    byte-identity contract structural rather than numerical.
+    grower's per-wave reduction unit.  The wave calls this once on the
+    fresh histogram product and once on the parent-minus-sibling
+    residual, so the two stacks are consumed IN PLACE by the same
+    traced program that produced them and no concatenated
+    ``(2 * wave, slots, 3)`` tensor ever materializes between the
+    histogram contraction and the scan.  vmap semantics are per-lane,
+    so the halves are bitwise the rows a scan of the concatenated
+    stack would yield.
 
     ``scales`` switches to the quantized-unit scan
     (:func:`find_best_split_quant`); the third return is then the (B, 3)

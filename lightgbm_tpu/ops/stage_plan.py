@@ -9,7 +9,7 @@ operand generation over all N rows) is width-independent, so at small
 frontiers it dominates and FEWER, WIDER stages win, while at large
 frontiers the column term dominates and width-matching the frontier
 wins.  ``ops/grow.py`` historically hardcoded a doubling plan from
-constants measured at 10.5M rows (scripts/ubench_hist.py); this module
+constants measured at 10.5M rows on an earlier backend; this module
 keeps that plan as the byte-stable default and adds
 
 * a cost model + simulator (``plan_cost``) over the leaf-growth
@@ -37,7 +37,7 @@ import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
-# constants measured on the chip at 10.5M rows (scripts/ubench_hist.py):
+# constants measured on an earlier backend's chip at 10.5M rows:
 # ~15.9 ms fixed one-hot operand generation + ~0.203 ms per stat column.
 # Both terms contract over all N rows, so ``fit_wave_costs`` scales them
 # linearly by rows/REF_ROWS when falling back for a different shape.
@@ -105,19 +105,6 @@ def plan_cost(plan: Sequence, num_leaves: int, hist_cols: int,
                         lambda w: fixed_ms + col_ms * w * hist_cols)
 
 
-def plan_dispatches(plan: Sequence, num_leaves: int,
-                    fused: bool = True) -> int:
-    """XLA program-dispatch equivalents for one tree under the plan:
-    a fused hist+find wave is ONE dispatch (the gain scan rides the
-    histogram program), while the two-pass layout pays a second
-    find-best program per wave.  The simulator's wave count itself is
-    layout-independent — fused waves count as one wave, never two
-    (the PR-16 counts-as-waves bug class) — only the dispatch factor
-    changes."""
-    _, waves = plan_cost_fn(plan, num_leaves, lambda w: 0.0)
-    return waves * (1 if fused else 2)
-
-
 def _ladder(wave_width: int) -> List[int]:
     out, w = [], 4
     while w < wave_width:
@@ -136,44 +123,27 @@ MIN_IMPROVEMENT = 0.02
 
 
 def wave_cost_fn(hist_cols: int, fixed_ms: float, col_ms: float,
-                 measured_ms: Optional[Dict[int, float]] = None,
-                 find_ms: Optional[Dict[int, float]] = None,
-                 fusion: str = "fused"):
+                 measured_ms: Optional[Dict[int, float]] = None):
     """Per-width wave cost (ms): the measured probe timing when one
     exists for the width, else the linear fixed + col * width * k model
     — shared by ``derive_stage_plan`` and ``plan_beats`` so the
     derivation and the legacy-bar comparison price plans identically.
-
-    Fused-mode cost term: under ``fusion="fused"`` the find-best scan
-    rides the histogram program, so ``measured_ms`` should carry the
-    END-TO-END fused wave timings and nothing is added.  Under
-    ``fusion="two_pass"`` each wave pays the second find-best dispatch:
-    ``find_ms`` (width -> per-wave gain-scan ms, from the fusion
-    probes) is added on top of the histogram cost.  With no ``find_ms``
-    both modes price identically — the historical behaviour, so every
-    pre-fusion call site is unchanged."""
+    The find-best scan rides the histogram program, so ``measured_ms``
+    should carry the END-TO-END wave timings."""
     def wave_ms(w):
-        base = float(measured_ms[w]) if measured_ms and w in measured_ms \
+        return float(measured_ms[w]) if measured_ms and w in measured_ms \
             else fixed_ms + col_ms * w * hist_cols
-        if fusion == "two_pass" and find_ms:
-            base += float(find_ms.get(w, 0.0))
-        return base
     return wave_ms
 
 
 def plan_beats(candidate: Sequence, incumbent: Sequence, num_leaves: int,
                hist_cols: int, fixed_ms: float, col_ms: float,
-               measured_ms: Optional[Dict[int, float]] = None,
-               find_ms: Optional[Dict[int, float]] = None,
-               fusion: str = "fused") -> bool:
+               measured_ms: Optional[Dict[int, float]] = None) -> bool:
     """Whether ``candidate``'s modeled per-tree cost beats
     ``incumbent``'s by the ``MIN_IMPROVEMENT`` bar — the gate
     ``wave_plan=auto`` applies before displacing the byte-stable legacy
-    ladder with a freshly measured plan.  ``find_ms``/``fusion`` carry
-    the find-best placement pricing so the bar compares plans under
-    the SAME wave layout the derivation used."""
-    wave_ms = wave_cost_fn(hist_cols, fixed_ms, col_ms, measured_ms,
-                           find_ms=find_ms, fusion=fusion)
+    ladder with a freshly measured plan."""
+    wave_ms = wave_cost_fn(hist_cols, fixed_ms, col_ms, measured_ms)
     c_cand, _ = plan_cost_fn(candidate, num_leaves, wave_ms)
     c_inc, _ = plan_cost_fn(incumbent, num_leaves, wave_ms)
     return c_cand < c_inc * (1.0 - MIN_IMPROVEMENT)
@@ -182,8 +152,6 @@ def plan_beats(candidate: Sequence, incumbent: Sequence, num_leaves: int,
 def derive_stage_plan(num_leaves: int, wave_width: int, hist_cols: int,
                       fixed_ms: float, col_ms: float,
                       measured_ms: Optional[Dict[int, float]] = None,
-                      find_ms: Optional[Dict[int, float]] = None,
-                      fusion: str = "fused",
                       frontier_packing: bool = True) -> Plan:
     """Cheapest plan from the doubling-ladder family: every subset of
     intermediate widths {4, 8, 16, ...} (stage (w, 2w) runs width w
@@ -203,13 +171,8 @@ def derive_stage_plan(num_leaves: int, wave_width: int, hist_cols: int,
     frontier-w wave to the next stage's 2w-wide (initially half-empty)
     dispatch, trading wasted lanes for one fewer wave.  Disabled, the
     candidate set collapses to the single strictly width-matched full
-    ladder, so every wave runs at (at most) its frontier's width.
-    ``find_ms``/``fusion`` price the find-best placement per wave
-    (:func:`wave_cost_fn`): under two_pass each wave carries the second
-    gain-scan dispatch, which makes packed (fewer-wave) plans win
-    earlier than under fused pricing."""
-    wave_ms = wave_cost_fn(hist_cols, fixed_ms, col_ms, measured_ms,
-                           find_ms=find_ms, fusion=fusion)
+    ladder, so every wave runs at (at most) its frontier's width."""
+    wave_ms = wave_cost_fn(hist_cols, fixed_ms, col_ms, measured_ms)
 
     rungs = _ladder(wave_width)
     full: Plan = [(w, 2 * w) for w in rungs
@@ -282,9 +245,9 @@ def cache_plan(signature: tuple, plan: Sequence,
 # signature text must match exactly and the stored digest must match
 # the stored plan, so a corrupt or hand-edited file degrades to the
 # legacy plan instead of training with an unvetted stage order.  The
-# backend is in the key because plans and fusion verdicts are TIMINGS:
-# a cache dir filled by XLA:CPU test runs travels to the chip with the
-# checkout, and a verdict timed on one backend says nothing on another.
+# backend is in the key because plans are TIMINGS: a cache dir filled by
+# XLA:CPU test runs travels to the chip with the checkout, and a plan
+# timed on one backend says nothing on another.
 # ---------------------------------------------------------------------------
 
 def store_dir() -> Optional[str]:
@@ -302,22 +265,18 @@ def backend_key() -> str:
     return f"{dev.platform}:{dev.device_kind}"
 
 
-def _store_path(kind: str, signature: tuple) -> Optional[str]:
+def _plan_path(signature: tuple) -> Optional[str]:
     d = store_dir()
     if d is None:
         return None
     key = hashlib.sha1(repr((backend_key(), tuple(signature))).encode()
                        ).hexdigest()[:20]
-    return os.path.join(d, f"{kind}_{key}.json")
+    return os.path.join(d, f"plan_{key}.json")
 
 
-def _plan_path(signature: tuple) -> Optional[str]:
-    return _store_path("plan", signature)
-
-
-def _write_payload(path: str, payload: dict, what: str) -> Optional[str]:
-    """Atomic best-effort write shared by the plan and fusion stores (a
-    read-only cache dir must not take down training over a verdict)."""
+def _write_payload(path: str, payload: dict) -> Optional[str]:
+    """Atomic best-effort write (a read-only cache dir must not take
+    down training over a plan)."""
     tmp = f"{path}.{os.getpid()}.tmp"
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -326,8 +285,8 @@ def _write_payload(path: str, payload: dict, what: str) -> Optional[str]:
         os.replace(tmp, path)
     except OSError as e:
         from ..utils.log import log_warning
-        log_warning(f"cannot persist the {what} to {path}: {e}; it "
-                    f"stays process-local")
+        log_warning(f"cannot persist the profiled stage plan to {path}: "
+                    f"{e}; it stays process-local")
         try:
             os.unlink(tmp)    # don't leave orphaned .tmp files behind
         except OSError:
@@ -363,8 +322,7 @@ def save_plan(signature: tuple, plan: Sequence) -> Optional[str]:
     return _write_payload(
         path, {"backend": backend_key(),
                "signature": repr(tuple(signature)),
-               "plan": canon, "digest": plan_digest(canon)},
-        "profiled stage plan")
+               "plan": canon, "digest": plan_digest(canon)})
 
 
 def load_plan(signature: tuple) -> Optional[Plan]:
@@ -390,83 +348,6 @@ def forget_plan(signature: tuple) -> None:
     with _PLAN_CACHE_LOCK:
         _PLAN_CACHE.pop(signature, None)
     path = _plan_path(signature)
-    if path is not None:
-        try:
-            os.remove(path)
-        except OSError:
-            pass
-
-
-# ---------------------------------------------------------------------------
-# fused-vs-two-pass verdicts: wave_plan=profiled times the find-best
-# scan in both wave layouts and the winner is recorded here, keyed and
-# persisted EXACTLY like the stage plan it was measured with (same
-# backend + signature key, same store beside the compile cache), so
-# ``find_best_fusion=auto`` resolves to the measured layout in this
-# process and every fresh process after it.  Like the plan, the
-# resolved mode shapes the traced program — ops/grow.py keys the
-# program cache on it — so a corrupt or mismatched file degrades to
-# the default (fused) rather than adopting an unvetted layout.
-# ---------------------------------------------------------------------------
-
-_FUSION_MODES = ("fused", "two_pass")
-_FUSION_CACHE: Dict[tuple, str] = {}
-
-
-def cached_fusion(signature: tuple) -> Optional[str]:
-    with _PLAN_CACHE_LOCK:
-        return _FUSION_CACHE.get(signature)
-
-
-def cache_fusion(signature: tuple, mode: str, persist: bool = True,
-                 detail: Optional[dict] = None) -> None:
-    """Record the measured find-best layout for ``signature`` in the
-    process cache and — unless ``persist=False`` — the on-disk store
-    (``persist=False`` is for verdicts that CAME from disk).
-    ``detail`` (e.g. the per-tree ms both layouts modeled) rides along
-    in the persisted file for bench/ops archaeology."""
-    if mode not in _FUSION_MODES:
-        raise ValueError(f"find-best fusion verdict must be one of "
-                         f"{_FUSION_MODES}, got {mode!r}")
-    with _PLAN_CACHE_LOCK:
-        _FUSION_CACHE[signature] = mode
-    if persist:
-        save_fusion(signature, mode, detail)
-
-
-def _fusion_path(signature: tuple) -> Optional[str]:
-    return _store_path("fusion", signature)
-
-
-def save_fusion(signature: tuple, mode: str,
-                detail: Optional[dict] = None) -> Optional[str]:
-    """Atomically persist the fusion verdict; best-effort like
-    :func:`save_plan`."""
-    path = _fusion_path(signature)
-    if path is None:
-        return None
-    payload = {"backend": backend_key(),
-               "signature": repr(tuple(signature)), "mode": str(mode)}
-    if detail:
-        payload["detail"] = detail
-    return _write_payload(path, payload, "fused-find verdict")
-
-
-def load_fusion(signature: tuple) -> Optional[str]:
-    """Load a persisted fusion verdict; None (-> default fused) when
-    absent, unreadable, backend- or signature-mismatched, or not a
-    known mode."""
-    payload = _read_payload(_fusion_path(signature), signature)
-    mode = payload.get("mode") if payload is not None else None
-    return mode if mode in _FUSION_MODES else None
-
-
-def forget_fusion(signature: tuple) -> None:
-    """Drop ``signature``'s fusion verdict from the process cache AND
-    the disk store."""
-    with _PLAN_CACHE_LOCK:
-        _FUSION_CACHE.pop(signature, None)
-    path = _fusion_path(signature)
     if path is not None:
         try:
             os.remove(path)

@@ -596,18 +596,7 @@ PARAM_SCHEMA: Sequence[Param] = (
     _p("tpu_double_precision", bool, False, (),
        desc="alias-level switch for float64 accumulation on TPU", section="device"),
     _p("tpu_rows_per_block", int, 0, (),
-       desc="rows per Pallas histogram grid block; 0 = auto", section="device"),
-    _p("hist_kernel", str, "auto", (),
-       check="auto/pallas/einsum/interpret",
-       desc="wave-histogram implementation for the device grower: "
-            "einsum = XLA one-hot matmul (default; fastest measured for "
-            "bf16), pallas = VMEM-resident Pallas TPU kernel "
-            "(ops/hist_pallas.py; serves full-width waves whose stat "
-            "columns fit one 128-lane tile, bf16 or int8 — the int8 "
-            "variant accumulates int8->int32 on the MXU and is "
-            "byte-identical to the int8 einsum), interpret = Pallas "
-            "interpreter mode (CPU testing/CI parity), auto = einsum. "
-            "Routing per dispatch is recorded as grow.hist.* counters",
+       desc="compat; read nowhere (the histogram kernel it sized is gone)",
        section="device"),
     _p("grad_quant_bits", int, 0, ("gradient_quant_bits", "quant_bits"),
        check=">= 0",
@@ -645,27 +634,6 @@ PARAM_SCHEMA: Sequence[Param] = (
             "so retrain windows AND fresh processes measure once "
             "(zero re-profiles; docs/ColdStart.md)",
        section="device"),
-    _p("find_best_fusion", str, "auto", (),
-       check="auto/fused/two_pass",
-       desc="find-best placement inside the device grower's wave "
-            "(ops/grow.py): fused = the wave's histogram contraction "
-            "feeds the per-feature gain scan in ONE traced program per "
-            "wave — the fresh and subtracted sibling histogram stacks "
-            "are scanned in place and only the packed winner records "
-            "plus the parent-minus-sibling residuals survive the wave, "
-            "never a concatenated (2*wave, slots, stats) tensor "
-            "round-tripping through HBM; two_pass = the legacy layout "
-            "(histograms materialize, then a second scan pass reduces "
-            "them); auto = fused, unless wave_plan=profiled measured "
-            "two-pass faster for this (shape, config) and persisted "
-            "that verdict beside the stage plan. Both paths are "
-            "byte-identical in every guaranteed regime (f32, int8 "
-            "einsum, int8 Pallas, striped columns, sharded "
-            "single-controller); the mode joins programs_signature so "
-            "switching retraces instead of reusing a stale program. "
-            "Per-wave dispatch equivalents are recorded as "
-            "grow.fused_find.* counters and the "
-            "grow.wave_dispatch_factor gauge", section="device"),
     _p("grower_cache", bool, True, (),
        desc="share the device grower's jitted programs process-wide, "
             "keyed on (shape signature, config hash): a warm retrain "

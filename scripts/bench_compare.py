@@ -3,7 +3,7 @@
 
 Compares every perf metric the two files share — ``ms_per_tree`` /
 ``rows_per_sec`` / speedups / coldstart ratios, including nested ones
-(``legs.int8_pallas.ms_per_tree``, ``mslr.rows_per_sec``, ...) — and
+(``legs.int8_einsum.ms_per_tree``, ``mslr.rows_per_sec``, ...) — and
 flags changes worse than the threshold (default 10%) in each metric's
 bad direction.  Accepts both raw ``bench.py`` stdout JSON and the
 committed round wrapper (``BENCH_r*.json``: ``{"parsed": {...}}``).

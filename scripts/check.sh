@@ -131,19 +131,6 @@ if [[ "${1:-}" != "--fast" ]]; then
     #     request host-exact then recovers (docs/Robustness.md)
     step "fault smoke" python scripts/check_faults.py
 
-    # 5d. quant smoke: the int8 Pallas wave-histogram kernel (interpret
-    #     mode) must be BYTE-identical to the int8 einsum at kernel and
-    #     whole-training level, with the int32 find-best scan active
-    #     (ROUND8_NOTES.md)
-    step "quant smoke" python scripts/check_quant.py
-
-    # 5d2. fused-find smoke: the fused hist+gain-scan wave layout
-    #      (find_best_fusion=fused) must train models BYTE-identical to
-    #      the legacy two-pass layout in f32 and int8, with the
-    #      grow.fused_find.* routing counters proving the fused program
-    #      actually dispatched (one program per wave, not two)
-    step "fused-find smoke" python scripts/check_fused.py
-
     # 5e. shard smoke: single-controller data-parallel training on a
     #     forced 4-device host mesh must emit trees byte-identical to
     #     the single-device fused path under grad_quant_bits=8, and a

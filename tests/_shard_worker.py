@@ -9,7 +9,7 @@ reported as ``{"skip": reason}`` so callers record WHY instead of
 failing — the ROADMAP memory note: such failures in the CPU container
 are environmental, the contract is validated on real multi-chip.
 
-Usage: python _shard_worker.py <scenario> [outdir]
+Usage: python _shard_worker.py <scenario> [outdir] [extra params, JSON]
 Scenarios: core | bucketing | checkpoint | fused_find
 """
 
@@ -209,25 +209,21 @@ def scenario_checkpoint(outdir):
             "resume_identical": resumed == straight}
 
 
-def scenario_fused_find():
-    """Fused find-best-in-wave composed with sharding: under quant8
-    (the exact-arithmetic regime) the 4-device mesh must emit trees
-    byte-identical to the single-device run in BOTH wave layouts, and
-    the two layouts must agree with each other — the psum lands inside
-    the fused program directly ahead of the replicated gain scan
-    (ops/shard.py determinism contract)."""
+def scenario_fused_find(extra=None):
+    """Find-best inside the wave composed with sharding: under quant8
+    (the exact-arithmetic regime) the 4-device mesh must emit trees and
+    scores byte-identical to the single-device run — the psum lands
+    inside the wave directly ahead of the replicated gain scan
+    (ops/shard.py determinism contract).  Returns both runs' digests
+    (tests/growth_regimes.py; ``extra`` is for recording them on a
+    commit that had more wave layouts than one)."""
+    from growth_regimes import digest_of
     x, y = _data()
-    q = {"grad_quant_bits": 8}
-    out = {}
-    ref = _train(x, y, {**q, "find_best_fusion": "fused"})
-    out["fused_1v4_identical"] = \
-        ref == _train(x, y, {**q, **SHARD, "find_best_fusion": "fused"})
-    two = _train(x, y, {**q, "find_best_fusion": "two_pass"})
-    out["two_pass_1v4_identical"] = \
-        two == _train(x, y,
-                      {**q, **SHARD, "find_best_fusion": "two_pass"})
-    out["fused_eq_two_pass"] = ref == two
-    return out
+    q = {"grad_quant_bits": 8, **(extra or {})}
+    single = digest_of(_train(x, y, q, return_booster=True))
+    sharded = digest_of(_train(x, y, {**q, **SHARD}, return_booster=True))
+    return {"single": single, "sharded": sharded,
+            "fused_1v4_identical": single == sharded}
 
 
 def main():
@@ -246,7 +242,8 @@ def main():
     elif scenario == "checkpoint":
         out = scenario_checkpoint(outdir)
     elif scenario == "fused_find":
-        out = scenario_fused_find()
+        out = scenario_fused_find(
+            json.loads(sys.argv[3]) if len(sys.argv) > 3 else None)
     else:
         raise SystemExit(f"unknown scenario {scenario!r}")
     out["scenario"] = scenario

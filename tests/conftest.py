@@ -43,8 +43,6 @@ compile_cache.configure()
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-REFERENCE_EXAMPLES = "/root/reference/examples"
-
 
 @pytest.fixture
 def private_cache_dir(tmp_path, monkeypatch):
@@ -152,44 +150,61 @@ def assert_models_bit_identical(a, b):
                                   np.asarray(b.train_score))
 
 
-def load_svmlight(path, n_features=None):
-    """Tiny LibSVM reader for the lambdarank fixtures."""
-    labels, rows, cols, vals = [], [], [], []
-    with open(path) as fh:
-        for i, line in enumerate(fh):
-            parts = line.strip().split()
-            labels.append(float(parts[0]))
-            for tok in parts[1:]:
-                c, v = tok.split(":")
-                rows.append(i)
-                cols.append(int(c))
-                vals.append(float(v))
-    n = len(labels)
-    nf = (max(cols) + 1) if n_features is None else n_features
-    x = np.zeros((n, nf), np.float64)
-    x[rows, cols] = vals
-    return x, np.asarray(labels, np.float64)
+# ---------------------------------------------------------------------------
+# the three example datasets, made here from fixed seeds at the upstream
+# examples' shapes (LightGBM examples/{binary_classification,regression,
+# lambdarank}): tier-1 reads nothing outside the checkout.  The
+# thresholds of the tests that take them were set on THESE arrays from
+# the host serial learner (tree/learner.py), each with its margin in a
+# comment beside it.
+# ---------------------------------------------------------------------------
 
-
-@pytest.fixture(scope="session")
-def regression_data():
-    d = np.loadtxt(f"{REFERENCE_EXAMPLES}/regression/regression.train")
-    dt = np.loadtxt(f"{REFERENCE_EXAMPLES}/regression/regression.test")
-    return d[:, 1:], d[:, 0], dt[:, 1:], dt[:, 0]
+def _higgs_like(seed, rows):
+    """(rows, 28) float64 features and a latent score: a few strong
+    columns, interactions and a long tail of weak ones, like the
+    examples' HIGGS sample."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((rows, 28))
+    x[:, 20:] = np.abs(x[:, 20:])            # one-sided, like the masses
+    f = (0.9 * x[:, 0] - 0.7 * x[:, 1] * (x[:, 2] > 0)
+         + 0.6 * np.sin(2.0 * x[:, 3]) + 0.5 * x[:, 4] * x[:, 5]
+         + 0.8 * (x[:, 25] - 0.8) + 0.15 * x[:, 6:16].sum(1))
+    return x, f, rng
 
 
 @pytest.fixture(scope="session")
 def binary_data():
-    d = np.loadtxt(f"{REFERENCE_EXAMPLES}/binary_classification/binary.train")
-    dt = np.loadtxt(f"{REFERENCE_EXAMPLES}/binary_classification/binary.test")
-    return d[:, 1:], d[:, 0], dt[:, 1:], dt[:, 0]
+    """7,000 + 500 rows x 28 columns; labels drawn from the latent score
+    through a logistic link, so no model reaches AUC 1."""
+    x, f, rng = _higgs_like(20261002, 7500)
+    y = (rng.random(7500) < 1.0 / (1.0 + np.exp(-1.2 * f))).astype(
+        np.float64)
+    return x[:7000], y[:7000], x[7000:], y[7000:]
+
+
+@pytest.fixture(scope="session")
+def regression_data():
+    """7,000 + 500 rows x 28 columns; a real-valued target of unit
+    variance, a third of it noise."""
+    x, f, rng = _higgs_like(20261003, 7500)
+    y = f / f.std() * np.sqrt(2.0 / 3.0) \
+        + rng.standard_normal(7500) * np.sqrt(1.0 / 3.0)
+    return x[:7000], y[:7000], x[7000:], y[7000:]
 
 
 @pytest.fixture(scope="session")
 def rank_data():
-    base = f"{REFERENCE_EXAMPLES}/lambdarank"
-    x, y = load_svmlight(f"{base}/rank.train")
-    xt, yt = load_svmlight(f"{base}/rank.test", n_features=x.shape[1])
-    q = np.loadtxt(f"{base}/rank.train.query").astype(np.int64)
-    qt = np.loadtxt(f"{base}/rank.test.query").astype(np.int64)
-    return x, y, q, xt, yt, qt
+    """~3,000 + ~770 rows of 300 sparse columns in 200 + 50 queries of 5
+    to 25 documents; relevance 0..4 cut from a latent score that twelve
+    of the columns carry."""
+    rng = np.random.default_rng(20261004)
+    sizes = rng.integers(5, 26, 250)
+    n = int(sizes.sum())
+    x = rng.random((n, 300)) * (rng.random((n, 300)) < 0.12)
+    w = rng.standard_normal(12)
+    f = x[:, :12] @ w + 0.35 * rng.standard_normal(n)
+    y = np.digitize(f, np.quantile(f, [0.55, 0.8, 0.92, 0.98])).astype(
+        np.float64)
+    cut = int(sizes[:200].sum())
+    return (x[:cut], y[:cut], sizes[:200].astype(np.int64),
+            x[cut:], y[cut:], sizes[200:].astype(np.int64))

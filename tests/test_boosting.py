@@ -1,5 +1,9 @@
-"""Boosting-layer end-to-end tests against the reference example fixtures
-(modelled on the reference tests/python_package_test/test_engine.py)."""
+"""Boosting-layer end-to-end tests (modelled on the reference
+tests/python_package_test/test_engine.py).  The example datasets are
+conftest's seeded stand-ins at the upstream examples' shapes; every
+threshold on them was read from the host serial learner on those arrays
+(PR 30) and stands a stated margin off the reading, far from what a
+constant predictor scores."""
 
 import numpy as np
 
@@ -50,34 +54,39 @@ def test_binary():
 
 
 def test_binary_fixture_auc(binary_data):
-    # the reference examples/binary_classification run: AUC ~0.78 @ 100
+    # reads 0.7856 (the latent score itself: 0.8327; a constant: 0.5)
     x, y, xt, yt = binary_data
     bst = _train({"objective": "binary", "metric": "auc",
                   "num_leaves": 31, "learning_rate": 0.1}, x, y, 60,
                  valid=(xt, yt))
     res = dict((f"{d}:{n}", v) for d, n, v, _ in bst.eval_valid())
-    assert res["valid_0:auc"] > 0.76
+    assert res["valid_0:auc"] > 0.77
 
 
 def test_regression(regression_data):
-    # sklearn HistGBM reaches valid mse 0.174 at the same settings
+    # reads 0.4676 (the noise alone: 0.333; the training mean: 1.015)
     x, y, xt, yt = regression_data
     bst = _train({"objective": "regression", "metric": "l2",
                   "num_leaves": 31, "learning_rate": 0.05}, x, y, 100,
                  valid=(xt, yt))
     res = dict((f"{d}:{n}", v) for d, n, v, _ in bst.eval_valid())
-    assert res["valid_0:l2"] < 0.2
+    assert res["valid_0:l2"] < 0.50
 
 
 def test_regression_l1_and_huber(regression_data):
     x, y, xt, yt = regression_data
-    for obj, metric in [("regression_l1", "l1"), ("huber", "huber"),
-                        ("fair", "fair"), ("quantile", "quantile"),
-                        ("mape", "mape")]:
+    # limit: the reading at 30 rounds + 8-19%; in the comment the
+    # reading, then the same booster before its first round (a constant)
+    for obj, metric, limit in [
+            ("regression_l1", "l1", 0.62),      # 0.5726; 0.8037
+            ("huber", "huber", 0.27),           # 0.2421; 0.4086
+            ("fair", "fair", 0.165),            # 0.1476; 0.2653
+            ("quantile", "quantile", 0.16),     # 0.1342; 0.3967
+            ("mape", "mape", 0.52)]:            # 0.4698; 0.6359
         bst = _train({"objective": obj, "metric": metric, "num_leaves": 31,
                       "learning_rate": 0.1}, x, y, 30, valid=(xt, yt))
         res = bst.eval_valid()
-        assert len(res) >= 1 and np.isfinite(res[0][2]), (obj, res)
+        assert len(res) >= 1 and res[0][2] < limit, (obj, res)
 
 
 def test_multiclass():
@@ -121,19 +130,21 @@ def test_lambdarank(rank_data):
                   "min_sum_hessian_in_leaf": 0}, x, y, 50,
                  group=q, valid=None)
     res = dict((n, v) for _, n, v, _ in bst.eval_train())
-    # reference test_sklearn.py:59 asserts ndcg floor ~0.57 at 50 rounds
-    assert res["ndcg@1"] > 0.55, res
-    assert res["ndcg@3"] > 0.55, res
+    # on the training queries: reads 0.9711 and 0.9645 (one score for
+    # every document: 0.1863 and 0.2595)
+    assert res["ndcg@1"] > 0.90, res
+    assert res["ndcg@3"] > 0.90, res
 
 
 def test_goss_and_dart(regression_data):
     x, y, xt, yt = regression_data
-    for boosting in ("goss", "dart"):
+    # reads 0.5097 and 0.5896 (the training mean: 1.015)
+    for boosting, limit in (("goss", 0.56), ("dart", 0.65)):
         bst = _train({"objective": "regression", "metric": "l2",
                       "boosting": boosting, "num_leaves": 31,
                       "learning_rate": 0.1}, x, y, 30, valid=(xt, yt))
         res = dict((f"{d}:{n}", v) for d, n, v, _ in bst.eval_valid())
-        assert res["valid_0:l2"] < 1.0, (boosting, res)
+        assert res["valid_0:l2"] < limit, (boosting, res)
 
 
 def test_rf():
@@ -166,7 +177,7 @@ def test_bagging_weights(regression_data):
                   "num_leaves": 31, "learning_rate": 0.05},
                  x, y, 50, weights=w, valid=(xt, yt))
     res = dict((f"{d}:{n}", v) for d, n, v, _ in bst.eval_valid())
-    assert res["valid_0:l2"] < 1.0
+    assert res["valid_0:l2"] < 0.58       # reads 0.5298 (the mean: 1.015)
 
 
 def test_model_roundtrip(binary_data, tmp_path):

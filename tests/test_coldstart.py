@@ -319,7 +319,7 @@ def test_no_entry_point_builds_a_tempfile_cache_dir():
 
 
 def test_plan_not_adopted_across_backends(private_cache_dir, monkeypatch):
-    """Plans and fusion verdicts are timings: one persisted under a
+    """Plans are timings: one persisted under a
     (platform, device_kind) must never load under another — the cache
     dir travels with the checkout, and XLA:CPU test runs fill it."""
     from lightgbm_tpu.ops import stage_plan as sp
@@ -328,12 +328,9 @@ def test_plan_not_adopted_across_backends(private_cache_dir, monkeypatch):
     plan = [(4, 8), (128, None)]
     monkeypatch.setattr(sp, "backend_key", lambda: "cpu:cpu")
     cpu_path = sp.save_plan(sig, plan)
-    sp.save_fusion(sig, "two_pass")
     assert sp.load_plan(sig) == plan
-    assert sp.load_fusion(sig) == "two_pass"
     monkeypatch.setattr(sp, "backend_key", lambda: "tpu:TPU v5 lite")
     assert sp.load_plan(sig) is None
-    assert sp.load_fusion(sig) is None
     # another backend's file at THIS backend's path (a copied or
     # renamed store) is refused on its stored backend field too
     os.replace(cpu_path, sp._plan_path(sig))
@@ -553,7 +550,7 @@ def test_grower_programs_lru_eviction():
 
 
 # ---------------------------------------------------------------------------
-# satellites: serve warmup defaults, pallas guard
+# satellites: serve warmup defaults
 # ---------------------------------------------------------------------------
 
 def test_serve_warmup_includes_min_rows_bucket():
@@ -577,19 +574,3 @@ def test_serve_warmup_includes_min_rows_bucket():
     server2 = PredictionServer(bst)
     assert server2.device_predict_min_rows == 65536
     assert 65536 in server2.default_warmup_buckets()
-
-
-def test_pallas_lane_overflow_raises_value_error():
-    """ops/hist_pallas.py must reject k*w > 128 with a ValueError (an
-    assert would vanish under python -O)."""
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.hist_pallas import wave_hist_pallas
-
-    binned = jnp.zeros((1024, 1), jnp.uint8)
-    leaf = jnp.zeros((1024,), jnp.int32)
-    ghk = jnp.zeros((1024, 3), jnp.bfloat16)
-    pend = jnp.arange(64, dtype=jnp.int32)
-    with pytest.raises(ValueError, match="lane"):
-        wave_hist_pallas(binned, leaf, ghk, pend, g=1, nb=64, k=3,
-                         w=64, interpret=True)

@@ -1,148 +1,37 @@
-"""Fused find-best-in-wave (``find_best_fusion``, ops/grow.py).
+"""Find-best inside the wave (ops/grow.py): each growth wave is ONE
+traced program — the per-feature gain scan consumes the wave histograms
+where the histogram contraction produced them.  Until PR 30 a second,
+``two_pass`` layout (a concatenated (2W, S, 3) stack scanned by a second
+pass) sat beside it and these tests trained both; what they pin now:
 
-The fused layout runs each growth wave as ONE traced program — the
-per-feature gain scan consumes the wave histograms where the histogram
-contraction produced them — instead of the legacy two-pass layout's
-second find-best dispatch over a concatenated (2W, S, 3) stack.  These
-tests pin the contract from ISSUE 18:
-
-* fused vs two-pass trains BYTE-identical models in every guaranteed
-  regime — f32, int8 einsum, int8 Pallas (interpret on CPU), the
-  striped >= 2^24-row count layout (forced small), and composed with
-  the fused multi-iteration scan;
-* ``find_best_fusion`` joins ``programs_signature`` (two layouts must
-  never share a compiled program);
-* a warm same-shape retrain window under the fused layout traces
-  NOTHING new;
-* (slow) 1-vs-4 forced-host-mesh shard identity under quant8, both
-  layouts (tests/_shard_worker.py ``fused_find`` scenario).
+* the one layout trains the BYTES both layouts trained on the commit
+  before the deletion, in every guaranteed regime — bf16, int8, the
+  striped >= 2^24-row count layout (forced small), per-iteration against
+  the fused multi-iteration scan with and without in-scan bagging and
+  feature_fraction (``tests/growth_regimes.py``,
+  ``tests/data/growth_digests.json``);
+* a warm same-shape retrain window traces NOTHING new;
+* (slow) 1-vs-4 forced-host-mesh shard identity under quant8
+  (tests/_shard_worker.py ``fused_find`` scenario), held to the same
+  file.
 """
 
-import numpy as np
+import growth_regimes as regimes
 import pytest
-from conftest import assert_models_bit_identical, train_device_booster
-
-from lightgbm_tpu.config import Config
-
-
-def _data(rows=3000, cols=10, seed=3):
-    rng = np.random.default_rng(seed)
-    x = rng.standard_normal((rows, cols)).astype(np.float32)
-    y = (x[:, 0] + np.abs(x[:, 1]) > 0.5).astype(np.float32)
-    return x, y
+from growth_regimes import data as _data
+from growth_regimes import train as _train
 
 
-def _train(params, x, y, n_iters=5, chunk=0):
-    return train_device_booster(
-        {"objective": "binary", "verbosity": -1, "device_growth": "on",
-         "num_leaves": 15, "max_bin": 63, "min_data_in_leaf": 5,
-         "seed": 7, **params},
-        x, y, n_iters, chunk=chunk)
-
-
-def _pair(extra, x, y, **kw):
-    a = _train({**extra, "find_best_fusion": "fused"}, x, y, **kw)
-    b = _train({**extra, "find_best_fusion": "two_pass"}, x, y, **kw)
-    assert a._grower.fused_find and not b._grower.fused_find
-    return a, b
-
-
-def test_fused_find_f32_byte_identical():
-    x, y = _data()
-    a, b = _pair({}, x, y)
-    assert_models_bit_identical(a, b)
-
-
-def test_fused_find_int8_einsum_byte_identical():
-    # the exact-arithmetic regime: the int32 scan sees the identical
-    # integer histograms either way, so identity is law, not luck
-    x, y = _data(seed=5)
-    a, b = _pair({"grad_quant_bits": 8}, x, y)
-    assert a._grower.int_scan and b._grower.int_scan
-    assert_models_bit_identical(a, b)
-
-
-def test_fused_find_int8_pallas_interpret_byte_identical():
-    x, y = _data(seed=6)
-    a, b = _pair({"grad_quant_bits": 8, "hist_kernel": "interpret"},
-                 x, y)
-    assert a._grower.hist_kernel_tag == "pallas_int8"
-    assert b._grower.hist_kernel_tag == "pallas_int8"
-    assert_models_bit_identical(a, b)
-
-
-def test_fused_find_striped_byte_identical():
-    # the striped six-column count layout (>= 2^24 rows in production,
-    # forced small here) scans per lane exactly like the plain layout
-    import lightgbm_tpu.ops.grow as growmod
-
-    rng = np.random.default_rng(8)
-    n = 6000
-    x = rng.standard_normal((n, 6)).astype(np.float32)
-    y = (x[:, 0] + 2 * (x[:, 1] > 0.3) > 0.5).astype(np.float32)
-    old = growmod.COUNT_SPLIT_ROWS
-    try:
-        growmod.COUNT_SPLIT_ROWS = 5000
-        a, b = _pair({"grad_quant_bits": 8}, x, y, n_iters=4)
-        assert a._grower.hist_cols == b._grower.hist_cols == 6
-        assert_models_bit_identical(a, b)
-    finally:
-        growmod.COUNT_SPLIT_ROWS = old
-
-
-def test_fused_find_composes_with_fused_scan():
-    # fused find-best inside fused multi-iteration training must match
-    # the per-iteration two-pass run: both tentpoles at once
-    x, y = _data(seed=9)
-    params = {"grad_quant_bits": 8, "feature_fraction": 0.8,
-              "bagging_freq": 5, "bagging_fraction": 0.8}
-    a = _train({**params, "find_best_fusion": "fused"}, x, y,
-               n_iters=8, chunk=4)
-    b = _train({**params, "find_best_fusion": "two_pass"}, x, y,
-               n_iters=8)
-    assert_models_bit_identical(a, b)
-
-
-def test_programs_signature_includes_find_best_fusion():
-    from lightgbm_tpu.ops.grow import programs_signature
-
-    base = {"objective": "binary", "device_growth": "on",
-            "num_leaves": 15}
-    sigs = {
-        mode: programs_signature(
-            8192, 10, 64, 10, False,
-            Config({**base, "find_best_fusion": mode}))
-        for mode in ("auto", "fused", "two_pass")
-    }
-    # every mode value must key its own trace family — auto included,
-    # because auto may RESOLVE differently than an explicit setting
-    assert len(set(sigs.values())) == 3
-
-
-def test_resolve_find_fusion_modes():
-    from lightgbm_tpu.ops import stage_plan as sp
-    from lightgbm_tpu.ops.grow import (programs_signature,
-                                       resolve_find_fusion)
-
-    base = {"objective": "binary", "device_growth": "on"}
-    assert resolve_find_fusion(
-        Config({**base, "find_best_fusion": "fused"})) == "fused"
-    assert resolve_find_fusion(
-        Config({**base, "find_best_fusion": "two_pass"})) == "two_pass"
-    cfg = Config({**base, "find_best_fusion": "auto"})
-    assert resolve_find_fusion(cfg) == "fused"
-    # auto adopts a cached wave_plan=profiled verdict for the signature
-    sig = programs_signature(8192, 10, 64, 10, False, cfg)
-    try:
-        sp.cache_fusion(sig, "two_pass", persist=False)
-        assert resolve_find_fusion(cfg, sig) == "two_pass"
-    finally:
-        sp._FUSION_CACHE.pop(sig, None)
-    with pytest.raises(ValueError):
-        sp.cache_fusion(sig, "bogus", persist=False)
-    # the config layer rejects unknown modes outright (wave_plan idiom)
-    with pytest.raises(ValueError, match="find_best_fusion"):
-        Config({**base, "find_best_fusion": "bogus"})
+@pytest.mark.parametrize("regime", sorted(regimes.REGIMES))
+def test_growth_digest_holds(regime):
+    # trees, header and training scores byte for byte as the parent of
+    # PR 30 trained them under either wave layout; the regimes of one
+    # key (per-iteration and the fused scan) are held to ONE digest,
+    # so they are also held to each other
+    bst = regimes.REGIMES[regime]({})
+    if regime == "int8":
+        assert bst._grower.int_scan
+    assert regimes.digest_of(bst) == regimes.load()[regimes.key_of(regime)]
 
 
 def test_fused_find_warm_window_zero_new_traces():
@@ -152,13 +41,13 @@ def test_fused_find_warm_window_zero_new_traces():
     try:
         obs.configure(enabled=True)
         x, y = _data(seed=21)
-        _train({"find_best_fusion": "fused"}, x, y)
+        _train({}, x, y)
         before = {k: v["compiles"]
                   for k, v in obs.registry().snapshot()["jit"].items()}
         # a NEW same-shape dataset through a FRESH booster must land in
-        # the already-traced fused programs
+        # the already-traced programs
         x2, y2 = _data(seed=22)
-        _train({"find_best_fusion": "fused"}, x2, y2)
+        _train({}, x2, y2)
         after = {k: v["compiles"]
                  for k, v in obs.registry().snapshot()["jit"].items()}
         assert sum(after.values()) == sum(before.values()), (
@@ -189,31 +78,30 @@ def test_fused_find_dispatch_counters():
                 "grow.wave_dispatch_factor")
             return hist, fused, gauge
 
-        hist, fused, gauge = deltas({"find_best_fusion": "fused"})
+        # every dispatch counts its histogram and its in-wave find
+        # under one tag, and a wave is one dispatch equivalent
+        hist, fused, gauge = deltas({})
         assert hist > 0 and fused == hist and gauge == 1
-        hist, fused, gauge = deltas({"find_best_fusion": "two_pass"})
-        assert hist > 0 and fused == 0 and gauge == 2
     finally:
         obs.configure(enabled=was_enabled)
 
 
 def test_stage_plan_fused_wave_accounting():
-    """A fused hist+find dispatch counts as ONE wave in the simulator
-    (the PR-16 counts-as-waves bug class): layout changes the dispatch
-    factor, never the wave count."""
+    """A hist+find wave counts as ONE wave in the simulator (the PR-16
+    counts-as-waves bug class) and is priced by its end-to-end probe
+    timing where one exists, by the linear model elsewhere."""
     from lightgbm_tpu.ops import stage_plan as sp
 
     plan = sp.legacy_stage_plan(31, 30, 3)
-    cost_fused, waves_fused = sp.plan_cost_fn(
-        plan, 31, sp.wave_cost_fn(3, 1.0, 0.01))
-    cost_two, waves_two = sp.plan_cost_fn(
-        plan, 31, sp.wave_cost_fn(3, 1.0, 0.01,
-                                  find_ms={4: 0.5, 30: 0.5},
-                                  fusion="two_pass"))
-    assert waves_fused == waves_two
-    assert cost_two > cost_fused            # the second dispatch costs
-    assert sp.plan_dispatches(plan, 31, fused=True) == waves_fused
-    assert sp.plan_dispatches(plan, 31, fused=False) == 2 * waves_fused
+    assert plan == [(4, 8), (30, None)]
+    cost, waves = sp.plan_cost_fn(plan, 31, sp.wave_cost_fn(3, 1.0, 0.01))
+    # 1 -> 2 -> 4 -> 8 leaves at width 4, -> 16 -> 31 at width 30
+    assert waves == 5
+    assert cost == pytest.approx(3 * (1.0 + 0.12) + 2 * (1.0 + 0.90))
+    cost_m, waves_m = sp.plan_cost_fn(
+        plan, 31, sp.wave_cost_fn(3, 1.0, 0.01, measured_ms={4: 2.0}))
+    assert waves_m == waves
+    assert cost_m == pytest.approx(3 * 2.0 + 2 * (1.0 + 0.90))
 
 
 def test_derive_stage_plan_frontier_packing_knob():
@@ -237,11 +125,11 @@ def test_derive_stage_plan_frontier_packing_knob():
 @pytest.mark.slow
 @pytest.mark.timeout(600)
 def test_fused_find_shard_1v4_byte_identity():
-    # quant8 on the forced 4-device host mesh: both layouts must match
-    # their single-device runs AND each other (ops/shard.py contract)
+    # quant8 on the forced 4-device host mesh must match its
+    # single-device run (ops/shard.py contract), and both the bytes of
+    # the commit before PR 30
     from test_shard import _run_worker
 
     out = _run_worker("fused_find", timeout=580)
     assert out["fused_1v4_identical"] is True
-    assert out["two_pass_1v4_identical"] is True
-    assert out["fused_eq_two_pass"] is True
+    assert out["single"] == out["sharded"] == regimes.load()["shard"]

@@ -264,40 +264,43 @@ def test_eligibility_gates():
     assert not device_growth_eligible(cfg3, ds, obj3, 1)
 
 
-def test_pallas_hist_matches_einsum(reg_data):
-    """The Pallas wave-histogram kernel (interpret mode on CPU) must
-    agree with the XLA einsum formulation bin-for-bin."""
+def test_wave_hist_matches_a_plain_histogram(reg_data):
+    """The wave histogram (one einsum over 64-bin strips, bf16 products
+    summed in f32) against a scatter-add over the same bf16 values, bin
+    for bin: rows of leaves that are not pending, and of the empty
+    slots, land nowhere."""
     import jax.numpy as jnp
     x, y = reg_data
-    # grower_cache off: the flags tweaked below live on the (otherwise
-    # process-shared) GrowerPrograms object, so this test needs a
-    # private instance
     params = {"objective": "regression", "num_leaves": 64,
-              "min_data_in_leaf": 50, "grower_cache": False}
+              "min_data_in_leaf": 50}
     bd = _make(params, x, y, True)
-    grower = bd._grower.programs
-    assert grower is not None
+    progs = bd._grower.programs
     binned = bd._grower.binned
-    n = grower.n_pad
+    n = progs.n_pad
     rng = np.random.default_rng(0)
-    leaf = jnp.asarray(rng.integers(0, 8, n).astype(np.int32))
+    leaf = rng.integers(0, 8, n).astype(np.int32)
     g = jnp.asarray(rng.standard_normal(n).astype(np.float32))
     h = jnp.asarray(rng.random(n).astype(np.float32))
-    one = jnp.ones((n,), jnp.bfloat16)
     ghk = jnp.stack([g.astype(jnp.bfloat16), h.astype(jnp.bfloat16),
-                     one], 1)
-    # the kernel handles single-tile widths (w*k <= 128); pin the wave
-    # width into that range (the production path gates the same way)
-    grower.wave_width = min(grower.wave_width, 128 // grower.hist_cols)
-    pending = jnp.asarray(
-        np.concatenate([np.arange(6), [-1] * (grower.wave_width - 6)])
-        .astype(np.int32))
-    grower.use_pallas = False
-    ref = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n)[0])
-    grower.use_pallas = True
-    grower.pallas_interpret = True
-    got = np.asarray(grower._wave_hist(binned, leaf, ghk, pending, n)[0])
+                     jnp.ones((n,), jnp.bfloat16)], 1)
+    w = progs.wave_width
+    pending = np.concatenate([np.arange(6), [-1] * (w - 6)]).astype(
+        np.int32)
+    got, (visited, live) = progs._wave_hist(
+        binned, jnp.asarray(leaf), ghk, jnp.asarray(pending), n)
+    got = np.asarray(got)
+    assert got.shape == (w, progs.num_slots, 3)
+
+    ref = np.zeros((w, progs.num_slots, 3), np.float64)
+    vals = np.asarray(ghk.astype(jnp.float32), np.float64)
+    bins = np.asarray(binned).astype(np.int64)
+    keep = leaf < 6
+    for grp in range(progs.num_groups):
+        np.add.at(ref, (leaf[keep], grp * progs.nb + bins[keep, grp]),
+                  vals[keep])
     np.testing.assert_allclose(got, ref, atol=1e-3, rtol=1e-4)
+    np.testing.assert_array_equal(got[6:], 0)
+    assert int(live) == int(keep.sum())
 
 
 # ---------------------------------------------------------------------------

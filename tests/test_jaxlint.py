@@ -339,21 +339,21 @@ def test_injected_jl101_traced_param_in_key(pkg_copy):
         p.write_text(orig)
 
 
-def test_injected_jl101_fusion_mode_excluded_from_key(pkg_copy):
-    """Excluding find_best_fusion from the digest while ``_grow_impl``
-    reads it in the traced region (the fused-vs-two-pass wave-layout
-    branch) must fire JL101: the two layouts are different programs, so
-    an un-keyed mode would let a cached trace serve the other layout."""
+def test_injected_jl101_trace_shaping_param_excluded_from_key(pkg_copy):
+    """Excluding max_depth from the digest while ``_grow_impl``'s wave
+    reads it in the traced region (``_splittable`` branches on it in
+    Python, so it decides which program is traced) must fire JL101: an
+    un-keyed value would let a cached trace serve another depth limit."""
     p, orig = _mutate(
         pkg_copy, "lightgbm_tpu/ops/grow.py",
         '_NON_TRACE_PARAMS = ("wave_plan", "grower_cache", '
         '"learning_rate")',
         '_NON_TRACE_PARAMS = ("wave_plan", "grower_cache", '
-        '"learning_rate", "find_best_fusion")')
+        '"learning_rate", "max_depth")')
     try:
         r = _lint(pkg_copy, "--select", "JL101", "--no-baseline")
         assert r.returncode == 1, r.stdout + r.stderr
-        assert "JL101" in r.stdout and "find_best_fusion" in r.stdout
+        assert "JL101" in r.stdout and "max_depth" in r.stdout
     finally:
         p.write_text(orig)
 

@@ -144,46 +144,6 @@ def test_quant_fused_parity_with_fork_harness_config():
     _assert_bit_identical(a, b)
 
 
-def test_quant_pallas_byte_identical_to_einsum():
-    """The int8 Pallas wave-histogram kernel (interpret mode on CPU)
-    must yield BYTE-identical models to the int8 einsum: both
-    accumulate int8->int32 and integer addition is associative, so any
-    divergence is a layout/masking bug, never rounding."""
-    rng = np.random.default_rng(3)
-    x = rng.standard_normal((3000, 10)).astype(np.float32)
-    y = (x[:, 0] + np.abs(x[:, 1]) > 0.5).astype(np.float32)
-    base = {"grad_quant_bits": 8, "num_leaves": 15,
-            "min_data_in_leaf": 5}
-    a = _train({**base, "hist_kernel": "einsum"}, x, y, 5)
-    b = _train({**base, "hist_kernel": "interpret"}, x, y, 5)
-    assert a._grower.hist_kernel_tag == "einsum_int8"
-    assert b._grower.hist_kernel_tag == "pallas_int8"
-    assert a._grower.int_scan and b._grower.int_scan
-    _assert_bit_identical(a, b)
-
-
-def test_quant_pallas_striped_byte_identical():
-    """Same contract on the striped six-column layout (the >= 2^24-row
-    path, forced small via COUNT_SPLIT_ROWS)."""
-    import lightgbm_tpu.ops.grow as growmod
-
-    rng = np.random.default_rng(8)
-    n = 6000
-    x = rng.standard_normal((n, 6)).astype(np.float32)
-    y = (x[:, 0] + 2 * (x[:, 1] > 0.3) > 0.5).astype(np.float32)
-    base = {"grad_quant_bits": 8, "num_leaves": 15, "seed": 77}
-    old = growmod.COUNT_SPLIT_ROWS
-    try:
-        growmod.COUNT_SPLIT_ROWS = 5000
-        a = _train({**base, "hist_kernel": "einsum"}, x, y, 4)
-        b = _train({**base, "hist_kernel": "interpret"}, x, y, 4)
-        assert a._grower.hist_cols == b._grower.hist_cols == 6
-        assert b._grower.hist_kernel_tag == "pallas_int8"
-        _assert_bit_identical(a, b)
-    finally:
-        growmod.COUNT_SPLIT_ROWS = old
-
-
 _CHUNK_WORKER = """
 import hashlib, json, sys
 import numpy as np
