@@ -26,25 +26,38 @@ def _is_sparse(data) -> bool:
     return hasattr(data, "tocsr") and hasattr(data, "nnz")
 
 
-def _to_2d_float(data, feature_name=None):
-    """Coerce user input (ndarray / pandas / scipy sparse / list) to a dense
-    float64 matrix + feature names.  (Sparse inputs in the Dataset
-    construction path never reach this - they bin CSR-natively; this
-    densify only serves prediction batches and is chunked by callers.)"""
+def _to_2d(data, feature_name=None):
+    """User input (ndarray / pandas / scipy sparse / list) as a dense 2-D
+    matrix + feature names.  A float32 or float64 ndarray (a DataFrame's
+    ``.values`` included) comes back as it is, whatever its order or
+    strides; anything else is coerced to float64."""
     names = None
     if hasattr(data, "toarray"):          # scipy sparse
         data = data.toarray()
     elif hasattr(data, "values") and hasattr(data, "columns"):  # DataFrame
         names = [str(c) for c in data.columns]
         data = data.values
-    arr = np.asarray(data, dtype=np.float64)
+    if isinstance(data, np.ndarray) and data.dtype in (np.float32,
+                                                       np.float64):
+        arr = data
+    else:
+        arr = np.asarray(data, dtype=np.float64)
     if arr.ndim == 1:
         arr = arr.reshape(-1, 1)
     if arr.ndim != 2:
         raise LightGBMError("data must be 2-dimensional")
     if feature_name not in (None, "auto"):
         names = list(feature_name)
-    return np.ascontiguousarray(arr), names
+    return arr, names
+
+
+def _to_2d_float(data, feature_name=None):
+    """:func:`_to_2d` as a contiguous float64 matrix, for prediction
+    batches (chunked by callers).  Dataset construction never comes here:
+    dense inputs are binned from their own dtype a row block at a time,
+    sparse ones CSR-natively."""
+    arr, names = _to_2d(data, feature_name)
+    return np.ascontiguousarray(arr, dtype=np.float64), names
 
 
 def _resolve_categorical(categorical_feature, feature_names, num_features):
@@ -67,7 +80,13 @@ def _resolve_categorical(categorical_feature, feature_names, num_features):
 
 class Dataset:
     """Training/validation data holder (lazy binning construction,
-    reference basic.py:626-1449)."""
+    reference basic.py:626-1449).
+
+    A dense float32 or float64 array is binned from the caller's own
+    buffer, a row block a core (``num_threads``), with no float64 copy of
+    the whole matrix; with ``free_raw_data=False``, ``raw`` is that array
+    in the caller's dtype.  Other dense inputs are coerced to float64
+    first; scipy sparse ones are binned from their CSR."""
 
     def __init__(self, data, label=None, reference=None, weight=None,
                  group=None, init_score=None, silent=False,
@@ -123,7 +142,7 @@ class Dataset:
                                 if self.feature_name not in (None, "auto")
                                 else None)
         else:
-            arr, names = _to_2d_float(self.data, self.feature_name)
+            arr, names = _to_2d(self.data, self.feature_name)
         ref_handle = (self.reference._handle if self.reference is not None
                       else None)
         if arr is None:

@@ -15,7 +15,7 @@ skipping: the TPU build keeps full dense histograms, so the reference's
 from __future__ import annotations
 
 import math
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -251,6 +251,7 @@ class BinMapper:
         self.min_val: float = 0.0
         self.max_val: float = 0.0
         self.default_bin: int = 0
+        self._cat_lut: Optional[np.ndarray] = None   # see _categorical_lut
 
     # ------------------------------------------------------------------
     def find_bin(self, values: np.ndarray, total_sample_cnt: int, max_bin: int,
@@ -378,6 +379,7 @@ class BinMapper:
         rest_cnt = total_sample_cnt - na_cnt
         cnt_in_bin: List[int] = []
         self.categorical_2_bin = {}
+        self._cat_lut = None
         b2c: List[int] = []
         if rest_cnt > 0 and cats:
             order = np.argsort(np.asarray(counts), kind="stable")[::-1]
@@ -461,22 +463,34 @@ class BinMapper:
             iv = np.where(nan_mask, -1, values).astype(np.int64)
             default = self.num_bin - 1
             if len(self.bin_2_categorical):
-                max_cat = int(max(self.categorical_2_bin.keys(), default=0))
-                if max_cat < (1 << 22):
-                    lut = np.full(max_cat + 2, default, dtype=np.int32)
-                    for c, b in self.categorical_2_bin.items():
-                        if c >= 0:
-                            lut[c] = b
-                    clipped = np.clip(iv, 0, max_cat + 1)
-                    out[:] = lut[clipped]
+                lut = self._categorical_lut()
+                if lut is not None:
+                    # past the largest category: the table's last entry
+                    out[:] = lut[np.clip(iv, 0, len(lut) - 1)]
                     out[iv < 0] = default
-                    out[iv > max_cat] = default
                 else:
                     out[:] = [self.categorical_2_bin.get(int(v), default)
                               if v >= 0 else default for v in iv]
             else:
                 out[:] = default
         return out
+
+    def _categorical_lut(self) -> Optional[np.ndarray]:
+        """category -> bin table of ``max_cat + 2`` entries (the last one
+        the default bin), or None when the largest category is too large
+        for a table.  Built on first use and kept: binning calls
+        ``values_to_bins`` once a row block, and threads that race here
+        build equal tables."""
+        if self._cat_lut is None:
+            max_cat = int(max(self.categorical_2_bin.keys(), default=0))
+            if max_cat >= (1 << 22):
+                return None
+            lut = np.full(max_cat + 2, self.num_bin - 1, dtype=np.int32)
+            for c, b in self.categorical_2_bin.items():
+                if c >= 0:
+                    lut[c] = b
+            self._cat_lut = lut
+        return self._cat_lut
 
     # ------------------------------------------------------------------
     def bin_to_value(self, bin_idx: int) -> float:

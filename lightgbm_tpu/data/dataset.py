@@ -32,8 +32,24 @@ from ..utils.random import make_rng
 from .binning import BIN_CATEGORICAL, BIN_NUMERICAL, BinMapper
 
 MAX_GROUP_BIN = 256   # static histogram bin axis on device
-CSR_BLOCK_ROWS = 1 << 17   # rows of a CSR binned as one block
+BIN_BLOCK_ROWS = 1 << 17   # rows binned as one block (dense and CSR)
 BINARY_MAGIC = b"LIGHTGBM_TPU_DATASET_V1\n"
+
+
+def _run_blocks(fill, n: int, num_threads: int) -> None:
+    """``fill(lo)`` for the first row ``lo`` of every block of
+    ``BIN_BLOCK_ROWS`` rows below ``n``, ``num_threads`` blocks at once
+    (0: every core); one block runs on the calling thread.  ``fill``
+    writes its own rows only, so any thread count gives the same bytes."""
+    blocks = range(0, n, BIN_BLOCK_ROWS)
+    obs.inc("bin.blocks", len(blocks))
+    threads = min(int(num_threads) or os.cpu_count() or 1, len(blocks))
+    if threads <= 1:
+        for lo in blocks:
+            fill(lo)
+    else:
+        with ThreadPoolExecutor(threads) as pool:
+            list(pool.map(fill, blocks))
 
 
 class Metadata:
@@ -146,6 +162,7 @@ class BinnedDataset:
         self.feature_penalty: np.ndarray = np.empty(0, np.float64)
         self.reference: Optional["BinnedDataset"] = None
         self.device_binned: bool = False   # .binned lives on device (jnp)
+        self._push_threads: int = 0        # construct_streaming_push's
 
     # -- accessors ---------------------------------------------------------
     @property
@@ -171,7 +188,14 @@ class BinnedDataset:
             reference: Optional["BinnedDataset"] = None,
             predefined_mappers: Optional[List[Optional[BinMapper]]] = None,
     ) -> "BinnedDataset":
-        """Build from a dense float matrix (rows, features).
+        """Build from a dense matrix (rows, features).
+
+        ``data`` is read in its own dtype, order and strides: the sampled
+        rows are widened to float64 for bin finding, and the group matrix
+        is filled ``BIN_BLOCK_ROWS`` rows at a time (``num_threads``
+        blocks at once), each column of a block widened to float64 as it
+        is binned.  float32 widens exactly, so a float32 matrix gets the
+        bins of its float64 copy without that copy being made.
 
         ``reference`` given -> validation-style construction reusing its bin
         mappers and grouping (reference ``Dataset::CreateValid``,
@@ -193,7 +217,7 @@ class BinnedDataset:
 
         if reference is not None:
             with obs.span("bin.apply", cat="data"):
-                ds._align_with_reference(data, reference)
+                ds._align_with_reference(data, reference, config)
             return ds
 
         with obs.span("bin.find", cat="data"):
@@ -203,7 +227,7 @@ class BinnedDataset:
         with obs.span("bin.bundle", cat="data"):
             ds._bundle_features(data, config)
         with obs.span("bin.apply", cat="data"):
-            ds._build_group_matrix(data)
+            ds._build_group_matrix(data, config)
         ds._build_feature_lookups(config)
         return ds
 
@@ -331,7 +355,7 @@ class BinnedDataset:
         """Bin directly from CSR triplets without densifying.
 
         Host memory is the caller's CSR, the final (N, G) uint8 binned
-        matrix, the sampled rows and ``CSR_BLOCK_ROWS`` rows of scratch
+        matrix, the sampled rows and ``BIN_BLOCK_ROWS`` rows of scratch
         a thread: no array of length nnz is made here, and the dense
         float64 matrix is never materialised.  Bins are found from the
         ``bin_construct_sample_cnt`` sampled rows alone; the group matrix
@@ -445,6 +469,7 @@ class BinnedDataset:
         ds = cls()
         ds.num_data = int(n_total)
         ds.num_total_features = int(num_cols)
+        ds._push_threads = int(config.num_threads)
         ds.metadata = Metadata(ds.num_data)
         ds.feature_names = ([f"Column_{i}" for i in range(num_cols)]
                             if feature_names is None
@@ -499,20 +524,12 @@ class BinnedDataset:
         """Bin ``chunk`` rows into ``binned[start_row:...]`` (the analog
         of ``Dataset::PushOneRow``, dataset.h:318-341, chunk-vectorized).
         """
-        chunk = np.asarray(chunk, np.float64)
+        chunk = np.asarray(chunk)
         end = start_row + chunk.shape[0]
         if end > self.num_data:
             raise LightGBMError("streaming push beyond declared num_data")
-        out = self.binned[start_row:end]
-        for gid, group in enumerate(self.groups):
-            col_out = out[:, gid]
-            for sub, f in enumerate(group.feature_indices):
-                m = self.bin_mappers[f]
-                bins = m.values_to_bins(chunk[:, f])
-                offset = group.bin_offsets[sub]
-                slot = bins + offset - (1 if m.default_bin == 0 else 0)
-                non_default = bins != m.default_bin
-                col_out[non_default] = slot[non_default].astype(np.uint8)
+        self._fill_dense(chunk, self.binned[start_row:end],
+                         self._push_threads)
 
     def construct_streaming_finish(self) -> None:
         """End of the stream (placeholder for integrity checks)."""
@@ -544,7 +561,7 @@ class BinnedDataset:
 
     def _build_group_matrix_csr(self, indptr, indices, values,
                                 config: Config) -> None:
-        """(N, G) uint8 matrix straight from the CSR, ``CSR_BLOCK_ROWS``
+        """(N, G) uint8 matrix straight from the CSR, ``BIN_BLOCK_ROWS``
         rows at a time: a block's entries are brought column-major by one
         stable sort of their (narrow) column numbers, each feature's run
         is binned and scattered into the block's rows.  Rows not recorded
@@ -558,14 +575,10 @@ class BinnedDataset:
         key_t = (np.uint8 if num_col <= 1 << 8 else np.uint16
                  if num_col <= 1 << 16 else indices.dtype)  # radix-sorted
         col_ids = np.arange(num_col + 1)
-        plan = [(gid, f, self.bin_mappers[f],
-                 group.bin_offsets[sub]
-                 - (1 if self.bin_mappers[f].default_bin == 0 else 0))
-                for gid, group in enumerate(self.groups)
-                for sub, f in enumerate(group.feature_indices)]
+        plan = self._bin_plan()
 
         def fill(lo: int) -> None:
-            hi = min(lo + CSR_BLOCK_ROWS, n)
+            hi = min(lo + BIN_BLOCK_ROWS, n)
             s, e = int(indptr[lo]), int(indptr[hi])
             cols = indices[s:e].astype(key_t, copy=False)
             order = np.argsort(cols, kind="stable")
@@ -582,15 +595,7 @@ class BinnedDataset:
                     np.uint8)
 
         obs.inc("bin.csr_nnz", int(indptr[n]) - int(indptr[0]))
-        blocks = range(0, n, CSR_BLOCK_ROWS)
-        threads = min(int(config.num_threads) or os.cpu_count() or 1,
-                      len(blocks))
-        if threads <= 1:
-            for lo in blocks:
-                fill(lo)
-        else:
-            with ThreadPoolExecutor(threads) as pool:
-                list(pool.map(fill, blocks))
+        _run_blocks(fill, n, config.num_threads)
         self.binned = binned
 
     # -- stage 1: bin mappers ---------------------------------------------
@@ -747,22 +752,46 @@ class BinnedDataset:
         return out
 
     # -- stage 3: binned group matrix -------------------------------------
-    def _build_group_matrix(self, data: np.ndarray) -> None:
-        n = self.num_data
-        g_count = len(self.groups)
-        binned = np.zeros((n, g_count), dtype=np.uint8)
-        for gid, group in enumerate(self.groups):
-            col_out = binned[:, gid]
-            for sub, f in enumerate(group.feature_indices):
-                m = self.bin_mappers[f]
-                bins = m.values_to_bins(np.asarray(data[:, f], dtype=np.float64))
-                offset = group.bin_offsets[sub]
-                slot = bins + offset - (1 if m.default_bin == 0 else 0)
-                non_default = bins != m.default_bin
-                # later features of a bundle overwrite on (rare) conflicts,
-                # same as the reference's push order
-                col_out[non_default] = slot[non_default].astype(np.uint8)
+    def _bin_plan(self):
+        """``(group, feature, mapper, shift)`` in the groups' order: a
+        non-default bin ``b`` of the feature is slot ``b + shift`` of the
+        group, and of a bundle's features the later one overwrites on
+        (rare) conflicts, same as the reference's push order."""
+        return [(gid, f, self.bin_mappers[f],
+                 group.bin_offsets[sub]
+                 - (1 if self.bin_mappers[f].default_bin == 0 else 0))
+                for gid, group in enumerate(self.groups)
+                for sub, f in enumerate(group.feature_indices)]
+
+    def _build_group_matrix(self, data: np.ndarray, config: Config) -> None:
+        binned = np.zeros((self.num_data, len(self.groups)), dtype=np.uint8)
+        self._fill_dense(data, binned, config.num_threads)
         self.binned = binned
+
+    def _fill_dense(self, data: np.ndarray, binned: np.ndarray,
+                    num_threads: int) -> None:
+        """Bin the rows of the dense ``data`` into ``binned`` (zeroed, as
+        many rows), ``BIN_BLOCK_ROWS`` rows at a time.  Blocks write
+        disjoint rows, so ``num_threads`` of them (0: every core) run at
+        once to the same bytes."""
+        plan = self._bin_plan()
+        n = len(data)
+
+        def fill(lo: int) -> None:
+            hi = min(lo + BIN_BLOCK_ROWS, n)
+            # the block column-major in its own dtype; values_to_bins
+            # widens a column at a time, and float32 -> float64 is exact,
+            # so each comparison with the float64 bounds is the one a
+            # float64 copy of the whole matrix would give
+            cols = np.ascontiguousarray(data[lo:hi].T)
+            out = binned[lo:hi]
+            for gid, f, m, shift in plan:
+                bins = m.values_to_bins(cols[f])
+                keep = bins != m.default_bin
+                out[keep, gid] = (bins[keep] + shift).astype(np.uint8)
+
+        obs.inc("bin.dense_values", n * data.shape[1])
+        _run_blocks(fill, n, num_threads)
 
     # -- stage 4: per-feature device lookups ------------------------------
     def _build_feature_lookups(self, config: Optional[Config]) -> None:
@@ -802,13 +831,14 @@ class BinnedDataset:
 
     # -- validation alignment ---------------------------------------------
     def _align_with_reference(self, data: np.ndarray,
-                              reference: "BinnedDataset") -> None:
+                              reference: "BinnedDataset",
+                              config: Config) -> None:
         if data.shape[1] != reference.num_total_features:
             raise LightGBMError(
                 f"validation data has {data.shape[1]} features, train has "
                 f"{reference.num_total_features}")
         self._align_with_reference_shared(reference)
-        self._build_group_matrix(np.asarray(data))
+        self._build_group_matrix(data, config)
 
     def check_align(self, other: "BinnedDataset") -> bool:
         """Reference ``Dataset::CheckAlign`` (dataset.h:300-316)."""

@@ -118,7 +118,10 @@ PARAM_SCHEMA: Sequence[Param] = (
        desc="serial, feature (feature_parallel), data (data_parallel), "
             "voting (voting_parallel)", section="core"),
     _p("num_threads", int, 0, ("num_thread", "nthread", "nthreads", "n_jobs"),
-       desc="number of host threads (0 = default)", section="core"),
+       desc="number of host threads (0 = every core). Host binning honours it on "
+            "both routes: a dense matrix and a CSR are binned in blocks of 2^17 "
+            "rows, this many blocks at once, to the same bytes at any count",
+       section="core"),
     _p("device_type", str, "tpu", ("device",),
        desc="device for tree learning: tpu (default here), cpu. The reference's "
             "cpu/gpu map to cpu/tpu in this framework", section="core"),

@@ -366,7 +366,7 @@ def test_csr_binning_is_the_parents_and_the_dense_paths_bytes(
         case, block_rows, monkeypatch):
     # 7 rows a block: block boundaries fall inside every column's run of
     # entries, at empty rows and at full ones
-    monkeypatch.setattr(dataset_mod, "CSR_BLOCK_ROWS", block_rows)
+    monkeypatch.setattr(dataset_mod, "BIN_BLOCK_ROWS", block_rows)
     x = _csr_case(case)
     cfg = Config({"objective": "binary", "max_bin": 255,
                   "bin_construct_sample_cnt": 1000, "num_threads": 3})
@@ -405,7 +405,7 @@ def test_csr_binning_counts_what_it_binned_and_spans_cover_it():
 
 
 def test_csr_binning_allocates_by_the_block_not_by_nnz(monkeypatch):
-    monkeypatch.setattr(dataset_mod, "CSR_BLOCK_ROWS", 2048)
+    monkeypatch.setattr(dataset_mod, "BIN_BLOCK_ROWS", 2048)
     rng = np.random.default_rng(3)
     cfg = Config({"objective": "binary", "bin_construct_sample_cnt": 2000,
                   "num_threads": 1})
