@@ -25,6 +25,7 @@ from ..metrics import create_metrics
 from ..objectives import create_objective
 from ..ops import stage_plan as stage_plan_mod
 from ..ops.grow import _CHUNK, DeviceGrower, device_growth_eligible
+from ..ops.shard import DealtRows
 from ..ops.traverse import add_tree_score, device_tree
 from ..robust import checkpoint as _checkpoint
 from ..robust import faults
@@ -54,10 +55,27 @@ class _ValidSet:
         self.applied_models = 0     # models already added to `score`
 
 
+#: columns of a tree's work vector ahead of a mesh's exact leaf rows
+_WORK_COLS = 9
+
+
+def _exact_counts(work):
+    """The exact rows of each leaf that a mesh's program appends to a
+    tree's work vector (``ops/grow.py::_grow_impl``), else ``None``."""
+    if work is None:
+        return None
+    work = np.asarray(work)
+    return work[_WORK_COLS:] if work.shape[-1] > _WORK_COLS else None
+
+
 def _replay_records(rec_i, rec_f, rec_c, nl, shrinkage, bias, dataset,
-                    config) -> Tree:
+                    config, leaf_rows=None) -> Tree:
     """Replay host-side split records of one device-grown tree into a
-    ``Tree`` (rec_i/rec_f/rec_c are numpy, nl an int)."""
+    ``Tree`` (rec_i/rec_f/rec_c are numpy, nl an int).  ``leaf_rows``,
+    where the program counted them (a mesh), are the exact rows of each
+    leaf: they replace the records' float32 counts, which are rounded
+    once a node holds more than 2^24 rows, and every internal count is
+    added up from them."""
     tree = Tree(config.num_leaves)
     if nl <= 1:
         # stump: the grower applied NOTHING to the training scores
@@ -90,6 +108,14 @@ def _replay_records(rec_i, rec_f, rec_c, nl, shrinkage, bias, dataset,
                 tree.split(leaf, f, real_f, thr,
                            mapper.bin_to_value(thr), lout, rout,
                            int(lc), int(rc), gain, missing, bool(dl))
+        if leaf_rows is not None:
+            tree.leaf_count[:nl] = np.asarray(leaf_rows[:nl], np.int64)
+            for node in range(nl - 2, -1, -1):   # children come later
+                tree.internal_count[node] = sum(
+                    tree.leaf_count[~c] if c < 0
+                    else tree.internal_count[c]
+                    for c in (tree.left_child[node],
+                              tree.right_child[node]))
         tree.apply_shrinkage(shrinkage)
     if abs(bias) > K_EPSILON:
         tree.add_bias(bias)
@@ -105,14 +131,15 @@ class _PendingTree(_Pending):
     replayed into a host ``Tree`` lazily (``GBDT._flush_pending``)."""
 
     __slots__ = ("rec_i", "rec_f", "rec_c", "nl", "root_value",
-                 "shrinkage", "bias")
+                 "shrinkage", "bias", "work")
 
     def __init__(self, rec_i, rec_f, rec_c, nl, root_value, shrinkage,
-                 bias):
+                 bias, work=None):
         self.rec_i = rec_i
         self.rec_f = rec_f
         self.rec_c = rec_c
         self.nl = nl
+        self.work = work
         self.root_value = root_value
         self.shrinkage = shrinkage
         self.bias = bias
@@ -124,7 +151,8 @@ class _PendingTree(_Pending):
                                np.asarray(self.rec_f),
                                np.asarray(self.rec_c),
                                int(np.asarray(self.nl)),
-                               self.shrinkage, self.bias, dataset, config)
+                               self.shrinkage, self.bias, dataset, config,
+                               leaf_rows=_exact_counts(self.work))
 
 
 class _RecStack:
@@ -134,8 +162,10 @@ class _RecStack:
 
     __slots__ = ("arrs", "_host", "qscales")
 
-    def __init__(self, rec_i, rec_f, rec_c, nl, qscales=None):
-        self.arrs = (rec_i, rec_f, rec_c, nl)
+    def __init__(self, rec_i, rec_f, rec_c, nl, work, qscales=None):
+        # the work vectors ride along: a mesh's end in each leaf's
+        # exact rows, which the replay takes
+        self.arrs = (rec_i, rec_f, rec_c, nl, work)
         self._host = None
         # (K, 2) per-tree quantization scales (grad_quant_bits only);
         # fetched lazily with the lagged stall check so gauge recording
@@ -163,10 +193,11 @@ class _PendingChunkTree(_Pending):
         self.bias = bias
 
     def materialize(self, dataset, config) -> Tree:
-        rec_i, rec_f, rec_c, nl = self.stack.host()
+        rec_i, rec_f, rec_c, nl, work = self.stack.host()
         return _replay_records(rec_i[self.idx], rec_f[self.idx],
                                rec_c[self.idx], int(nl[self.idx]),
-                               self.shrinkage, self.bias, dataset, config)
+                               self.shrinkage, self.bias, dataset, config,
+                               leaf_rows=_exact_counts(work[self.idx]))
 
 
 class _WorkDrain:
@@ -181,7 +212,10 @@ class _WorkDrain:
     ``rows_real`` (real rows x waves) / ``rows_scanned`` (the visited
     chunks' rows) / ``rows_live`` (both counted by the program, summed
     over waves and shards) / ``rows_in_bag`` / ``features_in_mask``
-    (both per tree) — at the next
+    (both per tree) and, when a mesh ran the dispatch (two more work
+    columns), ``rows_live_max`` (the fullest shard's live rows, summed
+    wave by wave) and ``psum_bytes`` (wave slots x the bytes one chip
+    hands the histogram psum for a slot) — at the next
     dispatch and whenever the registry is snapshotted (the booster
     registers ``drain`` as a collector), so the dispatch path never
     waits for the device and a chunk whose ``block_until_ready`` has
@@ -194,16 +228,37 @@ class _WorkDrain:
     def __init__(self):
         self._lock = threading.Lock()
         self._pending = collections.deque()
+        # bytes one chip hands the histogram psum for one stage slot
+        # (set with the grower; 0 off a mesh)
+        self.psum_slot_bytes = 0
+        # the output of the next pushed dispatch that its caller waits
+        # on (the score): once THAT is ready the dispatch is over and so
+        # are its counters, whatever the backend says of arrays nobody
+        # has waited for (on four chips the work vector of a dispatch
+        # whose score had been awaited still read not ready)
+        self.awaited = None
 
     def __len__(self):
         return len(self._pending)
+
+    @staticmethod
+    def _ready(a) -> bool:
+        """Whether ONE device's copy of a dispatch's output is there.
+        The devices of a mesh end a dispatch together (every wave ends
+        in a psum), while ``is_ready()`` of the whole array still reads
+        false right after a caller has waited for another output of the
+        same dispatch — or for device 0's copy alone, as ``np.asarray``
+        of a replicated array does."""
+        return a.addressable_shards[0].data.is_ready()
 
     def push(self, nl, work, rows_real: int) -> None:
         if not obs.enabled():
             return
         work.copy_to_host_async()
         with self._lock:
-            self._pending.append((nl, work, rows_real))
+            self._pending.append((nl, work, rows_real,
+                                  self.psum_slot_bytes, self.awaited))
+            self.awaited = None
         self.drain()
 
     def drain(self) -> None:
@@ -211,12 +266,14 @@ class _WorkDrain:
             done = []
             while self._pending and (
                     len(self._pending) > self.CAP
-                    or (self._pending[0][0].is_ready()
-                        and self._pending[0][1].is_ready())):
+                    or any(a is not None and self._ready(a)
+                           for a in (self._pending[0][4],
+                                     self._pending[0][1]))):
                 done.append(self._pending.popleft())
-        for nl, work, rows_real in done:
+        for nl, work, rows_real, slot_bytes, _ in done:
             nl = np.asarray(nl).reshape(-1)
-            work = np.asarray(work, np.int64).reshape(-1, 7)
+            work = np.asarray(work, np.int64)
+            work = work.reshape(-1, work.shape[-1])
             waves = int(work[:, 0].sum())
             obs.inc("grow.trees", int(nl.size))
             obs.inc("grow.leaves", int(nl.sum()))
@@ -228,6 +285,13 @@ class _WorkDrain:
                     + int(work[:, 6].sum()))
             obs.inc("grow.rows_in_bag", int(work[:, 2].sum()))
             obs.inc("grow.features_in_mask", int(work[:, 3].sum()))
+            if work.shape[1] > 7:
+                # a mesh ran it: what the fullest shard contracted, wave
+                # by wave, and the bytes a chip gave the histogram psums
+                obs.inc("grow.rows_live_max", int(work[:, 7].sum())
+                        * _CHUNK + int(work[:, 8].sum()))
+                obs.inc("grow.psum_bytes",
+                        int(work[:, 1].sum()) * slot_bytes)
 
 
 class GBDT:
@@ -383,6 +447,12 @@ class GBDT:
                 self._grower = DeviceGrower(train_set, cfg,
                                             row_bucketing=bucket_ok,
                                             mesh=mesh)
+                # a stage slot's share of a wave's histogram psum: its
+                # (slots, 3) block of 4-byte sums (float32 g and h and
+                # int32 counts, or three int32 under the integer scan)
+                self._work.psum_slot_bytes = \
+                    self._grower.num_slots * 3 * 4 \
+                    if self._grower.deal is not None else 0
                 log_info("Using on-device tree growth (device_growth="
                          f"{mode})")
                 wp = str(getattr(cfg, "wave_plan", "auto")).lower()
@@ -534,6 +604,7 @@ class GBDT:
         (no splittable leaves), mirroring GBDT::TrainOneIter."""
         device = (self._grower is not None and gradients is None
                   and hessians is None)
+        self._row_order_score()
         if not obs.enabled():
             return self._train_one_iter_device() if device \
                 else self._train_one_iter_host(gradients, hessians)
@@ -710,7 +781,7 @@ class GBDT:
             last_qscale = qscale
             self.models.append(_PendingTree(
                 rec_i, rec_f, rec_c, nl, root_val, shrink,
-                init_scores[k]))
+                init_scores[k], work))
             self._push_work(nl, work)
             nls.append(nl)
         self.iter += 1
@@ -764,7 +835,12 @@ class GBDT:
             # (gradients come in globally computed)
             return None
         if self._fused_grad is False:
-            self._fused_grad = self.objective.device_grad()
+            fg = self.objective.device_grad()
+            if fg is not None and self._grower.deal is not None:
+                # mesh: labels and weights are dealt over the shards
+                # once, here, and every dispatch takes them as they lie
+                fg = (fg[0], self._grower.deal_rows(fg[1]))
+            self._fused_grad = fg
         return self._fused_grad
 
     def fused_eligible(self) -> bool:
@@ -866,21 +942,36 @@ class GBDT:
         out to have stalled (every tree a stump)."""
         bias = self.boost_from_average(0) if not self.models else 0.0
         fused = self._grower.fused_train(chunk)
+        deal = self._grower.deal
+        if deal is None:
+            score_in = self.train_score[0]
+        elif isinstance(self.train_score, DealtRows):
+            score_in = self.train_score.dealt    # the last dispatch's
+        else:
+            score_in = self._grower.deal_rows(self.train_score[0])
         with obs.span("chunk.enqueue", cat="boost"):
             score, (rec_i, rec_f, rec_c, nl, _root, work, qscales) = \
                 self._dispatch_guard(lambda: fused(
                     self._grower.binned, self._grower.binned_t,
-                    self.train_score[0], lr, gargs,
+                    score_in, lr, gargs,
                     jnp.asarray(self.iter, jnp.int32), grad_fn=grad_fn))
         sp.sync_value = score
-        self.train_score = self.train_score.at[0].set(score)
+        if deal is None:
+            self.train_score = self.train_score.at[0].set(score)
+        else:
+            # the score stays dealt over the mesh until something reads
+            # it as an array (metrics, a checkpoint, a per-iteration
+            # step): the next dispatch takes it as it is
+            self.train_score = DealtRows(deal, score)
         quant = bool(getattr(self._grower, "quant_bits", 0))
-        stack = _RecStack(rec_i, rec_f, rec_c, nl,
+        stack = _RecStack(rec_i, rec_f, rec_c, nl, work,
                           qscales if quant else None)
         for i in range(chunk):
             self.models.append(_PendingChunkTree(
                 stack, i, self.shrinkage_rate * self._tree_multiplier(),
                 bias if i == 0 else 0.0))
+        if deal is not None:
+            self._work.awaited = score
         self._push_work(nl, work)
         self.iter += chunk
         # lagged stall check: the PREVIOUS chunk's records have landed
@@ -902,6 +993,13 @@ class GBDT:
         """Queue one dispatch's per-tree leaf counts and work counters
         for the registry (``_WorkDrain``)."""
         self._work.push(nl, work, self.num_data)
+
+    def _row_order_score(self) -> None:
+        """Bring a score that fused dispatches left dealt over the mesh
+        back to a plain ``(1, N)`` device array in row order, for the
+        paths that update it a tree at a time."""
+        if isinstance(self.train_score, DealtRows):
+            self.train_score = self.train_score.rows()
 
     def _sync_fused_bagging(self):
         """Restore the host-side bagging state to what a pure
@@ -1146,6 +1244,7 @@ class GBDT:
             return
         self._forbid_host_path("rollback_one_iter")
         self._flush_pending()
+        self._row_order_score()
         base = len(self.models) - self.num_model
         for k in range(self.num_model):
             tree = self.models[base + k]

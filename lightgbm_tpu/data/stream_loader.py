@@ -20,7 +20,7 @@ import os
 import queue
 import threading
 import time
-from typing import Iterator, List, Optional, Tuple
+from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -314,28 +314,33 @@ def _round_one(path: str, fmt: "_Format", config
 
 def _round_two(path: str, fmt: "_Format", ds, num_cols: int,
                n_total: int,
-               row_span: Optional[Tuple[int, int]] = None) -> np.ndarray:
+               placement: Optional[Sequence[Tuple[int, int, int]]] = None
+               ) -> np.ndarray:
     """Round two: re-stream the file and bin chunk-wise into the
     preallocated ``(N, G)`` matrix; returns the full label vector.
 
-    ``row_span=(lo, hi)`` restricts BINNING to the global row block
-    ``[lo, hi)``, pushed at LOCAL coordinates ``row - lo`` — the
-    host-sharded ingest path, where ``ds`` holds only this host's
-    padded block.  Labels are always parsed for every row (gradients
-    are computed host-side from the replicated score, so every pod
-    host needs the global label vector).  The double-buffered reader's
-    liveness timeout and parse-location errors apply to the filtered
-    path unchanged."""
+    ``placement=[(lo, hi, offset)]`` restricts BINNING to the global
+    row spans ``[lo, hi)``, each pushed at LOCAL coordinates ``offset +
+    row - lo`` — the host-sharded ingest path, where ``ds`` holds only
+    this host's padded block and every device's real rows fill the
+    front of its part of it (``ops/shard.py::process_real_rows``).
+    Labels are always parsed for every row (every pod host deals the
+    global label vector over the mesh itself).  The double-buffered
+    reader's liveness timeout and parse-location errors apply to the
+    filtered path unchanged."""
     start = 0
     label = np.zeros(n_total, np.float64)
-    lo, hi = row_span if row_span is not None else (0, n_total)
+    if placement is None:
+        placement = [(0, n_total, 0)]
     for line_no, lines in _chunk_reader(path, fmt.header):
         x, y = _parse_chunk_checked(fmt, path, line_no, lines, num_cols)
         m = x.shape[0]
         label[start:start + len(y)] = y
-        a, b = max(start, lo), min(start + m, hi)
-        if a < b:
-            ds.construct_streaming_push(x[a - start:b - start], a - lo)
+        for lo, hi, offset in placement:
+            a, b = max(start, lo), min(start + m, hi)
+            if a < b:
+                ds.construct_streaming_push(x[a - start:b - start],
+                                            offset + a - lo)
         start += m
     ds.construct_streaming_finish()
     return label
@@ -396,8 +401,8 @@ def load_text_multihost(path: str, config, categorical=()):
     """
     from .dataset import BinnedDataset
     from ..ops.shard import (make_pod_mesh, multihost_params,
-                             multihost_setup, process_row_span,
-                             shard_local_rows)
+                             multihost_setup, process_real_rows,
+                             process_row_span, shard_local_rows)
     from ..parallel.network import broadcast_blob, pod_broadcast_address
     from ..pipeline.bins import (reference_from_bytes,
                                  reference_layout_digest,
@@ -447,9 +452,11 @@ def load_text_multihost(path: str, config, categorical=()):
     if fmt.kind == "libsvm":
         fmt.num_cols = num_cols   # adopt host 0's global column bound
 
-    # ---- this host's contiguous padded block of the pod row layout ----
+    # ---- this host's padded block of the pod row layout: its devices'
+    # blocks side by side, each with its real rows at the front ---------
     n_loc = shard_local_rows(n_total, int(mesh.devices.size), config)
     lo, hi = process_row_span(mesh, n_loc)
+    placement = process_real_rows(mesh, n_total, n_loc)
     ds = BinnedDataset.construct_streaming_begin(
         np.zeros((0, num_cols)), hi - lo, num_cols, config, categorical,
         feature_names=fmt.names, reference=skeleton)
@@ -458,10 +465,10 @@ def load_text_multihost(path: str, config, categorical=()):
     t0 = time.perf_counter()
     try:
         label = _round_two(path, fmt, ds, num_cols, n_total,
-                           row_span=(lo, hi))
+                           placement=placement)
     except LightGBMError as e:
         raise LightGBMError(f"[host {rank}] {e}") from e
-    binned_rows = max(0, min(hi, n_total) - min(lo, n_total))
+    binned_rows = sum(b - a for a, b, _ in placement)
     obs.set_gauge("ingest.rows_per_s",
                   binned_rows / max(time.perf_counter() - t0, 1e-9))
 
@@ -482,6 +489,6 @@ def load_text_multihost(path: str, config, categorical=()):
             f"layout than host 0 (digest {my_digest.decode()[:12]} vs "
             f"{echoed.decode()[:12]}); pod ingest diverged")
     log_info(f"multihost load: host {rank}/{hosts} holds rows "
-             f"[{lo}, {hi}) of {n_total} "
-             f"({binned_rows} real, {fmt.kind})")
+             f"[{placement[0][0]}, {placement[-1][1]}) of {n_total} "
+             f"in padded rows [{lo}, {hi}) ({fmt.kind})")
     return ds, label

@@ -81,7 +81,7 @@ from .. import obs
 from . import stage_plan as stage_plan_mod
 from .histogram import (QUANT_MAX, bucket_size, quant_scales, quantize_gh,
                         stochastic_round_with)
-from .shard import (ShardSpec, local_valid_rows, shard_map_nocheck,
+from .shard import (RowDeal, ShardSpec, local_valid_rows, shard_map_nocheck,
                     slice_global_draw)
 from .split import (F_DEFAULT_LEFT, F_FEATURE, F_GAIN, F_IS_CAT, F_LEFT_C,
                     F_LEFT_G, F_LEFT_H, F_LEFT_OUT, F_RIGHT_C, F_RIGHT_G,
@@ -432,8 +432,9 @@ class GrowerPrograms:
         unsharded path would give it."""
         sp = self.shard
         sg, sh = quant_scales(grad, hess)
-        sg = jax.lax.pmax(sg, sp.axis)
-        sh = jax.lax.pmax(sh, sp.axis)
+        with jax.named_scope("lgb.psum"):
+            sg = jax.lax.pmax(sg, sp.axis)
+            sh = jax.lax.pmax(sh, sp.axis)
         kg, kh = jax.random.split(qkey)
 
         def noise(k):
@@ -731,7 +732,11 @@ class GrowerPrograms:
         i32, root_value f32, work (7,) i32 = [waves run, sum of their
         stage widths, in-bag real rows, features in the mask, row chunks
         the wave histograms visited, their live rows // _CHUNK, the sum
-        of the remainders], quant_scales (2,) f32).
+        of the remainders] — sharded (9 + L,): the last three summed
+        over the mesh, then the FULLEST shard's live rows summed wave by
+        wave as the same (// _CHUNK, remainder) pair, then the exact
+        (in-bag) rows of each of the L leaves —, quant_scales (2,)
+        f32).
         ``lr`` is traced so callbacks may reset the learning rate without
         recompiling; ``tree_idx`` is the global tree index keying the
         quantization rounding noise (unused when grad_quant_bits=0).
@@ -805,7 +810,10 @@ class GrowerPrograms:
             hwork: jnp.ndarray          # (3,) i32 histogram work so far:
             #                             chunks visited, live rows as
             #                             (// _CHUNK, % _CHUNK) sums — a
-            #                             tree's rows can pass int32
+            #                             tree's rows can pass int32;
+            #                             sharded (5,): then the same
+            #                             pair for the FULLEST shard's
+            #                             live rows, wave by wave
             done: jnp.ndarray           # bool
             rec_i: jnp.ndarray          # (L, 5) i32   (last row = junk)
             rec_f: jnp.ndarray          # (L, 9) f32   (last row = junk)
@@ -832,7 +840,7 @@ class GrowerPrograms:
             nl=jnp.asarray(1, jnp.int32),
             waves=jnp.asarray(0, jnp.int32),
             slots=jnp.asarray(0, jnp.int32),
-            hwork=jnp.zeros((3,), jnp.int32),
+            hwork=jnp.zeros((3 if self.shard is None else 5,), jnp.int32),
             done=jnp.asarray(False),
             rec_i=jnp.full((L, REC_I_FIELDS), -1, jnp.int32),
             rec_f=jnp.zeros((L, REC_F_FIELDS), jnp.float32),
@@ -1073,12 +1081,18 @@ class GrowerPrograms:
                 ps = jnp.where(sel, jnp.where(small_left, lsel, r_ids), -1)
                 pl = jnp.where(sel, jnp.where(small_left, r_ids, lsel), -1)
 
+            nl, waves, slots = st.nl + napply, st.waves + 1, st.slots + Ws
+            hw_add = [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK]
+            if self.shard is not None:
+                # the mesh waits at the psum for its fullest shard: what
+                # that shard contracted in this wave, beside the sum
+                with jax.named_scope("lgb.psum"):
+                    top = jax.lax.pmax(hw[1], self.shard.axis)
+                hw_add += [top // _CHUNK, top % _CHUNK]
             return _S(leaf_id=leaf_id, hist=hist, total=total, value=value,
                       depth=depth, best=best, bestc=bestc, bestl=bestl,
-                      nl=st.nl + napply,
-                      waves=st.waves + 1, slots=st.slots + Ws,
-                      hwork=st.hwork + jnp.stack(
-                          [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK]),
+                      nl=nl, waves=waves, slots=slots,
+                      hwork=st.hwork + jnp.stack(hw_add),
                       done=napply == 0,
                       rec_i=rec_i, rec_f=rec_f, rec_c=rec_c,
                       p_parent=pp, p_small=ps, p_large=pl)
@@ -1221,9 +1235,39 @@ class GrowerPrograms:
 
         hwork = final.hwork
         if self.shard is not None:
-            # each shard gathers and scans its own live rows
+            # each shard gathers and scans its own live rows; the
+            # fullest shard's pair is the same on every shard already
             with jax.named_scope("lgb.psum"):
-                hwork = jax.lax.psum(hwork, self.shard.axis)
+                hwork = jnp.concatenate(
+                    [jax.lax.psum(hwork[:3], self.shard.axis), hwork[3:]])
+            # the rows of every leaf, counted from where the rows ended
+            # up: the histogram state is float32, and a mesh is how a
+            # node comes to hold more rows than float32 counts one by
+            # one (2^24) — its prefix sums and parent-minus-child
+            # counts are rounded there, and the children inherit the
+            # error.  A chunk's float32 sums are whole numbers; int32
+            # from there, over the chunks that hold a real row.
+            with jax.named_scope("lgb.score_update"):
+                ch = _CHUNK
+                leaf_c = leaf_final.reshape(n // ch, ch)
+                inbag_c = (one_f > 0).astype(jnp.bfloat16).reshape(
+                    n // ch, ch)
+
+                def count(i, acc):
+                    lf, ib = (jax.lax.dynamic_index_in_dim(
+                        a, i, keepdims=False) for a in (leaf_c, inbag_c))
+                    oh_c = jax.nn.one_hot(lf, L, dtype=jnp.bfloat16)
+                    return acc + jnp.einsum(
+                        "cl,c->l", oh_c, ib,
+                        preferred_element_type=jnp.float32
+                    ).astype(jnp.int32)
+
+                leaf_rows = jax.lax.fori_loop(
+                    0, jnp.clip((num_valid + ch - 1) // ch, 0, n // ch),
+                    count, jnp.zeros((L,), jnp.int32))
+            with jax.named_scope("lgb.psum"):
+                leaf_rows = jax.lax.psum(leaf_rows, self.shard.axis)
+            hwork = jnp.concatenate([hwork, leaf_rows])
         return (new_score, final.rec_i[:max(L - 1, 1)],
                 rec_f_out[:max(L - 1, 1)],
                 final.rec_c[:max(L - 1, 1)], final.nl, final.value[0],
@@ -1559,15 +1603,22 @@ class DeviceGrower:
                 int(dataset.num_features), has_cat, config,
                 shard=self._shard_spec)
             self._num_valid = jnp.asarray(self.num_data, jnp.int32)
-            total_rows = d * self.programs.n_pad
-            self._row_pad = total_rows - self.num_data
+            # the even deal of the real rows over the shards' blocks:
+            # per-row state goes through it on its way in and out
+            self.deal = RowDeal(self.mesh, SHARD_AXIS, self.num_data,
+                                int(self.programs.n_pad))
+            self._row_pad = 0
+            counts = [c for _, c in self.deal.spans]
             obs.set_gauge("shard.devices", d)
             obs.set_gauge("shard.local_rows", int(self.programs.n_pad))
+            obs.set_gauge("shard.rows_real_min", min(counts))
+            obs.set_gauge("shard.rows_real_max", max(counts))
             if self._multihost:
                 import jax as _jax
                 obs.set_gauge("shard.hosts",
                               int(_jax.process_count()))
-            self._upload_binned(dataset, total_rows - self.num_data)
+            self._upload_binned(dataset,
+                                self.deal.total - self.num_data)
             self.meta = FeatureMeta.from_dataset(dataset, slot_stride=nb)
             self.hyper = SplitHyper.from_config(config)
             self.tables = FTables.from_dataset(dataset)
@@ -1575,6 +1626,7 @@ class DeviceGrower:
             return
         self._shard_spec = None
         self._multihost = False
+        self.deal = None
         bucket = self.num_data
         if row_bucketing and not quant_on:
             bucket = bucket_size(max(self.num_data, 1))
@@ -1634,8 +1686,8 @@ class DeviceGrower:
                 jax.block_until_ready((self.binned, self.binned_t))
 
     def _upload_binned_traced(self, dataset, pad: int):
-        if self._multihost:
-            self._upload_binned_multihost(dataset)
+        if self.mesh is not None:
+            self._upload_binned_sharded(dataset)
             return
         if getattr(dataset, "device_binned", False):
             # matrix already lives in HBM (construct_from_device_matrix)
@@ -1648,39 +1700,28 @@ class DeviceGrower:
             if pad:
                 binned = np.pad(binned, ((0, pad), (0, 0)))
             self.binned = jnp.asarray(binned)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            axis = self._shard_spec.axis
-            self.binned = jax.device_put(
-                self.binned, NamedSharding(self.mesh, P(axis, None)))
-            # transpose stays device-side; the explicit placement pins
-            # the (G, N) copy column-split so each device again holds
-            # only its rows
-            self.binned_t = jax.device_put(
-                jnp.transpose(self.binned),
-                NamedSharding(self.mesh, P(None, axis)))
-        else:
-            self.binned_t = jnp.transpose(self.binned)
+        self.binned_t = jnp.transpose(self.binned)
 
-    def _upload_binned_multihost(self, dataset):
-        """Pod-slice upload: each process contributes ONLY its own
-        contiguous padded row block via
-        ``make_array_from_process_local_data`` — no host ever
-        materializes (or ships) the global matrix.  Two sources:
+    def _upload_binned_sharded(self, dataset):
+        """Mesh upload: every shard's ``(n_pad, G)`` block — its real
+        rows by the even deal, pad behind them — goes from where the
+        matrix lies (the host, or HBM for ``device_binned``) to its own
+        device, and the ``(G, n_pad)`` transposes are made there, a
+        device each; no device ever holds ``D * n_pad`` rows.  A pod
+        host contributes only the blocks of its own devices.  Two
+        sources:
 
         * a host-sharded dataset from the streaming multihost loader
-          (``dataset.host_shard``): ``dataset.binned`` IS the local
-          padded block, validated against the mesh's row span;
-        * a replicated dataset (every process constructed the full
-          matrix, e.g. the test path): slice this process's block out.
+          (``dataset.host_shard``): ``dataset.binned`` IS the host's
+          dealt block, validated against the mesh's row span;
+        * the whole matrix (one controller, or every pod process
+          constructed it, e.g. the test path): each block is cut out.
         """
-        from jax.sharding import NamedSharding, PartitionSpec as P
         from ..utils.log import LightGBMError
         from .shard import process_row_span, transpose_col_sharded
         spec = self._shard_spec
-        n_pad = int(self.programs.n_pad)
-        lo, hi = process_row_span(self.mesh, n_pad)
         if getattr(dataset, "host_shard", False):
+            lo, hi = process_row_span(self.mesh, self.deal.local_rows)
             local = np.ascontiguousarray(dataset.binned)
             span = getattr(dataset, "host_row_span", None)
             if span is not None and tuple(span) != (lo, hi):
@@ -1693,15 +1734,13 @@ class DeviceGrower:
                 raise LightGBMError(
                     f"host-sharded binned block has {local.shape[0]} "
                     f"rows, mesh block needs {hi - lo}")
+            self.binned = self.deal.place_blocks(
+                local, lo // self.deal.local_rows)
         else:
-            full = np.asarray(dataset.binned)
-            total = spec.n_shards * n_pad
-            if full.shape[0] < total:
-                full = np.pad(full, ((0, total - full.shape[0]),
-                                     (0, 0)))
-            local = np.ascontiguousarray(full[lo:hi])
-        self.binned = jax.make_array_from_process_local_data(
-            NamedSharding(self.mesh, P(spec.axis, None)), local)
+            full = dataset.binned
+            if not getattr(dataset, "device_binned", False):
+                full = np.asarray(full)
+            self.binned = self.deal.place(full)
         self.binned_t = transpose_col_sharded(
             self.mesh, spec.axis)(self.binned)
 
@@ -1744,16 +1783,21 @@ class DeviceGrower:
         # routing attribution: which kernel serves this dispatch's
         # full-width histogram stage (BENCH digests read these)
         obs.inc(f"grow.hist.{self.programs.hist_kernel_tag}")
-        # twin counter and gauge (same tag family as grow.hist.*): a
-        # wave's hist+find is ONE dispatch equivalent, and rollups
-        # multiply wave counts by the gauge instead of assuming 2/wave
-        # (the PR-16 counts-as-waves bug class)
+        # twin counter (same tag family as grow.hist.*): a wave's
+        # hist+find is ONE dispatch equivalent
         obs.inc(f"grow.fused_find.{self.programs.hist_kernel_tag}")
-        obs.set_gauge("grow.wave_dispatch_factor", 1)
         if self.programs.shard is not None:
             obs.inc("grow.sharded_dispatches")
         ti = jnp.asarray(tree_idx, jnp.int32)
-        if self._row_pad:
+        if self.deal is not None:
+            # mesh: per-row operands come in row order and are dealt
+            # over the shards' blocks (ops/shard.py), the new score is
+            # gathered back (on every device, so any host may read it)
+            score, grad, hess = (self.deal.deal(a)
+                                 for a in (score, grad, hess))
+            if row_mask is not None:
+                row_mask = self.deal.deal(row_mask)
+        elif self._row_pad:
             # bucket pad: the program's row dim is the pow2 bucket; the
             # traced num_valid cuts the padding back out of every stat
             score = jnp.pad(score, (0, self._row_pad))
@@ -1772,14 +1816,9 @@ class DeviceGrower:
                 self.binned, self.binned_t, score, grad, hess,
                 feature_mask, jnp.asarray(lr, jnp.float32), row_mask, ti,
                 self._num_valid, self.meta, self.hyper, self.tables)
-        if self._multihost:
-            # the fused program's score comes back row-sharded across
-            # processes; reshard to fully-replicated so the host-side
-            # slice/flush below (and the caller's np.asarray) work
-            from .shard import replicate_to_all
-            out = (replicate_to_all(self.mesh)(out[0]),) + tuple(
-                out[1:])
-        if self._row_pad:
+        if self.deal is not None:
+            out = (self.deal.gather(out[0]),) + tuple(out[1:])
+        elif self._row_pad:
             out = (out[0][:self.num_data],) + tuple(out[1:])
         return out
 
@@ -1801,6 +1840,12 @@ class DeviceGrower:
         bound; same call contract the boosting layer always used::
 
             run(binned, binned_t, score, lr, gargs, it0, grad_fn=fn)
+
+        On a mesh ``score`` and the per-row leaves of ``gargs`` are
+        taken, and the new score returned, in the dealt layout
+        (:meth:`deal_rows`): the caller deals them once and keeps the
+        score dealt between dispatches, so a dispatch runs no per-row
+        operation of its own.
         """
         raw = self.programs.fused_train(length)
         meta, hyper, tables = self.meta, self.hyper, self.tables
@@ -1820,17 +1865,11 @@ class DeviceGrower:
 
         kernel_tag = self.programs.hist_kernel_tag
         sharded = self.programs.shard is not None
-        if self._multihost:
-            from .shard import replicate_to_all
-            replicate = replicate_to_all(self.mesh)
-        else:
-            replicate = None
 
         def run(binned, binned_t, score, lr, gargs, it0, grad_fn):
             obs.inc(f"grow.hist.{kernel_tag}")
-            # mirror of the per-iteration site's twin counter and gauge
+            # mirror of the per-iteration site's twin counter
             obs.inc(f"grow.fused_find.{kernel_tag}")
-            obs.set_gauge("grow.wave_dispatch_factor", 1)
             if sharded:
                 obs.inc("grow.sharded_dispatches")
             if row_pad:
@@ -1839,15 +1878,27 @@ class DeviceGrower:
             final_score, recs = raw(binned, binned_t, score, lr, gargs,
                                     it0, num_valid, meta, hyper, tables,
                                     grad_fn=grad_fn)
-            if replicate is not None:
-                # pod slice: score returns row-sharded across hosts;
-                # every host needs the full vector for the next
-                # dispatch's pad, checkpoints and metrics
-                final_score = replicate(final_score)
             if row_pad:
                 final_score = final_score[:real_n]
             return final_score, recs
         return run
+
+    def deal_rows(self, tree):
+        """``tree`` with every per-row leaf (leading axis ``num_data``,
+        rows in row order) dealt over the mesh (ops/shard.py
+        ``RowDeal.place``: each shard's block placed on its own device);
+        leaves already dealt, and anything else, pass through.  The
+        boosting layer calls it once for labels and weights and once
+        for the score it starts from."""
+        deal = self.deal
+
+        def one(a):
+            if (getattr(a, "ndim", 0) < 1 or deal.is_dealt(a)
+                    or a.shape[0] != self.num_data):
+                return a
+            return deal.place(np.asarray(a) if deal.multihost else a)
+
+        return jax.tree_util.tree_map(one, tree)
 
     # ------------------------------------------------------------------
     def profile_stage_plan(self, reps: int = 3, install: bool = True,

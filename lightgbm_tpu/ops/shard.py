@@ -25,13 +25,18 @@ Row layout (the :class:`ShardSpec` contract)
 --------------------------------------------
 
 Global padded row space = ``n_shards * local_rows``; shard ``d`` owns
-the contiguous block ``[d * local_rows, (d + 1) * local_rows)`` and a
-real dataset row ``r`` lives at global padded index ``r`` — so shard
-``r // local_rows`` holds it.  Trailing shards may be mostly (or all)
-bucket padding; that costs nothing, because the grower's dense
-formulation processes every padded row regardless.  The traced global
-``num_valid`` scalar cuts validity per shard
-(``clip(num_valid - d * local_rows, 0, local_rows)``).
+the block ``[d * local_rows, (d + 1) * local_rows)``.  The real rows
+are DEALT EVENLY (:func:`shard_span`): shard ``d`` holds the contiguous
+real rows ``[start_d, start_d + count_d)`` at the front of its block,
+counts differ by at most one, and the bucket pad lies at each block's
+tail.  A shard's histogram costs what its own real and live rows cost,
+and every wave ends in a psum, so the mesh runs at the pace of its
+fullest shard: with the pad dealt evenly no shard waits for another.
+:func:`shard_span` is the ONE place that knows the deal — the traced
+per-shard cutoff (:func:`local_valid_rows`), the canonical draws
+(:func:`slice_global_draw`: row ``r`` still reads element ``r``), the
+uploads, the multi-controller host blocks (:func:`process_row_span`)
+and the per-row state between dispatches (:class:`DealtRows`) read it.
 
 Determinism / byte-identity contract (docs/Sharding.md)
 -------------------------------------------------------
@@ -105,22 +110,57 @@ class ShardSpec(NamedTuple):
     bag_npad: int
 
 
+def shard_span(num_rows, n_shards: int, d):
+    """``(start, count)``: the real rows ``[start, start + count)`` that
+    shard ``d`` of ``n_shards`` holds of ``num_rows`` — THE row layout.
+    The first ``num_rows % n_shards`` shards hold one row more than the
+    others, spans follow one another in shard order and cover every row
+    once.  Plain integer arithmetic, so ``num_rows`` and ``d`` may be
+    Python ints or traced int32 scalars (no product passes
+    ``num_rows``)."""
+    base = num_rows // n_shards
+    rem = num_rows - base * n_shards
+    over = d - rem
+    # d * base + min(d, rem), written without a host-or-device `min`
+    start = d * base + d - (over + abs(over)) // 2
+    return start, base + (over < 0)
+
+
+def row_spans(num_rows: int, n_shards: int, local_rows: int):
+    """Host side of :func:`shard_span`: ``[(start, count)] * n_shards``
+    for ``num_rows`` real rows in blocks of ``local_rows`` padded rows
+    (shard ``d``'s row ``start_d + j`` sits at padded index
+    ``d * local_rows + j``)."""
+    spans = [tuple(int(v) for v in shard_span(int(num_rows),
+                                              int(n_shards), d))
+             for d in range(int(n_shards))]
+    if max(c for _, c in spans) > int(local_rows):
+        raise LightGBMError(
+            f"{num_rows} rows over {n_shards} shards need "
+            f"{max(c for _, c in spans)} rows a shard, the block holds "
+            f"{local_rows}")
+    return spans
+
+
 def local_valid_rows(spec: ShardSpec, local_rows: int, num_valid):
-    """Traced per-shard valid-row count: global rows are laid out in
-    contiguous ``local_rows`` blocks, so shard ``d`` is valid up to
-    ``num_valid - d * local_rows`` (clipped)."""
+    """Traced per-shard valid-row count: this shard's share of the
+    ``num_valid`` real rows under the even deal; they fill the front of
+    its block."""
     import jax.numpy as jnp
-    d = jax.lax.axis_index(spec.axis)
-    return jnp.clip(num_valid - d * local_rows, 0,
-                    local_rows).astype(jnp.int32)
+    _, count = shard_span(num_valid, spec.n_shards,
+                          jax.lax.axis_index(spec.axis))
+    return jnp.clip(count, 0, local_rows).astype(jnp.int32)
 
 
 def slice_global_draw(spec: ShardSpec, full, local_rows: int):
-    """Take this shard's block of a canonically-shaped global draw.
+    """Take this shard's rows of a canonically-shaped global draw.
 
     ``full`` is a 1-D array drawn at a canonical global shape
-    (``draw_npad`` / ``bag_npad``); rows beyond it (only ever bucket
-    padding, zeroed by the valid mask) read as 0.
+    (``draw_npad`` / ``bag_npad``) and indexed by GLOBAL real row: the
+    shard's block starts at its first real row's element, so row ``r``
+    reads element ``r`` whatever the mesh size.  Elements behind the
+    shard's last real row (the next shard's, or nothing) fall on bucket
+    padding, which the valid mask zeroes.
     """
     import jax.numpy as jnp
     total = spec.n_shards * local_rows
@@ -128,7 +168,8 @@ def slice_global_draw(spec: ShardSpec, full, local_rows: int):
         full = full[:total]
     else:
         full = jnp.pad(full, (0, total - full.shape[0]))
-    off = jax.lax.axis_index(spec.axis) * local_rows
+    off, _ = shard_span(spec.global_rows, spec.n_shards,
+                        jax.lax.axis_index(spec.axis))
     return jax.lax.dynamic_slice(full, (off,), (local_rows,))
 
 
@@ -302,11 +343,8 @@ def make_pod_mesh():
     return Mesh(np.asarray(devices), (SHARD_AXIS,))
 
 
-def process_row_span(mesh, local_rows: int,
-                     process_index: Optional[int] = None
-                     ) -> Tuple[int, int]:
-    """``[lo, hi)`` block of the global PADDED row space owned by one
-    process under a pod mesh with ``local_rows`` rows per device."""
+def _process_devices(mesh, process_index: Optional[int] = None):
+    """Mesh positions of one process's devices (a contiguous run)."""
     pid = (int(jax.process_index()) if process_index is None
            else int(process_index))
     idx = [i for i, d in enumerate(mesh.devices.flat)
@@ -319,7 +357,29 @@ def process_row_span(mesh, local_rows: int,
             f"pod mesh devices of process {pid} are not contiguous "
             f"(mesh positions {idx}); build the mesh with "
             f"make_pod_mesh()")
+    return idx
+
+
+def process_row_span(mesh, local_rows: int,
+                     process_index: Optional[int] = None
+                     ) -> Tuple[int, int]:
+    """``[lo, hi)`` block of the global PADDED row space owned by one
+    process under a pod mesh with ``local_rows`` rows per device."""
+    idx = _process_devices(mesh, process_index)
     return idx[0] * int(local_rows), (idx[-1] + 1) * int(local_rows)
+
+
+def process_real_rows(mesh, num_rows: int, local_rows: int,
+                      process_index: Optional[int] = None):
+    """Where one process's REAL rows go inside its padded block:
+    ``[(real_lo, real_hi, local_offset)]``, one entry a device — global
+    real rows ``[real_lo, real_hi)`` fill the host block from row
+    ``local_offset`` on (:func:`shard_span`'s deal; the rest of each
+    device's ``local_rows`` is pad)."""
+    idx = _process_devices(mesh, process_index)
+    spans = row_spans(num_rows, int(mesh.devices.size), local_rows)
+    return [(spans[d][0], spans[d][0] + spans[d][1],
+             (d - idx[0]) * int(local_rows)) for d in idx]
 
 
 def shard_local_rows(num_data: int, n_shards: int, config,
@@ -350,34 +410,14 @@ def shard_local_rows(num_data: int, n_shards: int, config,
     return _ceil_to(max(srows, _CHUNK), _CHUNK)
 
 
-# replicate-to-all programs keyed by mesh device ids: ONE compiled
-# identity per mesh, reused across growers/windows so warm same-shape
-# windows re-dispatch instead of re-tracing (obs.track_jit makes any
-# violation visible to the zero-retrace gates)
-_REPLICATE_CACHE: dict = {}
+# mesh programs (the transposing placement, the deal and its inverse)
+# keyed by mesh device ids and shape: ONE compiled program per mesh,
+# reused across growers/windows so warm same-shape windows re-dispatch
+# instead of re-tracing (obs.track_jit makes any violation visible to
+# the zero-retrace gates)
 _TRANSPOSE_CACHE: dict = {}
+_DEAL_CACHE: dict = {}
 _PROGRAM_CACHE_LOCK = threading.Lock()
-
-
-def replicate_to_all(mesh):
-    """Jitted identity resharding any array to fully-replicated over
-    ``mesh``.  Multi-controller growers apply it to the row-sharded
-    final score so every host holds the full vector (checkpoints,
-    metrics and the next dispatch all read it host-side); on a
-    single-process mesh the arrays are already fully addressable and
-    callers skip this entirely."""
-    key = tuple(int(d.id) for d in mesh.devices.flat)
-    fn = _REPLICATE_CACHE.get(key)
-    if fn is None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from .. import obs
-        fn = obs.track_jit(
-            "shard.replicate",
-            jax.jit(lambda x: x,
-                    out_shardings=NamedSharding(mesh, P())))
-        with _PROGRAM_CACHE_LOCK:
-            fn = _REPLICATE_CACHE.setdefault(key, fn)
-    return fn
 
 
 def transpose_col_sharded(mesh, axis: str = SHARD_AXIS):
@@ -410,6 +450,162 @@ def host_replicated(mesh, value):
     sh = NamedSharding(mesh, P())
     arr = np.asarray(value)
     return jax.make_array_from_process_local_data(sh, arr)
+
+
+class RowDeal:
+    """Per-row arrays in a mesh's padded, row-sharded layout: the
+    ``num_rows`` real rows dealt by :func:`shard_span` over blocks of
+    ``local_rows``.  One object per sharded grower; the two jitted
+    moves between row order and the dealt layout are cached per
+    (mesh, shape) like the other mesh programs, so warm windows
+    re-dispatch into them."""
+
+    def __init__(self, mesh, axis: str, num_rows: int, local_rows: int):
+        self.mesh, self.axis = mesh, axis
+        self.num_rows, self.local_rows = int(num_rows), int(local_rows)
+        self.n_shards = int(mesh.devices.size)
+        self.spans = row_spans(num_rows, self.n_shards, local_rows)
+        self.total = self.n_shards * self.local_rows
+        self.multihost = mesh_is_multihost(mesh)
+
+    def sharding(self, ndim: int = 1, row_axis: int = 0):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        spec = [None] * ndim
+        spec[row_axis] = self.axis
+        return NamedSharding(self.mesh, P(*spec))
+
+    def is_dealt(self, a) -> bool:
+        """Whether ``a`` is a per-row array already in this layout."""
+        return (getattr(a, "ndim", 0) >= 1 and a.shape[0] == self.total
+                and getattr(a, "sharding", None) == self.sharding(a.ndim))
+
+    def _blocks(self, block_of):
+        """Row-sharded ``(total, ...)`` array whose device ``d`` holds
+        ``block_of(d)`` — each block goes from the host (or from where
+        it lies) to its own device; nothing of ``total`` rows is ever
+        on one device."""
+        devs = list(self.mesh.devices.flat)
+        mine = [d for d, dev in enumerate(devs)
+                if dev.process_index == jax.process_index()]
+        parts = [jax.device_put(block_of(d), devs[d]) for d in mine]
+        shape = (self.total,) + tuple(parts[0].shape[1:])
+        return jax.make_array_from_single_device_arrays(
+            shape, self.sharding(len(shape)), parts)
+
+    def place(self, rows):
+        """Deal a ``(num_rows, ...)`` array (host, or device-resident)
+        onto the mesh."""
+        tail = [(0, 0)] * (rows.ndim - 1)
+
+        def block_of(d):
+            lo, cnt = self.spans[d]
+            pad = [(0, self.local_rows - cnt)] + tail
+            if isinstance(rows, np.ndarray):
+                return np.pad(rows[lo:lo + cnt], pad)
+            import jax.numpy as jnp
+            return jnp.pad(rows[lo:lo + cnt], pad)
+
+        return self._blocks(block_of)
+
+    def place_blocks(self, local, first_device: int):
+        """The same from a host block that is ALREADY dealt (a pod
+        host's streamed ``(devices * local_rows, G)`` block)."""
+        n = self.local_rows
+        return self._blocks(
+            lambda d: local[(d - first_device) * n:
+                            (d - first_device + 1) * n])
+
+    def _program(self, name: str, fn, out_sharding):
+        key = (name, tuple(int(d.id) for d in self.mesh.devices.flat),
+               self.num_rows, self.local_rows)
+        prog = _DEAL_CACHE.get(key)
+        if prog is None:
+            from .. import obs
+            prog = obs.track_jit(
+                f"shard.{name}", jax.jit(fn, out_shardings=out_sharding))
+            with _PROGRAM_CACHE_LOCK:
+                prog = _DEAL_CACHE.setdefault(key, prog)
+        return prog
+
+    def deal(self, rows):
+        """Device ``(num_rows,)`` in row order -> the dealt layout
+        (static slices; the per-iteration sharded path's way in)."""
+        if self.is_dealt(rows):
+            return rows
+        import jax.numpy as jnp
+        n, spans = self.local_rows, self.spans
+
+        def fn(x):
+            return jnp.concatenate(
+                [jnp.pad(x[lo:lo + cnt], (0, n - cnt))
+                 for lo, cnt in spans])
+
+        return self._program("deal", fn, self.sharding())(rows)
+
+    def gather(self, dealt):
+        """Dealt ``(total,)`` -> ``(num_rows,)`` in row order, on every
+        device of the mesh."""
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        n, spans = self.local_rows, self.spans
+
+        def fn(x):
+            return jnp.concatenate(
+                [x[d * n:d * n + cnt] for d, (_, cnt) in enumerate(spans)])
+
+        return self._program("gather", fn,
+                             NamedSharding(self.mesh, P()))(dealt)
+
+    def to_host(self, dealt) -> np.ndarray:
+        """Dealt ``(total,)`` -> host ``(num_rows,)`` in row order: each
+        shard's real rows are copied off its own device (a pod host,
+        which cannot address them all, reads the replicated gather)."""
+        if self.multihost:
+            return np.asarray(self.gather(dealt))
+        n = self.local_rows
+        out = np.empty((self.num_rows,) + tuple(dealt.shape[1:]),
+                       dealt.dtype)
+        for sh in dealt.addressable_shards:
+            lo, cnt = self.spans[(sh.index[0].start or 0) // n]
+            out[lo:lo + cnt] = np.asarray(sh.data)[:cnt]
+        return out
+
+
+class DealtRows:
+    """The booster's ``(1, num_rows)`` training score while it lives
+    dealt over the mesh between fused dispatches (``.dealt``, the
+    ``(total,)`` row-sharded device array the next dispatch takes as it
+    is).  Reading it as an array — ``np.asarray``, an index, a jnp
+    operation — gives real rows in row order; nothing is moved until
+    then, and ``block_until_ready`` waits on the dealt array alone."""
+
+    def __init__(self, deal: RowDeal, dealt):
+        self.deal, self.dealt = deal, dealt
+        self._rows = None
+
+    shape = property(lambda self: (1, self.deal.num_rows))
+    dtype = property(lambda self: self.dealt.dtype)
+    ndim = 2
+
+    def block_until_ready(self):
+        self.dealt.block_until_ready()
+        return self
+
+    def rows(self):
+        """The ``(1, num_rows)`` device array in row order (kept)."""
+        if self._rows is None:
+            self._rows = self.deal.gather(self.dealt)[None, :]
+        return self._rows
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.deal.to_host(self.dealt)[None, :]
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+    def __jax_array__(self):
+        return self.rows()
+
+    def __getitem__(self, idx):
+        return self.rows()[idx]
 
 
 def resolve_shard_mesh(config) -> Optional[object]:

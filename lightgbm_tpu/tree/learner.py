@@ -125,11 +125,19 @@ class SerialTreeLearner:
     def __init__(self, config, dataset):
         self.config = config
         self.dataset = dataset
-        self.binned = jnp.asarray(dataset.binned)
         self.num_data = dataset.num_data
         self.n_pad = bucket_size(max(self.num_data, 1))
+        # every booster builds this learner, also where the device
+        # grower ends up doing all the growing.  Under data_sharding the
+        # grower deals the matrix over the mesh a block a device, so the
+        # whole of it (and the row ids) goes to the default device only
+        # when a host path first asks: 53,125,000 x 67 rows put 4.6 GiB
+        # on chip 0 of four that way (PERF.md section 6, PR 32).
+        from ..ops.shard import sharding_mode
+        self._binned = self._full_indices_d = None
+        if sharding_mode(config) == "off":
+            _ = self.binned, self._full_indices     # upload now
         self.ctx = SplitContext(dataset, config)
-        self._full_indices = jnp.arange(self.n_pad, dtype=jnp.int32)
         self._rng = np.random.RandomState(
             (config.feature_fraction_seed if config.feature_fraction_seed
              else config.seed + 2) & 0x7FFFFFFF)
@@ -145,6 +153,23 @@ class SerialTreeLearner:
         # even when the device grower ends up doing all the growing.
         self._warn_quant = int(getattr(config, "grad_quant_bits", 0)
                                or 0) > 0
+
+    @property
+    def binned(self):
+        """(N, G) device matrix of the host learner's own kernels."""
+        if self._binned is None:
+            self._binned = jnp.asarray(self.dataset.binned)
+        return self._binned
+
+    @binned.setter
+    def binned(self, value):
+        self._binned = value
+
+    @property
+    def _full_indices(self):
+        if self._full_indices_d is None:
+            self._full_indices_d = jnp.arange(self.n_pad, dtype=jnp.int32)
+        return self._full_indices_d
 
     @property
     def traverse_binned(self):

@@ -140,8 +140,8 @@ def scenario_core():
     out["shard_digest"] = obs.summary().get("shard")
 
     # the work counters of one sharded run whose rows do not fill the
-    # mesh: 20,000 rows lie in contiguous 8,192-row blocks, so the
-    # shards hold 8,192 / 8,192 / 3,616 / 0 of them
+    # mesh's blocks: 20,000 rows dealt evenly over four 8,192-row
+    # blocks, 5,000 a shard
     def work():
         c = obs.registry().snapshot()["counters"]
         return [c.get(f"grow.{k}", 0)

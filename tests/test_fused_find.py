@@ -74,14 +74,15 @@ def test_fused_find_dispatch_counters():
             fused = sum(now.get(k, 0) - before.get(k, 0)
                         for k in now
                         if k.startswith("grow.fused_find."))
-            gauge = obs.registry().snapshot()["gauges"].get(
-                "grow.wave_dispatch_factor")
-            return hist, fused, gauge
+            return hist, fused
 
         # every dispatch counts its histogram and its in-wave find
-        # under one tag, and a wave is one dispatch equivalent
-        hist, fused, gauge = deltas({})
-        assert hist > 0 and fused == hist and gauge == 1
+        # under one tag, and a wave is one dispatch equivalent (the
+        # constant gauge grow.wave_dispatch_factor went with PR 32)
+        hist, fused = deltas({})
+        assert hist > 0 and fused == hist
+        assert "grow.wave_dispatch_factor" not in \
+            obs.registry().snapshot()["gauges"]
     finally:
         obs.configure(enabled=was_enabled)
 

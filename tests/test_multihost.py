@@ -259,14 +259,14 @@ def test_round_two_row_span_filter(tmp_path):
         np.zeros((0, num_cols)), hi - lo, num_cols, cfg,
         reference=full)
     part_label = _round_two(csv, fmt, part, num_cols, n_total,
-                            row_span=(lo, hi))
+                            placement=[(lo, hi, 0)])
     assert np.array_equal(part.binned, full.binned[lo:hi])
     assert np.array_equal(part_label, full_label)
     # a span past the real rows bins nothing but still parses labels
     tail = BinnedDataset.construct_streaming_begin(
         np.zeros((0, num_cols)), 64, num_cols, cfg, reference=full)
     tail_label = _round_two(csv, fmt, tail, num_cols, n_total,
-                            row_span=(n_total + 64, n_total + 128))
+                            placement=[(n_total + 64, n_total + 128, 0)])
     assert not tail.binned.any()
     assert np.array_equal(tail_label, full_label)
 

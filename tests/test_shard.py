@@ -96,15 +96,17 @@ def test_shard_rows_scanned_is_the_sum_of_the_shards_live_chunks(
         core_report):
     # the program counts, per shard, the chunks its histogram loop
     # visits and the live rows it finds, and sums them over the mesh:
-    # 20,000 rows in contiguous 8,192-row blocks give the four shards
-    # 1, 1, 1 and 0 chunks a wave (LGBM_TPU_CHUNK=8192 in the worker;
-    # a shard of one chunk contracts its rows where they lie)
+    # 20,000 rows dealt evenly over 8,192-row blocks give each of the
+    # four shards 5,000 rows, one chunk a wave (LGBM_TPU_CHUNK=8192 in
+    # the worker; a shard of one chunk contracts its rows where they
+    # lie).  Until PR 32 the blocks were filled in order, 8,192 / 8,192
+    # / 3,616 / 0 rows and 3 chunks a wave.
     w = core_report["work"]
     assert (w["shards"], w["n_pad"]) == (4, 8192)
     assert w["waves"] > w["trees"] > 0
     assert w["rows_real"] == w["waves"] * 20000
-    assert w["rows_scanned"] == w["waves"] * 3 * 8192 \
-        < w["waves"] * w["shards"] * w["n_pad"]
+    assert w["rows_scanned"] == w["waves"] * 4 * 8192 \
+        == w["waves"] * w["shards"] * w["n_pad"]
     # every root wave finds all rows live, every later one at most half,
     # whichever shards hold them
     assert w["trees"] * 20000 <= w["rows_live"] \
