@@ -20,7 +20,7 @@ SCOPES = (
     "lgb.bag_draw",      # bagging row mask / feature_fraction mask draws
     "lgb.stat_cols",     # pad/valid masking, stat columns, quantisation
     "lgb.wave_hist",     # the wave histogram (einsum over bin strips)
-    "lgb.wave_gather",   # its live rows brought to the front (einsum route)
+    "lgb.wave_gather",   # its live rows brought to the front (MXU compaction)
     "lgb.hist_state",    # sibling subtraction + per-leaf histogram writes
     "lgb.find_best",     # the gain scan over a histogram stack
     "lgb.split_apply",   # top-k selection, leaf_id routing, record writes

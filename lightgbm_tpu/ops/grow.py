@@ -93,14 +93,30 @@ from .split import (F_DEFAULT_LEFT, F_FEATURE, F_GAIN, F_IS_CAT, F_LEFT_C,
 import os as _os
 _CHUNK = int(_os.environ.get("LGBM_TPU_CHUNK", 32768))
 
-# a wave whose operand is at least this many lanes wide (stage width x
-# stat columns) gathers its live rows ahead of the chunk loop.  Measured
-# on the chip at 2^24 rows x 67 groups, 45% of them live (PERF.md §6):
-# the gather costs 0.135 s whatever the width; at 16 and 32 lanes the
-# wave takes 0.24-0.26 s with it or without, at 64 lanes 0.32 against
-# 0.42 s, at 384 lanes 0.78 against 1.42 s.  Module-level so tests can
-# gather in narrow waves on small data.
-_GATHER_MIN_LANES = 64
+# a wave over more than one row chunk brings its live rows to the front
+# ahead of the chunk loop (GrowerPrograms._gather_live) when fewer than
+# this share of the rows the plain loop would visit are live.  Measured
+# on the chip (PERF.md §6, PR 33): the compaction costs c = 3.1-3.3 ns a
+# SCANNED row whatever the share (2.2 of it level 1), 3.5-3.8 with the
+# decoding at 30-45% live; a narrow wave's scan costs s = 14-16 ns a row
+# at 67 groups and 10.5-12 at 53, so compacting pays below 1 - c / s =
+# 0.68 to 0.76, and the wider the stage the higher (96 slots: 85 ns a
+# row).  At 2^24 rows x 67 groups, 45% live, a wave takes 0.178 s
+# against 0.265 at 8 slots, 0.252 / 0.425 at 32, 0.708 / 1.420 at 96
+# (30% live: 0.135, 0.185, 0.494); a wave left where it lies pays the
+# cond's copy of its operands, 4.8 ms.  The smaller children hold at
+# most half the rows and a root wave all of them, or its bag: nothing
+# real lies near the line.  Rows wider than 128 bytes (over ~110
+# groups) raise c and s alike, so the rule does not read the width.
+# Module-level so tests and scripts/bench_wave_hist.py can move it.
+_COMPACT_MAX_LIVE = 0.7
+# rows a block of the MXU compaction (level 1: 2 * block * 128 FLOP a
+# scanned row; 256 / 512 / 1024 measured 1.94 / 2.20 / 2.91 ns) and rows
+# a tile of its level 2 (about tile / 2 all-zero rows a block ride along
+# into the contraction: 1.5% of the live rows at 45% live, 2.3% at 30%;
+# tiles of 16 and 32 rows gather no faster and carry 3.2% and 7.1%)
+_COMPACT_BLOCK = 512
+_COMPACT_TILE = 8
 
 # record field layout (host replay reads these)
 REC_I_FIELDS = 5    # leaf, right, feature, threshold, default_left
@@ -303,7 +319,7 @@ class GrowerPrograms:
         # with one very wide multi-tile wave for the tail.  gpu_use_dp
         # (k=5) scales each width down by 3/k to hold the column budget.
         self.wave_width = _wave_width(self.num_leaves, self.hist_cols)
-        self.gather_min_lanes = _GATHER_MIN_LANES
+        self.compact_max_live = _COMPACT_MAX_LIVE
         # plan is required and resolved by get_grower_programs (its
         # digest is part of the program-cache key — resolving it here
         # too could silently diverge from the keyed digest)
@@ -450,8 +466,9 @@ class GrowerPrograms:
     def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
                    scales=None):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
-        the mesh when sharded, and this shard's (2,) i32 ``[row chunks
-        the contraction visited, live rows it found]``."""
+        the mesh when sharded, and this shard's (3,) i32 ``[row chunks
+        the contraction visited, live rows it found, 1 if it compacted
+        them first]``."""
         with jax.named_scope("lgb.wave_hist"):
             hist, work = self._wave_hist_local(binned, leaf_id, ghk,
                                                pending, num_valid, scales)
@@ -461,73 +478,102 @@ class GrowerPrograms:
         # replicated global values
         return self._psum_hist(hist), work
 
-    def _gather_live(self, binned, leaf_id, ghk, live, n_live):
-        """Bring the ``n_live`` rows flagged in ``live`` (n_pad,) to the
-        front of chunked copies of the three row arrays, in row order:
-        ``(n_chunks, CH, G)``, ``(n_chunks, CH)``, ``(n_chunks, CH, K)``.
-        Only the ``ceil(n_live / CH)`` chunks the contraction will visit
-        are written; positions behind ``n_live`` in the last of them get
-        leaf id -2, which is no pending slot's.
+    @staticmethod
+    def _gather_live(binned, leaf_id, ghk, live, scan_chunks=None):
+        """Bring the rows flagged in ``live`` (n_pad,) to the front of
+        chunked copies of the three row arrays, in row order:
+        ``(n_chunks, CH, G)``, ``(n_chunks, CH)``, ``(n_chunks, CH, K)``,
+        and the i32 count of rows handed over.  Only the
+        ``ceil(handed / CH)`` chunks the contraction will visit are
+        written.  ``handed`` is the live rows plus, behind each block's,
+        the all-zero rows that fill its last tile (zero stat columns:
+        they add nothing to any histogram); positions past ``handed`` in
+        the last chunk get leaf id -2, which is no pending slot's.
+        ``scan_chunks`` (traced; default all) bounds the chunks that can
+        hold a live row: past the last real row there is none.
 
-        What the chip measured (PERF.md §6) decides each step.  The live
-        rows' ids, in row order, come from a sort inside each chunk
-        (local row ids with the dead flag as the top bit: distinct keys)
-        whose live prefixes are then laid end to end; a stable argsort
-        of all n_pad dead flags gives the same ids 1.5-2.5x slower and
-        compiles for half a minute per stage.  Each chunk of ids is then
-        ONE row gather: a gathered row costs the same ~11 ns whether it
-        is 4 bytes or 128, so bins, leaf id and stat columns travel as
-        the bytes of one row padded to whole 128-lane tiles (a gather
-        per array measured 3x the one).  The bytes are cut and joined
-        by shifts (a ``bitcast_convert_type`` that changes the shape
-        leaves (n_pad, 4) and (n_pad, 2K) arrays behind, each padded to
-        128 lanes in HBM) and put in their lanes by the MXU: the byte
-        columns lie along the lanes, a row wants them along its own, and
-        a product with a 0/1 placement matrix is that transpose — exact,
-        a byte being an integer bfloat16 holds — where a select per
-        column measured 83 ms a wave.  The gather is a loop
-        of its own ahead of the contraction's: gathering inside that
-        loop's body measured the same seconds, but this way the body
-        stays as it was."""
-        ch, n = _CHUNK, self.n_pad
+        Compaction is a monotone selection, and the MXU does it
+        (PERF.md §6 has what the chip measured for each step).  Bins,
+        leaf id and stat columns travel as the bytes of one row padded
+        to whole 128-lane tiles, cut and joined by shifts (a
+        ``bitcast_convert_type`` that changes the shape leaves (n_pad,
+        4) and (n_pad, 2K) arrays behind, each padded to 128 lanes in
+        HBM) and put in their lanes by a product with a 0/1 placement
+        matrix.  Level 1, a chunk at a time: inside a block of
+        ``_COMPACT_BLOCK`` rows live row i goes to its rank among the
+        block's live rows, ``out[j] = sum_i [live_i and rank_i == j] *
+        row[i]`` — one term a sum, a byte is an integer bfloat16 holds,
+        float32 accumulation: exact.  The rank is a product with a
+        triangle, the 0/1 matrix a bare iota-compare that XLA fuses into
+        the dot as it does the histogram's one-hot.  Level 2 lays the
+        blocks' live prefixes end to end by a gather of whole tiles of
+        ``_COMPACT_TILE`` rows, a chunk of the OUTPUT at a time, so it
+        costs what the live rows cost: out tile p comes from tile p +
+        (the empty tiles of the blocks that end at or before p), one
+        small scatter-add and a cumsum over the tiles.  No sort, no
+        gather of single rows."""
+        ch, n = _CHUNK, binned.shape[0]
         n_chunks = n // ch
-        g, k = self.num_groups, self.hist_cols
+        g, k = binned.shape[1], ghk.shape[1]
+        blk, tile = _COMPACT_BLOCK, _COMPACT_TILE
         wide = ghk.dtype.itemsize == 2             # bf16, else int8
         i32 = lambda a: a.astype(jnp.int32)
-        stat = i32(jax.lax.bitcast_convert_type(
-            ghk, jnp.uint16 if wide else jnp.uint8))
-        cols = [(leaf_id >> s) & 0xFF for s in (0, 8, 16, 24)]
-        for c in range(k):
-            cols += [stat[:, c] & 0xFF, stat[:, c] >> 8] if wide \
-                else [stat[:, c]]
-        width = _ceil_to(g + len(cols), 128)
-        place = jnp.arange(len(cols), dtype=jnp.int32)[:, None] + g \
-            == jnp.arange(width, dtype=jnp.int32)[None, :]
-        rows = jnp.pad(binned, ((0, 0), (0, width - g))) | jnp.einsum(
-            "cn,cl->nl", jnp.stack(cols).astype(jnp.bfloat16),
-            place.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32).astype(jnp.uint8)
+        ncols = 4 + k * (2 if wide else 1)
+        width = _ceil_to(g + ncols, 128)
+        place = (jnp.arange(ncols, dtype=jnp.int32)[:, None] + g
+                 == jnp.arange(width, dtype=jnp.int32)[None, :]
+                 ).astype(jnp.bfloat16)
+        bi = jnp.arange(blk, dtype=jnp.int32)
+        tri = (bi[:, None] <= bi[None, :]).astype(jnp.bfloat16)
+        chunked = [a.reshape((n_chunks, ch) + a.shape[1:])
+                   for a in (binned, leaf_id, ghk, live)]
+
+        def block_compact(c, buf):
+            b, l, gk, lv = (jax.lax.dynamic_index_in_dim(
+                a, c, keepdims=False) for a in chunked)
+            stat = i32(jax.lax.bitcast_convert_type(
+                gk, jnp.uint16 if wide else jnp.uint8))
+            cols = [(l >> s) & 0xFF for s in (0, 8, 16, 24)]
+            for j in range(k):
+                cols += [stat[:, j] & 0xFF, stat[:, j] >> 8] if wide \
+                    else [stat[:, j]]
+            rows = jnp.pad(b, ((0, 0), (0, width - g))) | jnp.einsum(
+                "cn,cl->nl", jnp.stack(cols).astype(jnp.bfloat16), place,
+                preferred_element_type=jnp.float32).astype(jnp.uint8)
+            lv = lv.reshape(ch // blk, blk)
+            rank = i32(jnp.einsum(
+                "bi,ik->bk", lv.astype(jnp.bfloat16), tri,
+                preferred_element_type=jnp.float32)) - 1
+            front = jnp.einsum(
+                "bij,bil->bjl",
+                jax.nn.one_hot(jnp.where(lv, rank, -1), blk,
+                               dtype=jnp.bfloat16),
+                rows.reshape(ch // blk, blk, width).astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32)
+            return jax.lax.dynamic_update_index_in_dim(
+                buf, front.astype(jnp.uint8).reshape(ch, width), c, 0)
+
+        fronts = jax.lax.fori_loop(
+            0, n_chunks if scan_chunks is None else scan_chunks,
+            block_compact,
+            jnp.zeros((n_chunks, ch, width), jnp.uint8))
+        cnt = live.reshape(n // blk, blk).sum(1, dtype=jnp.int32)
+        tiles = (cnt + tile - 1) // tile
+        handed = tiles.sum() * tile
+        src = (jnp.arange(n // tile, dtype=jnp.int32) + jnp.cumsum(
+            jnp.zeros((n // tile + 1,), jnp.int32)
+            .at[jnp.cumsum(tiles)].add(blk // tile - tiles))[:-1]
+        ).reshape(n_chunks, ch // tile)
+        # tiles kept (T, 128): as rows of T * 128 bytes XLA copies the
+        # whole array into another layout first
+        fronts = fronts.reshape(n // tile, tile, width)
         pos = jnp.arange(ch, dtype=jnp.int32)
-        live_c = live.reshape(n_chunks, ch)
-        dead = jnp.uint32(1 << 31)
-        key = pos.astype(jnp.uint32)[None, :]
-        ids = (jax.lax.sort(jnp.where(live_c, key, key | dead),
-                            dimension=1) & ~dead).astype(jnp.int32) \
-            + (jnp.arange(n_chunks, dtype=jnp.int32) * ch)[:, None]
-        cnt = live_c.sum(1, dtype=jnp.int32)
-        start = jnp.cumsum(cnt) - cnt
-        # chunk c's ids land behind chunk c-1's live ones, and the next
-        # chunk's overwrite its dead tail
-        order = jax.lax.fori_loop(
-            0, n_chunks,
-            lambda c, buf: jax.lax.dynamic_update_slice(
-                buf, ids[c], (start[c],)),
-            jnp.zeros((n + ch,), jnp.int32))[:n].reshape(n_chunks, ch)
 
         def body(i, bufs):
-            idx = jax.lax.dynamic_index_in_dim(order, i, keepdims=False)
-            r = jnp.take(rows, idx, axis=0)
-            x = i32(r[:, g:g + len(cols)])
+            idx = jax.lax.dynamic_index_in_dim(src, i, keepdims=False)
+            r = jnp.take(fronts, idx, axis=0, mode="clip") \
+                .reshape(ch, width)
+            x = i32(r[:, g:g + ncols])
             leaf = x[:, 0] | (x[:, 1] << 8) | (x[:, 2] << 16) \
                 | (x[:, 3] << 24)
             if wide:
@@ -535,7 +581,7 @@ class GrowerPrograms:
             else:
                 bits = x[:, 4:].astype(jnp.uint8)
             out = (r[:, :g],
-                   jnp.where(i * ch + pos < n_live, leaf, -2),
+                   jnp.where(i * ch + pos < handed, leaf, -2),
                    jax.lax.bitcast_convert_type(bits, ghk.dtype))
             return tuple(jax.lax.dynamic_update_index_in_dim(b, o, i, 0)
                          for b, o in zip(bufs, out))
@@ -543,7 +589,8 @@ class GrowerPrograms:
         bufs = (jnp.zeros((n_chunks, ch, g), binned.dtype),
                 jnp.full((n_chunks, ch), -2, jnp.int32),
                 jnp.zeros((n_chunks, ch, k), ghk.dtype))
-        return jax.lax.fori_loop(0, (n_live + ch - 1) // ch, body, bufs)
+        return (*jax.lax.fori_loop(0, (handed + ch - 1) // ch, body, bufs),
+                handed)
 
     def _wave_hist_local(self, binned, leaf_id, ghk, pending, num_valid,
                          scales):
@@ -555,23 +602,31 @@ class GrowerPrograms:
         ``scales`` is the (2,) [scale_g, scale_h] dequantization vector
         (quantized f32-fallback mode only).
 
-        Also returns (2,) i32 ``[row chunks visited, live rows]``.
+        Also returns (3,) i32 ``[row chunks visited, live rows, 1 if
+        the wave compacted else 0]``.
 
         A row is LIVE in a wave when its leaf is one of ``pending`` and
         its count column(s) are non-zero: every other row — another
         leaf's, bucket or shard padding, out of the bag, dropped by GOSS
-        — multiplies an all-zero operand row.  The live rows are gathered
-        to the front first (:meth:`_gather_live`, by rank
-        among live rows, so the chunks hold the same rows whatever
-        ``n_pad`` is) and its chunk loop then runs
-        ``ceil(live / _CHUNK)`` passes: a wave costs what the smaller
-        children and the bag hold, not every row.  Where that cannot
-        pay — a single chunk, or a stage narrower than
-        ``_GATHER_MIN_LANES`` — the rows stay where they are and the
-        loop's bound is the chunk that holds a row below ``num_valid``,
-        the (shard-local) count past which all rows are padding (traced
-        in training, ``n_pad`` in the plan probes).  Both are static
-        shapes: no parameter selects the path.
+        — multiplies an all-zero operand row.  The wave routes by what
+        it observes: where fewer than ``_COMPACT_MAX_LIVE`` of the rows
+        the plain loop would visit are live, they are brought to the
+        front first (:meth:`_gather_live`, by rank among live rows, so
+        the chunks hold the same rows whatever ``n_pad`` is) and the
+        chunk loop runs ``ceil(rows handed over / _CHUNK)`` passes: a
+        wave costs what the smaller children and the bag hold, not every
+        row, at any stage width.  Otherwise — a root wave, with or
+        without a bag, which the stage's ``while_loop`` body cannot tell
+        from its siblings statically — the rows stay where they are and
+        the loop's bound is the chunk that holds a row below
+        ``num_valid``, the (shard-local) count past which all rows are
+        padding (traced in training, ``n_pad`` in the plan probes, which
+        weight every row and so time this branch).  A ``lax.cond`` picks
+        the three chunked operands and the bound; the ONE chunk loop
+        below serves both (a second copy of it per stage would double
+        the fused program's compile).  Under ``shard_map`` each shard
+        decides from its own count.  A single chunk is never compacted,
+        nor a ``_CHUNK`` that is no multiple of the block.
 
         The one-hot must stay a bare iota-compare so XLA fuses its
         generation into the dot operand (a multi-hot built as
@@ -588,16 +643,24 @@ class GrowerPrograms:
                 & (pending >= 0)[None, :]).any(1) \
             & (ghk[:, 2 if k in (3, 4) else 4:] != 0).any(1)
         n_live = jnp.sum(live, dtype=jnp.int32)
-        if n_chunks > 1 and w * k >= self.gather_min_lanes:
+        real_chunks = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
+        plain = (binned.reshape(n_chunks, ch, g),
+                 leaf_id.reshape(n_chunks, ch),
+                 ghk.reshape(n_chunks, ch, k), real_chunks, jnp.int32(0))
+
+        def compact():
             with jax.named_scope("lgb.wave_gather"):
-                binned_c, leaf_c, ghk_c = self._gather_live(
-                    binned, leaf_id, ghk, live, n_live)
-            visited = (n_live + ch - 1) // ch
+                *rows, handed = self._gather_live(binned, leaf_id, ghk,
+                                                  live, real_chunks)
+            return (*rows, (handed + ch - 1) // ch, jnp.int32(1))
+
+        if n_chunks > 1 and ch % _COMPACT_BLOCK == 0:
+            binned_c, leaf_c, ghk_c, visited, gathered = jax.lax.cond(
+                n_live < self.compact_max_live
+                * (real_chunks * ch).astype(jnp.float32),
+                compact, lambda: plain)
         else:
-            binned_c = binned.reshape(n_chunks, ch, g)
-            leaf_c = leaf_id.reshape(n_chunks, ch)
-            ghk_c = ghk.reshape(n_chunks, ch, k)
-            visited = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
+            binned_c, leaf_c, ghk_c, visited, gathered = plain
         mdtype = jnp.int8 if quant else jnp.bfloat16
         adtype = jnp.int32 if quant else jnp.float32
 
@@ -658,7 +721,7 @@ class GrowerPrograms:
         else:
             hist = _combine_hist_cols(acc, k)                    # (G,NB,W,3)
         return (hist.transpose(2, 0, 1, 3).reshape(w, self.num_slots, 3),
-                jnp.stack([visited, n_live]))
+                jnp.stack([visited, n_live, gathered]))
 
     # ------------------------------------------------------------------
     def _stat_columns(self, grad, hess, one_f, tree_idx):
@@ -729,11 +792,13 @@ class GrowerPrograms:
                    *, with_mask):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
-        i32, root_value f32, work (7,) i32 = [waves run, sum of their
+        i32, root_value f32, work (8,) i32 = [waves run, sum of their
         stage widths, in-bag real rows, features in the mask, row chunks
         the wave histograms visited, their live rows // _CHUNK, the sum
-        of the remainders] — sharded (9 + L,): the last three summed
-        over the mesh, then the FULLEST shard's live rows summed wave by
+        of the remainders, the waves that compacted their live rows] —
+        sharded (10 + L,): the three before the last summed over the
+        mesh and the last a mean over the shards (each decides from its
+        own count), then the FULLEST shard's live rows summed wave by
         wave as the same (// _CHUNK, remainder) pair, then the exact
         (in-bag) rows of each of the L leaves —, quant_scales (2,)
         f32).
@@ -807,11 +872,12 @@ class GrowerPrograms:
             nl: jnp.ndarray             # i32 leaves so far
             waves: jnp.ndarray          # i32 wave count
             slots: jnp.ndarray          # i32 sum of wave widths run
-            hwork: jnp.ndarray          # (3,) i32 histogram work so far:
+            hwork: jnp.ndarray          # (4,) i32 histogram work so far:
             #                             chunks visited, live rows as
             #                             (// _CHUNK, % _CHUNK) sums — a
-            #                             tree's rows can pass int32;
-            #                             sharded (5,): then the same
+            #                             tree's rows can pass int32 —,
+            #                             waves that compacted;
+            #                             sharded (6,): then the same
             #                             pair for the FULLEST shard's
             #                             live rows, wave by wave
             done: jnp.ndarray           # bool
@@ -840,7 +906,7 @@ class GrowerPrograms:
             nl=jnp.asarray(1, jnp.int32),
             waves=jnp.asarray(0, jnp.int32),
             slots=jnp.asarray(0, jnp.int32),
-            hwork=jnp.zeros((3 if self.shard is None else 5,), jnp.int32),
+            hwork=jnp.zeros((4 if self.shard is None else 6,), jnp.int32),
             done=jnp.asarray(False),
             rec_i=jnp.full((L, REC_I_FIELDS), -1, jnp.int32),
             rec_f=jnp.zeros((L, REC_F_FIELDS), jnp.float32),
@@ -1082,7 +1148,7 @@ class GrowerPrograms:
                 pl = jnp.where(sel, jnp.where(small_left, r_ids, lsel), -1)
 
             nl, waves, slots = st.nl + napply, st.waves + 1, st.slots + Ws
-            hw_add = [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK]
+            hw_add = [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK, hw[2]]
             if self.shard is not None:
                 # the mesh waits at the psum for its fullest shard: what
                 # that shard contracted in this wave, beside the sum
@@ -1238,8 +1304,9 @@ class GrowerPrograms:
             # each shard gathers and scans its own live rows; the
             # fullest shard's pair is the same on every shard already
             with jax.named_scope("lgb.psum"):
-                hwork = jnp.concatenate(
-                    [jax.lax.psum(hwork[:3], self.shard.axis), hwork[3:]])
+                summed = jax.lax.psum(hwork[:4], self.shard.axis)
+            hwork = jnp.concatenate(
+                [summed[:3], summed[3:] // self.shard.n_shards, hwork[4:]])
             # the rows of every leaf, counted from where the rows ended
             # up: the histogram state is float32, and a mesh is how a
             # node comes to hold more rows than float32 counts one by

@@ -1,15 +1,15 @@
 """One wave histogram at a cell's shape, timed on the chip: the program's
 own ``GrowerPrograms._wave_hist`` at a stage width and a share of live
-rows, with the live rows gathered ahead of the chunk loop (``on``), left
-where they lie (``off``), or as the program's own width rule has it
-(``as_is``).
+rows, with the live rows brought to the front ahead of the chunk loop
+(``on``), left where they lie (``off``), or as the program's own rule
+on the live share has it (``as_is``).
 
     python3 scripts/bench_wave_hist.py --shape criteo --live 0.45 \\
         --variants "on:4,8,16,32,96 off:4,8,16"
 
 Lines go to stdout and to ``chiprun_out/wave_hist_bench.jsonl``.
-``--repo`` runs another unpacked tree (one without the gather answers
-every variant the same way).
+``--repo`` runs another unpacked tree (one before the compaction routes
+``on`` and ``off`` by its own constant, ``_GATHER_MIN_LANES``).
 """
 
 import argparse
@@ -54,7 +54,9 @@ def main(argv=None):
         todo += [(name, int(w)) for w in (ws or args.widths).split(",")]
     for variant, w in todo:
         if variant != "as_is":
-            # read when the programs object is built
+            # read when the programs object is built: every share of
+            # live rows compacts, or none does
+            growmod._COMPACT_MAX_LIVE = 0.0 if variant == "off" else 2.0
             growmod._GATHER_MIN_LANES = 1 << 30 if variant == "off" else 0
         progs = growmod.GrowerPrograms(
             num_data=n, num_groups=g, nb=256, num_features=g,

@@ -286,7 +286,7 @@ def test_wave_hist_matches_a_plain_histogram(reg_data):
     w = progs.wave_width
     pending = np.concatenate([np.arange(6), [-1] * (w - 6)]).astype(
         np.int32)
-    got, (visited, live) = progs._wave_hist(
+    got, (visited, live, _) = progs._wave_hist(
         binned, jnp.asarray(leaf), ghk, jnp.asarray(pending), n)
     got = np.asarray(got)
     assert got.shape == (w, progs.num_slots, 3)
@@ -311,26 +311,25 @@ _LAYOUTS = {"bf16_k3": ({}, False, 3), "bf16_k4_striped": ({}, True, 4),
             "int8_k3": ({"grad_quant_bits": 8}, False, 3)}
 
 
-def _six_slot_programs(layout):
-    """``GrowerPrograms`` of one six-slot stage over four row chunks in
+def _slot_programs(layout, w=6):
+    """``GrowerPrograms`` of one ``w``-slot stage over four row chunks in
     the stat-column layout ``layout`` — the module's comments invite
-    both overrides: counts striped on few rows, live rows gathered in a
-    narrow wave — and (n_pad, groups, bins, slots)."""
+    override: counts striped on few rows — and (n_pad, groups, bins,
+    slots)."""
     from lightgbm_tpu.ops import grow as growmod
 
     extra, striped, k = _LAYOUTS[layout]
-    n, groups, nb, w = 4 * growmod._CHUNK, 5, 64, 6
-    old = growmod.COUNT_SPLIT_ROWS, growmod._GATHER_MIN_LANES
+    n, groups, nb = 4 * growmod._CHUNK, 5, 64
+    old = growmod.COUNT_SPLIT_ROWS
     try:
-        growmod.COUNT_SPLIT_ROWS = 1 if striped else old[0]
-        growmod._GATHER_MIN_LANES = 0
+        growmod.COUNT_SPLIT_ROWS = 1 if striped else old
         progs = growmod.GrowerPrograms(
             num_data=n, num_groups=groups, nb=nb, num_features=groups,
             has_cat=False, plan=[(w, None)],
             config=Config({"objective": "binary", "num_leaves": w + 1,
                            "verbosity": -1, **extra}))
     finally:
-        growmod.COUNT_SPLIT_ROWS, growmod._GATHER_MIN_LANES = old
+        growmod.COUNT_SPLIT_ROWS = old
     assert (progs.n_pad, progs.hist_cols, progs.striped) == (n, k, striped)
     return progs, (n, groups, nb, w)
 
@@ -344,7 +343,7 @@ def wave_hist_case(request):
     import jax.numpy as jnp
 
     extra = _LAYOUTS[request.param][0]
-    progs, (n, groups, nb, w) = _six_slot_programs(request.param)
+    progs, (n, groups, nb, w) = _slot_programs(request.param)
     rng = np.random.default_rng(27)
     binned = jnp.asarray(rng.integers(0, nb - 1, (n, groups))
                          .astype(np.uint8))
@@ -392,15 +391,17 @@ def test_wave_hist_stops_at_the_last_live_chunk(wave_hist_case, rows):
 # the wave histogram contracts only the live rows of its pending leaves
 # ---------------------------------------------------------------------------
 
-_PENDING = {"one_leaf": [3, -1, -1, -1, -1, -1],
-            "half_the_leaves": [4, -1, 0, -1, 2, -1],
-            "all_leaves": [0, 1, 2, 3, 4, 5],
-            "none": [-1] * 6}
+_PENDING = {"one_leaf": [-1, 3, -1, -1, -1, -1, -1, -1],
+            "two_leaves": [6, -1, -1, -1, 1, -1, -1, -1],
+            "half_the_leaves": [4, -1, 0, -1, 2, -1, 7, -1],
+            "all_leaves": [0, 1, 2, 3, 4, 5, 6, 7],
+            "none": [-1] * 8}
 
 
 @pytest.fixture(scope="module", params=list(_LAYOUTS))
 def live_rows_case(request):
-    """(jitted ``(pending, bag) -> (hist, [chunks visited, live rows])``
+    """(jitted ``(pending, bag) -> (hist, [chunks visited, live rows,
+    compacted])`` of an eight-slot stage (the narrowest the cells run)
     over four row chunks whose last 100 rows are padding, and the numpy
     operands of the same call: bins, leaf ids, stat columns as float64 /
     int64, the count columns' positions) for one stat-column layout."""
@@ -408,7 +409,7 @@ def live_rows_case(request):
     import jax.numpy as jnp
 
     extra, striped, _ = _LAYOUTS[request.param]
-    progs, (n, groups, nb, w) = _six_slot_programs(request.param)
+    progs, (n, groups, nb, w) = _slot_programs(request.param, 8)
     rng = np.random.default_rng(29)
     bins = rng.integers(0, nb - 1, (n, groups)).astype(np.uint8)
     valid = np.arange(n) < n - 100
@@ -440,12 +441,18 @@ def live_rows_case(request):
 @pytest.mark.parametrize("pending", list(_PENDING))
 def test_wave_hist_contracts_only_the_live_rows(live_rows_case, pending,
                                                 bag):
-    """The compacted histogram is the plain histogram of the same
-    operands — counts and int8 sums exactly, bfloat16 sums to float32
-    re-association — and the loop visited ``ceil(live / _CHUNK)`` chunks,
-    live being the in-bag real rows of the pending leaves."""
+    """The histogram is the plain histogram of the same operands —
+    counts and int8 sums exactly, bfloat16 sums to float32
+    re-association — whatever the wave did with its rows.  Live are the
+    in-bag real rows of the pending leaves; a wave with fewer than
+    ``_COMPACT_MAX_LIVE`` of its rows live (one, two, four of eight
+    leaves) compacts them and its loop visits ``ceil(rows handed over /
+    _CHUNK)`` chunks, handed over being each block's live rows rounded
+    up to whole tiles; a wave of all eight leaves, bagged or not, scans
+    the rows where they lie."""
     import jax.numpy as jnp
-    from lightgbm_tpu.ops.grow import _CHUNK
+    from lightgbm_tpu.ops.grow import (_CHUNK, _COMPACT_BLOCK,
+                                       _COMPACT_MAX_LIVE, _COMPACT_TILE)
 
     fn, bins, leaf, numpy_operands, cnt_cols = live_rows_case
     n, groups = bins.shape
@@ -458,8 +465,12 @@ def test_wave_hist_contracts_only_the_live_rows(live_rows_case, pending,
     ghk = numpy_operands(in_bag)
     cols = [ghk[:, 0], ghk[:, 1], ghk[:, list(cnt_cols)].sum(1)]
     live = np.isin(leaf, pend[pend >= 0]) & (cols[2] != 0)
+    tiles = -(-live.reshape(-1, _COMPACT_BLOCK).sum(1) // _COMPACT_TILE)
+    compacts = pending != "all_leaves"
+    assert compacts == (live.sum() < _COMPACT_MAX_LIVE * n)
     assert [int(v) for v in np.asarray(work)] \
-        == [-(-int(live.sum()) // _CHUNK), int(live.sum())]
+        == [-(-int(tiles.sum()) * _COMPACT_TILE // _CHUNK) if compacts
+            else n // _CHUNK, int(live.sum()), int(compacts)]
     if pending != "none":
         assert 0 < live.sum() <= n - 100
     for slot, lf in enumerate(pend):
@@ -476,6 +487,143 @@ def test_wave_hist_contracts_only_the_live_rows(live_rows_case, pending,
                                        weights=np.abs(col[rows]),
                                        minlength=nb)
                     assert (np.abs(got - want) <= 1e-6 * room).all()
+
+
+# ---------------------------------------------------------------------------
+# the compaction alone: live rows to the front on the MXU, in row order
+# ---------------------------------------------------------------------------
+
+def _live_masks(n, blk):
+    rng = np.random.default_rng(37)
+    some = rng.random(n) < 0.4
+    blocks = some.copy()
+    blocks[3 * blk:4 * blk] = True
+    blocks[5 * blk:6 * blk] = False
+    return {"none": np.zeros(n, bool),
+            "one_row": np.arange(n) == 12345,
+            "share_0.4": some,
+            "all": np.ones(n, bool),
+            "a_block_all_live_and_one_all_dead": blocks,
+            "off_a_tile": np.arange(n) < blk + 3}
+
+
+_COMPACT_OPERANDS = {}
+
+
+def _compact_operands(layout):
+    """(jitted ``_gather_live``, bins, leaf ids, stat columns as the
+    integers their bits spell) over four row chunks in one layout."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import grow as growmod
+
+    if layout not in _COMPACT_OPERANDS:
+        # gpu_use_dp's five columns (hi / lo pairs) ride the same row
+        extra, _, k = _LAYOUTS.get(layout, ({}, False, 5))
+        n = 4 * growmod._CHUNK
+        rng = np.random.default_rng(41)
+        bins = rng.integers(0, 256, (n, 5)).astype(np.uint8)
+        # every byte of a leaf id travels: negative and large ones too
+        leaf = rng.integers(-3, 1 << 30, n).astype(np.int32)
+        if extra:
+            ghk = jnp.asarray(rng.integers(-127, 128, (n, k))
+                              .astype(np.int8))
+            bits = np.asarray(ghk).view(np.uint8)
+        else:
+            ghk = jnp.asarray(rng.standard_normal((n, k))
+                              .astype(np.float32)).astype(jnp.bfloat16)
+            bits = np.asarray(jax.lax.bitcast_convert_type(ghk,
+                                                           jnp.uint16))
+        _COMPACT_OPERANDS[layout] = (
+            jax.jit(growmod.GrowerPrograms._gather_live),
+            jnp.asarray(bins), jnp.asarray(leaf), ghk, bins, leaf, bits)
+    return _COMPACT_OPERANDS[layout]
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS) + ["bf16_k5_dp"])
+@pytest.mark.parametrize("case", ["none", "one_row", "share_0.4", "all",
+                                  "a_block_all_live_and_one_all_dead",
+                                  "off_a_tile"])
+def test_compaction_hands_over_the_live_rows_in_row_order(layout, case):
+    """``_gather_live`` against numpy's ``rows[live]``: block by block
+    the live rows in row order, every byte of bins, leaf id and stat
+    columns, then all-zero rows to the end of the block's last tile;
+    leaf id -2 past the rows handed over; the chunks the contraction
+    will not visit are left as they were made."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.grow import (_CHUNK, _COMPACT_BLOCK,
+                                       _COMPACT_TILE)
+
+    fn, binned, leaf_id, ghk, bins, leaf, bits = _compact_operands(layout)
+    n = len(leaf)
+    live = _live_masks(n, _COMPACT_BLOCK)[case]
+    b, l, g, handed = fn(binned, leaf_id, ghk, jnp.asarray(live))
+    want_b = np.zeros_like(bins)
+    want_l = np.full(n, -2, np.int32)
+    want_g = np.zeros_like(bits)
+    at = 0
+    for blk in range(0, n, _COMPACT_BLOCK):
+        rows = blk + np.flatnonzero(live[blk:blk + _COMPACT_BLOCK])
+        end = at + -(-len(rows) // _COMPACT_TILE) * _COMPACT_TILE
+        want_l[at:end] = 0
+        for want, src in ((want_b, bins), (want_l, leaf), (want_g, bits)):
+            want[at:at + len(rows)] = src[rows]
+        at = end
+    assert int(handed) == at
+    assert at - int(live.sum()) < _COMPACT_TILE * (n // _COMPACT_BLOCK)
+    visited = -(-at // _CHUNK) * _CHUNK
+    want_l[visited:] = -2
+    np.testing.assert_array_equal(np.asarray(b).reshape(bins.shape),
+                                  want_b)
+    np.testing.assert_array_equal(np.asarray(l).reshape(n), want_l)
+    got_g = jax.lax.bitcast_convert_type(
+        g, jnp.uint16 if bits.dtype == np.uint16 else jnp.uint8)
+    np.testing.assert_array_equal(np.asarray(got_g).reshape(bits.shape),
+                                  want_g)
+
+
+@pytest.mark.parametrize("bag", [None, 0.8], ids=["no_bag", "bag_0.8"])
+def test_root_waves_scan_in_place_and_their_children_compact(bag):
+    """``grow.waves_gathered``: of every tree's waves all but the root's
+    compact their live rows — the smaller children hold at most half
+    the rows; the root wave finds every row live, or the 0.8 bag, both
+    above ``_COMPACT_MAX_LIVE``, and scans them where they lie."""
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.ops.grow import (_CHUNK, _COMPACT_BLOCK,
+                                       _COMPACT_MAX_LIVE, _COMPACT_TILE)
+
+    assert 0.5 < _COMPACT_MAX_LIVE < 0.8
+    rng = np.random.default_rng(43)
+    x = rng.standard_normal((32000, 6)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] * x[:, 2] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": 15,
+              "fused_chunk": 2, "verbosity": -1, "device_growth": "on",
+              "min_data_in_leaf": 5,
+              **({} if bag is None else {"bagging_fraction": bag,
+                                         "bagging_freq": 1})}
+    obs.configure(enabled=True)
+    obs.reset()
+    try:
+        bst = lgb.train(params, lgb.Dataset(x, label=y, params=params),
+                        num_boost_round=2, verbose_eval=False,
+                        keep_training_booster=True)
+        jax.block_until_ready(bst._gbdt.train_score)
+        assert bst._gbdt._grower.n_pad // _CHUNK == 4
+        c = obs.registry().snapshot()["counters"]
+    finally:
+        obs.configure(enabled=False)
+        obs.reset()
+    assert c["grow.trees"] == 2 and c["grow.waves"] >= 6
+    assert c["grow.waves_gathered"] == c["grow.waves"] - c["grow.trees"]
+    # and the compacted waves' loops follow their live rows: the tile
+    # tails and the last chunk's are all they scan beyond them
+    assert c["grow.rows_scanned"] < 2 * 4 * _CHUNK + (
+        c["grow.rows_live"] - c["grow.rows_in_bag"]
+        + c["grow.waves_gathered"]
+        * (_CHUNK + 4 * _CHUNK // _COMPACT_BLOCK * _COMPACT_TILE))
 
 
 def test_device_bagging_matches_host(reg_data):

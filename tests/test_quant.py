@@ -149,7 +149,9 @@ import hashlib, json, sys
 import numpy as np
 sys.path.insert(0, {root!r})
 import lightgbm_tpu as lgb
+from lightgbm_tpu import obs
 from lightgbm_tpu.ops.grow import _CHUNK
+obs.configure(enabled=True)
 rng = np.random.default_rng(29)
 x = rng.standard_normal((32768, 8)).astype(np.float32)
 y = (x[:, 0] + np.abs(x[:, 1]) + 0.3 * rng.standard_normal(32768)
@@ -164,9 +166,10 @@ bst = lgb.train(params, ds, num_boost_round=4, verbose_eval=False,
 text = bst.model_to_string()
 text = text[:text.index("parameters:")]
 grower = bst._gbdt._grower
+counters = obs.registry().snapshot()["counters"]
 print(json.dumps(dict(chunks=int(grower.n_pad) // _CHUNK,
-                      lanes=max(w for w, _ in grower.stage_plan)
-                      * int(grower.hist_cols),
+                      waves=counters["grow.waves"],
+                      gathered=counters["grow.waves_gathered"],
                       trees=text.count("Tree="),
                       sha=hashlib.sha256(text.encode()).hexdigest())))
 """
@@ -175,14 +178,16 @@ print(json.dumps(dict(chunks=int(grower.n_pad) // _CHUNK,
 @pytest.mark.timeout(400)
 def test_quant_model_is_the_same_gathered_or_not():
     """Integer histograms are exact in any row order, so the model text
-    does not depend on whether a wave gathers its live rows ahead of the
-    chunk loop: four chunks of 8,192 rows (gathered in the wide stage)
-    and one chunk of 32,768 (every row contracted where it lies, the
-    path before the gather existed) write the same bytes."""
+    does not depend on whether a wave brings its live rows to the front
+    ahead of the chunk loop: four chunks of 8,192 rows (every wave
+    compacts but the root's, whose 0.8 bag is above
+    ``_COMPACT_MAX_LIVE``) and one chunk of 32,768 (every row contracted
+    where it lies, the path before the compaction existed) write the
+    same bytes."""
     import json
     import subprocess
 
-    from lightgbm_tpu.ops.grow import _GATHER_MIN_LANES
+    from lightgbm_tpu.ops.grow import _COMPACT_MAX_LIVE
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     got = {}
@@ -195,8 +200,9 @@ def test_quant_model_is_the_same_gathered_or_not():
         assert proc.returncode == 0, proc.stderr[-3000:]
         got[chunk] = json.loads(proc.stdout.strip().splitlines()[-1])
     assert (got[8192]["chunks"], got[32768]["chunks"]) == (4, 1)
-    assert got[8192]["lanes"] >= _GATHER_MIN_LANES
-    assert got[8192]["trees"] == 4
+    assert got[8192]["trees"] == 4 and _COMPACT_MAX_LIVE < 0.8
+    assert got[8192]["gathered"] == got[8192]["waves"] - 4 > 0
+    assert got[32768]["gathered"] == 0 < got[32768]["waves"]
     assert got[8192]["sha"] == got[32768]["sha"]
 
 

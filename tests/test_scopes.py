@@ -108,8 +108,8 @@ _TEXTS = {
                                     "feature_fraction": 0.8}),
     "sharded": lambda: _fused_text({"data_sharding": "single_controller",
                                     "shard_devices": 2}),
-    # 17,000 rows are three histogram chunks and 31 leaves end in a
-    # stage of 30 x 3 lanes: only such a wave gathers its live rows
+    # 17,000 rows are three histogram chunks: only over more than one
+    # chunk may a wave bring its live rows to the front
     "chunks": lambda: _fused_text({"num_leaves": 31}, rows=17000),
     "traverse": _traverse_text,
     "bin": _bin_text,
@@ -202,9 +202,9 @@ def _grow_counters():
 
 
 # 3,000 rows sit in one histogram chunk (the suite's LGBM_TPU_CHUNK is
-# 8192); 17,000 pad to the 32,768 bucket, whose fourth chunk holds no
-# row, and grow 31 leaves there: the last stage is wide enough to gather
-@pytest.fixture(params=[3000, 17000], ids=["one_chunk", "dead_chunk"])
+# 8192); 20,000 pad to the 32,768 bucket, whose fourth chunk holds no
+# row, and grow 31 leaves there: every wave but a tree's first compacts
+@pytest.fixture(params=[3000, 20000], ids=["one_chunk", "dead_chunk"])
 def two_chunks(request):
     """(booster, per-chunk [(nl, work)] as the program returned them,
     counters after chunk 1, counters after chunk 2)."""
@@ -233,12 +233,13 @@ def two_chunks(request):
 def test_counters_hold_every_tree_and_the_returned_waves(two_chunks):
     _, returned, _, c = two_chunks
     assert c["grow.trees"] == 4
-    work = np.concatenate([np.asarray(w).reshape(-1, 7)
+    work = np.concatenate([np.asarray(w).reshape(-1, 8)
                            for _, w, _ in returned])
     nl = np.concatenate([np.asarray(n).reshape(-1)
                          for n, _, _ in returned])
     assert c["grow.waves"] == int(work[:, 0].sum()) > 0
     assert c["grow.wave_slots"] == int(work[:, 1].sum())
+    assert c["grow.waves_gathered"] == int(work[:, 7].sum())
     assert c["grow.leaves"] == int(nl.sum())
     # no bagging, no feature sampling: every real row and every feature
     assert c["grow.rows_in_bag"] == int(work[:, 2].sum()) \
@@ -262,13 +263,17 @@ def test_counters_bound_each_other(two_chunks):
     if rows == 3000:
         # a single chunk is contracted where it lies, in every wave
         assert c["grow.rows_scanned"] == c["grow.waves"] * _CHUNK
+        assert c["grow.waves_gathered"] == 0
     else:
-        # three chunks hold the 17,000 rows: the narrow stage's waves
-        # visit them all, the wide stage's the chunks their live rows
-        # fill (at most 8,500 rows: two)
+        # three chunks hold the 20,000 rows (81% of theirs live, so a
+        # root wave scans them in place); every later wave, narrow stage
+        # or wide, visits the chunks its live rows fill (at most 10,000
+        # rows and the tile tails: two)
         assert n_pad // _CHUNK == 4
         assert [w for w, _ in bst._gbdt._grower.stage_plan] == [4, 30]
-        assert c["grow.rows_scanned"] < c["grow.waves"] * 3 * _CHUNK
+        assert c["grow.waves_gathered"] == c["grow.waves"] - 4
+        assert c["grow.rows_scanned"] <= (4 * 3 + (c["grow.waves"] - 4)
+                                          * 2) * _CHUNK
     # a wave of width W applies at most W splits
     assert 0 < c["grow.leaves"] - c["grow.trees"] <= c["grow.wave_slots"]
     # every wave offers at least one slot and at most the widest stage
@@ -324,10 +329,11 @@ def test_snapshot_delta_is_exactly_the_chunk_between(two_chunks):
     _, returned, c1, c2 = two_chunks
     assert c1["grow.trees"] == 2
     nl, work, real = returned[1]
-    work = np.asarray(work).reshape(-1, 7)
+    work = np.asarray(work).reshape(-1, 8)
     waves = int(work[:, 0].sum())
     want = {"grow.trees": 2, "grow.leaves": int(np.asarray(nl).sum()),
             "grow.waves": waves, "grow.wave_slots": int(work[:, 1].sum()),
+            "grow.waves_gathered": int(work[:, 7].sum()),
             "grow.rows_scanned": int(work[:, 4].sum()) * _CHUNK,
             "grow.rows_live": int(work[:, 5].sum()) * _CHUNK
             + int(work[:, 6].sum()),
