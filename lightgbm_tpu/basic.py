@@ -268,6 +268,18 @@ class Dataset:
         self.construct()
         return list(self._handle.feature_names)
 
+    def feature_groups(self) -> List[List[int]]:
+        """What exclusive feature bundling made of the columns: per
+        group the original column indices it holds, in push order.
+        Every used column is in exactly one group (a column with one
+        bin in the sampled rows is in none); a group holds at most 256
+        bins.  Columns share a group only where no row of the
+        bin-finding sample records two of them (``max_conflict_rate``
+        0); a row of the table that does record two columns of one
+        group is read as recording the later one only."""
+        self.construct()
+        return self._handle.feature_groups()
+
     def set_categorical_feature(self, categorical_feature):
         if self._handle is not None and \
                 categorical_feature != self.categorical_feature:

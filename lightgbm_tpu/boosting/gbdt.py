@@ -210,6 +210,8 @@ class _WorkDrain:
     queues the handles (their async host copies already started) and
     ``drain`` adds whatever ``is_ready()`` to
     ``grow.trees`` / ``leaves`` / ``waves`` / ``wave_slots`` /
+    ``find_slots`` (leaves evaluated by find-best, 2 x leaves - 1 a
+    tree, x the histogram slots a leaf's scan reads) /
     ``rows_real`` (real rows x waves) / ``rows_scanned`` (the visited
     chunks' rows) / ``rows_live`` (both counted by the program, summed
     over waves and shards) / ``waves_gathered`` (waves whose histogram
@@ -234,6 +236,10 @@ class _WorkDrain:
         # bytes one chip hands the histogram psum for one stage slot
         # (set with the grower; 0 off a mesh)
         self.psum_slot_bytes = 0
+        # histogram slots one leaf's find-best has to read (the bins the
+        # features hold, a default bin 0 left out as the layout leaves
+        # it out; set with the grower)
+        self.find_slots = 0
         # the output of the next pushed dispatch that its caller waits
         # on (the score): once THAT is ready the dispatch is over and so
         # are its counters, whatever the backend says of arrays nobody
@@ -260,7 +266,8 @@ class _WorkDrain:
         work.copy_to_host_async()
         with self._lock:
             self._pending.append((nl, work, rows_real,
-                                  self.psum_slot_bytes, self.awaited))
+                                  self.psum_slot_bytes, self.awaited,
+                                  self.find_slots))
             self.awaited = None
         self.drain()
 
@@ -273,7 +280,7 @@ class _WorkDrain:
                            for a in (self._pending[0][4],
                                      self._pending[0][1]))):
                 done.append(self._pending.popleft())
-        for nl, work, rows_real, slot_bytes, _ in done:
+        for nl, work, rows_real, slot_bytes, _, find_slots in done:
             nl = np.asarray(nl).reshape(-1)
             work = np.asarray(work, np.int64)
             work = work.reshape(-1, work.shape[-1])
@@ -282,6 +289,10 @@ class _WorkDrain:
             obs.inc("grow.leaves", int(nl.sum()))
             obs.inc("grow.waves", waves)
             obs.inc("grow.wave_slots", int(work[:, 1].sum()))
+            # every leaf a tree ever held is evaluated once: the root,
+            # then two children a split
+            obs.inc("grow.find_slots",
+                    int(2 * nl.sum() - nl.size) * find_slots)
             obs.inc("grow.rows_real", waves * rows_real)
             obs.inc("grow.rows_scanned", int(work[:, 4].sum()) * _CHUNK)
             obs.inc("grow.rows_live", int(work[:, 5].sum()) * _CHUNK
@@ -457,6 +468,9 @@ class GBDT:
                 self._work.psum_slot_bytes = \
                     self._grower.num_slots * 3 * 4 \
                     if self._grower.deal is not None else 0
+                self._work.find_slots = int(
+                    train_set.f_num_bin.sum()
+                    - (train_set.f_default_bin == 0).sum())
                 log_info("Using on-device tree growth (device_growth="
                          f"{mode})")
                 wp = str(getattr(cfg, "wave_plan", "auto")).lower()

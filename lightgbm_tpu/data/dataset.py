@@ -29,7 +29,8 @@ from .. import obs
 from ..config import Config
 from ..utils.log import LightGBMError, log_info, log_warning
 from ..utils.random import make_rng
-from .binning import BIN_CATEGORICAL, BIN_NUMERICAL, BinMapper
+from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN,
+                      BinMapper)
 
 MAX_GROUP_BIN = 256   # static histogram bin axis on device
 BIN_BLOCK_ROWS = 1 << 17   # rows binned as one block (dense and CSR)
@@ -172,6 +173,22 @@ class BinnedDataset:
     @property
     def num_features(self) -> int:
         return len(self.used_features)
+
+    def feature_groups(self) -> List[List[int]]:
+        """What was bundled: per group the original column indices it
+        holds, in push order (of a row that records two columns of one
+        group the LATER one is kept, see ``_bundle_from_masks``).  Every
+        used column is in exactly one group; trivial columns in none."""
+        return [list(g.feature_indices) for g in self.groups]
+
+    def _note_layout(self) -> None:
+        """Gauges of the layout that was built: groups, used features,
+        and the slots the groups hold (their bins, each group's slot 0
+        included) of the 256 a group may."""
+        obs.set_gauge("bin.groups", len(self.groups))
+        obs.set_gauge("bin.features_used", len(self.used_features))
+        obs.set_gauge("bin.slots_used",
+                      int(sum(g.num_total_bin for g in self.groups)))
 
     def group_bin_boundaries(self) -> np.ndarray:
         out = [0]
@@ -569,25 +586,87 @@ class BinnedDataset:
         dense path's non_default masking; of a cell recorded twice the
         later entry wins, and of a bundle's features the later one, as
         there.  Blocks write disjoint rows, so ``num_threads`` of them
-        (0: every core) run at once to the same bytes."""
+        (0: every core) run at once to the same bytes.
+
+        A group whose every feature is a two-bin numerical column (a
+        bundle of one-hot columns) is filled for all its features at
+        once: one upper bound decides a value's bin, so one comparison
+        bins every entry of the block that belongs to such a group.  A
+        loop over thousands of features, a few hundred entries each, is
+        a few small calls a feature that all hold the interpreter's lock:
+        the blocks' threads then wait on one another (at 12,184,290 x
+        4,228 the loop alone took 45 s on 13 cores, PERF.md section 6)."""
         n, num_col = self.num_data, self.num_total_features
-        binned = np.zeros((n, len(self.groups)), dtype=np.uint8)
+        ng = len(self.groups)
+        binned = np.zeros((n, ng), dtype=np.uint8)
         key_t = (np.uint8 if num_col <= 1 << 8 else np.uint16
                  if num_col <= 1 << 16 else indices.dtype)  # radix-sorted
         col_ids = np.arange(num_col + 1)
         plan = self._bin_plan()
 
+        def two_bin(m):
+            return (m.bin_type == BIN_NUMERICAL and m.num_bin == 2
+                    and m.missing_type != MISSING_NAN)
+
+        at_once = {gid for gid, g in enumerate(self.groups)
+                   if len(g.feature_indices) > 1
+                   and all(two_bin(self.bin_mappers[f])
+                           for f in g.feature_indices)}
+        looped = [p for p in plan if p[0] not in at_once]
+        # per column of an at-once group: its group, the bound between its
+        # two bins, its default bin, its slot shift and its push position
+        v_gid = np.full(num_col, -1, np.int32)
+        v_bound = np.zeros(num_col, np.float64)
+        v_default = np.zeros(num_col, np.int8)
+        v_shift = np.zeros(num_col, np.int16)
+        v_push = np.zeros(num_col, np.int32)
+        for push, (gid, f, m, shift) in enumerate(plan):
+            if gid in at_once:
+                v_gid[f], v_bound[f] = gid, m.bin_upper_bound[0]
+                v_default[f], v_shift[f], v_push[f] = (m.default_bin, shift,
+                                                       push)
+
+        def fill_at_once(out, cols, rows, vals) -> None:
+            """``cols``/``rows``/``vals``: the block's entries that belong
+            to at-once groups."""
+            v = np.where(np.isnan(vals), 0.0, vals)
+            bins = (v > v_bound[cols]).astype(np.int8)
+            keep = bins != v_default[cols]
+            cols, rows = cols[keep], rows[keep]
+            gids = v_gid[cols]
+            slots = (bins[keep] + v_shift[cols]).astype(np.uint8)
+            out[rows, gids] = slots
+            lost = out[rows, gids] != slots
+            if lost.any():
+                # rows that record two columns of one group: write those
+                # cells again in push order, so that the later column
+                # stays whatever order the assignment above took
+                cell = rows.astype(np.int64) * ng + gids
+                again = np.flatnonzero(np.isin(cell, cell[lost]))
+                for i in again[np.argsort(v_push[cols[again]],
+                                          kind="stable")]:
+                    out[rows[i], gids[i]] = slots[i]
+
         def fill(lo: int) -> None:
             hi = min(lo + BIN_BLOCK_ROWS, n)
             s, e = int(indptr[lo]), int(indptr[hi])
-            cols = indices[s:e].astype(key_t, copy=False)
+            cols, vals = indices[s:e], values[s:e]
+            rows = np.repeat(np.arange(hi - lo, dtype=np.int32),
+                             np.diff(indptr[lo:hi + 1]))
+            out = binned[lo:hi]
+            if at_once:
+                # these need no order: only the looped features' entries
+                # are brought column-major below
+                mine = v_gid[cols] >= 0
+                fill_at_once(out, cols[mine].astype(np.int64), rows[mine],
+                             np.asarray(vals[mine], np.float64))
+                rest = ~mine
+                cols, vals, rows = cols[rest], vals[rest], rows[rest]
+            cols = cols.astype(key_t, copy=False)
             order = np.argsort(cols, kind="stable")
             bounds = np.searchsorted(cols[order], col_ids)
-            rows = np.repeat(np.arange(hi - lo, dtype=np.int32),
-                             np.diff(indptr[lo:hi + 1]))[order]
-            vals = values[s:e][order]
-            out = binned[lo:hi]
-            for gid, f, m, shift in plan:
+            rows, vals = rows[order], vals[order]
+            for gid, f, m, shift in looped:
                 a, b = bounds[f], bounds[f + 1]
                 bins = m.values_to_bins(vals[a:b])
                 keep = bins != m.default_bin
@@ -684,7 +763,21 @@ class BinnedDataset:
     def _bundle_from_masks(self, config: Config, nz_masks, nz_counts,
                            total_sample: int):
         """The greedy conflict-bounded grouping over sampled
-        recorded-row masks (shared by the dense and CSR paths)."""
+        recorded-row masks (shared by the dense and CSR paths).
+
+        The conflict rule: two columns share a group only where at most
+        ``max_conflict_rate`` of the ``total_sample`` SAMPLED rows record
+        both (0 by default: no sampled row does).  Rows outside the
+        sample are not looked at, so a row of the table may still record
+        two columns of one group; it is then read as recording the LATER
+        one (in the group's push order, ``feature_groups()``) only — the
+        group matrix holds one slot a row and group, and the fill writes
+        a group's columns in that order (``_bin_plan``, upstream's push
+        order).  Every other row is read exactly.  Counter
+        ``bin.bundle_conflicts_sampled``, where columns were bundled:
+        the sampled rows that the chosen grouping lets record two
+        columns of a group (0 unless ``max_conflict_rate`` allows
+        any)."""
         used = self.used_features
         max_error_cnt = int(total_sample * config.max_conflict_rate)
         filter_cnt = int(0.95 * config.min_data_in_leaf
@@ -697,7 +790,7 @@ class BinnedDataset:
         def find_groups(order):
             groups: List[List[int]] = []
             marks: List[np.ndarray] = []
-            conflict_cnt: List[int] = []
+            conflict_cnt: List[int] = []     # returned with the groups
             non_zero_cnt: List[int] = []
             num_bin: List[int] = []
             for f in order:
@@ -728,13 +821,15 @@ class BinnedDataset:
                     conflict_cnt.append(0)
                     non_zero_cnt.append(cur_nz)
                     num_bin.append(1 + extra_bins(f))
-            return groups
+            return groups, sum(conflict_cnt)
 
         order1 = list(used)
         order2 = sorted(used, key=lambda f: -nz_counts[f])
-        g1 = find_groups(order1)
-        g2 = find_groups(order2)
-        groups = g2 if len(g2) < len(g1) else g1
+        g1, c1 = find_groups(order1)
+        g2, c2 = find_groups(order2)
+        groups, conflicts = (g2, c2) if len(g2) < len(g1) else (g1, c1)
+        if len(groups) < len(used):      # something was bundled
+            obs.inc("bin.bundle_conflicts_sampled", conflicts)
 
         # take small sparse groups apart (dataset.cpp:185-205)
         out: List[List[int]] = []
@@ -828,6 +923,7 @@ class BinnedDataset:
                     pen[i] = float(fp[f])
         self.monotone_constraints = mono
         self.feature_penalty = pen
+        self._note_layout()
 
     # -- validation alignment ---------------------------------------------
     def _align_with_reference(self, data: np.ndarray,

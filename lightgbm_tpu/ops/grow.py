@@ -154,7 +154,17 @@ class FTables(NamedTuple):
     the compile request and key the program cache on bin boundary
     content instead of shape).  Only the fields ``FeatureMeta`` does NOT
     already carry — num_bin/default_bin/missing are read from ``meta``
-    so there is one source of truth per array."""
+    so there is one source of truth per array.
+
+    A feature's bins lie in column ``group`` of the binned matrix as the
+    values ``offset .. offset + width - 1``, in bin order: value 0 of a
+    group means "every feature of the group at its default bin", a
+    feature whose default bin is 0 has that bin's value dropped (``width``
+    is ``num_bin - 1`` and value ``offset`` is bin 1), and one whose
+    default bin is not 0 keeps a never-written value in its place.  With
+    one feature a group ``offset`` is 1; in a bundle (EFB) the features'
+    runs follow one another, a one-hot column being a run of one value
+    (``FeatureMeta``'s docstring has the histogram's side of it)."""
     group: jnp.ndarray         # (F,) int32
     offset: jnp.ndarray        # (F,) int32
     width: jnp.ndarray         # (F,) int32  num_bin - (default_bin == 0)
@@ -1686,7 +1696,8 @@ class DeviceGrower:
                               int(_jax.process_count()))
             self._upload_binned(dataset,
                                 self.deal.total - self.num_data)
-            self.meta = FeatureMeta.from_dataset(dataset, slot_stride=nb)
+            self.meta = FeatureMeta.from_dataset(dataset, slot_stride=nb,
+                                                  by_slots=True)
             self.hyper = SplitHyper.from_config(config)
             self.tables = FTables.from_dataset(dataset)
             self.lr = float(config.learning_rate)
@@ -1734,7 +1745,8 @@ class DeviceGrower:
 
         self._upload_binned(dataset, self.programs.n_pad - self.num_data)
 
-        self.meta = FeatureMeta.from_dataset(dataset, slot_stride=nb)
+        self.meta = FeatureMeta.from_dataset(dataset, slot_stride=nb,
+                                                  by_slots=True)
         self.hyper = SplitHyper.from_config(config)
         self.tables = FTables.from_dataset(dataset)
         self.lr = float(config.learning_rate)

@@ -36,6 +36,12 @@ reuse it (SURVEY.md §2.3-2.4):
   returning each feature's best candidate (no argmax);
 * ``select_and_pack``     — masked argmax + the packed 13-float record.
 
+``pick_best`` chains the three for one leaf.  For a dataset that bundles
+(EFB: more features than groups) it follows the slots the groups hold and
+not features x 256: one-hot columns are evaluated slot by slot on the
+flat histogram, the other features in classes of their own bin width
+(``FeatureMeta``); the feature-space chain above is its oracle.
+
 Serial chains all three on the full feature set; feature-parallel runs them
 per device on its feature shard and allreduces the packed record; voting
 runs ``per_feature_best`` on local histograms for the vote, then again on
@@ -45,7 +51,7 @@ the psum-reduced elected features.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -93,9 +99,42 @@ class FeatureMeta(NamedTuple):
     ``global_id`` carries each feature's index in the full (unsharded)
     feature list: the serial learner's identity mapping, a shard's
     assignment for feature-parallel.  All split records report global ids.
+
+    **The layout it describes.**  The flat histogram holds ``slot_stride``
+    slots a group.  Slot 0 of a group counts the rows at every one of its
+    features' default bins; feature ``sub`` of the group then owns the run
+    of slots from ``f_offset`` on, one per bin, in bin order — except that
+    a feature whose default bin is 0 has that bin's slot DROPPED (its run
+    starts at bin 1 and is ``num_bin - 1`` long), and a feature whose
+    default bin is not 0 keeps an always-empty slot in its place.  Rows at
+    a feature's default bin are written nowhere in its run; the scan gets
+    their sums back as ``leaf total - run sum`` (``reconstruct_default``).
+    With one feature a group (no bundling) a run is a group; a bundle of
+    one-hot columns is slot 0 plus one slot a column.
+
+    ``classes`` and ``slot_feature`` are empty for the feature-space scan
+    (every feature a row of 256 lanes).  ``from_dataset(by_slots=True)``
+    fills them for a dataset that bundles (more features than groups,
+    none categorical), so that the scan's lanes follow the slots the
+    groups hold and not features x 256:
+
+    * ``slot_feature`` ``(S,)``: for every slot of the flat histogram the
+      PLAIN TWO-BIN feature that owns it (two bins, default bin 0, no NaN
+      bin: a one-hot column, whose one slot is its bin 1), -1 elsewhere.
+      Such a feature has one candidate, "recorded or not", whose left
+      sums are ``leaf total - slot``; the scan evaluates it slot by slot
+      on the flat histogram itself, with no gather.
+    * ``classes``: the other features sorted into width classes, each a
+      ``FeatureMeta`` of its own whose bin axis is the smallest power of
+      two that holds the class's bins (``global_id`` = the feature's
+      index, ascending through the classes' concatenation after
+      ``unsort``).
+
+    Which scan runs is thereby a static property of the dataset (the
+    pytree's structure): no parameter selects it.
     """
-    slot_idx: jnp.ndarray        # (F, 256) int32, flat index into the hist
-    valid_nondefault: jnp.ndarray  # (F, 256) bool
+    slot_idx: jnp.ndarray        # (F, bins) int32, flat index into the hist
+    valid_nondefault: jnp.ndarray  # (F, bins) bool
     num_bin: jnp.ndarray         # (F,) int32
     default_bin: jnp.ndarray     # (F,) int32
     missing: jnp.ndarray         # (F,) int32 0/1/2 none/zero/nan
@@ -103,11 +142,16 @@ class FeatureMeta(NamedTuple):
     mono: jnp.ndarray            # (F,) int32
     penalty: jnp.ndarray         # (F,) float32
     global_id: jnp.ndarray       # (F,) int32
+    classes: tuple = ()          # width classes (FeatureMeta each), or ()
+    unsort: Optional[jnp.ndarray] = None   # (F_classes,) int32: a class
+    #                              feature's place in their concatenation
+    slot_feature: Optional[jnp.ndarray] = None   # (S,) int32, or None
 
     @classmethod
     def from_dataset(cls, dataset, feature_subset=None,
                      slot_base: int = 0,
-                     slot_stride: int = 256) -> "FeatureMeta":
+                     slot_stride: int = 256,
+                     by_slots: bool = False) -> "FeatureMeta":
         """Build metadata arrays; ``feature_subset`` (host int array) keeps
         only those used-feature indices (feature-parallel shards).  Entries
         of -1 in the subset are padding (masked via num_bin=1).
@@ -116,7 +160,11 @@ class FeatureMeta(NamedTuple):
         its own slots).  ``slot_stride`` is the per-group slot pitch of the
         flat histogram (256 for the host path; the device grower packs
         groups at the smallest power-of-two that fits, e.g. 64 for
-        max_bin=63, to keep the one-hot matmul narrow)."""
+        max_bin=63, to keep the one-hot matmul narrow).  ``by_slots``
+        asks for the slot-following description of a bundled layout (see
+        the class docstring); a dataset that does not bundle, or holds a
+        categorical feature, keeps the feature-space scan whatever it
+        says."""
         nb = dataset.f_num_bin.astype(np.int32)
         db = dataset.f_default_bin.astype(np.int32)
         off = dataset.f_offset.astype(np.int64)
@@ -143,10 +191,49 @@ class FeatureMeta(NamedTuple):
             - shift[:, None] - int(slot_base)
         valid = (b < nb[:, None]) & (b != db[:, None])
         slot = np.where(valid, slot, 0)
-        return cls(jnp.asarray(slot, jnp.int32), jnp.asarray(valid),
-                   jnp.asarray(nb), jnp.asarray(db), jnp.asarray(miss),
-                   jnp.asarray(cat), jnp.asarray(mono), jnp.asarray(pen),
-                   jnp.asarray(gid))
+
+        def build(rows, bins, ids):
+            return cls(jnp.asarray(slot[rows, :bins], jnp.int32),
+                       jnp.asarray(valid[rows, :bins]),
+                       jnp.asarray(nb[rows]), jnp.asarray(db[rows]),
+                       jnp.asarray(miss[rows]), jnp.asarray(cat[rows]),
+                       jnp.asarray(mono[rows]), jnp.asarray(pen[rows]),
+                       jnp.asarray(ids))
+
+        every = np.arange(len(nb))
+        full = build(every, 256, gid)
+        if not (by_slots and feature_subset is None and not cat.any()
+                and len(nb) > int(dataset.num_groups)):
+            return full
+        # plain two-bin features are scanned in slot space: their one
+        # slot (bin 1) names them
+        plain = (nb == 2) & (db == 0) & (miss != 2)
+        slot_feature = np.full(int(dataset.num_groups) * int(slot_stride),
+                               -1, np.int32)
+        slot_feature[slot[plain, 1]] = every[plain]
+        # the others by the smallest power of two that holds their bins
+        rest = every[~plain]
+        lanes = np.maximum(2, 1 << np.ceil(np.log2(np.maximum(nb[rest], 1)))
+                           .astype(np.int64))
+        order = np.argsort(lanes, kind="stable")
+        unsort = np.empty(len(rest), np.int32)
+        unsort[order] = np.arange(len(rest))
+        classes = tuple(build(rest[lanes == w], int(w), rest[lanes == w])
+                        for w in np.unique(lanes))
+        return full._replace(
+            classes=classes, unsort=jnp.asarray(unsort),
+            slot_feature=jnp.asarray(slot_feature) if plain.any() else None)
+
+    @property
+    def scan_lanes(self) -> int:
+        """Histogram lanes one leaf's scan works through: features x 256
+        in feature space; by slots the flat histogram's slots (once, for
+        the plain two-bin features) and features x class width summed
+        over the width classes."""
+        if not self.classes and self.slot_feature is None:
+            return int(self.slot_idx.size)
+        return sum(int(c.slot_idx.size) for c in self.classes) + (
+            0 if self.slot_feature is None else int(self.slot_feature.size))
 
 
 def _threshold_l1(s, l1):
@@ -191,7 +278,7 @@ def reconstruct_default(fh, total, meta: FeatureMeta):
     (FixHistogram, src/io/dataset.cpp:802-822).  dtype-generic: the
     int32 quantized scan reconstructs EXACTLY (integer subtraction),
     where the f32 path carries the usual accumulation rounding."""
-    b = jnp.arange(256, dtype=jnp.int32)[None, :]
+    b = jnp.arange(fh.shape[1], dtype=jnp.int32)[None, :]
     default_vals = total[None, :] - fh.sum(axis=1)
     default_vals = default_vals.at[:, 2].set(
         jnp.maximum(default_vals[:, 2], 0))
@@ -239,8 +326,8 @@ def per_feature_best(fh, total, constraint, meta: FeatureMeta,
     nb = meta.num_bin[:, None].astype(jnp.float32)       # (F,1)
     db = meta.default_bin[:, None]
     miss = meta.missing[:, None]
-    b = jnp.arange(256, dtype=jnp.int32)[None, :]        # (1,256)
-    nf = fh.shape[0]
+    nf, bins = fh.shape[0], fh.shape[1]                  # bins: 256, or
+    b = jnp.arange(bins, dtype=jnp.int32)[None, :]       # a class's width
 
     # =====================================================================
     # numerical
@@ -290,10 +377,10 @@ def per_feature_best(fh, total, constraint, meta: FeatureMeta,
     flat_ng = num_gains.reshape(nf, -1)
     num_arg = jnp.argmax(flat_ng, axis=1)                # first max: dir=-1
     num_best_gain = jnp.take_along_axis(flat_ng, num_arg[:, None], 1)[:, 0]
-    num_dl = num_arg < 256                               # v0 => default_left
-    num_thr = (num_arg % 256).astype(jnp.int32)
+    num_dl = num_arg < bins                              # v0 => default_left
+    num_thr = (num_arg % bins).astype(jnp.int32)
     num_left = jnp.take_along_axis(
-        lefts.reshape(nf, 512, 3), num_arg[:, None, None], 1)[:, 0]
+        lefts.reshape(nf, 2 * bins, 3), num_arg[:, None, None], 1)[:, 0]
 
     if not has_cat:
         return PerFeatureBest(
@@ -450,16 +537,128 @@ def min_gain_shift_of(total, hp: SplitHyper):
             + hp.min_gain_to_split)
 
 
+def _slot_space_best(flat_hist, total, total_f, constraint, feature_mask,
+                     meta: FeatureMeta, hp: SplitHyper, shift, scales):
+    """The best PLAIN TWO-BIN feature of one leaf, scanned where its sums
+    lie: every slot of the flat histogram is a candidate "its feature
+    recorded or not" (``meta.slot_feature``), the left child the rows
+    that do not record it, ``leaf total - slot`` (``reconstruct_default``
+    for a run of one slot), default_left as the feature-space scan gives
+    it (variant 0, threshold 0).  Returns ``(value, feature, left (3,))``:
+    the masked, shifted, penalised gain ``masked_feature_gain`` would give
+    the feature, the lowest feature index among equal values."""
+    tg, th, tc = total_f[0], total_f[1] + 2.0 * K_EPSILON, total_f[2]
+    own = meta.slot_feature >= 0
+    f = jnp.where(own, meta.slot_feature, 0)
+    left = total[None, :] - flat_hist
+    left = left.at[:, 2].set(jnp.maximum(left[:, 2], 0))
+    left_f = left if scales is None else left.astype(jnp.float32) * scales
+    gl, hl, cl = left_f[:, 0], left_f[:, 1] + K_EPSILON, left_f[:, 2]
+    gr, hr, cr = tg - gl, th - hl, tc - cl
+    data_ok = ((cl >= hp.min_data_in_leaf) & (cr >= hp.min_data_in_leaf)
+               & (hl >= hp.min_sum_hessian_in_leaf)
+               & (hr >= hp.min_sum_hessian_in_leaf))
+    gains = _split_gain(gl, hl, gr, hr, hp.lambda_l1, hp.lambda_l2,
+                        hp.max_delta_step, constraint[0], constraint[1],
+                        meta.mono[f])
+    gains = jnp.where(data_ok & (gains > shift), gains, NEG_INF)
+    value = jnp.where(feature_mask[f] & (meta.global_id[f] >= 0),
+                      (gains - shift) * meta.penalty[f], NEG_INF)
+    value = jnp.where(own, value, -jnp.inf)      # no feature: never wins
+
+    def better(a, b):
+        # the larger value; of equal values the lower feature index (the
+        # slots lie in push order, not in feature order).  One reduction
+        # over (value, feature, slot), so no value is compared with a
+        # copy of itself that another fusion computed
+        take_a = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return tuple(jnp.where(take_a, x, y) for x, y in zip(a, b))
+
+    top, feature, at = jax.lax.reduce(
+        (value, f, jnp.arange(f.shape[0], dtype=jnp.int32)),
+        (jnp.float32(-jnp.inf), jnp.int32(jnp.iinfo(jnp.int32).max),
+         jnp.int32(0)), better, (0,))
+    return top, feature, left[at]
+
+
+def _class_best(flat_hist, total, total_f, constraint, feature_mask,
+                meta: FeatureMeta, hp: SplitHyper, shift, scales):
+    """The best feature of the width classes: each class gathered and
+    scanned at its own bin width by ``per_feature_best`` — a feature's
+    lanes, prefix sums and reconstructed default bin are those of the
+    256-lane scan cut at the class width (the lanes past ``num_bin`` hold
+    zeros there and are never candidates) —, the arg-max in feature
+    order.  Returns ``(value, feature, threshold, default_left, left)``."""
+    cols = []
+    for sub in meta.classes:
+        pf = per_feature_best(feature_histograms(flat_hist, total, sub),
+                              total_f, constraint, sub, hp, False, shift,
+                              scales=scales)
+        value = masked_feature_gain(pf, sub, feature_mask[sub.global_id],
+                                    shift)
+        cols.append((value, sub.global_id, pf.threshold, pf.default_left,
+                     pf.left))
+    value, feature, threshold, default_left, left = (
+        jnp.concatenate(c)[meta.unsort] for c in zip(*cols))
+    k = jnp.argmax(value)
+    return value[k], feature[k], threshold[k], default_left[k], left[k]
+
+
+def pick_best(flat_hist, total, total_f, constraint, feature_mask,
+              meta: FeatureMeta, hp: SplitHyper, has_cat: bool, shift,
+              scales=None):
+    """Stages 1 to 3 for one leaf, by whichever layout ``meta`` describes:
+    ``(best, feat_gain, pf, global_id)`` for ``pack_best`` — the winner
+    as an index into the three arrays.  ``total`` is in the histogram's
+    units (int32 under the quantized scan), ``total_f`` in real ones.
+
+    In feature space the arrays hold every feature.  By slots (a bundled
+    dataset, ``FeatureMeta.from_dataset(by_slots=True)``) they hold the
+    finalists alone: the best plain two-bin feature, found in slot space,
+    and the best of the width classes; every feature's candidate
+    statistics, gain and masking are the feature-space scan's, term for
+    term, and ties go to the lower feature index as its arg-max sends
+    them."""
+    if not meta.classes and meta.slot_feature is None:
+        fh = feature_histograms(flat_hist, total, meta)
+        pf = per_feature_best(fh, total_f, constraint, meta, hp, has_cat,
+                              shift, scales=scales)
+        feat_gain = masked_feature_gain(pf, meta, feature_mask, shift)
+        return jnp.argmax(feat_gain), feat_gain, pf, meta.global_id
+    args = (flat_hist, total, total_f, constraint, feature_mask, meta, hp,
+            shift, scales)
+    finalists = []
+    if meta.slot_feature is not None:
+        value, feature, left = _slot_space_best(*args)
+        finalists.append((value, feature, jnp.int32(0), jnp.asarray(True),
+                          left))
+    if meta.classes:
+        finalists.append(_class_best(*args))
+    value, feature, threshold, default_left, left = (
+        jnp.stack(c) for c in zip(*finalists))
+    n = len(finalists)
+    pf = PerFeatureBest(value, threshold, default_left, left,
+                        jnp.zeros(n, bool), jnp.zeros((n, 256), bool),
+                        jnp.zeros(n, jnp.float32))
+    if n == 1:
+        best = jnp.int32(0)
+    else:
+        slot_wins = (value[0] > value[1]) | ((value[0] == value[1])
+                                             & (feature[0] < feature[1]))
+        best = jnp.where(slot_wins, 0, 1)
+    return best, value, pf, meta.global_id[feature]
+
+
 def find_best_split_impl(flat_hist, total, constraint, feature_mask,
                          meta: FeatureMeta, hp: SplitHyper, has_cat: bool):
     """The full serial chain (also the per-shard body for feature-parallel;
     shard-level reduction happens in the caller)."""
     shift = min_gain_shift_of(total, hp)
-    fh = feature_histograms(flat_hist, total, meta)
-    pf = per_feature_best(fh, total, constraint, meta, hp, has_cat, shift)
-    feat_gain = masked_feature_gain(pf, meta, feature_mask, shift)
-    best_f = jnp.argmax(feat_gain)
-    return pack_best(best_f, feat_gain, pf, total, constraint, hp, meta)
+    best, feat_gain, pf, gids = pick_best(
+        flat_hist, total, total, constraint, feature_mask, meta, hp,
+        has_cat, shift)
+    return pack_best(best, feat_gain, pf, total, constraint, hp,
+                     meta._replace(global_id=gids))
 
 
 def find_best_split_quant(flat_hist, total, scales, constraint,
@@ -486,13 +685,11 @@ def find_best_split_quant(flat_hist, total, scales, constraint,
     svec = jnp.concatenate([scales, jnp.ones((1,), jnp.float32)])
     total_f = total.astype(jnp.float32) * svec
     shift = min_gain_shift_of(total_f, hp)
-    fh = feature_histograms(flat_hist, total, meta)      # int32 exact
-    pf = per_feature_best(fh, total_f, constraint, meta, hp, has_cat,
-                          shift, scales=svec)
-    feat_gain = masked_feature_gain(pf, meta, feature_mask, shift)
-    best_f = jnp.argmax(feat_gain)
+    best_f, feat_gain, pf, gids = pick_best(
+        flat_hist, total, total_f, constraint, feature_mask, meta, hp,
+        has_cat, shift, scales=svec)                   # int32 exact
     packed, catm = pack_best(best_f, feat_gain, pf, total_f, constraint,
-                             hp, meta, scales=svec)
+                             hp, meta._replace(global_id=gids), scales=svec)
     return packed, catm, pf.left[best_f]
 
 
