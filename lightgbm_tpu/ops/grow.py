@@ -125,13 +125,28 @@ REC_F_FIELDS = 9    # gain, lg, lh, lc, rg, rh, rc, left_out, right_out
 REC_F_LEFT_OUT = 7
 REC_F_RIGHT_OUT = 8
 
-# above this many rows a single f32 count cell can exceed 2^24 and lose
-# integer exactness; the wave matmul then carries TWO striped count
-# columns (each stripe < 2^24 rows, summed after accumulation — final
-# count error <= 1 ulp instead of unbounded drift).  Module-level so
-# tests can force the striped path on small data.  The int8 quantized
-# path stripes its g/h columns at the same threshold: 127 * 2^24 stays
-# below the int32 accumulator limit per stripe.
+# the most rows ONE accumulator cell of the wave matmul can count
+# exactly; a row bucket LARGER than this carries its counts (and under
+# int8 its g/h) in TWO stripes of n_pad // 2 rows each, summed after the
+# accumulation.  A bucket of exactly this many rows holds at most this
+# many rows (it is a multiple of _CHUNK, so n_pad equals it, and the
+# plan probes, which weight every padded row, count n_pad), and each
+# layout that shares the bound holds AT it, not only below it:
+#   * bfloat16 operands, float32 accumulation (K = 3, and the count
+#     column of gpu_use_dp's K = 5): a cell adds 0/1 values, so every
+#     partial sum in any order is an integer <= 2^24, and float32 holds
+#     every integer up to and INCLUDING 2^24 (512 additions of 32,768.0
+#     reach 16,777,216.0; the next + 1.0 is the first to be lost:
+#     tests/test_grow.py);
+#   * int8 operands, int32 accumulation (grad_quant_bits=8, K = 3):
+#     |sum q| <= 127 * 2^24 = 2,130,706,432 < 2^31.
+# So stripes are taken where the bucket is larger than the bound, not
+# where it reaches it: 8,388,609 to 16,777,216 real rows (per shard on a
+# mesh, where each shard sums its stripes before the psum) run one count
+# column and a 128-wide last stage where they ran two and 96.  The
+# layout is a static function of the bucket (num_valid stays traced: one
+# program a bucket).  Module-level so tests can force the striped path
+# on small data.
 COUNT_SPLIT_ROWS = 1 << 24
 
 # int32 find-best scan eligibility (grad_quant_bits=8): every histogram
@@ -205,7 +220,7 @@ def _combine_hist_cols(h, k: int):
         return _jnp.stack([h[..., 0] + h[..., 1], h[..., 2] + h[..., 3],
                            h[..., 4]], axis=-1)
     if k == 4:
-        # each stripe accumulated < 2^24 rows exactly; the sum is exact
+        # each stripe accumulated <= 2^24 rows exactly; the sum is exact
         # to <= 1 ulp at up to 2 * COUNT_SPLIT_ROWS rows
         return _jnp.stack([h[..., 0], h[..., 1], h[..., 2] + h[..., 3]],
                           axis=-1)
@@ -228,10 +243,11 @@ def _hi_lo_cols(grad, hess, one):
 
 
 def _hist_layout(num_data: int, config):
-    """(quant_bits, striped, hist_cols) for this row count + config."""
+    """(quant_bits, striped, hist_cols) for this row bucket (per shard
+    on a mesh) + config; see ``COUNT_SPLIT_ROWS`` for the bound."""
     dp = bool(getattr(config, "gpu_use_dp", False))
     quant_bits = int(getattr(config, "grad_quant_bits", 0) or 0)
-    striped = int(num_data) >= COUNT_SPLIT_ROWS
+    striped = int(num_data) > COUNT_SPLIT_ROWS
     if quant_bits:
         # striped mode stripes g/h too: 127 * 2^24 per stripe stays
         # inside the int32 accumulator
@@ -249,6 +265,25 @@ def _wave_width(num_leaves: int, hist_cols: int) -> int:
     scale = 3.0 / hist_cols
     wmax = max(int(128 * scale), 4)
     return min(wmax, max(int(num_leaves) - 1, 1))
+
+
+def _holds_underfull(ws: int, next_ws: int, next_cap, hist_cols: int) -> bool:
+    """Whether the stage ``ws`` wide keeps a frontier that has reached
+    its cap for as long as its waves come back under-full: only ahead
+    of a CLOSING stage (``next_cap`` None) that takes more than two
+    tiles of 128 stat columns where this one takes at most two.  Every
+    wave costs its WIDTH whatever it splits, and only a frontier that
+    fills the narrower stage (its last wave applied a split in every
+    slot) can use the slots of a third tile — 128 leaves to 255 in ONE
+    wave on dense numeric data.  A tree held back by
+    ``min_sum_hessian_in_leaf`` or one-hot peels closes in two or three
+    waves at any width, so it takes them at two tiles.  Measured
+    (PERF.md section 6, PR 35): the Allstate table's trees never fill a
+    128-wide wave — closed at 128 slots they grow 0.720 trees/s where
+    the striped layout's 96 grew 0.770, closed at two tiles 0.83-0.86 —
+    while the Criteo rows' go from 128 leaves to 255 in one wave."""
+    return next_cap is None \
+        and int(ws) * hist_cols <= 256 < int(next_ws) * hist_cols
 
 
 def default_stage_plan(num_data: int, config) -> list:
@@ -319,15 +354,24 @@ class GrowerPrograms:
             else shard.n_shards * self.n_pad
         self.int_scan = bool(self.quant_bits) \
             and int_rows <= INT32_SCAN_ROWS
-        # Wave cost measured on an earlier backend's chip (10.5M
-        # rows): ~15.9 ms fixed (the one-hot operand generation
-        # over all N, width-independent) + ~0.203 ms per stat column —
-        # LINEAR in columns, not column-tile-quantized, and 72% of MXU
-        # peak at 2 tiles (hist3_w84: 67.1 ms, 141.7 TF).  Since a wave
-        # can split at most the current frontier, the cheapest plan
-        # width-matches each stage to the frontier (doubling) and ends
-        # with one very wide multi-tile wave for the tail.  gpu_use_dp
-        # (k=5) scales each width down by 3/k to hold the column budget.
+        # Wave cost on the TPU v5 lite (2^24 rows x 67 groups x 256 bin
+        # lanes, every row live; scripts/bench_wave_hist.py --cols 3,4,
+        # PR 35): ~0.19 s for the scan of rows x groups x bin lanes
+        # whatever the width, then the lanes, paid in whole MXU tiles
+        # of 128 stat COLUMNS and not column by column — 0.26 s at 8
+        # slots (K = 3 and K = 4 alike), 0.42 at 32 (96 / 128 columns:
+        # one tile), 0.82 / 0.83 at 64 (192 / 256: two), 1.49 / 1.44 /
+        # 1.42 at 96 x 3, 128 x 3 and 96 x 4 (288 / 384 / 384: three),
+        # 1.66 at 128 x 4 (four); 85 x 3 (255: two) 0.84.  A slot of
+        # the last tile is free, so the last stage is as wide as three
+        # tiles hold: 128 leaves of K = 3 columns, 96 of K = 4, 76 of
+        # gpu_use_dp's K = 5.  Since a wave can split at most the
+        # current frontier, the cheapest plan width-matches each stage
+        # to the frontier (doubling) and ends with one multi-tile wave
+        # for the tail: at K = 3, 255 leaves in the least 8 waves there
+        # are (128 -> 255 in one), each at the fewest tiles its
+        # frontier fits.  A tree that cannot fill the stage before
+        # closes there, at two tiles (_holds_underfull, in _grow_impl).
         self.wave_width = _wave_width(self.num_leaves, self.hist_cols)
         self.compact_max_live = _COMPACT_MAX_LIVE
         # plan is required and resolved by get_grower_programs (its
@@ -436,7 +480,9 @@ class GrowerPrograms:
         reduction order is the compiled program's — deterministic
         run-to-run) and counts as int32, keeping row counts exact past
         2^24 global rows (per-shard counts are integer-exact by the
-        striping layout, so the cast is exact)."""
+        stat-column layout — one column up to 2^24 rows a shard, two
+        stripes summed before this point past it — so the cast is
+        exact)."""
         sp = self.shard
         if sp is None:
             return hist
@@ -767,7 +813,7 @@ class GrowerPrograms:
             gcols = [grad.astype(jnp.bfloat16) * one,
                      hess.astype(jnp.bfloat16) * one]
         if k in (4, 6):
-            # two striped count columns (< 2^24 rows each) keep counts
+            # two striped count columns (<= 2^24 rows each) keep counts
             # integer-exact beyond the single-column f32 limit
             stripe = (jnp.arange(n) < (n // 2)).astype(jnp.bfloat16)
             gcols += [one * stripe, one * (1.0 - stripe)]
@@ -1194,12 +1240,20 @@ class GrowerPrograms:
 
         plan = self.stage_plan
         st = init
-        for ws, cap in plan:
+        for i, (ws, cap) in enumerate(plan):
             st = resize(st, ws)
             limit = L if cap is None else min(cap, L)
-            st = jax.lax.while_loop(
-                lambda s, lim=limit: (~s.done) & (s.nl < lim),
-                make_wave(ws), st)
+            if i + 1 < len(plan) and _holds_underfull(
+                    ws, *plan[i + 1], self.hist_cols):
+                # hand over to the wide closing stage only when the last
+                # wave filled this one (_holds_underfull); replicated
+                # state decides, so every shard of a mesh agrees
+                go_on = lambda s, lim=limit: (~s.done) & (
+                    (s.nl < lim)
+                    | ((s.nl < L) & ~jnp.all(s.p_small >= 0)))
+            else:
+                go_on = lambda s, lim=limit: (~s.done) & (s.nl < lim)
+            st = jax.lax.while_loop(go_on, make_wave(ws), st)
         final = st
         leaf_final = final.leaf_id
         rec_f_out = final.rec_f
@@ -1530,11 +1584,14 @@ def programs_signature(num_data: int, num_groups: int, nb: int,
     full config (hashed — over-keying only costs cache hits, never
     correctness).  Sharded programs append the mesh size plus the
     canonical global draw shapes (``num_data`` is then the per-shard
-    row bucket); unsharded signatures keep the historical layout so
-    persisted stage plans stay valid."""
+    row bucket).  The stat columns are in it by value: ``num_data`` and
+    ``COUNT_SPLIT_ROWS`` do not say on which side of the bound the rule
+    puts a bucket that equals it, and a plan timed for four columns and
+    a 96-wide last stage is not one for three and 128."""
     base = (num_data, num_groups, nb, num_features, bool(has_cat),
             _CHUNK, COUNT_SPLIT_ROWS, INT32_SCAN_ROWS,
-            _config_digest(config))
+            _config_digest(config),
+            ("hist_cols", _hist_layout(num_data, config)[2]))
     if shard is not None:
         base = base + (("shard", shard.n_shards, shard.global_rows,
                         shard.draw_npad, shard.bag_npad),)
@@ -1686,6 +1743,7 @@ class DeviceGrower:
                                 int(self.programs.n_pad))
             self._row_pad = 0
             counts = [c for _, c in self.deal.spans]
+            self._gauge_layout()
             obs.set_gauge("shard.devices", d)
             obs.set_gauge("shard.local_rows", int(self.programs.n_pad))
             obs.set_gauge("shard.rows_real_min", min(counts))
@@ -1742,6 +1800,7 @@ class DeviceGrower:
             int(dataset.num_features), has_cat, config)
         self._num_valid = jnp.asarray(self.num_data, jnp.int32)
         self._row_pad = self.row_bucket - self.num_data
+        self._gauge_layout()
 
         self._upload_binned(dataset, self.programs.n_pad - self.num_data)
 
@@ -1750,6 +1809,14 @@ class DeviceGrower:
         self.hyper = SplitHyper.from_config(config)
         self.tables = FTables.from_dataset(dataset)
         self.lr = float(config.learning_rate)
+
+    def _gauge_layout(self):
+        """What the stat-column rule gave this grower's row bucket: the
+        columns a leaf takes in the wave matmul and the last stage's
+        width that follows from them (3 and 128 up to 2^24 rows a
+        bucket at 255 leaves, 4 and 96 past it)."""
+        obs.set_gauge("grow.hist_cols", int(self.programs.hist_cols))
+        obs.set_gauge("grow.wave_width", int(self.programs.wave_width))
 
     def _upload_binned(self, dataset, pad: int):
         """Upload the (N, G) binned matrix padded by ``pad`` rows, plus
@@ -2201,7 +2268,7 @@ def device_growth_eligible(config, dataset, objective, num_model,
         return False
     if getattr(config, "forcedsplits_filename", ""):
         return False
-    # single f32 count columns are exact below COUNT_SPLIT_ROWS (2^24);
+    # single f32 count columns are exact up to COUNT_SPLIT_ROWS (2^24);
     # the striped two-column layout extends that to twice the threshold
     # (the int8 path's striped int32 g/h accumulators share the bound).
     # The bound is per-ACCUMULATOR, i.e. per shard: a single-controller

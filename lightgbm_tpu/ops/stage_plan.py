@@ -37,8 +37,12 @@ import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
-# constants measured on an earlier backend's chip at 10.5M rows:
-# ~15.9 ms fixed one-hot operand generation + ~0.203 ms per stat column.
+# an EARLIER backend's readings (its chip, 10.5M rows, 63 bins): ~15.9 ms
+# fixed one-hot operand generation + ~0.203 ms per stat column.  They are
+# not the TPU v5 lite's (ops/grow.py has those beside ``wave_width``: its
+# lanes are paid in tiles of 128 columns) and stay only as the byte-stable
+# fallback of ``fit_wave_costs`` where a fit degenerates; every width a
+# plan can hold is probed, so no derived plan is priced by them.
 # Both terms contract over all N rows, so ``fit_wave_costs`` scales them
 # linearly by rows/REF_ROWS when falling back for a different shape.
 DEFAULT_FIXED_MS = 15.9

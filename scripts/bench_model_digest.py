@@ -9,7 +9,11 @@ and writes the SHA-256 and length of the text the kind's one
 ``chiprun_out/model_digest.<tag>.json``.  Two commits that print the same
 digest at the same seed trained byte-identical models at the cell's full
 size; no file of the harness is edited and the timed window is untouched
-(the kind asks for the text after the window has closed).
+(the kind asks for the text after the window has closed).  After the run
+it adds what the growth programs were built with: the ``grow.*`` and
+``shard.*`` gauges (``grow.hist_cols``, ``grow.wave_width``, the plan
+probes' ``grow.fused.w<W>_ms`` where they ran) and each cached
+``GrowerPrograms``' row bucket, stat columns, stage plan and its source.
 """
 
 import argparse
@@ -22,10 +26,35 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def programs_built() -> dict:
+    """Gauges and stage plans of the growth programs this process built
+    (attributes an older tree lacks are left out)."""
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.ops import grow
+
+    gauges = obs.registry().snapshot()["gauges"]
+    fields = ("num_data", "hist_cols", "wave_width", "stage_plan",
+              "plan_source")
+    return {"gauges": {k: v for k, v in sorted(gauges.items())
+                       if k.startswith(("grow.", "shard."))},
+            "programs": [{f: getattr(p, f) for f in fields
+                          if hasattr(p, f)}
+                         for p in grow._PROGRAM_CACHE.values()]}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tag", required=True)
     args, rest = ap.parse_known_args(argv)
+    path = os.path.join(ROOT, "chiprun_out",
+                        f"model_digest.{args.tag}.json")
+    # a kind that reads its trees through dump_model leaves no digest
+    rec = {"tag": args.tag, "argv": rest}
+
+    def write():
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(rec, f)
 
     from benchmark import run as bench_run
 
@@ -40,22 +69,22 @@ def main(argv=None) -> int:
 
             def digesting(self, *a, **kw):
                 text = to_string(self, *a, **kw)
-                rec = {"tag": args.tag, "argv": rest,
-                       "sha256": hashlib.sha256(text.encode()).hexdigest(),
-                       "chars": len(text)}
+                rec.update(
+                    sha256=hashlib.sha256(text.encode()).hexdigest(),
+                    chars=len(text))
                 sys.stderr.write(f"model digest {json.dumps(rec)}\n")
-                out = os.path.join(ROOT, "chiprun_out")
-                os.makedirs(out, exist_ok=True)
-                with open(os.path.join(
-                        out, f"model_digest.{args.tag}.json"), "w") as f:
-                    json.dump(rec, f)
+                write()
                 return text
 
             lgb.Booster.model_to_string = digesting
         return load_plugin(folder, name)
 
     bench_run.load_plugin = load_and_wrap
-    return bench_run.main(rest)
+    rc = bench_run.main(rest)
+    rec.update(programs_built())
+    sys.stderr.write(f"programs built {json.dumps(rec)}\n")
+    write()
+    return rc
 
 
 if __name__ == "__main__":

@@ -7,12 +7,20 @@ on the live share has it (``as_is``).
     python3 scripts/bench_wave_hist.py --shape criteo --live 0.45 \\
         --variants "on:4,8,16,32,96 off:4,8,16"
 
+``--cols 3|4`` chooses the stat-column layout whatever the shape's row
+bucket (one count column, or two striped ones), so one call gives the
+table of both layouts over the widths:
+
+    python3 scripts/bench_wave_hist.py --shape criteo --cols 3,4 \\
+        --live 1.0,0.33
+
 Lines go to stdout and to ``chiprun_out/wave_hist_bench.jsonl``.
 ``--repo`` runs another unpacked tree (one before the compaction routes
 ``on`` and ``off`` by its own constant, ``_GATHER_MIN_LANES``).
 """
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -27,7 +35,10 @@ def main(argv=None):
     ap.add_argument("--repo", default=".")
     ap.add_argument("--tag", default="")
     ap.add_argument("--shape", default="criteo")
-    ap.add_argument("--widths", default="4,8,32,96")
+    ap.add_argument("--widths", default="4,8,32,64,96,128")
+    ap.add_argument("--cols", default="",
+                    help="comma-separated 3 | 4: the layout(s) to time, "
+                         "in place of the one the row bucket takes")
     ap.add_argument("--live", default="1.0,0.45")
     ap.add_argument("--variants", default="as_is",
                     help="space-separated as_is | on:W,W | off:W,W")
@@ -52,7 +63,13 @@ def main(argv=None):
     for v in args.variants.split():
         name, _, ws = v.partition(":")
         todo += [(name, int(w)) for w in (ws or args.widths).split(",")]
-    for variant, w in todo:
+    bound = growmod.COUNT_SPLIT_ROWS
+    layouts = [int(c) for c in args.cols.split(",") if c] or [None]
+    for cols, (variant, w) in itertools.product(layouts, todo):
+        if cols is not None:
+            # read when the programs object is built, as the tests do
+            # it: every bucket striped, or none
+            growmod.COUNT_SPLIT_ROWS = 0 if cols == 4 else 1 << 62
         if variant != "as_is":
             # read when the programs object is built: every share of
             # live rows compacts, or none does
@@ -63,6 +80,8 @@ def main(argv=None):
             has_cat=False, plan=[(w, None)],
             config=Config({"objective": "binary", "num_leaves": leaves,
                            "verbosity": -1}))
+        growmod.COUNT_SPLIT_ROWS = bound
+        assert cols in (None, progs.hist_cols), (cols, progs.hist_cols)
         ghk, _ = progs._stat_columns(grad, hess,
                                      jnp.ones((n,), jnp.float32), 0)
         pend = jnp.arange(w, dtype=jnp.int32)

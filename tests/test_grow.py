@@ -777,40 +777,181 @@ def test_device_dart_rf_match_host(reg_data, boosting):
                                atol=5e-3)
 
 
-@pytest.mark.parametrize("extra,striped_cols,plain_cols", [
-    ({}, 4, 3),
-    ({"gpu_use_dp": True}, 6, 5),
-], ids=["plain", "gpu_use_dp"])
-def test_striped_count_columns_match_default(reg_data, extra,
-                                             striped_cols, plain_cols):
-    """N >= COUNT_SPLIT_ROWS switches the wave matmul to two striped
-    count columns (hist_cols 3->4, and 5->6 under gpu_use_dp so the
-    extra-precision path does not reintroduce the single-column count
-    overflow).  Forced on small data, the striped device trees must
-    match the default device layout exactly: identical g/h columns and
-    counts exact in both layouts at this size (the stripe only changes
-    the matmul's column split, summed back before any consumer)."""
+# layout -> (params, plain columns, striped columns)
+_BOUND_LAYOUTS = {"bf16": ({}, 3, 4),
+                  "gpu_use_dp": ({"gpu_use_dp": True}, 5, 6),
+                  "int8": ({"grad_quant_bits": 8}, 3, 6)}
+
+
+@pytest.mark.parametrize("layout", list(_BOUND_LAYOUTS))
+@pytest.mark.parametrize("rows_past", [-1, 0, 1],
+                         ids=["under", "at", "past"])
+def test_hist_layout_at_the_bound(layout, rows_past):
+    """Stripes are taken where a row bucket is LARGER than what one
+    accumulator cell counts exactly, not where it reaches it: 2^24 - 1
+    and 2^24 rows run the plain columns, 2^24 + 1 the striped ones."""
+    from lightgbm_tpu.ops import grow as growmod
+    extra, plain_cols, striped_cols = _BOUND_LAYOUTS[layout]
+    assert growmod.COUNT_SPLIT_ROWS == 1 << 24
+    cfg = Config({"objective": "binary", "verbosity": -1, **extra})
+    quant, striped, cols = growmod._hist_layout((1 << 24) + rows_past, cfg)
+    assert quant == extra.get("grad_quant_bits", 0)
+    assert striped == (rows_past > 0)
+    assert cols == (striped_cols if striped else plain_cols)
+    # the last stage follows the columns: 128 lanes of three
+    assert growmod._wave_width(255, cols) == min(128 * 3 // cols, 254)
+
+
+def test_one_cell_counts_the_bound_exactly():
+    """The arithmetic the bound rests on.  float32 holds every integer
+    up to AND including 2^24: 512 additions of a chunk's 32,768 rows
+    reach 16,777,216.0, and the next single row is the first one lost.
+    An int8 cell of |q| <= 127 over 2^24 rows stays inside int32."""
+    acc = np.float32(0.0)
+    for _ in range(512):
+        acc = np.float32(acc + np.float32(32768.0))
+    assert acc == np.float32(16777216.0) and int(acc) == 1 << 24
+    assert np.float32(np.float32((1 << 24) - 1) + np.float32(1.0)) == acc
+    assert np.float32(acc + np.float32(1.0)) == acc      # 2^24 + 1: lost
+    assert 127 * (1 << 24) == 2_130_706_432 < (1 << 31)
+    from lightgbm_tpu.ops import grow as growmod
+    assert growmod.COUNT_SPLIT_ROWS * 127 <= np.iinfo(np.int32).max
+    assert growmod.COUNT_SPLIT_ROWS % 32768 == 0   # n_pad == the bucket
+
+
+@pytest.mark.parametrize("ws,nxt,cols,want", [
+    (64, (128, None), 3, True),     # the three-column ladder: 192 | 384
+    (64, (96, None), 4, True),      # the striped layout's: 256 | 384
+    (48, (96, None), 4, True),      # ... and its default ladder
+    (32, (64, 128), 3, False),      # the next stage is no closing one
+    (8, (30, None), 4, False),      # 31 leaves close in one tile as it is
+    (64, (85, None), 3, False),     # two tiles close it already
+    (96, (128, None), 3, False),    # three tiles here: nothing to save
+])
+def test_holds_underfull(ws, nxt, cols, want):
+    from lightgbm_tpu.ops.grow import _holds_underfull
+    assert _holds_underfull(ws, *nxt, cols) is want
+
+
+def _close_case(kind):
+    rng = np.random.default_rng(0)
+    n = 20000
+    if kind == "fills":
+        # dense numeric rows: 64 leaves to 128 in one full wave
+        x = rng.standard_normal((n, 8)).astype(np.float32)
+        y = (x[:, 0] + x[:, 1] * x[:, 2]
+             + 0.5 * rng.standard_normal(n) > 0).astype(np.float32)
+        return x, y, {"min_data_in_leaf": 1}
+    # sparse indicator columns: a split peels a few rows off, so the
+    # frontier never fills its stage
+    x = (rng.random((n, 40)) < 0.03).astype(np.float32)
+    y = (x[:, :10].sum(1) + 0.3 * rng.standard_normal(n)
+         > 0.4).astype(np.float32)
+    return x, y, {"min_data_in_leaf": 5}
+
+
+@pytest.mark.parametrize("kind", ["fills", "underfull"])
+def test_closing_stage_follows_the_fill_the_tree_has_shown(kind):
+    """255 leaves, three stat columns: a tree whose 64-wide stage ended
+    on a full wave closes at the plan's 128 slots, to the slot the
+    program without the rule runs; one that never fills its stage stays
+    in it (two tiles of stat columns where the closing stage takes
+    three) and pays fewer slots for the same trees."""
+    import jax
+    from lightgbm_tpu import obs
     import lightgbm_tpu.ops.grow as growmod
+
+    x, y, extra = _close_case(kind)
+    was_enabled = obs.enabled()
+    obs.configure(enabled=True)
+
+    def work():
+        c = obs.registry().snapshot()["counters"]
+        return np.asarray([c.get("grow.waves", 0),
+                           c.get("grow.wave_slots", 0)], np.int64)
+
+    def grow(adaptive):
+        old = growmod._holds_underfull
+        try:
+            if not adaptive:
+                growmod._holds_underfull = lambda *a: False
+            # grower_cache off: the rule is no part of the programs' key
+            bst = _make({"objective": "binary", "num_leaves": 255,
+                         "max_bin": 63, "min_sum_hessian_in_leaf": 1e-3,
+                         "grower_cache": False, "verbosity": -1,
+                         **extra}, x, y, True)
+            assert bst._grower.hist_cols == 3
+            assert bst._grower.stage_plan[-2:] == [(64, 128), (128, None)]
+            before = work()
+            bst.train_chunked(2, chunk=2)
+            jax.block_until_ready(bst.train_score)
+            bst._flush_pending()
+            return work() - before, \
+                bst.model_to_string().split("\nparameters:")[0]
+        finally:
+            growmod._holds_underfull = old
+
+    try:
+        (waves, slots), trees = grow(True)
+        (waves_ref, slots_ref), trees_ref = grow(False)
+    finally:
+        if not was_enabled:
+            obs.configure(enabled=False)
+    assert trees == trees_ref and "Tree=1" in trees
+    if kind == "fills":
+        assert (waves, slots) == (waves_ref, slots_ref)
+    else:
+        # the closing waves ran 64 wide where the plan's are 128
+        assert waves >= waves_ref and slots < slots_ref
+
+
+@pytest.mark.parametrize("layout", list(_BOUND_LAYOUTS))
+@pytest.mark.parametrize("bound", ["at", "past", "far_past"])
+def test_striped_count_columns_match_default(reg_data, layout, bound):
+    """A row bucket LARGER than ``COUNT_SPLIT_ROWS`` switches the wave
+    matmul to two striped count columns (hist_cols 3 -> 4, 5 -> 6 under
+    gpu_use_dp so the extra-precision path does not reintroduce the
+    single-column count overflow, and 3 -> 6 under int8, whose g/h are
+    striped too).  With the bound forced to the data's own bucket, the
+    bucket that EQUALS it runs the plain columns and one row past it the
+    striped ones; either grows the trees (splits, thresholds,
+    ``leaf_count``) of the module's own bound: identical g/h columns
+    and counts exact in both layouts at this size (the stripe only
+    changes the matmul's column split, summed back before any
+    consumer)."""
+    import lightgbm_tpu.ops.grow as growmod
+    extra, plain_cols, striped_cols = _BOUND_LAYOUTS[layout]
     x, y = reg_data
     params = {"objective": "regression", "num_leaves": 31,
               "min_data_in_leaf": 20, **extra}
+    ref = _make(params, x, y, True)
+    bucket = ref._grower.row_bucket       # int8 keeps its exact rows
+    assert ref._grower.hist_cols == plain_cols
     old = growmod.COUNT_SPLIT_ROWS
     try:
-        # threshold <= N < 2x threshold keeps the config device-eligible
-        growmod.COUNT_SPLIT_ROWS = 3000
-        bs = _make(params, x, y, True)
-        assert bs._grower is not None
-        assert bs._grower.hist_cols == striped_cols
-        growmod.COUNT_SPLIT_ROWS = old
-        bp = _make(params, x, y, True)
-        assert bp._grower.hist_cols == plain_cols
-        for _ in range(5):
-            bs.train_one_iter()
-            bp.train_one_iter()
-        bs._flush_pending()
-        bp._flush_pending()
-        np.testing.assert_allclose(np.asarray(bs.predict(x[:256])),
-                                   np.asarray(bp.predict(x[:256])),
-                                   rtol=1e-5, atol=1e-6)
+        # bound <= N < 2x bound keeps the config device-eligible
+        growmod.COUNT_SPLIT_ROWS = {"at": bucket, "past": bucket - 1,
+                                    "far_past": 3000}[bound]
+        forced = _make(params, x, y, True)
     finally:
         growmod.COUNT_SPLIT_ROWS = old
+    striped = bound != "at"
+    assert forced._grower.row_bucket == bucket
+    assert forced._grower.programs.striped == striped
+    assert forced._grower.hist_cols \
+        == (striped_cols if striped else plain_cols)
+    for _ in range(5):
+        ref.train_one_iter()
+        forced.train_one_iter()
+    ref._flush_pending()
+    forced._flush_pending()
+    for tr, tf in zip(ref.models, forced.models):
+        assert _split_set(tr) == _split_set(tf)
+        assert np.array_equal(tr.leaf_count[:tr.num_leaves],
+                              tf.leaf_count[:tf.num_leaves])
+        assert int(np.sum(tf.leaf_count[:tf.num_leaves])) == len(y)
+    np.testing.assert_allclose(np.asarray(forced.predict(x[:256])),
+                               np.asarray(ref.predict(x[:256])),
+                               rtol=1e-5, atol=1e-6)
+
+

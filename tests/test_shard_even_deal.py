@@ -338,6 +338,9 @@ def test_fullest_shards_live_rows_bound_the_mean():
     assert sent == slots * bst._grower.num_slots * 12
     g = obs.registry().snapshot()["gauges"]
     assert (g["shard.rows_real_min"], g["shard.rows_real_max"]) == (625, 626)
+    # the stat-column layout a shard's bucket took, beside shard.devices
+    assert (g["shard.devices"], g["grow.hist_cols"],
+            g["grow.wave_width"]) == (4, 3, BASE["num_leaves"] - 1)
     xb, yb = _data(600)
     (live, top, _, _), _ = run(np.tile(xb, (4, 1)), np.tile(yb, 4))
     assert 4 * top == live

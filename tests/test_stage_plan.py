@@ -8,6 +8,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 from lightgbm_tpu.ops import stage_plan as sp
 
@@ -190,6 +191,82 @@ def test_auto_grower_adopts_persisted_plan(private_cache_dir):
         sp.forget_plan(sig)
 
 
+@pytest.mark.parametrize("saved_striped", [True, False],
+                         ids=["striped_plan_for_plain_program",
+                              "plain_plan_for_striped_program"])
+def test_plan_of_one_layout_is_not_adopted_by_the_other(
+        private_cache_dir, monkeypatch, saved_striped):
+    """A bucket of exactly the bound's rows, the same module constants
+    and config: the signature tells the four-column layout (last stage
+    96) from the three-column one (last stage 128), so a plan persisted
+    for one is not loaded by the other's programs.  The four-column
+    side is the rule as it stood before such a bucket took plain
+    columns: stripes where the bucket REACHES the bound."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops import grow
+
+    rows = 4096
+    monkeypatch.setattr(grow, "COUNT_SPLIT_ROWS", rows)
+    cfg = Config({"objective": "binary", "num_leaves": 255,
+                  "verbosity": -1, "seed": 424335})
+    shape = (rows, 3, 64, 3, False, cfg)
+    past = grow._hist_layout
+    rules = {False: past, True: lambda n, config: past(n + 1, config)}
+
+    def under(striped):
+        monkeypatch.setattr(grow, "_hist_layout", rules[striped])
+
+    sigs, plans = {}, {}
+    for striped in rules:
+        under(striped)
+        sigs[striped] = grow.programs_signature(*shape)
+        plans[striped] = grow.default_stage_plan(rows, cfg)
+        sp.forget_plan(sigs[striped])
+    assert sigs[True] != sigs[False]
+    assert (plans[True][-1], plans[False][-1]) \
+        == ((96, None), (128, None))
+    custom = [(8, 16), plans[saved_striped][-1]]
+    try:
+        under(saved_striped)
+        sp.save_plan(sigs[saved_striped], custom)
+        own = grow.get_grower_programs(*shape)
+        assert (own.stage_plan, own.plan_source) == (custom, "persisted")
+        under(not saved_striped)
+        other = grow.get_grower_programs(*shape)
+        assert other.plan_source == "default"
+        assert other.stage_plan == plans[not saved_striped]
+        assert (other.hist_cols, other.wave_width) \
+            == ((3, 128) if saved_striped else (4, 96))
+    finally:
+        for sig in sigs.values():
+            sp.forget_plan(sig)
+            with grow._PROGRAM_CACHE_LOCK:
+                for key in [k for k in grow._PROGRAM_CACHE
+                            if k[:len(sig)] == sig]:
+                    grow._PROGRAM_CACHE.pop(key)
+
+
+@pytest.mark.parametrize("rows_past,ladder,slots,waves", [
+    (0, [4, 16, 32, 64, 128], 268, 8),
+    (1, [4, 16, 24, 48, 96], 332, 10),
+], ids=["at_the_bound_k3", "past_the_bound_k4"])
+def test_default_plan_follows_the_stat_columns_at_the_bound(
+        monkeypatch, rows_past, ladder, slots, waves):
+    """255 leaves in a bucket of exactly the (forced) bound's rows grow
+    by the three-column ladder, 4/4/4/16/16/32/64/128; one row more and
+    the striped layout's 96-wide ladder takes ten waves."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops import grow
+
+    monkeypatch.setattr(grow, "COUNT_SPLIT_ROWS", 4096)
+    cfg = Config({"objective": "binary", "num_leaves": 255,
+                  "verbosity": -1})
+    plan = grow.default_stage_plan(4096 + rows_past, cfg)
+    assert [w for w, _ in plan] == ladder
+    assert plan == sp.legacy_stage_plan(255, ladder[-1], 3 + rows_past)
+    assert sp.plan_cost_fn(plan, 255, float) == (slots, waves)
+
+
 def test_persisted_plan_key_stable_across_hashseeds(tmp_path):
     """The on-disk plan filename must be PYTHONHASHSEED-independent —
     a hash-order-dependent key would quietly defeat the cross-process
@@ -262,6 +339,10 @@ def test_profile_stage_plan_records_and_installs():
         assert b1._grower.stage_plan == out["plan"]
         gauges = obs.registry().snapshot()["gauges"]
         assert any(k.startswith("grow.stage.w") for k in gauges), gauges
+        # set where the grower adopts its programs: three stat columns
+        # under the bound, the last stage as wide as 31 leaves allow
+        assert (gauges["grow.hist_cols"], gauges["grow.wave_width"]) \
+            == (3, 30)
         # second grower with the same signature adopts the cached plan
         b2 = build()
         assert b2._grower.stage_plan == out["plan"]
