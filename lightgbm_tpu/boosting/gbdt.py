@@ -990,7 +990,8 @@ class GBDT:
                 bias if i == 0 else 0.0))
         if deal is not None:
             self._work.awaited = score
-        self._push_work(nl, work)
+        with obs.span("chunk.work_drain", cat="boost"):
+            self._push_work(nl, work)
         self.iter += chunk
         # lagged stall check: the PREVIOUS chunk's records have landed
         # by now (this chunk is seconds of device work), so reading
@@ -1037,8 +1038,11 @@ class GBDT:
         last_done = self.iter - 1
         it_last = last_done - last_done % self.bag_freq
         seed = (self.config.bagging_seed + it_last) & 0x7FFFFFFF
-        self.bag_buffer, self.bag_count = self.learner.bagging_state(
-            seed, self.bag_fraction)
+        # a sibling of train.chunk: the bag's count is read on the
+        # host, so this waits for the dispatch it follows
+        with obs.span("chunk.bag_sync", cat="boost"):
+            self.bag_buffer, self.bag_count = self.learner.bagging_state(
+                seed, self.bag_fraction)
 
     # ------------------------------------------------------------------
     # what was sampled, recomputed on demand (nothing is kept per tree)

@@ -37,10 +37,13 @@ def _bag_selection(key, n_pad: int, num_data, fraction):
 
 @functools.partial(jax.jit, static_argnames=("n_pad",))
 def _bagging_impl(key, n_pad, num_data, fraction):
-    valid, selected = _bag_selection(key, n_pad, num_data, fraction)
-    sort_key = jnp.where(selected, 0, jnp.where(valid, 1, 2))
-    order = jnp.argsort(sort_key.astype(jnp.int32), stable=True)
-    return order.astype(jnp.int32), selected.sum().astype(jnp.int32)
+    # the scope sits inside the jit: one round its call would not reach
+    # the program's op names
+    with jax.named_scope("lgb.bag_sync"):
+        valid, selected = _bag_selection(key, n_pad, num_data, fraction)
+        sort_key = jnp.where(selected, 0, jnp.where(valid, 1, 2))
+        order = jnp.argsort(sort_key.astype(jnp.int32), stable=True)
+        return order.astype(jnp.int32), selected.sum().astype(jnp.int32)
 
 
 _bagging_impl = obs.track_jit("bagging_partition", _bagging_impl)

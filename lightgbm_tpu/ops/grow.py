@@ -67,6 +67,7 @@ monotone constraints, forced splits, renew-tree-output objectives.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 import threading
@@ -78,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
+from ..obs.scopes import wave_hist_stage
 from . import stage_plan as stage_plan_mod
 from .histogram import (QUANT_MAX, bucket_size, quant_scales, quantize_gh,
                         stochastic_round_with)
@@ -520,12 +522,17 @@ class GrowerPrograms:
     # wave histogram: one dense pass for up to W pending leaves
     # ------------------------------------------------------------------
     def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
-                   scales=None):
+                   scales=None, stage=None):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
         the mesh when sharded, and this shard's (3,) i32 ``[row chunks
         the contraction visited, live rows it found, 1 if it compacted
-        them first]``."""
-        with jax.named_scope("lgb.wave_hist"):
+        them first]``.  ``stage`` is the index of the plan's stage whose
+        wave body this is: its instructions take that stage's name
+        inside ``lgb.wave_hist`` (the plan probes have no stage and keep
+        the bare name)."""
+        with jax.named_scope("lgb.wave_hist"), \
+                (contextlib.nullcontext() if stage is None
+                 else jax.named_scope(wave_hist_stage(stage))):
             hist, work = self._wave_hist_local(binned, leaf_id, ghk,
                                                pending, num_valid, scales)
         # sharded: psum the combined per-shard histograms — the growth
@@ -993,12 +1000,12 @@ class GrowerPrograms:
             gain = jnp.where(ok, packed[:, F_GAIN], NEG_INF)
             return packed.at[:, F_GAIN].set(gain), catm, lint
 
-        def make_wave(Ws: int):
+        def make_wave(Ws: int, stage: int):
           def wave(st: _S) -> _S:
             # 1. fresh histograms for pending smaller children
             fresh, hw = self._wave_hist(binned, st.leaf_id, gh5,
                                         st.p_small, num_valid,
-                                        wave_scales)           # (W,S,3)
+                                        wave_scales, stage)    # (W,S,3)
             with jax.named_scope("lgb.hist_state"):
                 root_wave = st.p_parent[0] < 0
                 # root total from group-0 slot sums (every row hits one slot)
@@ -1253,7 +1260,10 @@ class GrowerPrograms:
                     | ((s.nl < L) & ~jnp.all(s.p_small >= 0)))
             else:
                 go_on = lambda s, lim=limit: (~s.done) & (s.nl < lim)
-            st = jax.lax.while_loop(go_on, make_wave(ws), st)
+            # the while's own self time (its carried-state copies) and
+            # its condition take this name; the body's phases are inner
+            with jax.named_scope("lgb.stage_loop"):
+                st = jax.lax.while_loop(go_on, make_wave(ws, i), st)
         final = st
         leaf_final = final.leaf_id
         rec_f_out = final.rec_f
@@ -1489,9 +1499,10 @@ class GrowerPrograms:
                     if use_bag:
                         # cond, not where: only redraw steps pay the
                         # (bag_npad,) uniform generation
-                        bmask = jax.lax.cond(it % bag_freq == 0,
-                                             lambda: draw_bag(it),
-                                             lambda: bmask)
+                        with jax.named_scope("lgb.bag_draw"):
+                            bmask = jax.lax.cond(it % bag_freq == 0,
+                                                 lambda: draw_bag(it),
+                                                 lambda: bmask)
                     (new_score, rec_i, rec_f, rec_c, nl, root, work,
                      qs) = self._grow_impl(
                         binned, binned_t, sc, g, h, fmask, lr,
@@ -1925,7 +1936,6 @@ class DeviceGrower:
         quantization rounding noise."""
         if lr is None:
             lr = self.lr
-        obs.inc("grow.dispatches")
         # routing attribution: which kernel serves this dispatch's
         # full-width histogram stage (BENCH digests read these)
         obs.inc(f"grow.hist.{self.programs.hist_kernel_tag}")
@@ -2197,8 +2207,6 @@ class DeviceGrower:
                     plan, legacy, progs.num_leaves, k, fixed, col,
                     measured_ms=fused_ms):
                 plan = legacy
-        obs.set_gauge("grow.stage.fixed_ms", round(fixed, 3))
-        obs.set_gauge("grow.stage.col_ms", round(col, 5))
         installed = False
         if install:
             stage_plan_mod.cache_plan(self._base_signature, plan)
@@ -2225,9 +2233,9 @@ class DeviceGrower:
         loop's sole sync point — via a separately-jitted shard_map whose
         body is just the collective, so the measured ms is communication
         (plus dispatch floor), not histogram compute.  Records the
-        ``shard.psum`` timing and ``shard.psum_ms`` gauge that
-        ``obs.summary()``'s shard digest and ``bench.py --suite shard``
-        read; returns ``{"psum_ms": ...}``, or None unsharded."""
+        ``shard.psum`` timing that ``obs.summary()``'s shard digest
+        reads; returns ``{"psum_ms": ...}`` (``bench.py --suite shard``
+        reads that), or None unsharded."""
         import time as _time
 
         progs = self.programs
@@ -2250,7 +2258,6 @@ class DeviceGrower:
         jax.block_until_ready(r)
         ms = (_time.perf_counter() - t0) / max(1, int(reps)) * 1e3
         obs.observe("shard.psum", ms / 1e3)
-        obs.set_gauge("shard.psum_ms", round(ms, 3))
         return {"psum_ms": round(ms, 3)}
 
 

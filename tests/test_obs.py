@@ -408,18 +408,57 @@ class TestSpanCounters:
                                       "bin.bundle", "bin.apply",
                                       "train.init", "grow.upload",
                                       "train.chunk", "chunk.enqueue",
-                                      "chunk.stall_check"])
+                                      "chunk.stall_check",
+                                      "chunk.work_drain"])
     def test_layer_boundary_spans_reach_the_counters(self, name):
         c = self._trained_counters()
         assert c[f"span_n.{name}"] >= 1 and c[f"span_s.{name}"] >= 0.0
 
     def test_child_spans_lie_inside_their_parents(self):
         c = self._trained_counters()
-        assert c["span_s.chunk.enqueue"] <= c["span_s.train.chunk"]
+        assert c["span_s.chunk.enqueue"] + c["span_s.chunk.work_drain"] \
+            + c["span_s.chunk.stall_check"] <= c["span_s.train.chunk"]
+        assert c["span_n.chunk.work_drain"] == c["span_n.train.chunk"]
+        # no bag, nothing to draw again on the host
+        assert "span_n.chunk.bag_sync" not in c
         assert c["span_s.bin.find"] + c["span_s.bin.apply"] \
             <= c["span_s.dataset.construct"]
         assert c["span_s.grow.upload"] <= c["span_s.train.init"]
         assert c["train.fused_chunks"] == c["span_n.train.chunk"] == 2
+
+    def test_bag_sync_is_a_span_beside_train_chunk(self, tmp_path):
+        """A bagged run draws the host's bag again after its fused
+        dispatches (``_sync_fused_bagging``, which waits for the
+        dispatch it follows): once an ``update_chunked``, under a name
+        of its own, after the last ``train.chunk`` has closed."""
+        obs.configure(enabled=True)
+        rng = np.random.default_rng(0)
+        x = rng.standard_normal((600, 5))
+        params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+                  "device_growth": "on", "fused_chunk": 2,
+                  "min_data_in_leaf": 5, "bagging_fraction": 0.7,
+                  "bagging_freq": 1}
+        ds = lgb.Dataset(x, label=(x[:, 0] > 0).astype(np.float64),
+                         params=params).construct()
+        bst = lgb.train(params, ds, num_boost_round=4, verbose_eval=False,
+                        keep_training_booster=True)
+        bst.update_chunked(2)
+        c = STATE.registry.snapshot()["counters"]
+        assert c["span_n.train.chunk"] == 3
+        assert c["span_n.chunk.bag_sync"] == 2 and \
+            c["span_s.chunk.bag_sync"] >= 0.0
+        path = str(tmp_path / "ev.jsonl")
+        obs.dump_events_jsonl(path)
+        spans = [json.loads(l) for l in open(path)]
+        chunks = [(e["t_unix"], e["t_unix"] + e["dur_s"]) for e in spans
+                  if e["name"] == "train.chunk"]
+        syncs = [(e["t_unix"], e["t_unix"] + e["dur_s"]) for e in spans
+                 if e["name"] == "chunk.bag_sync"]
+        assert len(chunks) == 3 and len(syncs) == 2
+        eps = 5e-6         # the records round to a microsecond
+        assert all(s0 >= c1 - eps or s1 <= c0 + eps
+                   for s0, s1 in syncs for c0, c1 in chunks)
+        assert syncs[-1][0] >= chunks[-1][1] - eps
 
     def test_compile_cache_counts_trace_and_lower_seconds(self):
         import jax

@@ -10,6 +10,7 @@ really dispatches (its call is recorded, then lowered again with the same
 arguments), and the model must not notice any of it.
 """
 
+import contextlib
 import os
 import re
 
@@ -20,7 +21,7 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu import obs
 from lightgbm_tpu.boosting.gbdt import _WorkDrain
-from lightgbm_tpu.obs.scopes import SCOPES
+from lightgbm_tpu.obs.scopes import MAX_STAGES, SCOPES, wave_hist_stage
 from lightgbm_tpu.obs.state import STATE
 from lightgbm_tpu.ops.grow import _CHUNK
 
@@ -67,19 +68,65 @@ class _Recorder:
         return self.fn(*args, **kwargs)
 
 
+_calls = {}
+
+
+def _fused_call(extra=None, rows=3000):
+    """(booster, jitted fused program, args, kwargs) of the dispatch
+    ``update_chunked`` makes under ``extra`` params."""
+    key = (repr(sorted((extra or {}).items())), rows)
+    if key not in _calls:
+        bst = _booster(extra, rows=rows)
+        progs = bst._gbdt._grower.programs
+        (length, fn), = progs._fused.items()
+        rec = progs._fused[length] = _Recorder(fn)
+        try:
+            bst.update_chunked(length)
+        finally:
+            progs._fused[length] = fn
+        _calls[key] = (bst, fn, *rec.call)
+    return _calls[key]
+
+
 def _fused_text(extra=None, rows=3000) -> str:
     """Debug text of the fused program ``update_chunked`` dispatches
     under ``extra`` params."""
-    bst = _booster(extra, rows=rows)
-    progs = bst._gbdt._grower.programs
-    (length, fn), = progs._fused.items()
-    rec = progs._fused[length] = _Recorder(fn)
-    try:
-        bst.update_chunked(length)
-    finally:
-        progs._fused[length] = fn
-    args, kwargs = rec.call
+    _, fn, args, kwargs = _fused_call(extra, rows)
     return fn.lower(*args, **kwargs).as_text(debug_info=True)
+
+
+def _rebuilt(progs, plan=None):
+    """A ``GrowerPrograms`` of ``progs``'s shapes and configuration that
+    shares no jitted object with it (so it is traced anew), over
+    ``plan`` if given."""
+    from lightgbm_tpu.ops.grow import GrowerPrograms
+    return GrowerPrograms(
+        num_data=progs.num_data, num_groups=progs.num_groups, nb=progs.nb,
+        num_features=progs.num_features, has_cat=progs.has_cat,
+        config=progs.config, plan=plan or progs.stage_plan,
+        shard=progs.shard, mesh=progs.mesh)
+
+
+# nine stages for 31 leaves: one more than SCOPES has stage names
+DEEP_PLAN = [(4, 5), (4, 7), (4, 9), (4, 11), (4, 13), (8, 17), (8, 21),
+             (8, 25), (30, None)]
+
+
+def _deep_plan_text() -> str:
+    """The fused program of the ``chunks`` run's shapes traced over a
+    plan of nine stages (lowered only: no probe derives such a plan for
+    shapes this small)."""
+    bst, _, args, kwargs = _fused_call({"num_leaves": 31}, rows=17000)
+    deep = _rebuilt(bst._gbdt._grower.programs, DEEP_PLAN)
+    return deep.fused_train(2).lower(*args, **kwargs).as_text(
+        debug_info=True)
+
+
+def _bag_sync_text() -> str:
+    from lightgbm_tpu.ops import bagging
+    return bagging._bagging_impl.lower(
+        jax.random.PRNGKey(3), 4096, np.int32(3000),
+        np.float32(0.7)).as_text(debug_info=True)
 
 
 def _traverse_text() -> str:
@@ -111,6 +158,10 @@ _TEXTS = {
     # 17,000 rows are three histogram chunks: only over more than one
     # chunk may a wave bring its live rows to the front
     "chunks": lambda: _fused_text({"num_leaves": 31}, rows=17000),
+    # 40 leaves grow in three stages (4, 16, 39 wide)
+    "stages": lambda: _fused_text({"num_leaves": 40}, rows=17000),
+    "deep_plan": _deep_plan_text,
+    "bag_sync": _bag_sync_text,
     "traverse": _traverse_text,
     "bin": _bin_text,
 }
@@ -131,6 +182,10 @@ REACHED_BY = {
     "lgb.score_update": "plain", "lgb.leaf_refit": "quant",
     "lgb.bag_draw": "bagging", "lgb.psum": "sharded",
     "lgb.traverse": "traverse", "lgb.bin": "bin",
+    "lgb.wave_hist.s0": "stages", "lgb.wave_hist.s1": "stages",
+    "lgb.wave_hist.s2": "stages", "lgb.stage_loop": "stages",
+    "lgb.bag_sync": "bag_sync",
+    **{wave_hist_stage(i): "deep_plan" for i in range(3, MAX_STAGES)},
 }
 
 
@@ -161,23 +216,162 @@ def test_scopes_sit_where_the_program_runs_them():
     assert not any("lgb.leaf_refit" in p.split("/") for p in paths)
 
 
+def _parts(text):
+    return [p.split("/") for p in re.findall(r'loc\("([^"]*)"', text)]
+
+
+def test_stage_names_nest_inside_wave_hist_and_round_the_gather():
+    text = _text("stages")
+    assert [w for w, _ in _fused_call(
+        {"num_leaves": 40}, rows=17000)[0]._gbdt._grower.stage_plan] \
+        == [4, 16, 39]
+    stages = [wave_hist_stage(i) for i in range(3)]
+    # (a path that ends in a bare "jit" is where a jitted helper, cached
+    # by its first caller, was defined: no instruction carries it)
+    hist = [p for p in _parts(text) if "lgb.wave_hist" in p
+            and p[-1] != "jit"]
+    assert hist
+    for p in hist:
+        i = p.index("lgb.wave_hist")
+        # no histogram of the fused program is left without its stage,
+        # the stage's name sits directly inside lgb.wave_hist, and both
+        # inside that stage's loop
+        assert p[i + 1] in stages, p
+        assert "lgb.stage_loop" in p[:i] and "while" in p[:i], p
+    gather = [p for p in hist if "lgb.wave_gather" in p]
+    assert gather and all(
+        p.index("lgb.wave_gather") > p.index("lgb.wave_hist") + 1
+        for p in gather)
+    # every stage compacts (three chunks of rows), and every stage's
+    # contraction carries its own name
+    for st in stages:
+        assert any(st in p for p in gather)
+        assert any(st in p and "lgb.wave_gather" not in p
+                   and p[-1].startswith("dot_general") for p in hist)
+    # a while's condition is inside the loop's name and no phase's
+    cond = [p for p in _parts(text) if "lgb.stage_loop" in p
+            and "cond" in p[p.index("lgb.stage_loop"):]
+            and "body" not in p[p.index("lgb.stage_loop"):]]
+    assert cond and not any(set(p) & set(SCOPES) - {"lgb.stage_loop"}
+                            for p in cond)
+
+
+def test_a_plan_longer_than_the_names_shares_the_last_one():
+    assert [wave_hist_stage(i) for i in range(MAX_STAGES)] \
+        == [s for s in SCOPES if s.startswith("lgb.wave_hist.")]
+    assert wave_hist_stage(MAX_STAGES) == wave_hist_stage(MAX_STAGES - 1)
+    assert len(DEEP_PLAN) == MAX_STAGES + 1
+    found = {c for p in _parts(_text("deep_plan")) for c in p
+             if c.startswith("lgb.wave_hist.")}
+    assert found == {wave_hist_stage(i) for i in range(MAX_STAGES)}
+
+
+def test_probes_and_lone_waves_keep_the_bare_name():
+    """``_wave_hist`` without a stage (the plan probes): ``lgb.wave_hist``
+    and no stage name."""
+    import jax.numpy as jnp
+    bst = _fused_call()[0]
+    progs = bst._gbdt._grower.programs
+    n, k = progs.n_pad, progs.hist_cols
+    text = jax.jit(lambda b, l, g, p: progs._wave_hist(
+        b, l, g, p, n)[0]).lower(
+        jax.ShapeDtypeStruct((n, progs.num_groups), jnp.uint8),
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((n, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((4,), jnp.int32)).as_text(debug_info=True)
+    names = {c for p in _parts(text) for c in p if c.startswith("lgb.")}
+    assert names == {"lgb.wave_hist"}
+
+
 def test_named_scope_literals_are_exactly_the_tuple():
-    found, other = set(), []
+    """Every call site writes a literal of ``SCOPES`` or asks the one
+    helper that generates the stage names the tuple holds."""
+    found, helper, other = set(), [], []
     for dirpath, _, files in os.walk(PKG):
         for name in files:
             if not name.endswith(".py") or "jaxlint" in dirpath:
                 continue
             with open(os.path.join(dirpath, name)) as f:
                 src = f.read()
-            for m in re.finditer(r"named_scope\(\s*([^)]*)\)", src):
+            for m in re.finditer(
+                    r"named_scope\(\s*((?:[^()]|\([^()]*\))*)\)", src):
                 arg = m.group(1).strip()
                 lit = re.fullmatch(r'"([^"]+)"', arg)
                 if lit:
                     found.add(lit.group(1))
+                elif re.fullmatch(r"wave_hist_stage\(\w+\)", arg):
+                    helper.append(name)
                 else:
                     other.append((name, arg))
-    assert found == set(SCOPES)
+    generated = {wave_hist_stage(i) for i in range(MAX_STAGES)}
+    assert helper == ["grow.py"]
+    assert not found & generated
+    assert found | generated == set(SCOPES)
     assert not other, f"named_scope without a literal of SCOPES: {other}"
+
+
+# ---------------------------------------------------------------------------
+# a scope is metadata: the modules lower to the same text without one
+# ---------------------------------------------------------------------------
+
+class _NoScope(contextlib.ContextDecorator, contextlib.nullcontext):
+    """``jax.named_scope`` that names nothing, as a context manager and
+    as the decorator ``draw_bag`` wears."""
+
+
+def _module_text(which: str) -> str:
+    """The lowered text, locations left out, of one of the modules a
+    bagged run dispatches, traced anew (the programs object is rebuilt,
+    the bagging program jitted again) under whatever ``jax.named_scope``
+    is at the moment."""
+    import jax.numpy as jnp
+    extra = {"num_leaves": 40, "bagging_fraction": 0.7, "bagging_freq": 1,
+             "feature_fraction": 0.8}
+    if which == "sharded":
+        extra.update(data_sharding="single_controller", shard_devices=2)
+    if which == "bagging":
+        from lightgbm_tpu.ops import bagging
+        impl = bagging._bagging_impl.fn.__wrapped__
+        # a function object of its own: JAX keeps the trace of one it
+        # has seen with these shapes
+        fn = jax.jit(lambda *a: impl(*a), static_argnums=1)
+        return fn.lower(jax.random.PRNGKey(3), 4096, np.int32(3000),
+                        np.float32(0.7)).as_text()
+    bst, _, args, kwargs = _fused_call(extra, rows=17000)
+    progs = _rebuilt(bst._gbdt._grower.programs)
+    if which in ("fused", "sharded"):
+        return progs.fused_train(2).lower(*args, **kwargs).as_text()
+    # the per-iteration program over the same buffers: gradients, a
+    # feature mask and a row mask where the fused scan makes its own
+    binned, binned_t, score, lr, _, it0, num_valid, meta, hyper, tables \
+        = args
+    row = jax.ShapeDtypeStruct(score.shape, jnp.float32)
+    return progs._grow_masked.lower(
+        binned, binned_t, score, row, row,
+        jax.ShapeDtypeStruct((progs.num_features,), jnp.bool_), lr, row,
+        it0, num_valid, meta, hyper, tables).as_text()
+
+
+@pytest.mark.parametrize("which", ["fused", "per_iteration", "bagging",
+                                   "sharded"])
+def test_modules_lower_to_the_same_text_without_the_scopes(
+        which, monkeypatch):
+    named = _module_text(which)
+    assert "loc(" not in named
+    seen = []
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: seen.append(name) or real(name))
+    again = _module_text(which)
+    monkeypatch.setattr(jax, "named_scope", _NoScope)
+    bare = _module_text(which)
+    assert named == again == bare
+    # the patch was in the way of every new name this module holds
+    new = {"fused": {"lgb.stage_loop", "lgb.wave_hist.s2"},
+           "per_iteration": {"lgb.stage_loop", "lgb.wave_hist.s2"},
+           "bagging": {"lgb.bag_sync"},
+           "sharded": {"lgb.stage_loop", "lgb.wave_hist.s2"}}[which]
+    assert new <= set(seen)
 
 
 def _model_text(enabled: bool) -> str:
