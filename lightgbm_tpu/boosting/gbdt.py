@@ -56,7 +56,7 @@ class _ValidSet:
 
 
 #: columns of a tree's work vector ahead of a mesh's exact leaf rows
-_WORK_COLS = 10
+_WORK_COLS = 11
 
 
 def _exact_counts(work):
@@ -206,7 +206,8 @@ class _WorkDrain:
     Each dispatch returns, per tree, its leaf count and ``[waves, wave
     slots, in-bag rows, features in the mask, row chunks its wave
     histograms visited, their live rows // _CHUNK, the remainders, the
-    waves that compacted their live rows]`` as device arrays.  ``push``
+    waves that compacted their live rows, the tiles of 128 stat columns
+    their chunk loops contracted]`` as device arrays.  ``push``
     queues the handles (their async host copies already started) and
     ``drain`` adds whatever ``is_ready()`` to
     ``grow.trees`` / ``leaves`` / ``waves`` / ``wave_slots`` /
@@ -216,7 +217,10 @@ class _WorkDrain:
     chunks' rows) / ``rows_live`` (both counted by the program, summed
     over waves and shards) / ``waves_gathered`` (waves whose histogram
     brought its live rows to the front first; a mean over the shards of
-    a mesh) / ``rows_in_bag`` / ``features_in_mask`` (both per tree)
+    a mesh) / ``hist_tiles`` (Σ over waves of the 128-column tiles the
+    wave matmul contracted: what its pending leaves reach, not its
+    width; one shard's on a mesh, where every shard counts the same) /
+    ``rows_in_bag`` / ``features_in_mask`` (both per tree)
     and, when a mesh ran the dispatch (two more work columns),
     ``rows_live_max`` (the fullest shard's live rows, summed
     wave by wave) and ``psum_bytes`` (wave slots x the bytes one chip
@@ -298,13 +302,14 @@ class _WorkDrain:
             obs.inc("grow.rows_live", int(work[:, 5].sum()) * _CHUNK
                     + int(work[:, 6].sum()))
             obs.inc("grow.waves_gathered", int(work[:, 7].sum()))
+            obs.inc("grow.hist_tiles", int(work[:, 8].sum()))
             obs.inc("grow.rows_in_bag", int(work[:, 2].sum()))
             obs.inc("grow.features_in_mask", int(work[:, 3].sum()))
-            if work.shape[1] > 8:
+            if work.shape[1] > 9:
                 # a mesh ran it: what the fullest shard contracted, wave
                 # by wave, and the bytes a chip gave the histogram psums
-                obs.inc("grow.rows_live_max", int(work[:, 8].sum())
-                        * _CHUNK + int(work[:, 9].sum()))
+                obs.inc("grow.rows_live_max", int(work[:, 9].sum())
+                        * _CHUNK + int(work[:, 10].sum()))
                 obs.inc("grow.psum_bytes",
                         int(work[:, 1].sum()) * slot_bytes)
 

@@ -273,11 +273,13 @@ def _holds_underfull(ws: int, next_ws: int, next_cap, hist_cols: int) -> bool:
     """Whether the stage ``ws`` wide keeps a frontier that has reached
     its cap for as long as its waves come back under-full: only ahead
     of a CLOSING stage (``next_cap`` None) that takes more than two
-    tiles of 128 stat columns where this one takes at most two.  Every
-    wave costs its WIDTH whatever it splits, and only a frontier that
-    fills the narrower stage (its last wave applied a split in every
-    slot) can use the slots of a third tile — 128 leaves to 255 in ONE
-    wave on dense numeric data.  A tree held back by
+    tiles of 128 stat columns where this one takes at most two.  A
+    wave's find-best, selection and ``split_apply`` cost its WIDTH
+    whatever it splits (its histogram no longer: it contracts the tiles
+    its pending leaves reach, ``_wave_hist_local``), and only a frontier
+    that fills the narrower stage (its last wave applied a split in
+    every slot) can use the slots of a third tile — 128 leaves to 255
+    in ONE wave on dense numeric data.  A tree held back by
     ``min_sum_hessian_in_leaf`` or one-hot peels closes in two or three
     waves at any width, so it takes them at two tiles.  Measured
     (PERF.md section 6, PR 35): the Allstate table's trees never fill a
@@ -286,6 +288,19 @@ def _holds_underfull(ws: int, next_ws: int, next_cap, hist_cols: int) -> bool:
     while the Criteo rows' go from 128 leaves to 255 in one wave."""
     return next_cap is None \
         and int(ws) * hist_cols <= 256 < int(next_ws) * hist_cols
+
+
+def _pending_tiles(pending, hist_cols: int):
+    """The 128-column tiles of the wave matmul that the (W,) pending
+    leaf ids (-1 = empty slot) reach, as a traced i32: their slots
+    through the highest occupied one, ``hist_cols`` columns each.  The
+    occupied slots are a prefix today (a wave's selection is a prefix of
+    a descending ``top_k`` and the stages pad at the end); counting
+    through the highest makes a hole cost a tile, never a histogram."""
+    w = pending.shape[0]
+    n_pending = jnp.max(jnp.where(
+        pending >= 0, jnp.arange(1, w + 1, dtype=jnp.int32), 0))
+    return (n_pending * hist_cols + 127) // 128
 
 
 def default_stage_plan(num_data: int, config) -> list:
@@ -524,12 +539,13 @@ class GrowerPrograms:
     def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
                    scales=None, stage=None):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
-        the mesh when sharded, and this shard's (3,) i32 ``[row chunks
+        the mesh when sharded, and this shard's (4,) i32 ``[row chunks
         the contraction visited, live rows it found, 1 if it compacted
-        them first]``.  ``stage`` is the index of the plan's stage whose
-        wave body this is: its instructions take that stage's name
-        inside ``lgb.wave_hist`` (the plan probes have no stage and keep
-        the bare name)."""
+        them first, tiles of 128 stat columns it contracted]``.
+        ``stage`` is the index of the plan's stage whose wave body this
+        is: its instructions take that stage's name inside
+        ``lgb.wave_hist`` (the plan probes have no stage and keep the
+        bare name)."""
         with jax.named_scope("lgb.wave_hist"), \
                 (contextlib.nullcontext() if stage is None
                  else jax.named_scope(wave_hist_stage(stage))):
@@ -665,8 +681,9 @@ class GrowerPrograms:
         ``scales`` is the (2,) [scale_g, scale_h] dequantization vector
         (quantized f32-fallback mode only).
 
-        Also returns (3,) i32 ``[row chunks visited, live rows, 1 if
-        the wave compacted else 0]``.
+        Also returns (4,) i32 ``[row chunks visited, live rows, 1 if
+        the wave compacted else 0, tiles of 128 stat columns the chunk
+        loop contracted]``.
 
         A row is LIVE in a wave when its leaf is one of ``pending`` and
         its count column(s) are non-zero: every other row — another
@@ -690,6 +707,19 @@ class GrowerPrograms:
         the fused program's compile).  Under ``shard_map`` each shard
         decides from its own count.  A single chunk is never compacted,
         nor a ``_CHUNK`` that is no multiple of the block.
+
+        The contraction costs its tiles of 128 stat columns (PERF.md
+        section 5), and the leaves a wave holds pending were made by the
+        selection of the wave before it: half the stage's width on a
+        doubling ladder.  Where ``W * hist_cols`` passes one tile, a
+        ``lax.switch`` on :func:`_pending_tiles` runs the chunk loop over
+        the slots that ``t`` tiles hold (42 / 85 / 128 of three columns)
+        for the fewest ``t`` that reaches the highest occupied slot, the
+        columns behind them zero as the full contraction leaves them.  A
+        branch is a chunk loop and nothing else: the live mask, the
+        compaction and everything behind the accumulator are shared.
+        ``pending`` is replicated under ``shard_map``, so every shard
+        takes the same branch.  A stage of one tile gets no switch.
 
         The one-hot must stay a bare iota-compare so XLA fuses its
         generation into the dot operand (a multi-hot built as
@@ -727,32 +757,54 @@ class GrowerPrograms:
         mdtype = jnp.int8 if quant else jnp.bfloat16
         adtype = jnp.int32 if quant else jnp.float32
 
-        def body(i, acc):
-            b, l, gk = (jax.lax.dynamic_index_in_dim(
-                a, i, keepdims=False)
-                for a in (binned_c, leaf_c, ghk_c))
-            lm = (l[:, None] == pending[None, :]).astype(mdtype)
-            bmat = (lm[:, :, None] * gk[:, None, :]).reshape(ch,
-                                                             w * k)
-            # bin tiling: a one-hot wider than 64 breaks XLA's
-            # operand fusion (max_bin=255 measured 10x the
-            # max_bin=63 wave, not the expected 4x) — strips of 64
-            # keep each einsum in the known-fused regime; out-of-
-            # strip bins make all-zero one-hot rows, so the concat
-            # reassembles exactly
-            bi = b.astype(jnp.int32)
-            outs = []
-            for off in range(0, nb, 64):
-                oh = jax.nn.one_hot(bi - off, min(nb, 64),
-                                    dtype=mdtype)           # (CH,G,64)
-                outs.append(jnp.einsum("cgn,cb->gnb", oh, bmat,
-                                       preferred_element_type=adtype))
-            out = outs[0] if len(outs) == 1 \
-                else jnp.concatenate(outs, axis=1)
-            return acc + out
+        def chunk_loop(w_t):
+            """The contraction over the first ``w_t`` pending slots,
+            the slots behind them zero."""
+            pend = pending[:w_t]
 
-        acc0 = jnp.zeros((g, nb, w * k), adtype)
-        acc = jax.lax.fori_loop(0, visited, body, acc0)
+            def body(i, acc):
+                b, l, gk = (jax.lax.dynamic_index_in_dim(
+                    a, i, keepdims=False)
+                    for a in (binned_c, leaf_c, ghk_c))
+                lm = (l[:, None] == pend[None, :]).astype(mdtype)
+                bmat = (lm[:, :, None] * gk[:, None, :]).reshape(
+                    ch, w_t * k)
+                # bin tiling: a one-hot wider than 64 breaks XLA's
+                # operand fusion (max_bin=255 measured 10x the
+                # max_bin=63 wave, not the expected 4x) — strips of 64
+                # keep each einsum in the known-fused regime; out-of-
+                # strip bins make all-zero one-hot rows, so the concat
+                # reassembles exactly
+                bi = b.astype(jnp.int32)
+                outs = []
+                for off in range(0, nb, 64):
+                    oh = jax.nn.one_hot(bi - off, min(nb, 64),
+                                        dtype=mdtype)       # (CH,G,64)
+                    outs.append(jnp.einsum(
+                        "cgn,cb->gnb", oh, bmat,
+                        preferred_element_type=adtype))
+                out = outs[0] if len(outs) == 1 \
+                    else jnp.concatenate(outs, axis=1)
+                return acc + out
+
+            acc = jax.lax.fori_loop(0, visited, body,
+                                    jnp.zeros((g, nb, w_t * k), adtype))
+            # lax.pad, not jnp.pad: a jitted helper of jax.numpy is
+            # lowered as a shared function outside the stage's scope
+            return jax.lax.pad(acc, jnp.zeros((), adtype),
+                               [(0, 0, 0), (0, 0, 0),
+                                (0, (w - w_t) * k, 0)])
+
+        full_tiles = -(-w * k // 128)
+        if full_tiles > 1:
+            tiles = jnp.clip(_pending_tiles(pending, k), 1, full_tiles)
+            acc = jax.lax.switch(
+                tiles - 1,
+                [functools.partial(chunk_loop, min(w, 128 * t // k))
+                 for t in range(1, full_tiles + 1)])
+        else:
+            tiles = jnp.int32(full_tiles)
+            acc = chunk_loop(w)
         acc = acc.reshape(g, nb, w, k)
         if quant and self.int_scan:
             # int32 end-to-end: the histogram stays in quantized units
@@ -784,7 +836,7 @@ class GrowerPrograms:
         else:
             hist = _combine_hist_cols(acc, k)                    # (G,NB,W,3)
         return (hist.transpose(2, 0, 1, 3).reshape(w, self.num_slots, 3),
-                jnp.stack([visited, n_live, gathered]))
+                jnp.stack([visited, n_live, gathered, tiles]))
 
     # ------------------------------------------------------------------
     def _stat_columns(self, grad, hess, one_f, tree_idx):
@@ -855,16 +907,18 @@ class GrowerPrograms:
                    *, with_mask):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
-        i32, root_value f32, work (8,) i32 = [waves run, sum of their
+        i32, root_value f32, work (9,) i32 = [waves run, sum of their
         stage widths, in-bag real rows, features in the mask, row chunks
         the wave histograms visited, their live rows // _CHUNK, the sum
-        of the remainders, the waves that compacted their live rows] —
-        sharded (10 + L,): the three before the last summed over the
-        mesh and the last a mean over the shards (each decides from its
-        own count), then the FULLEST shard's live rows summed wave by
-        wave as the same (// _CHUNK, remainder) pair, then the exact
-        (in-bag) rows of each of the L leaves —, quant_scales (2,)
-        f32).
+        of the remainders, the waves that compacted their live rows,
+        the tiles of 128 stat columns their chunk loops contracted] —
+        sharded (11 + L,): of the five histogram columns the first
+        three summed over the mesh, the fourth a mean over the shards
+        (each decides from its own count) and the tiles one shard's
+        (replicated state decides them), then the FULLEST shard's live
+        rows summed wave by wave as the same (// _CHUNK, remainder)
+        pair, then the exact (in-bag) rows of each of the L leaves —,
+        quant_scales (2,) f32).
         ``lr`` is traced so callbacks may reset the learning rate without
         recompiling; ``tree_idx`` is the global tree index keying the
         quantization rounding noise (unused when grad_quant_bits=0).
@@ -935,12 +989,13 @@ class GrowerPrograms:
             nl: jnp.ndarray             # i32 leaves so far
             waves: jnp.ndarray          # i32 wave count
             slots: jnp.ndarray          # i32 sum of wave widths run
-            hwork: jnp.ndarray          # (4,) i32 histogram work so far:
+            hwork: jnp.ndarray          # (5,) i32 histogram work so far:
             #                             chunks visited, live rows as
             #                             (// _CHUNK, % _CHUNK) sums — a
             #                             tree's rows can pass int32 —,
-            #                             waves that compacted;
-            #                             sharded (6,): then the same
+            #                             waves that compacted, tiles
+            #                             of stat columns contracted;
+            #                             sharded (7,): then the same
             #                             pair for the FULLEST shard's
             #                             live rows, wave by wave
             done: jnp.ndarray           # bool
@@ -969,7 +1024,7 @@ class GrowerPrograms:
             nl=jnp.asarray(1, jnp.int32),
             waves=jnp.asarray(0, jnp.int32),
             slots=jnp.asarray(0, jnp.int32),
-            hwork=jnp.zeros((4 if self.shard is None else 6,), jnp.int32),
+            hwork=jnp.zeros((5 if self.shard is None else 7,), jnp.int32),
             done=jnp.asarray(False),
             rec_i=jnp.full((L, REC_I_FIELDS), -1, jnp.int32),
             rec_f=jnp.zeros((L, REC_F_FIELDS), jnp.float32),
@@ -1211,7 +1266,7 @@ class GrowerPrograms:
                 pl = jnp.where(sel, jnp.where(small_left, r_ids, lsel), -1)
 
             nl, waves, slots = st.nl + napply, st.waves + 1, st.slots + Ws
-            hw_add = [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK, hw[2]]
+            hw_add = [hw[0], hw[1] // _CHUNK, hw[1] % _CHUNK, hw[2], hw[3]]
             if self.shard is not None:
                 # the mesh waits at the psum for its fullest shard: what
                 # that shard contracted in this wave, beside the sum
@@ -1228,13 +1283,15 @@ class GrowerPrograms:
           return wave
 
         # staged wave widths: the early frontier has 1 -> 2 -> 4 -> ...
-        # pending leaves, so a full-width wave wastes almost its whole
-        # column tile on empty lanes (the matmul cost is W x hist_cols
-        # columns regardless of how many are live).  Growing the width
-        # with the frontier cuts the early waves' cost ~5-10x; each stage
-        # is its own while_loop over the same state with the pending
-        # arrays padded to the next width.  The plan comes from
-        # ops/stage_plan.py (byte-stable default or profile-derived).
+        # pending leaves, and find-best, the selection and split_apply
+        # cost a wave's WIDTH whatever it holds (the histogram its tiles
+        # of 128 stat columns through the highest pending slot:
+        # _wave_hist_local).  Growing the width with the frontier cuts
+        # the early waves' cost ~5-10x; each stage is its own while_loop
+        # over the same state with the pending arrays padded to the next
+        # width — a stage's first wave holds the leaves the narrower
+        # stage selected.  The plan comes from ops/stage_plan.py
+        # (byte-stable default or profile-derived).
         def resize(st: _S, w_to: int) -> _S:
             pad = w_to - st.p_parent.shape[0]
             if pad <= 0:
@@ -1375,8 +1432,9 @@ class GrowerPrograms:
 
         hwork = final.hwork
         if self.shard is not None:
-            # each shard gathers and scans its own live rows; the
-            # fullest shard's pair is the same on every shard already
+            # each shard gathers and scans its own live rows; the tiles
+            # and the fullest shard's pair are the same on every shard
+            # already
             with jax.named_scope("lgb.psum"):
                 summed = jax.lax.psum(hwork[:4], self.shard.axis)
             hwork = jnp.concatenate(
@@ -1446,7 +1504,7 @@ class GrowerPrograms:
                 meta, hyper, tables, grad_fn=fn)
             -> (final_score,
                 (rec_i (K,L-1,5), rec_f (K,L-1,9), rec_c (K,L-1,8),
-                 nl (K,), root_value (K,), work (K,7), qscales (K,2)))
+                 nl (K,), root_value (K,), work (K,9), qscales (K,2)))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);

@@ -14,6 +14,15 @@ table of both layouts over the widths:
     python3 scripts/bench_wave_hist.py --shape criteo --cols 3,4 \\
         --live 1.0,0.33
 
+``--pending n[,n...]`` hands each ``W``-wide wave ``n`` pending leaves
+(slots 0..n-1; the live rows lie in them alone) where the default fills
+every slot: what a wave costs by the tiles of 128 stat columns its
+pending leaves reach, beside the full wave (an ``n`` past ``W`` is
+skipped):
+
+    python3 scripts/bench_wave_hist.py --shape criteo --cols 3 \\
+        --widths 64,128 --pending 32,42,64,128 --live 1.0,0.45
+
 Lines go to stdout and to ``chiprun_out/wave_hist_bench.jsonl``.
 ``--repo`` runs another unpacked tree (one before the compaction routes
 ``on`` and ``off`` by its own constant, ``_GATHER_MIN_LANES``).
@@ -40,6 +49,9 @@ def main(argv=None):
                     help="comma-separated 3 | 4: the layout(s) to time, "
                          "in place of the one the row bucket takes")
     ap.add_argument("--live", default="1.0,0.45")
+    ap.add_argument("--pending", default="",
+                    help="comma-separated counts of pending leaves a "
+                         "wave is handed, in place of a full frontier")
     ap.add_argument("--variants", default="as_is",
                     help="space-separated as_is | on:W,W | off:W,W")
     args = ap.parse_args(argv)
@@ -84,18 +96,22 @@ def main(argv=None):
         assert cols in (None, progs.hist_cols), (cols, progs.hist_cols)
         ghk, _ = progs._stat_columns(grad, hess,
                                      jnp.ones((n,), jnp.float32), 0)
-        pend = jnp.arange(w, dtype=jnp.int32)
+        slots = jnp.arange(w, dtype=jnp.int32)
+        counts = [int(c) for c in args.pending.split(",") if c] or [w]
 
         def call(b, l, g2, p):
             out = progs._wave_hist(b, l, g2, p, n, None)
             return out[0] if isinstance(out, tuple) else out
 
         fn = jax.jit(call)
-        for share in (float(v) for v in args.live.split(",")):
-            # leaves 0..w-1 are pending and hold ``share`` of the rows;
-            # the others sit in leaf w
+        for share, npend in itertools.product(
+                (float(v) for v in args.live.split(",")),
+                (c for c in counts if c <= w)):
+            # leaves 0..npend-1 are pending and hold ``share`` of the
+            # rows; the others sit in leaf w
+            pend = jnp.where(slots < npend, slots, -1)
             leaf = jnp.where(u < share,
-                             jax.random.randint(k4, (n,), 0, w), w)
+                             jax.random.randint(k4, (n,), 0, npend), w)
             jax.block_until_ready(fn(binned, leaf, ghk, pend))
             t0 = time.perf_counter()
             for _ in range(2):
@@ -104,7 +120,8 @@ def main(argv=None):
             line = json.dumps({
                 "tag": args.tag, "variant": variant,
                 "shape": args.shape, "n": n, "g": g,
-                "k": int(progs.hist_cols), "w": w, "live_share": share,
+                "k": int(progs.hist_cols), "w": w, "pending": npend,
+                "live_share": share,
                 "seconds": round((time.perf_counter() - t0) / 2, 6),
                 "count_sum": float(r[..., 2].sum()),
                 "device": dev.device_kind})

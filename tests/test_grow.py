@@ -286,7 +286,7 @@ def test_wave_hist_matches_a_plain_histogram(reg_data):
     w = progs.wave_width
     pending = np.concatenate([np.arange(6), [-1] * (w - 6)]).astype(
         np.int32)
-    got, (visited, live, _) = progs._wave_hist(
+    got, (visited, live, _, _) = progs._wave_hist(
         binned, jnp.asarray(leaf), ghk, jnp.asarray(pending), n)
     got = np.asarray(got)
     assert got.shape == (w, progs.num_slots, 3)
@@ -470,7 +470,7 @@ def test_wave_hist_contracts_only_the_live_rows(live_rows_case, pending,
     assert compacts == (live.sum() < _COMPACT_MAX_LIVE * n)
     assert [int(v) for v in np.asarray(work)] \
         == [-(-int(tiles.sum()) * _COMPACT_TILE // _CHUNK) if compacts
-            else n // _CHUNK, int(live.sum()), int(compacts)]
+            else n // _CHUNK, int(live.sum()), int(compacts), 1]
     if pending != "none":
         assert 0 < live.sum() <= n - 100
     for slot, lf in enumerate(pend):

@@ -427,7 +427,7 @@ def two_chunks(request):
 def test_counters_hold_every_tree_and_the_returned_waves(two_chunks):
     _, returned, _, c = two_chunks
     assert c["grow.trees"] == 4
-    work = np.concatenate([np.asarray(w).reshape(-1, 8)
+    work = np.concatenate([np.asarray(w).reshape(-1, 9)
                            for _, w, _ in returned])
     nl = np.concatenate([np.asarray(n).reshape(-1)
                          for n, _, _ in returned])
@@ -523,7 +523,7 @@ def test_snapshot_delta_is_exactly_the_chunk_between(two_chunks):
     _, returned, c1, c2 = two_chunks
     assert c1["grow.trees"] == 2
     nl, work, real = returned[1]
-    work = np.asarray(work).reshape(-1, 8)
+    work = np.asarray(work).reshape(-1, 9)
     waves = int(work[:, 0].sum())
     want = {"grow.trees": 2, "grow.leaves": int(np.asarray(nl).sum()),
             "grow.waves": waves, "grow.wave_slots": int(work[:, 1].sum()),

@@ -445,11 +445,11 @@ def test_replay_takes_exact_leaf_rows_over_the_records_float_counts():
          [4.0, 0, 0, 16_777_218.0, 0, 0, 16_347_784.0, 0.2, -0.2]],
         np.float32)
     rec_c = np.zeros((2, 8), np.int32)
-    work = np.concatenate([np.zeros(10, np.int64),
+    work = np.concatenate([np.zeros(11, np.int64),
                            [20_000_001, 16_777_217, 16_347_782]
                            + [0] * 12])
-    assert _exact_counts(work[:10]) is None \
-        and _exact_counts(work[:8]) is None
+    assert _exact_counts(work[:11]) is None \
+        and _exact_counts(work[:9]) is None
     rows = _exact_counts(work)
     rough = _replay_records(rec_i, rec_f, rec_c, 3, 1.0, 0.0, ds, cfg)
     exact = _replay_records(rec_i, rec_f, rec_c, 3, 1.0, 0.0, ds, cfg,

@@ -246,6 +246,23 @@ def test_plan_of_one_layout_is_not_adopted_by_the_other(
                     grow._PROGRAM_CACHE.pop(key)
 
 
+def test_signature_holds_nothing_of_the_tile_rule():
+    """A wave contracts the tiles its pending leaves reach by what it
+    observes (PR 37): no knob, no plan field, and nothing of the wider
+    work vector in the signature, so a plan persisted by the program
+    before is still this one's."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops import grow
+
+    cfg = Config({"objective": "binary", "num_leaves": 255,
+                  "verbosity": -1, "seed": 424337})
+    assert grow.programs_signature(4096, 3, 64, 3, False, cfg) == (
+        4096, 3, 64, 3, False, grow._CHUNK, grow.COUNT_SPLIT_ROWS,
+        grow.INT32_SCAN_ROWS, grow._config_digest(cfg), ("hist_cols", 3))
+    assert grow.default_stage_plan(4096, cfg) == [
+        (4, 8), (16, 32), (32, 64), (64, 128), (128, None)]
+
+
 @pytest.mark.parametrize("rows_past,ladder,slots,waves", [
     (0, [4, 16, 32, 64, 128], 268, 8),
     (1, [4, 16, 24, 48, 96], 332, 10),
