@@ -487,6 +487,22 @@ class Booster:
         get its output added to their training score."""
         return self._gbdt.sampled_rows(iteration)
 
+    def goss_rows(self, iteration: int):
+        """``(top, sampled, weight)`` of boosting iteration ``iteration``
+        of a GOSS booster: ``bool[num_data]`` of the rows kept for their
+        large |gradient x hessian|, of the other rows sampled, and the
+        weight a sampled row's gradient and hessian took (a warm-up tree,
+        ``iteration < int(1 / learning_rate)``: every row on top, weight
+        1.0).  Read back from what training recorded of the tree, so it
+        answers for trees a fused chunk has not yet brought to the host;
+        the booster keeps the last ``boosting.goss.GOSS_KEEP``
+        iterations'.  Raises for any other boosting."""
+        g = self._gbdt
+        if not hasattr(g, "goss_rows"):
+            raise LightGBMError(f"{type(g).__name__} does not select rows "
+                                "by GOSS")
+        return g.goss_rows(iteration)
+
     def sampled_features(self, tree_index: int) -> np.ndarray:
         """``bool[num_features]`` over the dataset's columns: those tree
         ``tree_index`` was allowed to split on (``feature_fraction``;

@@ -264,14 +264,16 @@ class _WorkDrain:
         of a replicated array does."""
         return a.addressable_shards[0].data.is_ready()
 
-    def push(self, nl, work, rows_real: int) -> None:
+    def push(self, nl, work, rows_real: int, goss=None) -> None:
+        """Queue one dispatch.  ``goss`` is a fused GOSS scan's (K, 3)
+        i32 ``[top rows, sampled rows, keys read]`` a tree, or None."""
         if not obs.enabled():
             return
         work.copy_to_host_async()
         with self._lock:
             self._pending.append((nl, work, rows_real,
                                   self.psum_slot_bytes, self.awaited,
-                                  self.find_slots))
+                                  self.find_slots, goss))
             self.awaited = None
         self.drain()
 
@@ -284,7 +286,7 @@ class _WorkDrain:
                            for a in (self._pending[0][4],
                                      self._pending[0][1]))):
                 done.append(self._pending.popleft())
-        for nl, work, rows_real, slot_bytes, _, find_slots in done:
+        for nl, work, rows_real, slot_bytes, _, find_slots, goss in done:
             nl = np.asarray(nl).reshape(-1)
             work = np.asarray(work, np.int64)
             work = work.reshape(-1, work.shape[-1])
@@ -305,6 +307,12 @@ class _WorkDrain:
             obs.inc("grow.hist_tiles", int(work[:, 8].sum()))
             obs.inc("grow.rows_in_bag", int(work[:, 2].sum()))
             obs.inc("grow.features_in_mask", int(work[:, 3].sum()))
+            if goss is not None:
+                # GOSS in the fused scan: the rows each tree kept on top,
+                # sampled, and whose |g*h| key its selection read
+                goss = np.asarray(goss, np.int64).reshape(-1, 3).sum(0)
+                for name, v in zip(("top", "sampled", "keys"), goss):
+                    obs.inc(f"grow.goss_{name}", int(v))
             if work.shape[1] > 9:
                 # a mesh ran it: what the fullest shard contracted, wave
                 # by wave, and the bytes a chip gave the histogram psums
@@ -316,6 +324,12 @@ class _WorkDrain:
 
 class GBDT:
     """Gradient Boosting Decision Tree driver."""
+
+    # whether THIS class's trees may grow in the fused K-trees-per-
+    # dispatch scan; read from the class's own namespace, so a subclass
+    # that overrides the per-iteration hooks (DART, RF) stays off it
+    # until it says otherwise
+    _FUSED_SCAN = True
 
     def __init__(self, config: Config):
         self.config = config
@@ -836,14 +850,16 @@ class GBDT:
     # dispatch (lax.scan over trees, gradients computed on device)
     def _fused_grad_fn(self):
         """(grad_fn, gargs) when fused multi-iteration training is sound
-        for the CURRENT state, else None.  Sound means: plain GBDT (no
-        DART/GOSS/RF overrides), single model, and an objective exposing
-        a pure device gradient.  Bagging and feature_fraction no longer
-        disqualify: their draws moved inside the fused scan
-        (DeviceGrower.fused_train), which is what lets the fork
-        harness's exact config (feature_fraction=0.8, bagging_freq=5)
-        use the fastest path."""
-        if (self._grower is None or type(self) is not GBDT
+        for the CURRENT state, else None.  Sound means: a boosting class
+        that declares the scan its own (``_FUSED_SCAN``: GBDT, and GOSS
+        on one chip; not DART or RF), single model, and an objective
+        exposing a pure device gradient.  Bagging, feature_fraction and
+        GOSS's row selection do not disqualify: their draws live inside
+        the fused scan (DeviceGrower.fused_train), which is what lets
+        the fork harness's exact config (feature_fraction=0.8,
+        bagging_freq=5) use the fastest path."""
+        if (self._grower is None
+                or not type(self).__dict__.get("_FUSED_SCAN", False)
                 or self.num_model != 1
                 or self.train_set.num_features == 0
                 or self.objective is None
@@ -973,11 +989,13 @@ class GBDT:
         else:
             score_in = self._grower.deal_rows(self.train_score[0])
         with obs.span("chunk.enqueue", cat="boost"):
-            score, (rec_i, rec_f, rec_c, nl, _root, work, qscales) = \
-                self._dispatch_guard(lambda: fused(
-                    self._grower.binned, self._grower.binned_t,
-                    score_in, lr, gargs,
-                    jnp.asarray(self.iter, jnp.int32), grad_fn=grad_fn))
+            score, recs = self._dispatch_guard(lambda: fused(
+                self._grower.binned, self._grower.binned_t,
+                score_in, lr, gargs,
+                jnp.asarray(self.iter, jnp.int32), grad_fn=grad_fn))
+        rec_i, rec_f, rec_c, nl, _root, work, qscales = recs[:7]
+        # a GOSS scan hands each tree's row selection on besides
+        rows = recs[7] if len(recs) > 7 else None
         sp.sync_value = score
         if deal is None:
             self.train_score = self.train_score.at[0].set(score)
@@ -995,8 +1013,11 @@ class GBDT:
                 bias if i == 0 else 0.0))
         if deal is not None:
             self._work.awaited = score
+        if rows is not None:
+            self._keep_rows(self.iter, chunk, rows)
         with obs.span("chunk.work_drain", cat="boost"):
-            self._push_work(nl, work)
+            self._push_work(nl, work,
+                            None if rows is None else rows[1])
         self.iter += chunk
         # lagged stall check: the PREVIOUS chunk's records have landed
         # by now (this chunk is seconds of device work), so reading
@@ -1013,10 +1034,15 @@ class GBDT:
                     np.asarray(prev.qscales)[-1].tolist())
             return bool((prev.host()[3] <= 1).all())
 
-    def _push_work(self, nl, work) -> None:
+    def _push_work(self, nl, work, goss=None) -> None:
         """Queue one dispatch's per-tree leaf counts and work counters
         for the registry (``_WorkDrain``)."""
-        self._work.push(nl, work, self.num_data)
+        self._work.push(nl, work, self.num_data, goss)
+
+    def _keep_rows(self, it0, chunk, rows) -> None:
+        """What a fused scan recorded of the rows trees ``it0`` ..
+        ``it0 + chunk - 1`` selected (GOSS keeps it; nothing else
+        records any)."""
 
     def _row_order_score(self) -> None:
         """Bring a score that fused dispatches left dealt over the mesh
@@ -1063,7 +1089,9 @@ class GBDT:
         if type(self).bagging is not GBDT.bagging:
             raise LightGBMError(
                 f"{type(self).__name__} selects rows from the gradients "
-                f"of the moment; its selection cannot be drawn again")
+                f"of the moment; its selection cannot be drawn again"
+                + (" (GOSS: goss_rows reads what it kept)"
+                   if hasattr(self, "goss_rows") else ""))
         n = self.num_data
         if not self.need_bagging:
             return np.ones(n, bool)

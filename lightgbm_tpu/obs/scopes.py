@@ -35,6 +35,7 @@ def wave_hist_stage(stage: int) -> str:
 SCOPES = (
     "lgb.gradient",      # objective gradients inside the fused scan
     "lgb.bag_draw",      # bagging row mask / feature_fraction mask draws
+    "lgb.goss_select",   # GOSS: a tree's top |g*h| rows and its sample
     "lgb.stat_cols",     # pad/valid masking, stat columns, quantisation
     "lgb.wave_hist",     # the wave histogram (einsum over bin strips)
     *(wave_hist_stage(i) for i in range(MAX_STAGES)),   # ... by stage

@@ -448,6 +448,10 @@ class GrowerPrograms:
         self._bag_npad = shard.bag_npad if shard is not None \
             else bucket_size(max(self.num_data, 1))
         self._quant_seed = (int(config.seed) + 5) & 0x7FFFFFFF
+        # GOSS (top_rate, other_rate, warm-up trees): the fused scan
+        # selects each tree's rows from the gradients it has just
+        # computed, with GOSS.bagging's seeding over the same pad
+        self._goss = goss_facts(config) if shard is None else None
 
     # ------------------------------------------------------------------
     def feature_mask_for(self, tree_idx):
@@ -1493,10 +1497,13 @@ class GrowerPrograms:
         Sampling lives INSIDE the scan: the per-tree feature_fraction
         mask is ``fold_in(key, tree_idx)``, the bagging row mask is
         re-drawn every ``bagging_freq`` trees with the per-iteration
-        path's exact ``(bagging_seed + it)`` seeding, and the int8
-        quantization noise is keyed by the same global tree index — so
-        fused and per-iteration emit bit-identical trees even with
-        quantization on (tests/test_fused.py, tests/test_quant.py).
+        path's exact ``(bagging_seed + it)`` seeding, GOSS selects each
+        tree's rows from the gradients the scan has just computed with
+        ``GOSS.bagging``'s seeding, and the int8 quantization noise is
+        keyed by the same global tree index — so fused and per-iteration
+        emit bit-identical trees even with quantization on
+        (tests/test_fused.py, tests/test_goss_fused.py,
+        tests/test_quant.py).
 
         Signature of the returned (raw) program::
 
@@ -1504,7 +1511,9 @@ class GrowerPrograms:
                 meta, hyper, tables, grad_fn=fn)
             -> (final_score,
                 (rec_i (K,L-1,5), rec_f (K,L-1,9), rec_c (K,L-1,8),
-                 nl (K,), root_value (K,), work (K,9), qscales (K,2)))
+                 nl (K,), root_value (K,), work (K,9), qscales (K,2)
+                 [, GOSS: (rows (K,2,ceil(n/32)) u32, counts (K,3) i32,
+                 weight (K,) f32), :meth:`_goss_rows`]))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);
@@ -1520,9 +1529,50 @@ class GrowerPrograms:
         with self._fused_lock:
             return self._fused_program(length)
 
+    def _goss_rows(self, g, h, it, num_valid):
+        """The rows of tree ``it`` under GOSS, inside the fused scan:
+        ``(g, h, row mask, record)``.  From tree ``int(1 / lr)`` on,
+        :func:`~.bagging.goss_selection` over |g*h| of the real rows,
+        seeded ``(bagging_seed + it)`` over the learner's bagging pad as
+        ``GOSS.bagging`` seeds it, a sampled row's g and h multiplied by
+        the weight (the count column counts rows, unweighted); before,
+        every row (a ``lax.cond`` on the traced ``it``: a chunk may
+        straddle the warm-up).  ``record`` is what ``GOSS.goss_rows``
+        reads again: the top and the sampled rows packed a bit a row
+        (2, ceil(n / 32)) u32, the (3,) i32 counts ``[top rows, sampled
+        rows, keys read]`` and the f32 weight (zeros and 1.0 in a
+        warm-up tree)."""
+        from .bagging import goss_selection, pack_rows
+        top_rate, other_rate, warm = self._goss
+        words = -(-g.shape[0] // 32)
+
+        def select():
+            seed = (self._bag_seed + it) & 0x7FFFFFFF
+            top, sampled, weight = goss_selection(
+                jax.random.PRNGKey(seed), jnp.abs(g * h), self._bag_npad,
+                num_valid, top_rate, other_rate)
+            m = jnp.where(sampled, weight, 1.0)
+            counts = jnp.stack([jnp.sum(top, dtype=jnp.int32),
+                                jnp.sum(sampled, dtype=jnp.int32),
+                                jnp.asarray(num_valid, jnp.int32)])
+            return (g * m, h * m, (top | sampled).astype(jnp.float32),
+                    (jnp.stack([pack_rows(top), pack_rows(sampled)]),
+                     counts, weight))
+
+        def every_row():
+            return (g, h, jnp.ones_like(g),
+                    (jnp.zeros((2, words), jnp.uint32),
+                     jnp.zeros((3,), jnp.int32), jnp.float32(1.0)))
+
+        with jax.named_scope("lgb.goss_select"):
+            return jax.lax.cond(it >= warm, select, every_row)
+
     def _fused_program(self, length: int):
         if length not in self._fused:
-            use_bag = self._bag_fraction < 1.0 and self._bag_freq > 0
+            use_goss = self._goss is not None
+            use_bag = (not use_goss and self._bag_fraction < 1.0
+                       and self._bag_freq > 0)
+            with_mask = use_bag or use_goss
             bag_freq, bag_seed = self._bag_freq, self._bag_seed
             bag_frac, bag_npad = self._bag_fraction, self._bag_npad
             sp = self.shard
@@ -1561,12 +1611,17 @@ class GrowerPrograms:
                             bmask = jax.lax.cond(it % bag_freq == 0,
                                                  lambda: draw_bag(it),
                                                  lambda: bmask)
+                    goss = ()
+                    if use_goss:
+                        g, h, bmask, rec = self._goss_rows(g, h, it,
+                                                           num_valid)
+                        goss = (rec,)
                     (new_score, rec_i, rec_f, rec_c, nl, root, work,
                      qs) = self._grow_impl(
                         binned, binned_t, sc, g, h, fmask, lr,
-                        bmask if use_bag else no_mask, it, num_valid,
-                        meta, hyper, tables, with_mask=use_bag)
-                    out = (rec_i, rec_f, rec_c, nl, root, work, qs)
+                        bmask if with_mask else no_mask, it, num_valid,
+                        meta, hyper, tables, with_mask=with_mask)
+                    out = (rec_i, rec_f, rec_c, nl, root, work, qs) + goss
                     return ((new_score, bmask) if use_bag
                             else new_score), out
 
@@ -1645,6 +1700,18 @@ def _config_digest(config) -> str:
     return hashlib.sha1(repr(items).encode()).hexdigest()
 
 
+def goss_facts(config):
+    """``(top_rate, other_rate, warm-up trees)`` of a GOSS configuration,
+    else None.  The warm-up follows ``learning_rate``, which no other
+    trace reads (it is a traced argument), so it is part of the programs'
+    signature by value."""
+    if getattr(config, "boosting", "gbdt") != "goss":
+        return None
+    from .bagging import goss_warmup
+    return (float(config.top_rate), float(config.other_rate),
+            goss_warmup(config.learning_rate))
+
+
 def programs_signature(num_data: int, num_groups: int, nb: int,
                        num_features: int, has_cat: bool, config,
                        shard: Optional[ShardSpec] = None) -> tuple:
@@ -1664,6 +1731,8 @@ def programs_signature(num_data: int, num_groups: int, nb: int,
     if shard is not None:
         base = base + (("shard", shard.n_shards, shard.global_rows,
                         shard.draw_npad, shard.bag_npad),)
+    elif goss_facts(config) is not None:
+        base = base + (("goss_warmup", goss_facts(config)[2]),)
     return base
 
 

@@ -134,7 +134,9 @@ class DataParallelTreeLearner(SerialTreeLearner):
         samples the rest with its own counts, matching the reference's
         GOSS over rank-local rows (goss.hpp:88-133 with pre-partitioned
         data).  Returns the (buffer, counts) state the DP ``_init_state``
-        consumes, the global selected count, and the (N,) multiplier."""
+        consumes, the global selected count, the (N,) multiplier, and
+        None where the serial learner hands back the rows it kept: a
+        rank's selection is its own."""
         if getattr(self, "_goss_fn", None) is None:
             net = self.net
             n_loc = self.n_loc
@@ -186,7 +188,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
             jnp.asarray(other_rate, jnp.float32))
         counts_np = np.asarray(counts)
         return ((buf, counts_np), int(counts_np.sum()),
-                jnp.asarray(mult)[:self.num_data])
+                jnp.asarray(mult)[:self.num_data], None)
 
     def _init_state(self, indices_buffer, data_count, grad, hess):
         if indices_buffer is None:

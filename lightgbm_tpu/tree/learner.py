@@ -210,21 +210,23 @@ class SerialTreeLearner:
     def goss_state(self, seed: int, score_abs, top_rate: float,
                    other_rate: float):
         """GOSS row selection (goss.hpp:88-133): returns (opaque buffer
-        state, global selected count, (N,) grad/hess multiplier).  The
-        distributed learners override this with rank-local selection, like
-        the reference running GOSS on each rank's rows."""
+        state, global selected count, (N,) grad/hess multiplier, rows)
+        where ``rows`` is ``(packed (2, words) u32 top and sampled rows,
+        f32 weight)``.  The distributed learners override this with
+        rank-local selection, like the reference running GOSS on each
+        rank's rows (their ``rows`` is None)."""
         from ..ops.bagging import goss_partition
         key = jax.random.PRNGKey(seed)
         pad = self.n_pad - self.num_data
         if pad > 0:
             score_abs = jnp.concatenate(
                 [score_abs, jnp.zeros(pad, jnp.float32)])
-        buf, cnt, mult = goss_partition(
+        buf, cnt, mult, packed, weight = goss_partition(
             key, score_abs, self.n_pad,
             jnp.asarray(self.num_data, jnp.int32),
             jnp.asarray(top_rate, jnp.float32),
             jnp.asarray(other_rate, jnp.float32))
-        return buf, int(cnt), mult[:self.num_data]
+        return buf, int(cnt), mult[:self.num_data], (packed, weight)
 
     def _init_state(self, indices_buffer, data_count, grad, hess):
         """Set up the per-tree partition state; returns possibly-resharded

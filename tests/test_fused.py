@@ -146,10 +146,11 @@ def test_fork_harness_config_chunked_bit_identical():
 
 
 def test_ineligible_config_falls_back():
-    # GOSS overrides the gradient/bagging hooks, so the fused path must
-    # refuse and train_chunked must still train correctly per-iteration
+    # DART rescales earlier trees between iterations, so the fused path
+    # must refuse and train_chunked must still train correctly
+    # per-iteration (GOSS fuses: tests/test_goss_fused.py)
     x, y = _binary_data(rows=1500)
-    params = {"objective": "binary", "boosting": "goss",
+    params = {"objective": "binary", "boosting": "dart",
               "learning_rate": 0.3}
     a = _train(params, x, y, 6)
     b = _train(params, x, y, 6, chunk=3)

@@ -153,6 +153,8 @@ _TEXTS = {
     "bagging": lambda: _fused_text({"bagging_fraction": 0.7,
                                     "bagging_freq": 1,
                                     "feature_fraction": 0.8}),
+    "goss": lambda: _fused_text({"boosting": "goss", "top_rate": 0.2,
+                                 "other_rate": 0.1}),
     "sharded": lambda: _fused_text({"data_sharding": "single_controller",
                                     "shard_devices": 2}),
     # 17,000 rows are three histogram chunks: only over more than one
@@ -180,7 +182,8 @@ REACHED_BY = {
     "lgb.hist_state": "plain",
     "lgb.find_best": "plain", "lgb.split_apply": "plain",
     "lgb.score_update": "plain", "lgb.leaf_refit": "quant",
-    "lgb.bag_draw": "bagging", "lgb.psum": "sharded",
+    "lgb.bag_draw": "bagging", "lgb.goss_select": "goss",
+    "lgb.psum": "sharded",
     "lgb.traverse": "traverse", "lgb.bin": "bin",
     "lgb.wave_hist.s0": "stages", "lgb.wave_hist.s1": "stages",
     "lgb.wave_hist.s2": "stages", "lgb.stage_loop": "stages",
@@ -406,9 +409,9 @@ def two_chunks(request):
     returned = []
     orig = _WorkDrain.push
 
-    def spy(self, nl, work, rows_real):
+    def spy(self, nl, work, rows_real, goss=None):
         returned.append((nl, work, rows_real))
-        return orig(self, nl, work, rows_real)
+        return orig(self, nl, work, rows_real, goss)
 
     _WorkDrain.push = spy
     try:
