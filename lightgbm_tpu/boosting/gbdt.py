@@ -265,8 +265,9 @@ class _WorkDrain:
         return a.addressable_shards[0].data.is_ready()
 
     def push(self, nl, work, rows_real: int, goss=None) -> None:
-        """Queue one dispatch.  ``goss`` is a fused GOSS scan's (K, 3)
-        i32 ``[top rows, sampled rows, keys read]`` a tree, or None."""
+        """Queue one dispatch.  ``goss`` is a fused GOSS scan's (K, 4)
+        i32 ``[top rows, sampled rows, keys read, rows its waves may
+        scan]`` a tree, or None."""
         if not obs.enabled():
             return
         work.copy_to_host_async()
@@ -309,9 +310,13 @@ class _WorkDrain:
             obs.inc("grow.features_in_mask", int(work[:, 3].sum()))
             if goss is not None:
                 # GOSS in the fused scan: the rows each tree kept on top,
-                # sampled, and whose |g*h| key its selection read
-                goss = np.asarray(goss, np.int64).reshape(-1, 3).sum(0)
-                for name, v in zip(("top", "sampled", "keys"), goss):
+                # sampled, whose |g*h| key its selection read, and that
+                # its waves may scan (the row set gathered once, tile
+                # padding included; a tree of every row its real rows)
+                goss = np.asarray(goss, np.int64)
+                goss = goss.reshape(-1, goss.shape[-1]).sum(0)
+                for name, v in zip(("top", "sampled", "keys", "set_rows"),
+                                   goss):
                     obs.inc(f"grow.goss_{name}", int(v))
             if work.shape[1] > 9:
                 # a mesh ran it: what the fullest shard contracted, wave

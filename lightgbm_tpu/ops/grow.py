@@ -541,7 +541,7 @@ class GrowerPrograms:
     # wave histogram: one dense pass for up to W pending leaves
     # ------------------------------------------------------------------
     def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
-                   scales=None, stage=None):
+                   scales=None, stage=None, bounded=False):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
         the mesh when sharded, and this shard's (4,) i32 ``[row chunks
         the contraction visited, live rows it found, 1 if it compacted
@@ -554,7 +554,8 @@ class GrowerPrograms:
                 (contextlib.nullcontext() if stage is None
                  else jax.named_scope(wave_hist_stage(stage))):
             hist, work = self._wave_hist_local(binned, leaf_id, ghk,
-                                               pending, num_valid, scales)
+                                               pending, num_valid, scales,
+                                               bounded)
         # sharded: psum the combined per-shard histograms — the growth
         # loop's sole cross-device sync (docs/Sharding.md); everything
         # downstream (find-best, totals, root stats) then runs on
@@ -676,7 +677,7 @@ class GrowerPrograms:
                 handed)
 
     def _wave_hist_local(self, binned, leaf_id, ghk, pending, num_valid,
-                         scales):
+                         scales, bounded=False):
         """(n_pad,) leaf ids, (n_pad, K) stat columns (bf16 — K=3:
         [g,h,1]; K=5: [g_hi,g_lo,h_hi,h_lo,1] — or int8 under
         grad_quant_bits), (W,) pending leaf ids (-1 = empty slot)
@@ -710,7 +711,15 @@ class GrowerPrograms:
         below serves both (a second copy of it per stage would double
         the fused program's compile).  Under ``shard_map`` each shard
         decides from its own count.  A single chunk is never compacted,
-        nor a ``_CHUNK`` that is no multiple of the block.
+        nor a ``_CHUNK`` that is no multiple of the block.  ``bounded``
+        says that no row past the chunk of ``num_valid`` can be live (a
+        GOSS tree's rows gathered once, :meth:`_grow_impl`): the live
+        test then reads only the chunks below it, a chunk at a time, and
+        every wave compacts, its root wave too (~93% live, compacted for
+        a few ms over the set's chunks), with no ``cond``: handed the
+        working rows in place, the ``cond`` lays its result out as they
+        lie and the chunk loop then re-lays the whole bucket in every
+        wave (a TPU v5e compile shows the copy after the ``cond``).
 
         The contraction costs its tiles of 128 stat columns (PERF.md
         section 5), and the leaves a wave holds pending were made by the
@@ -736,9 +745,23 @@ class GrowerPrograms:
         quant = bool(self.quant_bits)
         ch = _CHUNK
         n_chunks = self.n_pad // ch
-        live = ((leaf_id[:, None] == pending[None, :])
-                & (pending >= 0)[None, :]).any(1) \
-            & (ghk[:, 2 if k in (3, 4) else 4:] != 0).any(1)
+
+        def live_of(l, gk):
+            return ((l[:, None] == pending[None, :])
+                    & (pending >= 0)[None, :]).any(1) \
+                & (gk[:, 2 if k in (3, 4) else 4:] != 0).any(1)
+
+        if bounded:
+            lc, gc = leaf_id.reshape(n_chunks, ch), ghk.reshape(n_chunks,
+                                                                ch, k)
+            live = jax.lax.fori_loop(
+                0, jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks),
+                lambda i, buf: jax.lax.dynamic_update_index_in_dim(
+                    buf, live_of(*(jax.lax.dynamic_index_in_dim(
+                        a, i, keepdims=False) for a in (lc, gc))), i, 0),
+                jnp.zeros((n_chunks, ch), bool)).reshape(self.n_pad)
+        else:
+            live = live_of(leaf_id, ghk)
         n_live = jnp.sum(live, dtype=jnp.int32)
         real_chunks = jnp.clip((num_valid + ch - 1) // ch, 0, n_chunks)
         plain = (binned.reshape(n_chunks, ch, g),
@@ -751,7 +774,9 @@ class GrowerPrograms:
                                                   live, real_chunks)
             return (*rows, (handed + ch - 1) // ch, jnp.int32(1))
 
-        if n_chunks > 1 and ch % _COMPACT_BLOCK == 0:
+        if bounded:
+            binned_c, leaf_c, ghk_c, visited, gathered = compact()
+        elif n_chunks > 1 and ch % _COMPACT_BLOCK == 0:
             binned_c, leaf_c, ghk_c, visited, gathered = jax.lax.cond(
                 n_live < self.compact_max_live
                 * (real_chunks * ch).astype(jnp.float32),
@@ -908,7 +933,7 @@ class GrowerPrograms:
     # ------------------------------------------------------------------
     def _grow_impl(self, binned, binned_t, score, grad, hess, feature_mask,
                    lr, row_mask, tree_idx, num_valid, meta, hyper, tables,
-                   *, with_mask):
+                   *, with_mask, row_set=False):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
         i32, root_value f32, work (9,) i32 = [waves run, sum of their
@@ -936,7 +961,26 @@ class GrowerPrograms:
         becomes an XLA constant baked into the executable (hundreds of
         MB at 10M-row scale, and a compile-cache key on its content),
         and argument-passing is what lets the program cache serve every
-        same-shaped dataset."""
+        same-shaped dataset.
+
+        ``row_set`` (the fused GOSS scan): the rows of ``row_mask`` are
+        the tree's row set — the top and the sampled rows, the weights
+        already in ``grad`` and ``hess``; every real row in a warm-up
+        tree — and they are brought to the front ONCE, in row order, of
+        working copies of the bins, the leaf ids and the stat columns
+        (:meth:`_gather_live`, the bucket's own shapes), bounded by the
+        count handed over, tile padding included.  Every wave reads the
+        working copies up to the set's last chunk in place of all the
+        real rows: its live test, its compaction and its chunk loop (the
+        set's root wave compacts too: ``bounded`` of
+        :meth:`_wave_hist_local`).  The working rows are
+        routed by the wave's split records a chunk of the set at a time;
+        the full rows as ever, for the score update of the rows outside
+        the set — goss.hpp's subset (``is_use_subset_``,
+        ``Dataset::CopySubset``) and its out-of-bag traversal.  One more
+        output then: the rows the waves may scan, at most the real rows
+        (all of them where one chunk holds the bucket: nothing to
+        gather)."""
         L, W, S = self.num_leaves, self.wave_width, self.num_slots
         n = self.n_pad
         npad_rows = n - self.num_data
@@ -978,6 +1022,20 @@ class GrowerPrograms:
 
         leaf_id0 = jnp.where(jnp.arange(n, dtype=jnp.int32) < num_valid,
                              0, -1)
+        # the row set's working copies: (n_chunks, CH, G) bins, (n, K)
+        # stat columns, the rows handed over and their chunks; its
+        # (n_chunks, CH) leaf ids ride in the loop state
+        n_chunks = n // _CHUNK
+        use_set = row_set and n_chunks > 1 and _CHUNK % _COMPACT_BLOCK == 0
+        set_rows = num_valid
+        if use_set:
+            with jax.named_scope("lgb.wave_gather"):
+                wbins, wleaf0, wstats, handed = self._gather_live(
+                    binned, leaf_id0, gh5, one_f > 0, jnp.clip(
+                        (num_valid + _CHUNK - 1) // _CHUNK, 0, n_chunks))
+            wstats = wstats.reshape(n, -1)
+            wchunks = (handed + _CHUNK - 1) // _CHUNK
+            set_rows = jnp.minimum(handed, num_valid)
 
         class _S(NamedTuple):
             leaf_id: jnp.ndarray        # (n,) i32
@@ -1009,6 +1067,9 @@ class GrowerPrograms:
             p_parent: jnp.ndarray       # (W,) i32  parent slot (-1 empty)
             p_small: jnp.ndarray        # (W,) i32  leaf whose hist is fresh
             p_large: jnp.ndarray        # (W,) i32  sibling (subtraction)
+            wleaf: Optional[jnp.ndarray] = None  # (n_chunks, CH) i32:
+            #                             the row set's leaf ids (None
+            #                             without a row set: no array)
 
         # every per-leaf array carries one junk slot (index L; records:
         # index L-1) absorbing vector-scatter writes from empty lanes, so
@@ -1038,6 +1099,7 @@ class GrowerPrograms:
                                      jnp.full((W0 - 1,), -1, jnp.int32)])
             if W0 > 1 else jnp.zeros((1,), jnp.int32),
             p_large=jnp.full((W0,), -1, jnp.int32),
+            wleaf=wleaf0 if use_set else None,
         )
 
         has_cat = self.has_cat
@@ -1062,9 +1124,14 @@ class GrowerPrograms:
         def make_wave(Ws: int, stage: int):
           def wave(st: _S) -> _S:
             # 1. fresh histograms for pending smaller children
-            fresh, hw = self._wave_hist(binned, st.leaf_id, gh5,
-                                        st.p_small, num_valid,
-                                        wave_scales, stage)    # (W,S,3)
+            if use_set:
+                fresh, hw = self._wave_hist(
+                    wbins.reshape(n, -1), st.wleaf.reshape(n), wstats,
+                    st.p_small, handed, wave_scales, stage, bounded=True)
+            else:
+                fresh, hw = self._wave_hist(binned, st.leaf_id, gh5,
+                                            st.p_small, num_valid,
+                                            wave_scales, stage)  # (W,S,3)
             with jax.named_scope("lgb.hist_state"):
                 root_wave = st.p_parent[0] < 0
                 # root total from group-0 slot sums (every row hits one slot)
@@ -1174,39 +1241,72 @@ class GrowerPrograms:
                 db16, nbin16 = i16(db)[:, None], i16(nbin)[:, None]
                 thr16 = i16(thr)[:, None]
                 shift = jnp.where(db16 == 0, jnp.int16(1), jnp.int16(0))
-                in_range = (cols >= off16) & (cols < off16 + wid16)
-                bin_ = jnp.where(in_range, cols - off16 + shift, db16)
-                is_default = bin_ == db16
-                is_na = (miss[:, None] == 2) & (bin_ == nbin16 - 1)
-                goes_left = jnp.where(is_default, def_left[:, None],
-                                      jnp.where(is_na, dl[:, None],
-                                                bin_ <= thr16))
-                if has_cat:
-                    # categorical routing: left iff the decoded bin is in the
-                    # winning category set (partition.py:49 semantics); the
-                    # (W,256) membership is packed into 8 x i32 words and the
-                    # per-row word picked with an 8-way select chain (a
-                    # table gather here measured far slower on TPU)
-                    cm = bestc[jnp.clip(lsel, 0, L)]            # (W, 256)
-                    cmw = jnp.sum(
-                        cm.reshape(Ws, 8, 32).astype(jnp.int32)
-                        << jnp.arange(32, dtype=jnp.int32)[None, None, :],
-                        axis=-1)                                # (W, 8)
-                    binc = bin_.astype(jnp.int32)   # 32-bit word arithmetic
-                    widx = binc >> 5
-                    bit = binc & 31
-                    wv = jnp.zeros_like(binc)
-                    for j in range(8):
-                        wv = wv + jnp.where(widx == j, cmw[:, j:j + 1], 0)
-                    left_cat = ((wv >> bit) & 1) == 1
-                    is_cat_w = vecs[:, F_IS_CAT] > 0.5
-                    goes_left = jnp.where(is_cat_w[:, None], left_cat,
-                                          goes_left)
-                mask = (sel[:, None] & (st.leaf_id[None, :] == lsel[:, None])
-                        & ~goes_left)
-                upd = jnp.sum(mask * (r_ids - lsel)[:, None], axis=0,
-                              dtype=jnp.int32)
-                leaf_id = st.leaf_id + upd
+
+                def route(cols, leaf):
+                    """``leaf`` (m,) after the selected splits, from the
+                    (W, m) bins of each split's group; and the (W, 8)
+                    category bitsets (None without categorical
+                    features)."""
+                    in_range = (cols >= off16) & (cols < off16 + wid16)
+                    bin_ = jnp.where(in_range, cols - off16 + shift, db16)
+                    is_default = bin_ == db16
+                    is_na = (miss[:, None] == 2) & (bin_ == nbin16 - 1)
+                    goes_left = jnp.where(is_default, def_left[:, None],
+                                          jnp.where(is_na, dl[:, None],
+                                                    bin_ <= thr16))
+                    cmw = None
+                    if has_cat:
+                        # categorical routing: left iff the decoded bin is
+                        # in the winning category set (partition.py:49
+                        # semantics); the (W,256) membership is packed
+                        # into 8 x i32 words and the per-row word picked
+                        # with an 8-way select chain (a table gather here
+                        # measured far slower on TPU)
+                        cm = bestc[jnp.clip(lsel, 0, L)]        # (W, 256)
+                        cmw = jnp.sum(
+                            cm.reshape(Ws, 8, 32).astype(jnp.int32)
+                            << jnp.arange(32, dtype=jnp.int32)[None, None, :],
+                            axis=-1)                            # (W, 8)
+                        binc = bin_.astype(jnp.int32)   # 32-bit words
+                        widx = binc >> 5
+                        bit = binc & 31
+                        wv = jnp.zeros_like(binc)
+                        for j in range(8):
+                            wv = wv + jnp.where(widx == j, cmw[:, j:j + 1],
+                                                0)
+                        left_cat = ((wv >> bit) & 1) == 1
+                        is_cat_w = vecs[:, F_IS_CAT] > 0.5
+                        goes_left = jnp.where(is_cat_w[:, None], left_cat,
+                                              goes_left)
+                    mask = (sel[:, None] & (leaf[None, :] == lsel[:, None])
+                            & ~goes_left)
+                    upd = jnp.sum(mask * (r_ids - lsel)[:, None], axis=0,
+                                  dtype=jnp.int32)
+                    return leaf + upd, cmw
+
+                leaf_id, cmw = route(cols, st.leaf_id)
+                wleaf = st.wleaf
+                if use_set:
+                    # the row set's leaf ids, a chunk of it at a time: a
+                    # split's group bins by a 0/1 product with the
+                    # chunk's rows (one term a sum, a byte bfloat16
+                    # holds: exact), as _gather_live places its bytes
+                    pick = (grp[:, None] == jnp.arange(
+                        self.num_groups, dtype=jnp.int32)[None, :]
+                            ).astype(jnp.bfloat16)              # (W, G)
+
+                    def route_chunk(i, wl):
+                        b = jax.lax.dynamic_index_in_dim(
+                            wbins, i, keepdims=False)           # (CH, G)
+                        c = i16(jnp.einsum(
+                            "wg,cg->wc", pick, b.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32))
+                        return jax.lax.dynamic_update_index_in_dim(
+                            wl, route(c, jax.lax.dynamic_index_in_dim(
+                                wl, i, keepdims=False))[0], i, 0)
+
+                    wleaf = jax.lax.fori_loop(0, wchunks, route_chunk,
+                                              wleaf)
 
                 # bookkeeping (vectorized scatters into the L-padded arrays)
                 safe_l = jnp.where(sel, lsel, L)
@@ -1283,7 +1383,7 @@ class GrowerPrograms:
                       hwork=st.hwork + jnp.stack(hw_add),
                       done=napply == 0,
                       rec_i=rec_i, rec_f=rec_f, rec_c=rec_c,
-                      p_parent=pp, p_small=ps, p_large=pl)
+                      p_parent=pp, p_small=ps, p_large=pl, wleaf=wleaf)
           return wave
 
         # staged wave widths: the early frontier has 1 -> 2 -> 4 -> ...
@@ -1471,14 +1571,15 @@ class GrowerPrograms:
             with jax.named_scope("lgb.psum"):
                 leaf_rows = jax.lax.psum(leaf_rows, self.shard.axis)
             hwork = jnp.concatenate([hwork, leaf_rows])
-        return (new_score, final.rec_i[:max(L - 1, 1)],
-                rec_f_out[:max(L - 1, 1)],
-                final.rec_c[:max(L - 1, 1)], final.nl, final.value[0],
-                jnp.concatenate([
-                    jnp.stack([final.waves, final.slots, rows_in_bag,
-                               jnp.sum(feature_mask, dtype=jnp.int32)]),
-                    hwork]),
-                qscales)
+        out = (new_score, final.rec_i[:max(L - 1, 1)],
+               rec_f_out[:max(L - 1, 1)],
+               final.rec_c[:max(L - 1, 1)], final.nl, final.value[0],
+               jnp.concatenate([
+                   jnp.stack([final.waves, final.slots, rows_in_bag,
+                              jnp.sum(feature_mask, dtype=jnp.int32)]),
+                   hwork]),
+               qscales)
+        return out + (set_rows,) if row_set else out
 
     # ------------------------------------------------------------------
     def fused_train(self, length: int):
@@ -1512,8 +1613,10 @@ class GrowerPrograms:
             -> (final_score,
                 (rec_i (K,L-1,5), rec_f (K,L-1,9), rec_c (K,L-1,8),
                  nl (K,), root_value (K,), work (K,9), qscales (K,2)
-                 [, GOSS: (rows (K,2,ceil(n/32)) u32, counts (K,3) i32,
-                 weight (K,) f32), :meth:`_goss_rows`]))
+                 [, GOSS: (rows (K,2,ceil(n/32)) u32, counts (K,4) i32,
+                 weight (K,) f32): :meth:`_goss_rows`' and, last among
+                 the counts, the rows the tree's waves may scan
+                 (``row_set`` of :meth:`_grow_impl`)]))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);
@@ -1611,16 +1714,22 @@ class GrowerPrograms:
                             bmask = jax.lax.cond(it % bag_freq == 0,
                                                  lambda: draw_bag(it),
                                                  lambda: bmask)
-                    goss = ()
                     if use_goss:
                         g, h, bmask, rec = self._goss_rows(g, h, it,
                                                            num_valid)
-                        goss = (rec,)
                     (new_score, rec_i, rec_f, rec_c, nl, root, work,
-                     qs) = self._grow_impl(
+                     qs, *set_rows) = self._grow_impl(
                         binned, binned_t, sc, g, h, fmask, lr,
                         bmask if with_mask else no_mask, it, num_valid,
-                        meta, hyper, tables, with_mask=with_mask)
+                        meta, hyper, tables, with_mask=with_mask,
+                        row_set=use_goss)
+                    goss = ()
+                    if use_goss:
+                        # the rows the tree's waves may scan, beside
+                        # the selection's counts
+                        rows, counts, weight = rec
+                        goss = ((rows, jnp.concatenate(
+                            [counts, jnp.stack(set_rows)]), weight),)
                     out = (rec_i, rec_f, rec_c, nl, root, work, qs) + goss
                     return ((new_score, bmask) if use_bag
                             else new_score), out
