@@ -336,9 +336,11 @@ class Booster:
     def update(self, train_set=None, fobj=None) -> bool:
         """One boosting iteration; returns True if stopped early
         (no more splits).  Drives ``GBDT.train_chunked`` — a single
-        iteration takes the per-iteration device path, but the unified
-        driver keeps host bagging state consistent when fused chunks
-        (``update_chunked``, ``engine.train``) and single updates mix."""
+        iteration takes the per-iteration device path (a softmax
+        multiclass one grows its class trees in one fused dispatch), but
+        the unified driver keeps host bagging state consistent when
+        fused chunks (``update_chunked``, ``engine.train``) and single
+        updates mix."""
         if train_set is not None:
             raise LightGBMError(
                 "resetting training data mid-training is not supported yet")

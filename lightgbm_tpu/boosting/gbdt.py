@@ -24,7 +24,8 @@ from ..data.dataset import BinnedDataset
 from ..metrics import create_metrics
 from ..objectives import create_objective
 from ..ops import stage_plan as stage_plan_mod
-from ..ops.grow import _CHUNK, DeviceGrower, device_growth_eligible
+from ..ops.grow import (_CHUNK, BucketRows, DeviceGrower,
+                        device_growth_eligible)
 from ..ops.shard import DealtRows
 from ..ops.traverse import add_tree_score, device_tree
 from ..robust import checkpoint as _checkpoint
@@ -224,7 +225,10 @@ class _WorkDrain:
     and, when a mesh ran the dispatch (two more work columns),
     ``rows_live_max`` (the fullest shard's live rows, summed
     wave by wave) and ``psum_bytes`` (wave slots x the bytes one chip
-    hands the histogram psum for a slot) — at the next
+    hands the histogram psum for a slot), and where a fused multiclass
+    dispatch ran, ``grow.class_trees`` and ``grow.softmax_rows`` (real
+    rows x its iterations), and on a dataset with a categorical feature
+    ``grow.cat_splits`` (from the split records) — at the next
     dispatch and whenever the registry is snapshotted (the booster
     registers ``drain`` as a collector), so the dispatch path never
     waits for the device and a chunk whose ``block_until_ready`` has
@@ -244,6 +248,10 @@ class _WorkDrain:
         # features hold, a default bin 0 left out as the layout leaves
         # it out; set with the grower)
         self.find_slots = 0
+        # per used feature whether it is categorical (set with the
+        # grower; None where no feature is): ``grow.cat_splits`` counts
+        # the splits the records made on one
+        self.cat_features = None
         # the output of the next pushed dispatch that its caller waits
         # on (the score): once THAT is ready the dispatch is over and so
         # are its counters, whatever the backend says of arrays nobody
@@ -264,17 +272,23 @@ class _WorkDrain:
         of a replicated array does."""
         return a.addressable_shards[0].data.is_ready()
 
-    def push(self, nl, work, rows_real: int, goss=None) -> None:
+    def push(self, nl, work, rows_real: int, goss=None, rec_i=None,
+             softmax_iters: int = 0) -> None:
         """Queue one dispatch.  ``goss`` is a fused GOSS scan's (K, 4)
         i32 ``[top rows, sampled rows, keys read, rows its waves may
-        scan]`` a tree, or None."""
+        scan]`` a tree, or None; ``rec_i`` the trees' split records
+        (read only where a feature is categorical); ``softmax_iters``
+        the iterations a fused multiclass dispatch ran (0 elsewhere)."""
         if not obs.enabled():
             return
         work.copy_to_host_async()
+        if self.cat_features is None:
+            rec_i = None
         with self._lock:
             self._pending.append((nl, work, rows_real,
                                   self.psum_slot_bytes, self.awaited,
-                                  self.find_slots, goss))
+                                  self.find_slots, goss, rec_i,
+                                  softmax_iters, self.cat_features))
             self.awaited = None
         self.drain()
 
@@ -287,7 +301,8 @@ class _WorkDrain:
                            for a in (self._pending[0][4],
                                      self._pending[0][1]))):
                 done.append(self._pending.popleft())
-        for nl, work, rows_real, slot_bytes, _, find_slots, goss in done:
+        for (nl, work, rows_real, slot_bytes, _, find_slots, goss, rec_i,
+             softmax_iters, cat_features) in done:
             nl = np.asarray(nl).reshape(-1)
             work = np.asarray(work, np.int64)
             work = work.reshape(-1, work.shape[-1])
@@ -318,6 +333,19 @@ class _WorkDrain:
                 for name, v in zip(("top", "sampled", "keys", "set_rows"),
                                    goss):
                     obs.inc(f"grow.goss_{name}", int(v))
+            if softmax_iters:
+                # a fused multiclass dispatch: its class trees, and the
+                # real rows its softmax read once an iteration
+                obs.inc("grow.class_trees", int(nl.size))
+                obs.inc("grow.softmax_rows", softmax_iters * rows_real)
+            if rec_i is not None:
+                # splits on a categorical feature: record s of a tree of
+                # nl leaves is a split while s < nl - 1
+                rec = np.asarray(rec_i).reshape(nl.size, -1, 5)
+                made = np.arange(rec.shape[1])[None, :] < (nl - 1)[:, None]
+                on_cat = cat_features[np.clip(rec[..., 2], 0,
+                                              cat_features.size - 1)]
+                obs.inc("grow.cat_splits", int((made & on_cat).sum()))
             if work.shape[1] > 9:
                 # a mesh ran it: what the fullest shard contracted, wave
                 # by wave, and the bytes a chip gave the histogram psums
@@ -495,6 +523,9 @@ class GBDT:
                 self._work.find_slots = int(
                     train_set.f_num_bin.sum()
                     - (train_set.f_default_bin == 0).sum())
+                is_cat = np.asarray(train_set.f_is_categorical, bool)
+                self._work.cat_features = is_cat if is_cat.any() else None
+                obs.set_gauge("grow.num_class", self.num_model)
                 log_info("Using on-device tree growth (device_growth="
                          f"{mode})")
                 wp = str(getattr(cfg, "wave_plan", "auto")).lower()
@@ -592,27 +623,34 @@ class GBDT:
         self.valid_sets.append(vs)
 
     # ------------------------------------------------------------------
-    def boost_from_average(self, class_id: int) -> float:
+    def boost_from_average(self) -> List[float]:
+        """Each class's starting score (0.0 where none), added to the
+        training score in ONE operation: an update a class would hold a
+        copy of the whole (K, N) score per class in flight, K of them
+        queued at once ahead of the device."""
         cfg = self.config
+        init_scores = [0.0] * self.num_model
         if (self.models or self.has_init_score or self.objective is None):
-            return 0.0
+            return init_scores
         if cfg.boost_from_average or self.train_set.num_features == 0:
-            init_score = self.objective.boost_from_score(class_id)
-            if abs(init_score) > K_EPSILON:
-                self.train_score = self.train_score.at[class_id].add(
-                    init_score)
+            for k in range(self.num_model):
+                init_score = self.objective.boost_from_score(k)
+                if abs(init_score) > K_EPSILON:
+                    init_scores[k] = init_score
+                    log_info(f"Start training from score {init_score:f}")
+            if any(init_scores):
+                add = jnp.asarray(init_scores, jnp.float32)[:, None]
+                self.train_score = self.train_score + add
                 if self._grower is None:
                     # device path: valid sets receive the bias through the
                     # materialized first tree at catch-up time instead
                     for v in self.valid_sets:
-                        v.score = v.score.at[class_id].add(init_score)
-                log_info(f"Start training from score {init_score:f}")
-                return init_score
+                        v.score = v.score + add
         elif self.objective.name in ("regression_l1", "quantile", "mape"):
             log_warning(f"Disabling boost_from_average in "
                         f"{self.objective.name} may cause the slow "
                         f"convergence")
-        return 0.0
+        return init_scores
 
     # ------------------------------------------------------------------
     @property
@@ -679,8 +717,7 @@ class GBDT:
                               "or device_growth fallback)")
         init_scores = [0.0] * self.num_model
         if gradients is None or hessians is None:
-            for k in range(self.num_model):
-                init_scores[k] = self.boost_from_average(k)
+            init_scores = self.boost_from_average()
             grad, hess = self.objective.get_gradients(self.train_score)
             if grad.ndim == 1:
                 grad, hess = grad[None, :], hess[None, :]
@@ -735,8 +772,9 @@ class GBDT:
         return False
 
     # ------------------------------------------------------------------
-    # on-device fast path: one dispatch per class per iteration, no
-    # per-split sync
+    # on-device per-iteration path: one dispatch per tree, no per-split
+    # sync (train_chunked fuses a softmax multiclass iteration's class
+    # trees instead)
     def _device_row_mask(self):
         """(N,) f32 0/1 in-bag indicator from the learner's permutation
         buffer, or None when every row is in the bag.  Cached until the
@@ -756,8 +794,7 @@ class GBDT:
     def _device_gradients(self):
         """(grad (K,N), hess (K,N), per-class init biases) for the
         device path; RF overrides with its fixed targets."""
-        init_scores = [self.boost_from_average(k)
-                       for k in range(self.num_model)]
+        init_scores = self.boost_from_average()
         grad, hess = self.objective.get_gradients(self.train_score)
         if grad.ndim == 1:
             grad, hess = grad[None, :], hess[None, :]
@@ -824,7 +861,7 @@ class GBDT:
             self.models.append(_PendingTree(
                 rec_i, rec_f, rec_c, nl, root_val, shrink,
                 init_scores[k], work))
-            self._push_work(nl, work)
+            self._push_work(nl, work, rec_i=rec_i)
             nls.append(nl)
         self.iter += 1
         # stump check: inspect num_leaves with a 4-iteration lag — the
@@ -857,18 +894,24 @@ class GBDT:
         """(grad_fn, gargs) when fused multi-iteration training is sound
         for the CURRENT state, else None.  Sound means: a boosting class
         that declares the scan its own (``_FUSED_SCAN``: GBDT, and GOSS
-        on one chip; not DART or RF), single model, and an objective
-        exposing a pure device gradient.  Bagging, feature_fraction and
-        GOSS's row selection do not disqualify: their draws live inside
-        the fused scan (DeviceGrower.fused_train), which is what lets
-        the fork harness's exact config (feature_fraction=0.8,
-        bagging_freq=5) use the fastest path."""
+        on one chip; not DART or RF) and an objective exposing a pure
+        device gradient: one model, or the softmax multiclass objective
+        on one chip, whose scan grows every class tree of an iteration
+        in the same dispatch (``GrowerPrograms._class_scan``; the
+        classes ``class_need_train`` keeps).  Bagging, feature_fraction
+        and GOSS's row selection do not disqualify: their draws live
+        inside the fused scan (DeviceGrower.fused_train), which is what
+        lets the fork harness's exact config (feature_fraction=0.8,
+        bagging_freq=5) use the fastest path.  ``multiclassova`` stays
+        off the scan (its K binary objectives give no device gradient:
+        a dispatch a tree), as does multiclass on a mesh."""
         if (self._grower is None
                 or not type(self).__dict__.get("_FUSED_SCAN", False)
-                or self.num_model != 1
                 or self.train_set.num_features == 0
                 or self.objective is None
-                or not self.class_need_train[0]):
+                or not any(self.class_need_train)
+                or (self.num_model > 1
+                    and self._grower.deal is not None)):
             return None
         if (getattr(self._grower, "mesh", None) is not None
                 and not getattr(self.objective, "device_grad_rowwise",
@@ -940,11 +983,24 @@ class GBDT:
         iterations exactly as before.
         """
         fg = self._fused_grad_fn()
+        if (fg is not None and self.num_model > 1 and not self.models
+                and not all(self.class_need_train) and n_iters > 0):
+            # a class with nothing to learn adds its constant to its
+            # score after the first iteration's gradients were taken:
+            # that iteration runs a tree a dispatch, the rest fuse
+            if self.train_one_iter():
+                return True
+            n_iters -= 1
         # a request smaller than the chunk still deserves ONE fused
         # dispatch of its own length (otherwise update_chunked(15) with
-        # the default chunk=20 would silently run fully per-iteration)
+        # the default chunk=20 would silently run fully per-iteration);
+        # a softmax iteration's class trees always share one dispatch
         chunk = min(chunk, n_iters)
-        if fg is None or chunk <= 1:
+        if self.num_model > 1:
+            chunk = min(max(chunk, 1), n_iters)
+        elif chunk < 2:
+            fg = None
+        if fg is None:
             for _ in range(n_iters):
                 if self.train_one_iter():
                     return True
@@ -981,13 +1037,20 @@ class GBDT:
         return False
 
     def _fused_chunk(self, chunk, lr, gargs, grad_fn, sp) -> bool:
-        """One fused dispatch of ``chunk`` trees inside the
+        """One fused dispatch of ``chunk`` iterations inside the
         ``train.chunk`` span ``sp``; True when the PREVIOUS chunk turned
         out to have stalled (every tree a stump)."""
-        bias = self.boost_from_average(0) if not self.models else 0.0
+        biases = self.boost_from_average()
+        multi = self.num_model > 1
         fused = self._grower.fused_train(chunk)
         deal = self._grower.deal
-        if deal is None:
+        if multi:
+            if not isinstance(self.train_score, BucketRows):
+                self.train_score = BucketRows(
+                    self._grower.bucket_rows(self.train_score),
+                    self.num_data)
+            score_in = self.train_score.padded   # written in place
+        elif deal is None:
             score_in = self.train_score[0]
         elif isinstance(self.train_score, DealtRows):
             score_in = self.train_score.dealt    # the last dispatch's
@@ -1002,7 +1065,9 @@ class GBDT:
         # a GOSS scan hands each tree's row selection on besides
         rows = recs[7] if len(recs) > 7 else None
         sp.sync_value = score
-        if deal is None:
+        if multi:
+            self.train_score = BucketRows(score, self.num_data)
+        elif deal is None:
             self.train_score = self.train_score.at[0].set(score)
         else:
             # the score stays dealt over the mesh until something reads
@@ -1012,17 +1077,27 @@ class GBDT:
         quant = bool(getattr(self._grower, "quant_bits", 0))
         stack = _RecStack(rec_i, rec_f, rec_c, nl, work,
                           qscales if quant else None)
+        # iteration-major, class-minor; a class with nothing to learn
+        # takes a stump of its own (its constant went in at iteration 0)
+        classes = getattr(grad_fn, "classes", (0,))
+        shrink = self.shrinkage_rate * self._tree_multiplier()
+        j = 0
         for i in range(chunk):
-            self.models.append(_PendingChunkTree(
-                stack, i, self.shrinkage_rate * self._tree_multiplier(),
-                bias if i == 0 else 0.0))
+            for k in range(self.num_model):
+                if k not in classes:
+                    self.models.append(Tree(2))
+                    continue
+                self.models.append(_PendingChunkTree(
+                    stack, j, shrink, biases[k] if i == 0 else 0.0))
+                j += 1
         if deal is not None:
             self._work.awaited = score
         if rows is not None:
             self._keep_rows(self.iter, chunk, rows)
         with obs.span("chunk.work_drain", cat="boost"):
             self._push_work(nl, work,
-                            None if rows is None else rows[1])
+                            None if rows is None else rows[1], rec_i,
+                            chunk if multi else 0)
         self.iter += chunk
         # lagged stall check: the PREVIOUS chunk's records have landed
         # by now (this chunk is seconds of device work), so reading
@@ -1039,10 +1114,12 @@ class GBDT:
                     np.asarray(prev.qscales)[-1].tolist())
             return bool((prev.host()[3] <= 1).all())
 
-    def _push_work(self, nl, work, goss=None) -> None:
+    def _push_work(self, nl, work, goss=None, rec_i=None,
+                   softmax_iters=0) -> None:
         """Queue one dispatch's per-tree leaf counts and work counters
         for the registry (``_WorkDrain``)."""
-        self._work.push(nl, work, self.num_data, goss)
+        self._work.push(nl, work, self.num_data, goss, rec_i,
+                        softmax_iters)
 
     def _keep_rows(self, it0, chunk, rows) -> None:
         """What a fused scan recorded of the rows trees ``it0`` ..
@@ -1050,10 +1127,11 @@ class GBDT:
         records any)."""
 
     def _row_order_score(self) -> None:
-        """Bring a score that fused dispatches left dealt over the mesh
-        back to a plain ``(1, N)`` device array in row order, for the
-        paths that update it a tree at a time."""
-        if isinstance(self.train_score, DealtRows):
+        """Bring a score that fused dispatches left dealt over the mesh,
+        or at the row bucket's width, back to a plain ``(K, N)`` device
+        array in row order, for the paths that update it a tree at a
+        time."""
+        if isinstance(self.train_score, (DealtRows, BucketRows)):
             self.train_score = self.train_score.rows()
 
     def _sync_fused_bagging(self):

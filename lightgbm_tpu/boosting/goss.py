@@ -53,9 +53,11 @@ class GOSS(GBDT):
         built for; anywhere else GOSS trains a tree a dispatch: under a
         mesh or the row-sharded learners each rank selects from its own
         rows, and a learning rate changed since the programs were built
-        moves the warm-up."""
+        moves the warm-up; and a multiclass objective selects over every
+        class's gradients at once, which the scan does not."""
         g = self._grower
         if (g is None or getattr(g, "mesh", None) is not None
+                or self.num_model > 1
                 or type(self.learner).goss_state
                 is not SerialTreeLearner.goss_state
                 or g.programs._goss is None

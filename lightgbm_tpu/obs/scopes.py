@@ -34,6 +34,8 @@ def wave_hist_stage(stage: int) -> str:
 
 SCOPES = (
     "lgb.gradient",      # objective gradients inside the fused scan
+    "lgb.softmax_grad",  # ... multiclass: the iteration's softmax
+    #                      normaliser and each class tree's g and h
     "lgb.bag_draw",      # bagging row mask / feature_fraction mask draws
     "lgb.goss_select",   # GOSS: a tree's top |g*h| rows and its sample
     "lgb.stat_cols",     # pad/valid masking, stat columns, quantisation
@@ -42,6 +44,7 @@ SCOPES = (
     "lgb.wave_gather",   # its live rows brought to the front (MXU compaction)
     "lgb.hist_state",    # sibling subtraction + per-leaf histogram writes
     "lgb.find_best",     # the gain scan over a histogram stack
+    "lgb.find_best_cat",  # ... its categorical half (sorted subsets)
     "lgb.split_apply",   # top-k selection, leaf_id routing, record writes
     "lgb.stage_loop",    # a stage's while: carried-state copies, condition
     "lgb.leaf_refit",    # quantised runs: full-precision leaf refit

@@ -60,7 +60,8 @@ performs ZERO new traces (obs counters ``grow.cache_hits``/``misses``).
 Supports: numerical features, missing-value routing (None/Zero/NaN),
 categorical optimal splits (the winning category set travels as an
 8-word bin bitset), feature_fraction masks, bagging/GOSS via a 0/1
-row-mask column, multiclass (one dispatch per class),
+row-mask column, multiclass (softmax: every class tree of an
+iteration in one dispatch of the fused scan),
 L1/L2/max_delta_step, DART/RF (driven from boosting/).  Still host-only:
 monotone constraints, forced splits, renew-tree-output objectives.
 """
@@ -340,6 +341,13 @@ class GrowerPrograms:
         self.nb = int(nb)
         self.num_features = int(num_features)
         self.has_cat = bool(has_cat)
+        # a softmax multiclass booster hands its (K, bucket) score, the
+        # largest array of a dispatch, to the fused program to be
+        # written in place (it keeps it at the bucket's width between
+        # dispatches: DeviceGrower.fused_train)
+        self.donate_score = (shard is None
+                             and str(config.objective) == "multiclass"
+                             and int(config.num_class) > 1)
         self.num_leaves = int(config.num_leaves)
         self.num_slots = self.num_groups * self.nb
         self.n_pad = _ceil_to(max(self.num_data, _CHUNK), _CHUNK)
@@ -1624,7 +1632,11 @@ class GrowerPrograms:
         rows past it are train_row_bucketing pad).
         ``grad_fn(score, gargs) -> (grad, hess)`` comes from
         ``ObjectiveFunction.device_grad`` (pure jnp; all arrays via
-        ``gargs``).  Compiled once per (length, grad_fn) pair — callers
+        ``gargs``); a softmax multiclass ``grad_fn`` (one with
+        ``classes``) makes ``score`` the ``(K, n)`` score of every class
+        and each iteration yield a tree of each of its classes
+        (:meth:`_class_scan`: the records' leading axis is then
+        ``length x len(classes)``).  Compiled once per (length, grad_fn) pair — callers
         must reuse one grad_fn instance to hit the jit cache.
         ``DeviceGrower.fused_train`` wraps this with the grower's own
         meta/hyper/tables so boosting-layer call sites stay unchanged.
@@ -1701,6 +1713,12 @@ class GrowerPrograms:
                 shard-local cutoff when sharded."""
                 no_mask = jnp.zeros((0,), jnp.float32)
                 its = jnp.arange(length, dtype=jnp.int32) + it0
+                classes = getattr(grad_fn, "classes", None)
+                if classes is not None:
+                    return self._class_scan(
+                        binned, binned_t, score, lr, gargs, its,
+                        num_valid, meta, hyper, tables, grad_fn,
+                        draw_bag if use_bag else None)
 
                 def body(carry, it):
                     sc, bmask = (carry if use_bag else (carry, None))
@@ -1777,9 +1795,68 @@ class GrowerPrograms:
 
             self._fused[length] = obs.track_jit(
                 "fused_train_sharded" if sp is not None else "fused_train",
-                jax.jit(run, static_argnames=("grad_fn",)),
+                jax.jit(run, static_argnames=("grad_fn",),
+                        donate_argnums=(2,) if self.donate_score else ()),
                 static_info=(f"len={length}",))
         return self._fused[length]
+
+    def _class_scan(self, binned, binned_t, score, lr, gargs, its,
+                    num_valid, meta, hyper, tables, grad_fn, draw_bag):
+        """The fused scan of a softmax multiclass objective (one chip):
+        ``score`` is the ``(K, n)`` score of every class, and each
+        iteration of ``its`` takes the softmax's normaliser once from
+        the scores at its start (LightGBM's ``Boosting()``), then grows
+        the tree of each class of ``grad_fn.classes`` in a ``lax.scan``
+        over them, class ``k`` from its own gradient (read from its own
+        score row, which no tree before it touched) into its own score
+        row, its feature mask and quantisation noise keyed by the global
+        tree index ``it * K + k``.  The bag (``draw_bag``, else None) is
+        drawn an iteration, for all its classes.  The records come out
+        iteration-major, class-minor: ``(len(its) * len(classes), ...)``,
+        LightGBM's order of the trees."""
+        if self._goss is not None or self.shard is not None:
+            raise ValueError("the multiclass scan runs on one chip, "
+                             "without GOSS")
+        num_class = score.shape[0]
+        classes = jnp.asarray(grad_fn.classes, jnp.int32)
+        bag_freq = self._bag_freq
+        with_mask = draw_bag is not None
+        no_mask = jnp.zeros((0,), jnp.float32)
+
+        def iteration(carry, it):
+            sc, bmask = carry if with_mask else (carry, no_mask)
+            with jax.named_scope("lgb.gradient"), \
+                    jax.named_scope("lgb.softmax_grad"):
+                rows = grad_fn.rows(sc)
+            if with_mask:
+                with jax.named_scope("lgb.bag_draw"):
+                    bmask = jax.lax.cond(it % bag_freq == 0,
+                                         lambda: draw_bag(it),
+                                         lambda: bmask)
+
+            def grow_class(s, k):
+                own = jax.lax.dynamic_index_in_dim(s, k, keepdims=False)
+                with jax.named_scope("lgb.gradient"), \
+                        jax.named_scope("lgb.softmax_grad"):
+                    g, h = grad_fn(own, gargs, rows, k)
+                tree = it * num_class + k
+                new_row, *recs = self._grow_impl(
+                    binned, binned_t, own, g, h,
+                    self.feature_mask_for(tree), lr, bmask, tree,
+                    num_valid, meta, hyper, tables, with_mask=with_mask)
+                return jax.lax.dynamic_update_index_in_dim(
+                    s, new_row, k, 0), tuple(recs)
+
+            sc, recs = jax.lax.scan(grow_class, sc, classes)
+            return ((sc, bmask) if with_mask else sc), recs
+
+        init = score
+        if with_mask:
+            it0 = its[0]
+            init = (score, draw_bag(it0 - it0 % bag_freq))
+        final, recs = jax.lax.scan(iteration, init, its)
+        recs = tuple(r.reshape((-1,) + r.shape[2:]) for r in recs)
+        return (final[0] if with_mask else final), recs
 
 
 # ---------------------------------------------------------------------------
@@ -2237,7 +2314,10 @@ class DeviceGrower:
         taken, and the new score returned, in the dealt layout
         (:meth:`deal_rows`): the caller deals them once and keeps the
         score dealt between dispatches, so a dispatch runs no per-row
-        operation of its own.
+        operation of its own.  A multiclass ``(K, n)`` score is taken
+        and returned at the row bucket's width (:meth:`bucket_rows`),
+        and written in place (``GrowerPrograms.donate_score``): the
+        caller keeps it so between dispatches.
         """
         raw = self.programs.fused_train(length)
         meta, hyper, tables = self.meta, self.hyper, self.tables
@@ -2265,15 +2345,22 @@ class DeviceGrower:
             if sharded:
                 obs.inc("grow.sharded_dispatches")
             if row_pad:
-                score = jnp.pad(score, (0, row_pad))
+                if score.ndim == 1:
+                    score = jnp.pad(score, (0, row_pad))
                 gargs = jax.tree_util.tree_map(_pad_rows, gargs)
             final_score, recs = raw(binned, binned_t, score, lr, gargs,
                                     it0, num_valid, meta, hyper, tables,
                                     grad_fn=grad_fn)
-            if row_pad:
+            if row_pad and final_score.ndim == 1:
                 final_score = final_score[:real_n]
             return final_score, recs
         return run
+
+    def bucket_rows(self, a):
+        """``a`` with its last (row) axis padded out to the row bucket."""
+        if not self._row_pad:
+            return a
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, self._row_pad)])
 
     def deal_rows(self, tree):
         """``tree`` with every per-row leaf (leading axis ``num_data``,
@@ -2497,12 +2584,54 @@ class DeviceGrower:
         return {"psum_ms": round(ms, 3)}
 
 
+class BucketRows:
+    """A booster's ``(K, num_rows)`` multiclass training score while it
+    lives at the row bucket's width between fused dispatches
+    (``.padded``, the ``(K, bucket)`` array the next dispatch takes and
+    writes in place: ``DeviceGrower.fused_train``).  Reading it as an
+    array -- ``np.asarray``, an index, a jnp operation -- gives the real
+    rows; nothing is copied until then (as ``ops/shard.py``'s
+    ``DealtRows`` keeps a score dealt over a mesh)."""
+
+    def __init__(self, padded, num_rows: int):
+        self.padded, self.num_rows = padded, int(num_rows)
+        self._rows = None
+
+    shape = property(lambda self: (self.padded.shape[0], self.num_rows))
+    dtype = property(lambda self: self.padded.dtype)
+    ndim = 2
+
+    def block_until_ready(self):
+        self.padded.block_until_ready()
+        return self
+
+    def rows(self):
+        """The ``(K, num_rows)`` device array (kept)."""
+        if self._rows is None:
+            self._rows = (self.padded
+                          if self.padded.shape[1] == self.num_rows
+                          else self.padded[:, :self.num_rows])
+        return self._rows
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.asarray(self.rows())
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+    def __jax_array__(self):
+        return self.rows()
+
+    def __getitem__(self, idx):
+        return self.rows()[idx]
+
+
 def device_growth_eligible(config, dataset, objective, num_model,
                            n_shards: int = 1) -> bool:
     """Whether the dense device grower covers this training configuration.
     Anything it can't do falls back to the host-driven learner.
-    Multiclass runs one grow dispatch per class; bagging/GOSS route a
-    0/1 row mask into the wave histogram's count column."""
+    Every class of a multiclass objective grows its trees here (softmax
+    in the fused scan, one dispatch an iteration; OVA a dispatch a
+    tree); bagging/GOSS route a 0/1 row mask into the wave histogram's
+    count column."""
     if dataset.num_groups == 0 or dataset.num_features == 0:
         return False
     if np.asarray(dataset.monotone_constraints).any():

@@ -19,10 +19,10 @@ single argmax picks the winner.  Semantics mirror the reference:
 * categorical one-hot mode (``num_bin <= max_cat_to_onehot``) and
   sorted-by-gradient-ratio subset scan from both ends with ``cat_smooth`` /
   ``cat_l2`` / ``max_cat_threshold`` (``FindBestThresholdCategorical``,
-  feature_histogram.hpp:113-273).  The reference's sequential
-  ``cnt_cur_group`` gate (an extra thinning of candidates by
-  ``min_data_per_group``) is relaxed to the equivalent right-count bound,
-  which vectorizes; accuracy-level behaviour is covered by the test suite.
+  feature_histogram.hpp:113-273), its sequential ``cnt_cur_group`` gate
+  included: walking the sorted categories, a subset is evaluated only once
+  ``min_data_per_group`` rows have joined it since the last one evaluated
+  (:func:`_group_walk`, a scan over the first half of the bins).
 
 Tie-breaking is deterministic: first-max argmax = the reference's strict
 ``operator>`` sequential updates (lower feature index, dir=-1 first).
@@ -307,6 +307,29 @@ class PerFeatureBest(NamedTuple):
     cat_extra_l2: jnp.ndarray  # (F,) additional l2 for the winning cat mode
 
 
+def _group_walk(cnt, fit, per_group):
+    """v2.2.2's ``cnt_cur_group`` over sorted categories (the last axis):
+    a candidate that ``fit`` admits is evaluated only once ``per_group``
+    rows (``cnt``, each category's) have joined the left side since the
+    candidate evaluated before it; one that is not admitted resets
+    nothing.  The walk covers the first half of the positions, as far as
+    ``(used + 1) / 2`` categories reach; no later one is evaluated."""
+    reach = (cnt.shape[-1] + 1) // 2
+
+    def step(cur, xs):
+        c, ok = xs
+        cur = cur + c
+        ev = ok & (cur >= per_group)
+        return jnp.where(ev, jnp.zeros_like(cur), cur), ev
+
+    c = jnp.moveaxis(cnt[..., :reach], -1, 0)
+    ok = jnp.moveaxis(fit[..., :reach], -1, 0)
+    _, ev = jax.lax.scan(step, jnp.zeros_like(c[0]), (c, ok), unroll=8)
+    ev = jnp.moveaxis(ev, 0, -1)
+    return jnp.pad(ev, [(0, 0)] * (ev.ndim - 1)
+                   + [(0, cnt.shape[-1] - reach)])
+
+
 def per_feature_best(fh, total, constraint, meta: FeatureMeta,
                      hp: SplitHyper, has_cat: bool,
                      min_gain_shift, scales=None) -> PerFeatureBest:
@@ -391,87 +414,94 @@ def per_feature_best(fh, total, constraint, meta: FeatureMeta,
     # =====================================================================
     # categorical
     # =====================================================================
-    fh_f = fh if scales is None else fh.astype(jnp.float32) * scales
-    cnt = fh[..., 2]
-    used_bin_mask = b < (meta.num_bin[:, None] - 1 + (miss == 0))
-    # one-hot mode: left = single bin t (regular l2); single-bin stats
-    # dequantize exactly (one multiply, no summation)
-    oh_gl, oh_hl, oh_cl = fh_f[..., 0], fh_f[..., 1] + K_EPSILON, \
-        fh_f[..., 2]
-    oh_gr, oh_hr, oh_cr = tg - oh_gl, th - oh_hl, tc - oh_cl
-    oh_ok = (used_bin_mask & (oh_cl >= hp.min_data_in_leaf)
-             & (oh_cr >= hp.min_data_in_leaf)
-             & (oh_hl >= hp.min_sum_hessian_in_leaf)
-             & (oh_hr >= hp.min_sum_hessian_in_leaf))
-    oh_gains = _split_gain(oh_gl, oh_hl, oh_gr, oh_hr, l1, l2, mds,
-                           cmin, cmax, 0)
-    oh_gains = jnp.where(oh_ok & (oh_gains > min_gain_shift), oh_gains,
-                         NEG_INF)
-    oh_arg = jnp.argmax(oh_gains, axis=1)
-    oh_best = jnp.take_along_axis(oh_gains, oh_arg[:, None], 1)[:, 0]
+    # the sorted-subset and one-hot scans of every feature, under a name
+    # of their own inside lgb.find_best (has_cat programs only)
+    with jax.named_scope("lgb.find_best_cat"):
+        fh_f = fh if scales is None else fh.astype(jnp.float32) * scales
+        cnt = fh[..., 2]
+        used_bin_mask = b < (meta.num_bin[:, None] - 1 + (miss == 0))
+        # one-hot mode: left = single bin t (regular l2); single-bin stats
+        # dequantize exactly (one multiply, no summation)
+        oh_gl, oh_hl, oh_cl = fh_f[..., 0], fh_f[..., 1] + K_EPSILON, \
+            fh_f[..., 2]
+        oh_gr, oh_hr, oh_cr = tg - oh_gl, th - oh_hl, tc - oh_cl
+        oh_ok = (used_bin_mask & (oh_cl >= hp.min_data_in_leaf)
+                 & (oh_cr >= hp.min_data_in_leaf)
+                 & (oh_hl >= hp.min_sum_hessian_in_leaf)
+                 & (oh_hr >= hp.min_sum_hessian_in_leaf))
+        oh_gains = _split_gain(oh_gl, oh_hl, oh_gr, oh_hr, l1, l2, mds,
+                               cmin, cmax, 0)
+        oh_gains = jnp.where(oh_ok & (oh_gains > min_gain_shift), oh_gains,
+                             NEG_INF)
+        oh_arg = jnp.argmax(oh_gains, axis=1)
+        oh_best = jnp.take_along_axis(oh_gains, oh_arg[:, None], 1)[:, 0]
 
-    # sorted-subset mode (l2 + cat_l2, ratio = g / (h + cat_smooth))
-    l2c = l2 + hp.cat_l2
-    eligible = used_bin_mask & (cnt >= hp.cat_smooth)
-    n_used = eligible.sum(axis=1).astype(jnp.float32)    # (F,)
-    ratio = jnp.where(eligible,
-                      fh_f[..., 0] / (fh_f[..., 1] + hp.cat_smooth),
-                      jnp.inf)
-    order = jnp.argsort(ratio, axis=1, stable=True)      # (F,256)
-    sorted_fh = jnp.take_along_axis(fh, order[..., None], 1)
-    sorted_el = jnp.take_along_axis(eligible, order, 1)
-    sorted_fh = sorted_fh * sorted_el[..., None]
-    rank = b.astype(jnp.float32)                         # sorted position
-    max_num_cat = jnp.minimum(hp.max_cat_threshold,
-                              jnp.floor((n_used + 1.0) / 2.0))[:, None]
+        # sorted-subset mode (l2 + cat_l2, ratio = g / (h + cat_smooth))
+        l2c = l2 + hp.cat_l2
+        eligible = used_bin_mask & (cnt >= hp.cat_smooth)
+        n_used = eligible.sum(axis=1).astype(jnp.float32)    # (F,)
+        ratio = jnp.where(eligible,
+                          fh_f[..., 0] / (fh_f[..., 1] + hp.cat_smooth),
+                          jnp.inf)
+        order = jnp.argsort(ratio, axis=1, stable=True)      # (F,256)
+        sorted_fh = jnp.take_along_axis(fh, order[..., None], 1)
+        sorted_el = jnp.take_along_axis(eligible, order, 1)
+        sorted_fh = sorted_fh * sorted_el[..., None]
+        rank = b.astype(jnp.float32)                         # sorted position
+        max_num_cat = jnp.minimum(hp.max_cat_threshold,
+                                  jnp.floor((n_used + 1.0) / 2.0))[:, None]
 
-    def _cat_scan(sfh):
-        ps = jnp.cumsum(sfh, axis=1)                     # exact when int
-        psf = ps if scales is None else ps.astype(jnp.float32) * scales
-        k = rank + 1.0                                   # bins taken
-        sgl, shl, scl = psf[..., 0], psf[..., 1] + K_EPSILON, psf[..., 2]
-        sgr, shr, scr = tg - sgl, th - shl, tc - scl
-        ok = ((k <= max_num_cat)
-              & (k <= jnp.maximum(n_used[:, None] - 1.0, 0.0))
-              & (scl >= hp.min_data_in_leaf)
-              & (scr >= jnp.maximum(hp.min_data_in_leaf,
-                                    hp.min_data_per_group))
-              & (shl >= hp.min_sum_hessian_in_leaf)
-              & (shr >= hp.min_sum_hessian_in_leaf))
-        g = _split_gain(sgl, shl, sgr, shr, l1, l2c, mds, cmin, cmax, 0)
-        g = jnp.where(ok & (g > min_gain_shift), g, NEG_INF)
-        return g, ps
+        def _cat_scan(sfh):
+            """Gains of the first k sorted categories as the left side,
+            from both ends at once (``sfh`` (2, F, 256, 3)), where
+            v2.2.2's walk evaluates them."""
+            ps = jnp.cumsum(sfh, axis=2)                     # exact when int
+            psf = ps if scales is None else ps.astype(jnp.float32) * scales
+            k = rank + 1.0                                   # bins taken
+            sgl, shl, scl = psf[..., 0], psf[..., 1] + K_EPSILON, psf[..., 2]
+            sgr, shr, scr = tg - sgl, th - shl, tc - scl
+            # the left side grows with k and the right shrinks: the walk
+            # passes over the first k that fail on the left and stops at
+            # the first that fails on the right
+            fit = ((k <= max_num_cat)
+                   & (scl >= hp.min_data_in_leaf)
+                   & (scr >= jnp.maximum(hp.min_data_in_leaf,
+                                         hp.min_data_per_group))
+                   & (shl >= hp.min_sum_hessian_in_leaf)
+                   & (shr >= hp.min_sum_hessian_in_leaf))
+            ok = _group_walk(sfh[..., 2], fit, hp.min_data_per_group)
+            g = _split_gain(sgl, shl, sgr, shr, l1, l2c, mds, cmin, cmax, 0)
+            return jnp.where(ok & (g > min_gain_shift), g, NEG_INF)
 
-    fwd_gains, _ = _cat_scan(sorted_fh)
-    rev_fh = jnp.flip(jnp.where(sorted_el[..., None], sorted_fh, 0), axis=1)
-    # reversed order: take from the high-ratio end of the eligible prefix;
-    # roll so eligible entries lead
-    shift_amt = (256 - n_used.astype(jnp.int32))
-    rev_fh = jax.vmap(lambda x, s: jnp.roll(x, -s, axis=0))(rev_fh, shift_amt)
-    rev_gains, _ = _cat_scan(rev_fh)
-    both = jnp.stack([fwd_gains, rev_gains], axis=1)     # (F,2,256)
-    flat_cg = both.reshape(nf, -1)
-    srt_arg = jnp.argmax(flat_cg, axis=1)
-    srt_best = jnp.take_along_axis(flat_cg, srt_arg[:, None], 1)[:, 0]
-    srt_dir_fwd = srt_arg < 256
-    srt_k = (srt_arg % 256) + 1
+        rev_fh = jnp.flip(jnp.where(sorted_el[..., None], sorted_fh, 0), axis=1)
+        # reversed order: take from the high-ratio end of the eligible prefix;
+        # roll so eligible entries lead
+        shift_amt = (256 - n_used.astype(jnp.int32))
+        rev_fh = jax.vmap(lambda x, s: jnp.roll(x, -s, axis=0))(rev_fh, shift_amt)
+        both = jnp.moveaxis(_cat_scan(jnp.stack([sorted_fh, rev_fh])),
+                            0, 1)                        # (F,2,256)
+        flat_cg = both.reshape(nf, -1)
+        srt_arg = jnp.argmax(flat_cg, axis=1)
+        srt_best = jnp.take_along_axis(flat_cg, srt_arg[:, None], 1)[:, 0]
+        srt_dir_fwd = srt_arg < 256
+        srt_k = (srt_arg % 256) + 1
 
-    use_onehot = nb[:, 0] <= hp.max_cat_to_onehot
-    cat_best_gain = jnp.where(use_onehot, oh_best, srt_best)
+        use_onehot = nb[:, 0] <= hp.max_cat_to_onehot
+        cat_best_gain = jnp.where(use_onehot, oh_best, srt_best)
 
-    # membership mask over bins for the winning candidate of each feature
-    inv_pos = jnp.argsort(order, axis=1, stable=True)    # bin -> sorted pos
-    fwd_member = inv_pos < srt_k[:, None]
-    rev_member = ((inv_pos >= (n_used[:, None].astype(jnp.int32)
-                               - srt_k[:, None]))
-                  & (inv_pos < n_used[:, None].astype(jnp.int32)))
-    srt_member = (jnp.where(srt_dir_fwd[:, None], fwd_member, rev_member)
-                  & eligible)
-    oh_member = b == oh_arg[:, None]
-    cat_member = jnp.where(use_onehot[:, None], oh_member, srt_member)
-    # raw-unit left stats (exact int32 sums under the quantized scan)
-    cat_left = jnp.einsum("fb,fbk->fk", cat_member.astype(fh.dtype), fh)
-    cat_extra_l2 = jnp.where(use_onehot, 0.0, hp.cat_l2)
+        # membership mask over bins for the winning candidate of each feature
+        inv_pos = jnp.argsort(order, axis=1, stable=True)    # bin -> sorted pos
+        fwd_member = inv_pos < srt_k[:, None]
+        rev_member = ((inv_pos >= (n_used[:, None].astype(jnp.int32)
+                                   - srt_k[:, None]))
+                      & (inv_pos < n_used[:, None].astype(jnp.int32)))
+        srt_member = (jnp.where(srt_dir_fwd[:, None], fwd_member, rev_member)
+                      & eligible)
+        oh_member = b == oh_arg[:, None]
+        cat_member = jnp.where(use_onehot[:, None], oh_member, srt_member)
+        # raw-unit left stats (exact int32 sums under the quantized scan)
+        cat_left = jnp.einsum("fb,fbk->fk", cat_member.astype(fh.dtype), fh)
+        cat_extra_l2 = jnp.where(use_onehot, 0.0, hp.cat_l2)
 
     is_cat = meta.is_cat == 1
     return PerFeatureBest(

@@ -331,11 +331,22 @@ class Tree:
             dt = int(self.decision_type[idx])
             is_cat = bool(dt & K_CATEGORICAL_MASK)
             missing = (dt >> 2) & 3
+            if is_cat:
+                # the categories that go left, as LightGBM's dump writes
+                # them (tree.cpp NodeToJSON): "1||3||5"
+                ci = int(self.threshold[idx])
+                words = self.cat_threshold[self.cat_boundaries[ci]:
+                                           self.cat_boundaries[ci + 1]]
+                threshold = "||".join(
+                    str(c) for c in range(32 * len(words))
+                    if find_in_bitset(words, c))
+            else:
+                threshold = float(self.threshold[idx])
             out = {
                 "split_index": int(idx),
                 "split_feature": int(self.split_feature[idx]),
                 "split_gain": float(self.split_gain[idx]),
-                "threshold": float(self.threshold[idx]),
+                "threshold": threshold,
                 "decision_type": "==" if is_cat else "<=",
                 "default_left": bool(dt & K_DEFAULT_LEFT_MASK),
                 "missing_type": ["None", "Zero", "NaN"][missing],

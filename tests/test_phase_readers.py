@@ -187,7 +187,9 @@ def test_entry_lists_only_cells_whose_kind_hands_scopes_over(name):
     else:
         assert entry["workloads"] == [c for c in entry["workloads"]
                                       if c != "criteo-share.train"]
-        assert len(entry["workloads"]) == 3
+        assert entry["workloads"] == [
+            "cdn-window.retrain", "criteo-dp.train-4chip",
+            "allstate-onehot.train", "expedia-hotel.train"]
 
 
 def test_new_entries_follow_the_accepted_ones():
