@@ -226,8 +226,11 @@ class _WorkDrain:
     ``rows_live_max`` (the fullest shard's live rows, summed
     wave by wave) and ``psum_bytes`` (wave slots x the bytes one chip
     hands the histogram psum for a slot), and where a fused multiclass
-    dispatch ran, ``grow.class_trees`` and ``grow.softmax_rows`` (real
-    rows x its iterations), and on a dataset with a categorical feature
+    dispatch ran, ``grow.class_trees``, ``grow.softmax_rows`` (real
+    rows x its iterations) and ``grow.root_shared`` (class trees whose
+    root histogram the iteration's shared pass took, whose chunks and
+    tiles ``rows_scanned`` and ``hist_tiles`` count once an iteration),
+    and on a dataset with a categorical feature
     ``grow.cat_splits`` (from the split records) — at the next
     dispatch and whenever the registry is snapshotted (the booster
     registers ``drain`` as a collector), so the dispatch path never
@@ -273,22 +276,28 @@ class _WorkDrain:
         return a.addressable_shards[0].data.is_ready()
 
     def push(self, nl, work, rows_real: int, goss=None, rec_i=None,
-             softmax_iters: int = 0) -> None:
+             softmax_iters: int = 0, root_pass=None) -> None:
         """Queue one dispatch.  ``goss`` is a fused GOSS scan's (K, 4)
         i32 ``[top rows, sampled rows, keys read, rows its waves may
         scan]`` a tree, or None; ``rec_i`` the trees' split records
         (read only where a feature is categorical); ``softmax_iters``
-        the iterations a fused multiclass dispatch ran (0 elsewhere)."""
+        the iterations a fused multiclass dispatch ran (0 elsewhere),
+        ``root_pass`` its (iterations, 3) i32 ``[row chunks, tiles,
+        class trees rooted]`` of each iteration's shared root pass
+        (zeros where its trees contracted their own roots)."""
         if not obs.enabled():
             return
         work.copy_to_host_async()
+        if root_pass is not None:
+            root_pass.copy_to_host_async()
         if self.cat_features is None:
             rec_i = None
         with self._lock:
             self._pending.append((nl, work, rows_real,
                                   self.psum_slot_bytes, self.awaited,
                                   self.find_slots, goss, rec_i,
-                                  softmax_iters, self.cat_features))
+                                  softmax_iters, self.cat_features,
+                                  root_pass))
             self.awaited = None
         self.drain()
 
@@ -302,7 +311,7 @@ class _WorkDrain:
                                      self._pending[0][1]))):
                 done.append(self._pending.popleft())
         for (nl, work, rows_real, slot_bytes, _, find_slots, goss, rec_i,
-             softmax_iters, cat_features) in done:
+             softmax_iters, cat_features, root_pass) in done:
             nl = np.asarray(nl).reshape(-1)
             work = np.asarray(work, np.int64)
             work = work.reshape(-1, work.shape[-1])
@@ -338,6 +347,15 @@ class _WorkDrain:
                 # real rows its softmax read once an iteration
                 obs.inc("grow.class_trees", int(nl.size))
                 obs.inc("grow.softmax_rows", softmax_iters * rows_real)
+            if root_pass is not None:
+                # the shared root passes: their chunks and tiles once an
+                # iteration (the root waves they stood in for ran none),
+                # and the class trees whose root histogram they took
+                chunks, tiles, rooted = np.asarray(
+                    root_pass, np.int64).reshape(-1, 3).sum(0).tolist()
+                obs.inc("grow.rows_scanned", chunks * _CHUNK)
+                obs.inc("grow.hist_tiles", tiles)
+                obs.inc("grow.root_shared", rooted)
             if rec_i is not None:
                 # splits on a categorical feature: record s of a tree of
                 # nl leaves is a split while s < nl - 1
@@ -1062,8 +1080,10 @@ class GBDT:
                 score_in, lr, gargs,
                 jnp.asarray(self.iter, jnp.int32), grad_fn=grad_fn))
         rec_i, rec_f, rec_c, nl, _root, work, qscales = recs[:7]
-        # a GOSS scan hands each tree's row selection on besides
-        rows = recs[7] if len(recs) > 7 else None
+        # a GOSS scan hands each tree's row selection on besides, a
+        # multiclass scan the work of each iteration's shared root pass
+        extra = recs[7] if len(recs) > 7 else None
+        rows, root_pass = (None, extra) if multi else (extra, None)
         sp.sync_value = score
         if multi:
             self.train_score = BucketRows(score, self.num_data)
@@ -1097,7 +1117,7 @@ class GBDT:
         with obs.span("chunk.work_drain", cat="boost"):
             self._push_work(nl, work,
                             None if rows is None else rows[1], rec_i,
-                            chunk if multi else 0)
+                            chunk if multi else 0, root_pass)
         self.iter += chunk
         # lagged stall check: the PREVIOUS chunk's records have landed
         # by now (this chunk is seconds of device work), so reading
@@ -1115,11 +1135,11 @@ class GBDT:
             return bool((prev.host()[3] <= 1).all())
 
     def _push_work(self, nl, work, goss=None, rec_i=None,
-                   softmax_iters=0) -> None:
+                   softmax_iters=0, root_pass=None) -> None:
         """Queue one dispatch's per-tree leaf counts and work counters
         for the registry (``_WorkDrain``)."""
         self._work.push(nl, work, self.num_data, goss, rec_i,
-                        softmax_iters)
+                        softmax_iters, root_pass)
 
     def _keep_rows(self, it0, chunk, rows) -> None:
         """What a fused scan recorded of the rows trees ``it0`` ..

@@ -304,6 +304,25 @@ def _pending_tiles(pending, hist_cols: int):
     return (n_pending * hist_cols + 127) // 128
 
 
+def _contract_strips(b, bmat, nb: int, mdtype, adtype):
+    """One chunk's histogram: its (CH, G) bins one-hot against the
+    (CH, B) stat columns ``bmat``, (G, nb, B) in ``adtype``.  Bin tiling:
+    a one-hot wider than 64 breaks XLA's operand fusion (max_bin=255
+    measured 10x the max_bin=63 wave, not the expected 4x) — strips of
+    64 keep each einsum in the known-fused regime; out-of-strip bins make
+    all-zero one-hot rows, so the concat reassembles exactly."""
+    bi = b.astype(jnp.int32)
+    outs = []
+    for off in range(0, nb, 64):
+        oh = jax.nn.one_hot(bi - off, min(nb, 64),
+                            dtype=mdtype)       # (CH,G,64)
+        outs.append(jnp.einsum(
+            "cgn,cb->gnb", oh, bmat,
+            preferred_element_type=adtype))
+    return outs[0] if len(outs) == 1 \
+        else jnp.concatenate(outs, axis=1)
+
+
 def default_stage_plan(num_data: int, config) -> list:
     """The legacy doubling plan :func:`get_grower_programs` resolves
     when no explicit plan is given — the single resolution point, so
@@ -806,23 +825,7 @@ class GrowerPrograms:
                 lm = (l[:, None] == pend[None, :]).astype(mdtype)
                 bmat = (lm[:, :, None] * gk[:, None, :]).reshape(
                     ch, w_t * k)
-                # bin tiling: a one-hot wider than 64 breaks XLA's
-                # operand fusion (max_bin=255 measured 10x the
-                # max_bin=63 wave, not the expected 4x) — strips of 64
-                # keep each einsum in the known-fused regime; out-of-
-                # strip bins make all-zero one-hot rows, so the concat
-                # reassembles exactly
-                bi = b.astype(jnp.int32)
-                outs = []
-                for off in range(0, nb, 64):
-                    oh = jax.nn.one_hot(bi - off, min(nb, 64),
-                                        dtype=mdtype)       # (CH,G,64)
-                    outs.append(jnp.einsum(
-                        "cgn,cb->gnb", oh, bmat,
-                        preferred_element_type=adtype))
-                out = outs[0] if len(outs) == 1 \
-                    else jnp.concatenate(outs, axis=1)
-                return acc + out
+                return acc + _contract_strips(b, bmat, nb, mdtype, adtype)
 
             acc = jax.lax.fori_loop(0, visited, body,
                                     jnp.zeros((g, nb, w_t * k), adtype))
@@ -876,12 +879,15 @@ class GrowerPrograms:
                 jnp.stack([visited, n_live, gathered, tiles]))
 
     # ------------------------------------------------------------------
-    def _stat_columns(self, grad, hess, one_f, tree_idx):
+    def _stat_columns(self, grad, hess, one_f, tree_idx, row0=None):
         """(n_pad, K) wave stat columns + (2,) dequantization scales
         (zeros when quantization is off).  ``one_f`` is the f32 0/1 row
         indicator (valid-row mask x bagging mask).  The ONE assembly
-        shared by the production grow program and the profiling probes,
-        so probes time exactly the operand pipeline training runs."""
+        shared by the production grow program, the profiling probes and
+        the shared root pass (:meth:`_root_hists`), so probes time
+        exactly the operand pipeline training runs.  ``row0`` (traced,
+        unquantised only): the rows are the chunk of ``n_pad`` that
+        starts there, which decides their count stripe."""
         n = one_f.shape[0]
         k = self.hist_cols
         if self.quant_bits:
@@ -911,7 +917,9 @@ class GrowerPrograms:
         if k in (4, 6):
             # two striped count columns (<= 2^24 rows each) keep counts
             # integer-exact beyond the single-column f32 limit
-            stripe = (jnp.arange(n) < (n // 2)).astype(jnp.bfloat16)
+            stripe = (jnp.arange(n) < (n // 2)) if row0 is None \
+                else (row0 + jnp.arange(n) < self.n_pad // 2)
+            stripe = stripe.astype(jnp.bfloat16)
             gcols += [one * stripe, one * (1.0 - stripe)]
         else:
             gcols += [one]
@@ -941,7 +949,7 @@ class GrowerPrograms:
     # ------------------------------------------------------------------
     def _grow_impl(self, binned, binned_t, score, grad, hess, feature_mask,
                    lr, row_mask, tree_idx, num_valid, meta, hyper, tables,
-                   *, with_mask, row_set=False):
+                   *, with_mask, row_set=False, root_hist=None):
         """One boosting iteration on device.  Returns (new_score, rec_i
         (L-1,5) i32, rec_f (L-1,9) f32, rec_c (L-1,8) i32, num_leaves
         i32, root_value f32, work (9,) i32 = [waves run, sum of their
@@ -988,14 +996,25 @@ class GrowerPrograms:
         ``Dataset::CopySubset``) and its out-of-bag traversal.  One more
         output then: the rows the waves may scan, at most the real rows
         (all of them where one chunk holds the bucket: nothing to
-        gather)."""
+        gather).
+
+        ``root_hist`` (the multiclass scan, unquantised): the tree's
+        ``(S, 3)`` root histogram, contracted by the iteration's shared
+        pass (:meth:`_root_hists`) from the stat columns this tree would
+        build.  The root wave then runs ahead of the first stage's loop
+        on it, its histogram step left out and everything after it as on
+        a contracted one: the same partitions and leaf counts, but on a
+        TPU some recorded gains, child sums and leaf outputs part from
+        the per-iteration path's in their last bits (PERF.md section 7,
+        row 20).  ``score``, ``grad`` and ``hess`` may come at ``n_pad``
+        rows already (the multiclass scan pads them once)."""
         L, W, S = self.num_leaves, self.wave_width, self.num_slots
         n = self.n_pad
         npad_rows = n - self.num_data
 
         with jax.named_scope("lgb.stat_cols"):
-            grad = jnp.pad(grad, (0, npad_rows))
-            hess = jnp.pad(hess, (0, npad_rows))
+            grad = jnp.pad(grad, (0, n - grad.shape[0]))
+            hess = jnp.pad(hess, (0, n - hess.shape[0]))
             valid_f = jnp.where(jnp.arange(n) < num_valid, 1.0, 0.0)
             # bucket-pad rows may carry garbage gradients (the fused path's
             # grad_fn computes them from padded scores/labels): zero them
@@ -1129,10 +1148,21 @@ class GrowerPrograms:
             gain = jnp.where(ok, packed[:, F_GAIN], NEG_INF)
             return packed.at[:, F_GAIN].set(gain), catm, lint
 
-        def make_wave(Ws: int, stage: int):
+        def make_wave(Ws: int, stage: int, handed_root=None):
           def wave(st: _S) -> _S:
             # 1. fresh histograms for pending smaller children
-            if use_set:
+            if handed_root is not None:
+                # the root wave of a tree whose root histogram came from
+                # the shared pass: slot 0 holds it, the empty slots the
+                # zeros a contraction leaves them; every valid in-bag
+                # row is live in it, and no chunk or tile ran here
+                fresh = jnp.concatenate(
+                    [handed_root[None],
+                     jnp.zeros((Ws - 1,) + handed_root.shape,
+                               handed_root.dtype)])
+                hw = jnp.stack([jnp.int32(0), rows_in_bag, jnp.int32(0),
+                                jnp.int32(0)])
+            elif use_set:
                 fresh, hw = self._wave_hist(
                     wbins.reshape(n, -1), st.wleaf.reshape(n), wstats,
                     st.p_small, handed, wave_scales, stage, bounded=True)
@@ -1432,6 +1462,11 @@ class GrowerPrograms:
             # the while's own self time (its carried-state copies) and
             # its condition take this name; the body's phases are inner
             with jax.named_scope("lgb.stage_loop"):
+                if i == 0 and root_hist is not None:
+                    # the root wave peeled off the first stage's loop,
+                    # on the handed histogram: it always runs (one leaf,
+                    # below every stage's cap of at least 8)
+                    st = make_wave(ws, i, root_hist)(st)
                 st = jax.lax.while_loop(go_on, make_wave(ws, i), st)
         final = st
         leaf_final = final.leaf_id
@@ -1540,7 +1575,7 @@ class GrowerPrograms:
             oh = jax.nn.one_hot(leaf_final, L, dtype=jnp.bfloat16)
             upd = jnp.einsum("nl,lk->nk", oh, vmat,
                              preferred_element_type=jnp.float32)
-            new_score = score + (upd[:, 0] + upd[:, 1])[:self.num_data]
+            new_score = score + (upd[:, 0] + upd[:, 1])[:score.shape[0]]
 
         hwork = final.hwork
         if self.shard is not None:
@@ -1624,7 +1659,9 @@ class GrowerPrograms:
                  [, GOSS: (rows (K,2,ceil(n/32)) u32, counts (K,4) i32,
                  weight (K,) f32): :meth:`_goss_rows`' and, last among
                  the counts, the rows the tree's waves may scan
-                 (``row_set`` of :meth:`_grow_impl`)]))
+                 (``row_set`` of :meth:`_grow_impl`); multiclass: the
+                 (length, 3) i32 work of each iteration's shared root
+                 pass, :meth:`_root_hists`]))
 
         ``it0`` is the global iteration index of the chunk's first tree
         (traced, so resuming mid-run reuses the compiled program);
@@ -1811,9 +1848,11 @@ class GrowerPrograms:
         score row, which no tree before it touched) into its own score
         row, its feature mask and quantisation noise keyed by the global
         tree index ``it * K + k``.  The bag (``draw_bag``, else None) is
-        drawn an iteration, for all its classes.  The records come out
-        iteration-major, class-minor: ``(len(its) * len(classes), ...)``,
-        LightGBM's order of the trees."""
+        drawn an iteration, for all its classes.  Unquantised, each
+        iteration first takes every class's root histogram in one pass
+        (:meth:`_root_hists`).  The records come out iteration-major,
+        class-minor: ``(len(its) * len(classes), ...)``, LightGBM's order
+        of the trees."""
         if self._goss is not None or self.shard is not None:
             raise ValueError("the multiclass scan runs on one chip, "
                              "without GOSS")
@@ -1822,6 +1861,23 @@ class GrowerPrograms:
         bag_freq = self._bag_freq
         with_mask = draw_bag is not None
         no_mask = jnp.zeros((0,), jnp.float32)
+        # quantised, each tree rounds its own g and h with noise keyed
+        # by its own tree index; under a bag of compact_max_live of the
+        # rows or less each root wave brings its bag to the front first
+        # and costs that: there the root waves contract their own
+        shared = not self.quant_bits and not (
+            with_mask and self._bag_fraction <= self.compact_max_live)
+        # the shared pass cuts the score and the softmax's arguments
+        # (label and weights, both a value a row) into the bucket's
+        # chunks: padded once to its rows, the trees' g and h with them
+        m = score.shape[1]
+        pad = self.n_pad - m
+        if not shared:
+            pad = 0
+        if pad:
+            score = jnp.pad(score, ((0, 0), (0, pad)))
+            gargs = jax.tree_util.tree_map(
+                lambda a: jnp.pad(a, (0, pad)), gargs)
 
         def iteration(carry, it):
             sc, bmask = carry if with_mask else (carry, no_mask)
@@ -1833,8 +1889,14 @@ class GrowerPrograms:
                     bmask = jax.lax.cond(it % bag_freq == 0,
                                          lambda: draw_bag(it),
                                          lambda: bmask)
+            roots, pass_work = None, jnp.zeros((3,), jnp.int32)
+            if shared:
+                roots, pass_work = self._root_hists(
+                    binned, sc, gargs, rows, grad_fn,
+                    bmask if with_mask else None, num_valid)
 
-            def grow_class(s, k):
+            def grow_class(s, x):
+                k, root = x
                 own = jax.lax.dynamic_index_in_dim(s, k, keepdims=False)
                 with jax.named_scope("lgb.gradient"), \
                         jax.named_scope("lgb.softmax_grad"):
@@ -1843,20 +1905,96 @@ class GrowerPrograms:
                 new_row, *recs = self._grow_impl(
                     binned, binned_t, own, g, h,
                     self.feature_mask_for(tree), lr, bmask, tree,
-                    num_valid, meta, hyper, tables, with_mask=with_mask)
+                    num_valid, meta, hyper, tables, with_mask=with_mask,
+                    root_hist=root)
                 return jax.lax.dynamic_update_index_in_dim(
                     s, new_row, k, 0), tuple(recs)
 
-            sc, recs = jax.lax.scan(grow_class, sc, classes)
-            return ((sc, bmask) if with_mask else sc), recs
+            sc, recs = jax.lax.scan(grow_class, sc, (classes, roots))
+            return ((sc, bmask) if with_mask else sc), (recs, pass_work)
 
         init = score
         if with_mask:
             it0 = its[0]
             init = (score, draw_bag(it0 - it0 % bag_freq))
-        final, recs = jax.lax.scan(iteration, init, its)
+        final, (recs, pass_work) = jax.lax.scan(iteration, init, its)
         recs = tuple(r.reshape((-1,) + r.shape[2:]) for r in recs)
-        return (final[0] if with_mask else final), recs
+        final = final[0] if with_mask else final
+        return (final[:, :m] if pad else final), recs + (pass_work,)
+
+    def _root_hists(self, binned, score, gargs, rows, grad_fn, bmask,
+                    num_valid):
+        """Every trained class's root histogram of one multiclass
+        iteration in ONE chunk loop: ``(len(classes), S, 3)`` f32, and
+        the (3,) i32 ``[row chunks visited, tiles of 128 stat columns a
+        chunk contracted, class trees rooted]``.  ``score``, ``gargs``
+        and ``rows`` come at ``n_pad`` rows (:meth:`_class_scan`).
+
+        A class's root histogram reads the bins and the g and h of its
+        own score row alone, and no class tree of the iteration touches
+        that row before its own, so the roots of all of them can be
+        taken before the first tree.  Each chunk builds every class's
+        stat columns as :meth:`_grow_impl` builds its tree's —
+        ``grad_fn`` on that chunk of the class's score row, times the
+        valid-row mask, the bag indicator ``bmask`` (None: no bag),
+        :meth:`_stat_columns` — and contracts its one-hot bins against
+        all of them at once: ``ceil(classes x K / 128)`` tiles, where
+        each root wave took one for its K columns.
+
+        The pass visits the real chunks in place, in their order, as a
+        root wave does that leaves its rows where they lie: each class's
+        sums are then that root wave's (to the bit on a TPU v5e compiled
+        alone; XLA:CPU's dot sums in an order its width sets).  A root
+        wave brings its live rows to the front first where they fill
+        less than ``compact_max_live`` of the real chunks.  Under a bag
+        of that share or less the class scan takes no pass: a compacted
+        root wave costs its bag's rows, and at few classes one scan of
+        every row can cost more than all of them.  Where the rows still
+        fill less (a bag just above it, or a last chunk mostly empty:
+        one to 1.4 chunks of rows, or two to 2.1), the pass sums the
+        same rows in another order.  No ``(classes, n)`` g or h is
+        made."""
+        g, nb, k, ch = self.num_groups, self.nb, self.hist_cols, _CHUNK
+        n = self.n_pad
+        c = len(grad_fn.classes)
+        classes = jnp.asarray(grad_fn.classes, jnp.int32)
+        every = grad_fn.classes == tuple(range(score.shape[0]))
+        binned_c = binned.reshape(n // ch, ch, g)
+        real_chunks = jnp.clip((num_valid + ch - 1) // ch, 0, n // ch)
+        bag = None if bmask is None \
+            else jnp.pad(bmask, (0, n - bmask.shape[0]))
+
+        def body(i, acc):
+            r0 = i * ch
+            cut = lambda a, axis=0: jax.lax.dynamic_slice_in_dim(
+                a, r0, ch, axis)
+            own = cut(score, 1)
+            if not every:
+                own = jnp.take(own, classes, axis=0)
+            ga, rw = (jax.tree_util.tree_map(cut, t) for t in (gargs, rows))
+            with jax.named_scope("lgb.gradient"), \
+                    jax.named_scope("lgb.softmax_grad"):
+                gr, he = jax.vmap(lambda s, kk: grad_fn(s, ga, rw, kk))(
+                    own, classes)
+                # written out apart from the contraction that reads
+                # them, so the trace times this softmax under its name
+                gr, he = jax.lax.optimization_barrier((gr, he))
+            valid = jnp.where(r0 + jnp.arange(ch) < num_valid, 1.0, 0.0)
+            one = valid if bag is None else valid * cut(bag)
+            cols = jax.vmap(lambda gk, hk: self._stat_columns(
+                gk * valid, hk * valid, one, None, row0=r0)[0])(gr, he)
+            bmat = cols.transpose(1, 0, 2).reshape(ch, c * k)
+            return acc + _contract_strips(
+                jax.lax.dynamic_index_in_dim(binned_c, i, keepdims=False),
+                bmat, nb, jnp.bfloat16, jnp.float32)
+
+        with jax.named_scope("lgb.wave_hist"):
+            acc = jax.lax.fori_loop(0, real_chunks, body,
+                                    jnp.zeros((g, nb, c * k), jnp.float32))
+            hist = _combine_hist_cols(acc.reshape(g, nb, c, k), k)
+        return (hist.transpose(2, 0, 1, 3).reshape(c, self.num_slots, 3),
+                jnp.stack([real_chunks, jnp.int32(-(-c * k // 128)),
+                           jnp.int32(c)]))
 
 
 # ---------------------------------------------------------------------------

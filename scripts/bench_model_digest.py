@@ -9,10 +9,15 @@ and writes the SHA-256 and length of the text the kind's one
 ``chiprun_out/model_digest.<tag>.json``.  Two commits that print the same
 digest at the same seed trained byte-identical models at the cell's full
 size; no file of the harness is edited and the timed window is untouched
-(the kind asks for the text after the window has closed).  After the run
-it adds what the growth programs were built with: the ``grow.*`` and
-``shard.*`` gauges (``grow.hist_cols``, ``grow.wave_width``, the plan
-probes' ``grow.fused.w<W>_ms`` where they ran) and each cached
+(the kind asks for the text after the window has closed).  A kind that
+reads its trees through ``Booster.dump_model()`` (the multiclass cell's)
+records instead the SHA-256 of the dump's JSON and of each tree's in
+``tree_info`` order, under ``tree_sha256`` in the file (stderr gets the
+count and the dump's digest): two runs of unequal windows agree tree by
+tree on the trees both grew.  After the run it adds what the growth
+programs were built with: the ``grow.*`` and ``shard.*`` gauges
+(``grow.hist_cols``, ``grow.wave_width``, the plan probes'
+``grow.fused.w<W>_ms`` where they ran) and each cached
 ``GrowerPrograms``' row bucket, stat columns, stage plan and its source.
 """
 
@@ -77,12 +82,27 @@ def main(argv=None) -> int:
                 return text
 
             lgb.Booster.model_to_string = digesting
+            dump = lgb.Booster.dump_model
+
+            def dump_digesting(self, *a, **kw):
+                model = dump(self, *a, **kw)
+                sha = lambda o: hashlib.sha256(json.dumps(
+                    o, sort_keys=True).encode()).hexdigest()
+                trees = [sha(t) for t in model.get("tree_info", [])]
+                rec.update(dump_sha256=sha(model), trees=len(trees))
+                sys.stderr.write(f"model dump digest {json.dumps(rec)}\n")
+                rec["tree_sha256"] = trees
+                write()
+                return model
+
+            lgb.Booster.dump_model = dump_digesting
         return load_plugin(folder, name)
 
     bench_run.load_plugin = load_and_wrap
     rc = bench_run.main(rest)
     rec.update(programs_built())
-    sys.stderr.write(f"programs built {json.dumps(rec)}\n")
+    shown = {k: v for k, v in rec.items() if k != "tree_sha256"}
+    sys.stderr.write(f"programs built {json.dumps(shown)}\n")
     write()
     return rc
 

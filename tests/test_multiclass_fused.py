@@ -20,6 +20,7 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu import obs
+from lightgbm_tpu.ops import grow as growmod
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -219,6 +220,137 @@ def test_the_reference_catches_a_stand_in(judged, stand_in):
     swapped = ("leaf_value_gap", "leaf_value_gap_rms", "gain_gap_rms")
     put = {**judged, **{k: judged[f"{stand_in}_{k}"] for k in swapped}}
     assert set(_fails(put, _limits())) & set(swapped)
+
+
+# ---------------------------------------------------------------------------
+# one root histogram pass an iteration for all of its classes
+# ---------------------------------------------------------------------------
+
+# a bag of 90% of the rows: its root waves scan them in place; of half
+# of them, its root waves over three chunks bring them to the front first
+# (below _COMPACT_MAX_LIVE), so the class scan takes no shared pass
+BAG = {"bagging_fraction": 0.9, "bagging_freq": 1}
+HALF_BAG = {"bagging_fraction": 0.5, "bagging_freq": 1}
+# extra params, the empty class, rows (3,000: a bucket of half a chunk,
+# the rest of it padding)
+ROOT_CASES = {"plain": ({}, None, 20000), "bagged": (BAG, None, 20000),
+              "half_bag": (HALF_BAG, None, 20000),
+              "empty": ({}, 3, 20000), "short": ({}, None, 3000)}
+
+
+@pytest.mark.parametrize("case", sorted(ROOT_CASES))
+def test_the_shared_pass_takes_each_root_waves_histogram(case):
+    """At 20,000 rows (three real chunks of a four-chunk bucket) and an
+    iteration's scores, ``_root_hists``' histogram of each trained class
+    is, to the bit, what that class's root wave contracts from the stat
+    columns its tree builds: with a bag, with a class that
+    ``class_need_train`` drops, and where the row bucket is shorter than
+    a chunk.  The same rows of the same chunks in the same order: a bin
+    of a chunk this size sums a few dozen rows, which float32 adds
+    exactly in any order (at 32,768 rows a chunk XLA:CPU's dot sums in
+    an order its width sets and can move a last bit; a TPU v5e's does
+    not: PERF.md section 6).  Under a bag of half the rows each root
+    wave brings its rows to the front first, which is why the class scan
+    takes no pass there; the sums, exact at this size, agree still."""
+    import jax.numpy as jnp
+    extra, empty, rows = ROOT_CASES[case]
+    x, y = _data(rows=rows, empty=empty)
+    bst = _booster({**BASE, **extra, "fused_chunk": 1}, x, y)
+    gbdt = bst._gbdt
+    bst.update_chunked(1)
+    grower, (grad_fn, gargs) = gbdt._grower, gbdt._fused_grad_fn()
+    progs = grower.programs
+    assert grad_fn.classes == tuple(k for k in range(5) if k != empty)
+    m, n = progs.num_data, progs.n_pad
+    # the class scan hands the pass its arrays at the bucket's chunks
+    to_n = lambda a: jnp.pad(grower.bucket_rows(a), [(0, 0)] * (a.ndim - 1)
+                             + [(0, n - m)])
+    gargs = jax.tree_util.tree_map(to_n, gargs)
+    # (an empty class grows its first iteration a tree a dispatch)
+    score = to_n(jnp.asarray(gbdt.train_score))
+    bag = None
+    if extra:
+        bag = jnp.asarray((np.random.default_rng(1).random(m)
+                           < extra["bagging_fraction"]).astype(np.float32))
+
+    @jax.jit
+    def shared(binned, score, gargs, bag, nv):
+        return progs._root_hists(binned, score, gargs, grad_fn.rows(score),
+                                 grad_fn, bag, nv)
+
+    @jax.jit
+    def own(binned, score, gargs, bag, nv, k):
+        # _grow_impl's stat columns, then its root wave
+        g, h = grad_fn(score[k], gargs, grad_fn.rows(score), k)
+        valid = jnp.where(jnp.arange(n) < nv, 1.0, 0.0)
+        one = valid if bag is None else valid * jnp.pad(bag, (0, n - m))
+        cols, _ = progs._stat_columns(g * valid, h * valid, one, k)
+        pending = jnp.full((progs.stage_plan[0][0],), -1, jnp.int32)
+        return progs._wave_hist(
+            binned, jnp.where(jnp.arange(n) < nv, 0, -1), cols,
+            pending.at[0].set(0), nv, None, 0)
+
+    args = (grower.binned, score, gargs, bag, grower._num_valid)
+    roots, work = shared(*args)
+    assert [int(v) for v in work] == [-(-rows // growmod._CHUNK), 1,
+                                      len(grad_fn.classes)]
+    for j, k in enumerate(grad_fn.classes):
+        want, hw = own(*args, jnp.int32(k))
+        # 1 where the root wave compacted its rows first
+        assert int(hw[2]) == (case == "half_bag")
+        assert float(want[0, :, 2].sum()) > 0
+        np.testing.assert_array_equal(np.asarray(roots[j]),
+                                      np.asarray(want[0]))
+
+
+def _counted(fused, extra):
+    """A booster of 20,000 rows trained two iterations, fused in one
+    dispatch or a tree a dispatch, and the work counters it left."""
+    obs.configure(enabled=True)
+    obs.reset()
+    try:
+        bst, _, _ = _trained(fused, iters=2, extra=extra, rows=20000)
+        jax.block_until_ready(bst._gbdt.train_score)
+        bst._gbdt._flush_pending()
+        counters = dict(obs.registry().snapshot()["counters"])
+    finally:
+        obs.configure(enabled=False)
+        obs.reset()
+    return bst, counters
+
+
+SHARED_CASES = {"plain": {}, "bagged": BAG, "half_bag": HALF_BAG,
+                "int8": {"grad_quant_bits": 8}}
+
+
+@pytest.mark.parametrize("case", sorted(SHARED_CASES))
+def test_shared_roots_grow_the_per_iteration_trees(case):
+    """The fused scan grows the per-iteration path's trees to the byte
+    over three row chunks, bag or not; its counters count every root
+    wave as a wave with all its live rows, and the chunks and tiles the
+    loops ran: one shared pass an iteration (one tile for five classes of
+    three columns) in place of ten root waves.  Under ``grad_quant_bits``
+    each tree rounds its own g and h, and under a bag of half the rows
+    each root wave compacts its rows first: there the root waves
+    contract their own histograms and ``grow.root_shared`` reads 0."""
+    fused, cf = _counted(True, SHARED_CASES[case])
+    plain, cp = _counted(False, SHARED_CASES[case])
+    assert cf["train.fused_chunks"] == 1 and cf["grow.class_trees"] == 10
+    assert fused.model_to_string() == plain.model_to_string()
+    for name in ("grow.trees", "grow.waves", "grow.wave_slots",
+                 "grow.rows_real", "grow.rows_live", "grow.rows_in_bag"):
+        assert cf[name] == cp[name], name
+    if case in ("int8", "half_bag"):
+        assert cf["grow.root_shared"] == 0
+        for name in ("grow.rows_scanned", "grow.hist_tiles"):
+            assert cf[name] == cp[name], name
+        return
+    assert cf["grow.root_shared"] == 10
+    assert "grow.root_shared" not in cp
+    chunk_rows = 3 * growmod._CHUNK
+    assert cp["grow.rows_scanned"] - cf["grow.rows_scanned"] \
+        == (10 - 2) * chunk_rows
+    assert cp["grow.hist_tiles"] - cf["grow.hist_tiles"] == 10 - 2
 
 
 # ---------------------------------------------------------------------------
