@@ -323,6 +323,69 @@ def _contract_strips(b, bmat, nb: int, mdtype, adtype):
         else jnp.concatenate(outs, axis=1)
 
 
+def group_strips(groups, nb: int):
+    """Per feature group, the 64-bin strips of the ``nb`` pitch that its
+    bins reach (``ceil(num_total_bin / 64)``), or None where every group
+    reaches every strip: the full contraction, whose program is then the
+    one the pitch alone gives."""
+    need = tuple(-(-int(g.num_total_bin) // 64) for g in groups)
+    return None if min(need) * 64 >= nb else need
+
+
+def _strip_runs(strips) -> tuple:
+    """Per strip that some group reaches, the runs ``(lo, hi)`` of
+    consecutive groups whose bins reach it (``strips`` from
+    :func:`group_strips`)."""
+    runs = []
+    for s in range(max(strips)):
+        r = []
+        for gi, need in enumerate(strips):
+            if need > s:
+                if r and r[-1][1] == gi:
+                    r[-1] = (r[-1][0], gi + 1)
+                else:
+                    r.append((gi, gi + 1))
+        runs.append(tuple(r))
+    return tuple(runs)
+
+
+def _contract_runs(acc, b, bmat, runs, mdtype, adtype):
+    """:func:`_contract_strips` where strip ``s`` one-hots only the
+    groups of ``runs[s]``: ``acc`` holds a (groups of the strip, 64, B)
+    sum per strip, and a group's bins past its own strips are never
+    contracted.  Each strip's einsum is the full one's rows for those
+    groups, so the sums are the same to the bit."""
+    bi = b.astype(jnp.int32)
+    out = []
+    for s, (a, rs) in enumerate(zip(acc, runs)):
+        sub = jnp.concatenate([bi[:, lo:hi] for lo, hi in rs], axis=1)
+        oh = jax.nn.one_hot(sub - 64 * s, 64, dtype=mdtype)   # (CH,G_s,64)
+        out.append(a + jnp.einsum("cgn,cb->gnb", oh, bmat,
+                                  preferred_element_type=adtype))
+    return tuple(out)
+
+
+def _runs_hist(acc, runs, g: int, nb: int):
+    """The (G, nb, B) histogram of :func:`_contract_runs`' per-strip
+    sums: each group's rows where its runs put them, zero in the strips
+    its bins do not reach."""
+    blocks = []
+    for a, rs in zip(acc, runs):
+        parts, at, prev = [], 0, 0
+        for lo, hi in rs:
+            if lo > prev:
+                parts.append(jnp.zeros((lo - prev,) + a.shape[1:], a.dtype))
+            parts.append(a[at:at + hi - lo])
+            at, prev = at + hi - lo, hi
+        if prev < g:
+            parts.append(jnp.zeros((g - prev,) + a.shape[1:], a.dtype))
+        blocks.append(parts[0] if len(parts) == 1
+                      else jnp.concatenate(parts, axis=0))
+    hist = jnp.concatenate(blocks, axis=1) if len(blocks) > 1 else blocks[0]
+    return jax.lax.pad(hist, jnp.zeros((), hist.dtype),
+                       [(0, 0, 0), (0, nb - hist.shape[1], 0), (0, 0, 0)])
+
+
 def default_stage_plan(num_data: int, config) -> list:
     """The legacy doubling plan :func:`get_grower_programs` resolves
     when no explicit plan is given — the single resolution point, so
@@ -346,7 +409,8 @@ class GrowerPrograms:
     def __init__(self, *, num_data: int, num_groups: int, nb: int,
                  num_features: int, has_cat: bool, config,
                  plan: list, plan_source: str = "default",
-                 shard: Optional[ShardSpec] = None, mesh=None):
+                 shard: Optional[ShardSpec] = None, mesh=None,
+                 strips: Optional[tuple] = None):
         self.config = config.clone()
         config = self.config
         # sharded layout (ops/shard.py): ``num_data`` is then the
@@ -369,6 +433,10 @@ class GrowerPrograms:
                              and int(config.num_class) > 1)
         self.num_leaves = int(config.num_leaves)
         self.num_slots = self.num_groups * self.nb
+        # the wave contraction's 64-bin strips (group_strips): per strip
+        # the runs of groups whose bins reach it, None where all do
+        self.strips = strips
+        self.strip_runs = None if strips is None else _strip_runs(strips)
         self.n_pad = _ceil_to(max(self.num_data, _CHUNK), _CHUNK)
 
         # stat columns per leaf in the wave matmul.  Default 3 — bf16
@@ -567,6 +635,28 @@ class GrowerPrograms:
     # ------------------------------------------------------------------
     # wave histogram: one dense pass for up to W pending leaves
     # ------------------------------------------------------------------
+    def _strips_zero(self, cols: int, adtype):
+        """A chunk loop's zero accumulator for ``cols`` stat columns:
+        (G, nb, cols), or a (groups of the strip, 64, cols) sum a strip
+        where the groups do not all reach every strip."""
+        if self.strip_runs is None:
+            return jnp.zeros((self.num_groups, self.nb, cols), adtype)
+        return tuple(jnp.zeros((sum(hi - lo for lo, hi in rs), 64, cols),
+                               adtype) for rs in self.strip_runs)
+
+    def _strips_add(self, acc, b, bmat, mdtype, adtype):
+        """``acc`` plus one chunk's (CH, G) bins contracted against its
+        (CH, cols) stat columns, each strip over the groups it holds."""
+        if self.strip_runs is None:
+            return acc + _contract_strips(b, bmat, self.nb, mdtype, adtype)
+        return _contract_runs(acc, b, bmat, self.strip_runs, mdtype, adtype)
+
+    def _strips_hist(self, acc):
+        """The (G, nb, cols) histogram of a chunk loop's accumulator."""
+        if self.strip_runs is None:
+            return acc
+        return _runs_hist(acc, self.strip_runs, self.num_groups, self.nb)
+
     def _wave_hist(self, binned, leaf_id, ghk, pending, num_valid,
                    scales=None, stage=None, bounded=False):
         """The wave histogram of :meth:`_wave_hist_local`, summed over
@@ -825,10 +915,10 @@ class GrowerPrograms:
                 lm = (l[:, None] == pend[None, :]).astype(mdtype)
                 bmat = (lm[:, :, None] * gk[:, None, :]).reshape(
                     ch, w_t * k)
-                return acc + _contract_strips(b, bmat, nb, mdtype, adtype)
+                return self._strips_add(acc, b, bmat, mdtype, adtype)
 
-            acc = jax.lax.fori_loop(0, visited, body,
-                                    jnp.zeros((g, nb, w_t * k), adtype))
+            acc = self._strips_hist(jax.lax.fori_loop(
+                0, visited, body, self._strips_zero(w_t * k, adtype)))
             # lax.pad, not jnp.pad: a jitted helper of jax.numpy is
             # lowered as a shared function outside the stage's scope
             return jax.lax.pad(acc, jnp.zeros((), adtype),
@@ -1984,13 +2074,15 @@ class GrowerPrograms:
             cols = jax.vmap(lambda gk, hk: self._stat_columns(
                 gk * valid, hk * valid, one, None, row0=r0)[0])(gr, he)
             bmat = cols.transpose(1, 0, 2).reshape(ch, c * k)
-            return acc + _contract_strips(
-                jax.lax.dynamic_index_in_dim(binned_c, i, keepdims=False),
-                bmat, nb, jnp.bfloat16, jnp.float32)
+            return self._strips_add(
+                acc, jax.lax.dynamic_index_in_dim(binned_c, i,
+                                                  keepdims=False),
+                bmat, jnp.bfloat16, jnp.float32)
 
         with jax.named_scope("lgb.wave_hist"):
-            acc = jax.lax.fori_loop(0, real_chunks, body,
-                                    jnp.zeros((g, nb, c * k), jnp.float32))
+            acc = self._strips_hist(jax.lax.fori_loop(
+                0, real_chunks, body,
+                self._strips_zero(c * k, jnp.float32)))
             hist = _combine_hist_cols(acc.reshape(g, nb, c, k), k)
         return (hist.transpose(2, 0, 1, 3).reshape(c, self.num_slots, 3),
                 jnp.stack([real_chunks, jnp.int32(-(-c * k // 128)),
@@ -2038,7 +2130,8 @@ def goss_facts(config):
 
 def programs_signature(num_data: int, num_groups: int, nb: int,
                        num_features: int, has_cat: bool, config,
-                       shard: Optional[ShardSpec] = None) -> tuple:
+                       shard: Optional[ShardSpec] = None,
+                       strips: Optional[tuple] = None) -> tuple:
     """Everything a GrowerPrograms trace depends on besides the stage
     plan: array shapes, bin-structure flags, module tunables and the
     full config (hashed — over-keying only costs cache hits, never
@@ -2047,7 +2140,11 @@ def programs_signature(num_data: int, num_groups: int, nb: int,
     row bucket).  The stat columns are in it by value: ``num_data`` and
     ``COUNT_SPLIT_ROWS`` do not say on which side of the bound the rule
     puts a bucket that equals it, and a plan timed for four columns and
-    a 96-wide last stage is not one for three and 128."""
+    a 96-wide last stage is not one for three and 128.  The groups'
+    64-bin strips (:func:`group_strips`) are in it where they restrict
+    the contraction: two datasets whose groups reach the same strips
+    share a program, and one whose groups all reach every strip keys as
+    the pitch alone does."""
     base = (num_data, num_groups, nb, num_features, bool(has_cat),
             _CHUNK, COUNT_SPLIT_ROWS, INT32_SCAN_ROWS,
             _config_digest(config),
@@ -2057,6 +2154,8 @@ def programs_signature(num_data: int, num_groups: int, nb: int,
                         shard.draw_npad, shard.bag_npad),)
     elif goss_facts(config) is not None:
         base = base + (("goss_warmup", goss_facts(config)[2]),)
+    if strips is not None:
+        base = base + (("strips", tuple(strips)),)
     return base
 
 
@@ -2065,13 +2164,14 @@ def get_grower_programs(num_data: int, num_groups: int, nb: int,
                         plan: Optional[list] = None,
                         plan_source: str = "default",
                         shard: Optional[ShardSpec] = None,
-                        mesh=None) -> GrowerPrograms:
+                        mesh=None,
+                        strips: Optional[tuple] = None) -> GrowerPrograms:
     """Fetch (or build) the shared programs for this signature.  When no
     explicit plan is given, a profile-derived plan cached for the same
     signature (``DeviceGrower.profile_stage_plan``) is picked up under
     ``wave_plan=auto``/``profiled``."""
     base = programs_signature(num_data, num_groups, nb, num_features,
-                              has_cat, config, shard=shard)
+                              has_cat, config, shard=shard, strips=strips)
     if shard is not None and mesh is not None:
         # same shard layout over a different device set must not share
         # compiled programs (the mesh is baked into the shard_map)
@@ -2097,7 +2197,8 @@ def get_grower_programs(num_data: int, num_groups: int, nb: int,
     build = functools.partial(
         GrowerPrograms, num_data=num_data, num_groups=num_groups, nb=nb,
         num_features=num_features, has_cat=has_cat, config=config,
-        plan=plan, plan_source=plan_source, shard=shard, mesh=mesh)
+        plan=plan, plan_source=plan_source, shard=shard, mesh=mesh,
+        strips=strips)
     if not bool(getattr(config, "grower_cache", True)):
         return build()
     key = base + (pd,)
@@ -2146,6 +2247,7 @@ class DeviceGrower:
         for g in dataset.groups:
             while g.num_total_bin > nb:
                 nb *= 2
+        strips = group_strips(dataset.groups, nb)
 
         # training-shape bucketing: key the program cache (in-process
         # AND the persistent XLA cache, docs/ColdStart.md) on a pow2 row
@@ -2193,11 +2295,11 @@ class DeviceGrower:
             self.programs = get_grower_programs(
                 self.row_bucket, int(dataset.num_groups), nb,
                 int(dataset.num_features), has_cat, config,
-                shard=self._shard_spec, mesh=self.mesh)
+                shard=self._shard_spec, mesh=self.mesh, strips=strips)
             self._base_signature = programs_signature(
                 self.row_bucket, int(dataset.num_groups), nb,
                 int(dataset.num_features), has_cat, config,
-                shard=self._shard_spec)
+                shard=self._shard_spec, strips=strips)
             self._num_valid = jnp.asarray(self.num_data, jnp.int32)
             # the even deal of the real rows over the shards' blocks:
             # per-row state goes through it on its way in and out
@@ -2256,10 +2358,10 @@ class DeviceGrower:
         has_cat = bool(np.asarray(dataset.f_is_categorical).any())
         self.programs = get_grower_programs(
             self.row_bucket, int(dataset.num_groups), nb,
-            int(dataset.num_features), has_cat, config)
+            int(dataset.num_features), has_cat, config, strips=strips)
         self._base_signature = programs_signature(
             self.row_bucket, int(dataset.num_groups), nb,
-            int(dataset.num_features), has_cat, config)
+            int(dataset.num_features), has_cat, config, strips=strips)
         self._num_valid = jnp.asarray(self.num_data, jnp.int32)
         self._row_pad = self.row_bucket - self.num_data
         self._gauge_layout()
@@ -2276,9 +2378,16 @@ class DeviceGrower:
         """What the stat-column rule gave this grower's row bucket: the
         columns a leaf takes in the wave matmul and the last stage's
         width that follows from them (3 and 128 up to 2^24 rows a
-        bucket at 255 leaves, 4 and 96 past it)."""
-        obs.set_gauge("grow.hist_cols", int(self.programs.hist_cols))
-        obs.set_gauge("grow.wave_width", int(self.programs.wave_width))
+        bucket at 255 leaves, 4 and 96 past it); and the strip x group
+        products a chunk of the wave contracts, beside the groups x
+        64-bin strips of the pitch that the full contraction takes."""
+        progs = self.programs
+        obs.set_gauge("grow.hist_cols", int(progs.hist_cols))
+        obs.set_gauge("grow.wave_width", int(progs.wave_width))
+        full = progs.num_groups * (progs.nb // 64)
+        obs.set_gauge("grow.hist_strips_full", full)
+        obs.set_gauge("grow.hist_strips",
+                      full if progs.strips is None else sum(progs.strips))
 
     def _upload_binned(self, dataset, pad: int):
         """Upload the (N, G) binned matrix padded by ``pad`` rows, plus
@@ -2675,7 +2784,8 @@ class DeviceGrower:
                 self.programs = get_grower_programs(
                     progs.num_data, progs.num_groups, progs.nb,
                     progs.num_features, progs.has_cat, self.config,
-                    plan=plan, plan_source="profiled")
+                    plan=plan, plan_source="profiled",
+                    strips=progs.strips)
                 installed = True
             else:
                 # derived plan == current plan: nothing to rebuild, but

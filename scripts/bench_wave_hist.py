@@ -23,6 +23,16 @@ skipped):
     python3 scripts/bench_wave_hist.py --shape criteo --cols 3 \\
         --widths 64,128 --pending 32,42,64,128 --live 1.0,0.45
 
+``--strips full,rule`` times each wave twice: with every group
+contracted over every 64-bin strip of the pitch, and with each strip
+restricted to the groups whose bins reach it (``grow.group_strips``).
+The ``allstate`` and ``expedia`` shapes hold their cells' own groups,
+by bins; a line's ``digest`` is the SHA-256 of the histogram's bytes,
+equal where the two contract the same sums:
+
+    python3 scripts/bench_wave_hist.py --shape allstate --strips full,rule \
+        --widths 8,128 --live 1.0,0.28
+
 Lines go to stdout and to ``chiprun_out/wave_hist_bench.jsonl``.
 ``--repo`` runs another unpacked tree (one before the compaction routes
 ``on`` and ``off`` by its own constant, ``_GATHER_MIN_LANES``).
@@ -35,8 +45,17 @@ import os
 import sys
 import time
 
+# rows (the cells' to whole chunks of 32,768), the groups' bins (an int:
+# that many groups of 255), leaves
 SHAPES = {"criteo": (1 << 24, 67, 255), "cdn": (20_447_232, 53, 31),
-          "tiny": (1 << 16, 9, 31)}
+          "tiny": (1 << 16, 9, 31),
+          "allstate": (12_189_696,
+                       [98] * 12 + [4, 6, 9, 13] + [256] * 15
+                       + [219, 76, 16, 11, 9, 8, 8, 8, 8, 7, 6, 6, 6, 5,
+                          5, 5], 255),
+          "expedia": (4_718_592,
+                      [254, 54, 6, 240, 255, 255, 255, 2, 2, 12, 255, 255,
+                       5, 4, 4, 255, 10, 2, 13, 8, 201, 255], 31)}
 
 
 def main(argv=None):
@@ -54,20 +73,34 @@ def main(argv=None):
                          "wave is handed, in place of a full frontier")
     ap.add_argument("--variants", default="as_is",
                     help="space-separated as_is | on:W,W | off:W,W")
+    ap.add_argument("--strips", default="rule",
+                    help="comma-separated full | rule: every group over "
+                         "every 64-bin strip, or as grow.group_strips has "
+                         "it")
     args = ap.parse_args(argv)
     sys.path.insert(0, os.path.abspath(args.repo))
+    import hashlib
+    from types import SimpleNamespace
+
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.ops import grow as growmod
 
-    n, g, leaves = SHAPES[args.shape]
+    n, bins, leaves = SHAPES[args.shape]
+    if isinstance(bins, int):
+        bins = [255] * bins
+    g = len(bins)
+    # a tree from before the restriction contracts every strip
+    rule = getattr(growmod, "group_strips", lambda *a: None)
+    strips = rule([SimpleNamespace(num_total_bin=b) for b in bins], 256)
     dev = jax.devices()[0]
     os.makedirs("chiprun_out", exist_ok=True)
     sink = open("chiprun_out/wave_hist_bench.jsonl", "a")
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(11), 4)
-    binned = jax.random.randint(k1, (n, g), 0, 255, jnp.int32) \
-        .astype(jnp.uint8)
+    binned = jax.random.randint(k1, (n, g), 0, jnp.asarray(bins),
+                                jnp.int32).astype(jnp.uint8)
     grad = jax.random.normal(k2, (n,), jnp.float32)
     hess = jnp.abs(grad) + 0.1
     u = jax.random.uniform(k3, (n,))
@@ -77,7 +110,8 @@ def main(argv=None):
         todo += [(name, int(w)) for w in (ws or args.widths).split(",")]
     bound = growmod.COUNT_SPLIT_ROWS
     layouts = [int(c) for c in args.cols.split(",") if c] or [None]
-    for cols, (variant, w) in itertools.product(layouts, todo):
+    for cols, (variant, w), st in itertools.product(
+            layouts, todo, args.strips.split(",")):
         if cols is not None:
             # read when the programs object is built, as the tests do
             # it: every bucket striped, or none
@@ -91,7 +125,9 @@ def main(argv=None):
             num_data=n, num_groups=g, nb=256, num_features=g,
             has_cat=False, plan=[(w, None)],
             config=Config({"objective": "binary", "num_leaves": leaves,
-                           "verbosity": -1}))
+                           "verbosity": -1}),
+            **({} if st == "full" or strips is None
+               else {"strips": strips}))
         growmod.COUNT_SPLIT_ROWS = bound
         assert cols in (None, progs.hist_cols), (cols, progs.hist_cols)
         ghk, _ = progs._stat_columns(grad, hess,
@@ -123,7 +159,12 @@ def main(argv=None):
                 "k": int(progs.hist_cols), "w": w, "pending": npend,
                 "live_share": share,
                 "seconds": round((time.perf_counter() - t0) / 2, 6),
+                "strips": st,
+                "hist_strips": g * 4 if st == "full" or strips is None
+                else sum(strips),
                 "count_sum": float(r[..., 2].sum()),
+                "digest": hashlib.sha256(
+                    np.asarray(r).tobytes()).hexdigest()[:16],
                 "device": dev.device_kind})
             print(line, flush=True)
             sink.write(line + "\n")
